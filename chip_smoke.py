@@ -1,0 +1,638 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof that the system still starts on the chip.
+
+One process, one TPU chip: drives the main path once through the entry points
+a user calls, at the full width of models the repo supports, and checks what
+comes out by the repo's own means. Run it through the chip tool from the root
+of a checkout:
+
+    python3 chip_smoke.py              # one chip: kernels, adag, lm, serve
+    python3 chip_smoke.py --chips 4    # four chips: adag4, lm4 (and no other)
+
+Each phase prints one JSON line (its name, seconds, what it checked); the last
+line of stdout is ``{"ok": true, "device": {...}}`` and the exit code 0 only
+if every check of every phase passed. A device that is not a TPU, a failed
+check or an exception means a non-zero exit and no such line — nothing here
+falls back to the CPU, and no switch turns the device check off. The phases
+are plain functions of their sizes, so tests/test_chip_smoke.py calls them
+tiny on the CPU mesh; the sizes ``main`` passes are the defaults below.
+"""
+
+from __future__ import annotations
+
+import argparse
+import json
+import math
+import sys
+import threading
+import time
+
+import numpy as np
+
+SEED = 0
+#: normalized max error ``max|got - want| / max|want|`` a kernel may show
+#: against its XLA reference, by dtype — the repo's interpret-mode tests' own
+#: (tests/test_recurrent.py bf16 gradients; tests/test_pallas_kernels.py f32)
+TOL = {"bfloat16": 2e-2, "float32": 1e-5}
+#: held-out accuracy of the ``adag`` job in the CPU rehearsal (synthetic
+#: MNIST stand-in, seed 0: 1024 of 1024, loss 2.49 -> 0.002 over 32
+#: windows); the chip must come within 0.02 of it
+ADAG_CPU_ACCURACY = 1.0
+#: how one natively compiled Pallas kernel call reads in a compiled program
+KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
+
+
+class SmokeFailure(RuntimeError):
+    """A check of what came out failed."""
+
+
+def _check(ok, what: str) -> None:
+    if not ok:
+        raise SmokeFailure(what)
+
+
+def _report(phase: str, t0: float, **checked) -> dict:
+    line = {"phase": phase, "seconds": round(time.perf_counter() - t0, 1),
+            **checked}
+    print(json.dumps(line), flush=True)
+    return line
+
+
+def _norm_err(got, want) -> float:
+    """``max|got - want| / max|want|`` in float32, NaN-propagating."""
+    import jax.numpy as jnp
+
+    got, want = got.astype(jnp.float32), want.astype(jnp.float32)
+    return float(jnp.max(jnp.abs(got - want))
+                 / (jnp.max(jnp.abs(want)) + 1e-30))
+
+
+def _device_set(tree) -> set:
+    import jax
+
+    return {d for leaf in jax.tree.leaves(tree) for d in leaf.devices()}
+
+
+def _peak_bytes(devices) -> list:
+    """Per device ``[peak_bytes_in_use, peak_bytes_reserved]`` — live buffers,
+    and what running programs reserved for their temporaries (None where
+    the backend reports no memory stats)."""
+    stats = [d.memory_stats() or {} for d in devices]
+    return [[st.get("peak_bytes_in_use"), st.get("peak_bytes_reserved")]
+            for st in stats]
+
+
+# ---------------------------------------------------------------------------
+# kernels: each Pallas kernel once, against its own XLA reference
+# ---------------------------------------------------------------------------
+
+
+def kernels(*, attn=(8, 2048, 8, 128),
+            qmm=((8, 2048, 8192), (1024, 8192, 2048)),
+            adam=(16384, 1024), lstm=(64, 200, 512), interpret=False):
+    """flash attention fwd+bwd (causal, bf16), ``q_matmul`` (bf16 × int8),
+    fused Adam (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real
+    call shape with ``interpret`` EXPLICIT, against ``attention_reference``,
+    ``_q_matmul_xla``, ``optax.adam`` and ``lstm_scan_reference``."""
+    import jax
+    import jax.numpy as jnp
+    import optax
+
+    from distkeras_tpu.ops import kernel_impl
+    from distkeras_tpu.ops.flash_attention import flash_attention
+    from distkeras_tpu.ops.pallas_kernels import fused_adam
+    from distkeras_tpu.ops.quant import _q_matmul_xla, q_matmul, quantize
+    from distkeras_tpu.ops.recurrent import lstm_scan, lstm_scan_reference
+    from distkeras_tpu.parallel.sequence import attention_reference
+
+    t0 = time.perf_counter()
+    bf16 = jnp.bfloat16
+    keys = iter(jax.random.split(jax.random.PRNGKey(SEED), 32))
+    errs: dict[str, float] = {}
+
+    def fwd_bwd(fn, args, cot):
+        """(out, *grads) of ``fn`` at ``args`` under cotangent ``cot`` — an
+        ARGUMENT of the program: closed over, its tens of megabytes would be
+        a constant in the HLO and in the compile-cache entry."""
+        def run(cot, *a):
+            out, vjp = jax.vjp(fn, *a)
+            return (out,) + vjp(cot.astype(out.dtype))
+        return jax.jit(run)(cot, *args)
+
+    def compare(name, got, want, parts):
+        for part, g, w in zip(parts, got, want):
+            errs[f"{name}.{part}"] = (_norm_err(g, w), str(w.dtype))
+
+    L = attn[1]
+    q, k, v, g = (jax.random.normal(next(keys), attn, bf16) for _ in range(4))
+    compare(
+        "flash",
+        fwd_bwd(lambda q, k, v: flash_attention(
+            q, k, v, causal=True, interpret=interpret), (q, k, v), g),
+        fwd_bwd(lambda q, k, v: attention_reference(
+            q, k, v, causal=True), (q, k, v), g),
+        ("out", "dq", "dk", "dv"),
+    )
+
+    for m, kk, n in qmm:
+        x = jax.random.normal(next(keys), (m, kk), bf16)
+        qt = quantize(jax.random.normal(next(keys), (kk, n), jnp.float32))
+        got = jax.jit(lambda x, qt: q_matmul(
+            x, qt, impl="pallas", interpret=interpret))(x, qt)
+        want = jax.jit(lambda x, qt: _q_matmul_xla(x, qt, x.dtype))(x, qt)
+        compare(f"q_matmul[{m},{kk}]x[{kk},{n}]", (got,), (want,), ("out",))
+
+    # two updates, so the second runs on non-zero moments and a bias
+    # correction that is not 1/(1-b)
+    p = {"w": jax.random.normal(next(keys), adam, jnp.float32)}
+    g1, g2 = ({"w": jax.random.normal(next(keys), adam, jnp.float32)}
+              for _ in range(2))
+
+    def two_updates(tx):
+        def run(p, g1, g2):
+            u1, s = tx.update(g1, tx.init(p), p)
+            u2, s = tx.update(g2, s, optax.apply_updates(p, u1))
+            return u1["w"], u2["w"], s
+        return jax.jit(run)(p, g1, g2)
+
+    fu1, fu2, fs = two_updates(fused_adam(1e-3, interpret=interpret))
+    ou1, ou2, os_ = two_updates(optax.adam(1e-3))
+    compare("fused_adam", (fu1, fu2, fs.mu["w"], fs.nu["w"]),
+            (ou1, ou2, os_[0].mu["w"], os_[0].nu["w"]),
+            ("update1", "update2", "mu", "nu"))
+
+    Bl, T, Hl = lstm
+    gx = (0.5 * jax.random.normal(next(keys), (Bl, T, 4 * Hl))).astype(bf16)
+    wh = jax.random.normal(next(keys), (Hl, 4 * Hl)) / math.sqrt(Hl)
+    probe = jax.random.normal(next(keys), (Bl, T, Hl))
+    compare(
+        "lstm",
+        fwd_bwd(lambda gx, wh: lstm_scan(
+            gx, wh, impl="pallas", interpret=interpret), (gx, wh), probe),
+        fwd_bwd(lstm_scan_reference, (gx, wh), probe),
+        ("hs", "dgates", "dwh"),
+    )
+
+    # what "auto" resolves to for these shapes: natively it must be the
+    # kernel everywhere, or the main path would run the references
+    auto = {
+        "attention": kernel_impl("attention", L=L),
+        "lstm_scan": kernel_impl("lstm_scan", B=Bl, H=Hl),
+        "q_matmul": kernel_impl("q_matmul", k=qmm[0][1], n=qmm[0][2]),
+    }
+    line = _report("kernels", t0, interpret=bool(interpret), auto=auto,
+                   norm_err={k: e for k, (e, _) in errs.items()})
+    for name, (e, dtype) in errs.items():
+        # wh's gradient is f32 but flows through the bf16 recurrence
+        tol = TOL["bfloat16"] if name.startswith("lstm") else TOL[dtype]
+        _check(e <= tol, f"kernels: {name} off its reference by {e:.3g} "
+                         f"(normalized), tolerance {tol:g}")
+    if not interpret:
+        _check(auto == {"attention": "flash", "lstm_scan": "pallas",
+                        "q_matmul": "pallas"},
+               f"kernels: 'auto' does not pick the kernels here: {auto}")
+    return line
+
+
+# ---------------------------------------------------------------------------
+# adag: the paper's path
+# ---------------------------------------------------------------------------
+
+
+def _adag_trainer(num_workers, mesh, batch_size, window, epochs,
+                  optimizer=("adam", 1e-3)):
+    from distkeras_tpu import ADAG
+    from distkeras_tpu.models import lenet
+
+    return ADAG(lenet(), loss="sparse_softmax_cross_entropy",
+                worker_optimizer=optimizer[0], learning_rate=optimizer[1],
+                batch_size=batch_size, communication_window=window,
+                num_epoch=epochs, num_workers=num_workers, mesh=mesh)
+
+
+def _accuracy(trainer, params, test) -> float:
+    import jax
+
+    spec = trainer.spec
+    out, _ = jax.jit(lambda p, n, x: spec.apply(p, n, x, False))(
+        params, trainer.trained_nt_, test["features"])
+    return float(np.mean(np.argmax(np.asarray(out), -1) == test["label"]))
+
+
+def adag(*, n_train=8192, n_test=1024, batch_size=128, window=4, epochs=2,
+         num_workers=None, min_accuracy=ADAG_CPU_ACCURACY - 0.02):
+    """``ADAG(lenet()).train`` on the MNIST stand-in, as the verify skill
+    drives it: the loss must fall by more than half, held-out accuracy must
+    reach the CPU rehearsal's (less 0.02), and the state the trainer's
+    engine builds must live on the devices of its mesh."""
+    import jax
+
+    from distkeras_tpu.datasets import is_synthetic, mnist
+
+    t0 = time.perf_counter()
+    train, test = mnist(n_train=n_train, n_test=n_test)
+    t = _adag_trainer(num_workers, None, batch_size, window, epochs)
+    params = t.train(train, shuffle=True)
+    losses = t.get_history().losses()
+    acc = _accuracy(t, params, test)
+    state = t._build_engine().init_state(*t.spec.init_np(t.seed))
+    placed = _device_set((state.center, state.workers))
+    line = _report(
+        "adag", t0, synthetic_data=is_synthetic("mnist"),
+        windows=len(losses), loss_first=losses[0], loss_last=losses[-1],
+        accuracy=acc, min_accuracy=min_accuracy,
+        state_devices=sorted(str(d) for d in placed),
+    )
+    _check(all(math.isfinite(x) for x in losses), "adag: non-finite loss")
+    _check(losses[-1] < 0.5 * losses[0],
+           f"adag: loss fell {losses[0]:.4f} -> {losses[-1]:.4f}, not by half")
+    _check(acc >= min_accuracy,
+           f"adag: held-out accuracy {acc:.4f} < {min_accuracy:.4f}")
+    _check(placed == set(t.mesh.devices.flat)
+           and placed <= set(jax.devices()),
+           f"adag: trainer state on {placed}, mesh {t.mesh.devices}")
+    return line
+
+
+# ---------------------------------------------------------------------------
+# lm: MeshTrainer on the config-9 decoder
+# ---------------------------------------------------------------------------
+
+
+def _lm_spec(vocab, maxlen, dim, heads, depth, ce_chunk, plain=False):
+    """The decoder on its kernel path (bf16, flash attention, fused chunked
+    cross-entropy) or, ``plain``, its float32 twin on the reference path —
+    one parameter tree serves both."""
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models import transformer_lm
+
+    kw = dict(vocab=vocab, maxlen=maxlen, dim=dim, heads=heads, depth=depth,
+              pos_embedding="rope")
+    if plain:
+        return transformer_lm(dtype=jnp.float32, attn_impl="reference",
+                              fused_ce=False, **kw)
+    return transformer_lm(dtype=jnp.bfloat16, attn_impl="flash",
+                          fused_ce=True, ce_chunk=ce_chunk, remat=False, **kw)
+
+
+def _token_dataset(vocab, maxlen, rows):
+    from distkeras_tpu.data import Dataset
+
+    toks = np.random.default_rng(SEED).integers(
+        0, vocab, size=(rows, maxlen + 1)).astype(np.int32)
+    return Dataset({"features": toks[:, :-1], "label": toks[:, 1:]})
+
+
+def _lm_trainer(spec, mesh_shape, sharding, batch, epochs):
+    from distkeras_tpu.trainers import MeshTrainer
+
+    return MeshTrainer(
+        spec, loss="sparse_softmax_cross_entropy", worker_optimizer="adam",
+        learning_rate=1e-4, mesh_shape=mesh_shape,
+        parameter_sharding=sharding, batch_size=batch, num_epoch=epochs,
+        input_mode="resident", seed=SEED,
+    )
+
+
+def _compiled_step(trainer, init, ds, batch):
+    """The trainer's own step program, compiled for its mesh on the state
+    its engine builds from ``init = (params, nt)``: ``(compiled, params as
+    placed)``."""
+    engine, to_engine, _ = trainer._build_engine()
+    params, nt, opt = engine.init_state(to_engine(init[0]), init[1])
+    first = engine.place_batch((ds["features"][:batch], ds["label"][:batch]))
+    return engine._step.lower(params, nt, opt, first).compile(), params
+
+
+def lm(*, vocab=16384, maxlen=2048, dim=1024, heads=8, depth=8, ce_chunk=512,
+       batch=8, steps=4, epochs=2, kernel_calls=24):
+    """``MeshTrainer(...).train`` on ``transformer_lm`` with flash attention,
+    RoPE and the fused cross-entropy in bf16: the compiled step must hold
+    ``kernel_calls`` Pallas kernel calls, every loss must be finite and the
+    first within 1.0 of ln(vocab), and that first loss must equal the plain
+    model's (reference attention, unfused loss, true float32) on the same
+    batch and parameters to bf16 tolerance."""
+    import jax
+
+    from distkeras_tpu.ops import get_loss
+
+    t0 = time.perf_counter()
+    model = (vocab, maxlen, dim, heads, depth, ce_chunk)
+    spec, plain = _lm_spec(*model), _lm_spec(*model, plain=True)
+    ds = _token_dataset(vocab, maxlen, batch * steps)
+    trainer = _lm_trainer(spec, {"dp": 1}, "megatron", batch, epochs)
+    trainer.train(ds)
+    losses = trainer.get_history().losses()
+    peak = _peak_bytes(trainer.mesh.devices.flat)
+
+    p0, nt0 = spec.init_np(SEED)
+    compiled, _ = _compiled_step(trainer, (p0, nt0), ds, batch)
+    calls = compiled.as_text().count(KERNEL_CALL)
+    mem = compiled.memory_analysis()
+
+    # the plain twin shares the parameter tree; HIGHEST makes its float32
+    # matmuls real float32 on a TPU too (the default rounds through bf16)
+    x, y = ds["features"][:batch], ds["label"][:batch]
+    loss_fn = get_loss("sparse_softmax_cross_entropy")
+    with jax.default_matmul_precision("highest"):
+        want = float(jax.jit(
+            lambda p, x, y: loss_fn(y, plain.apply(p, nt0, x, False)[0])
+        )(p0, x, y))
+
+    line = _report(
+        "lm", t0, steps=len(losses), losses=[round(v, 4) for v in losses],
+        ln_vocab=round(math.log(vocab), 4), plain_f32_first_loss=want,
+        kernel_calls_in_step=calls,
+        step_temp_bytes=getattr(mem, "temp_size_in_bytes", None),
+        peak_bytes_in_use=peak,
+    )
+    _check(len(losses) == steps * epochs,
+           f"lm: {len(losses)} losses for {steps * epochs} steps")
+    _check(all(math.isfinite(v) for v in losses), "lm: non-finite loss")
+    # unit-variance logits at initialisation put the loss near ln V + 1/2
+    _check(abs(losses[0] - math.log(vocab)) <= 1.0,
+           f"lm: first loss {losses[0]:.4f} not near ln(vocab)")
+    _check(abs(losses[0] - want) <= 1e-2 * abs(want),
+           f"lm: first loss {losses[0]:.5f} vs plain float32 {want:.5f}")
+    _check(calls == kernel_calls,
+           f"lm: {calls} Pallas kernel calls in the compiled step, "
+           f"expected {kernel_calls}")
+    return line
+
+
+# ---------------------------------------------------------------------------
+# serve: GenerationEngine behind GenerationServer, four concurrent clients
+# ---------------------------------------------------------------------------
+
+
+def serve(*, vocab=16384, maxlen=1024, dim=2048, heads=16, depth=8,
+          kv_heads=1, prompt_lens=(37, 96, 128, 256), new_tokens=32,
+          max_batch=8):
+    """The 400M MQA decoder in a ``GenerationEngine`` behind a
+    ``GenerationServer`` on loopback; one ``GenerationClient`` per prompt
+    sends concurrently, greedy. Every request must answer with exactly the
+    tokens dense ``models.generate`` emits for that prompt (the repo's
+    pinned bit-identity oracle), and the server must stop cleanly."""
+    import socket
+
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models import generate, transformer_lm
+    from distkeras_tpu.serving import (GenerationClient, GenerationEngine,
+                                       GenerationServer)
+
+    t0 = time.perf_counter()
+    spec = transformer_lm(vocab=vocab, maxlen=maxlen, dim=dim, heads=heads,
+                          depth=depth, kv_heads=kv_heads, dtype=jnp.bfloat16,
+                          attn_impl="flash", pos_embedding="rope")
+    params = jax.device_put(spec.init_np(SEED)[0])
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, vocab, (n,)).astype(np.int32)
+               for n in prompt_lens]
+
+    server = GenerationServer(GenerationEngine(spec, params,
+                                               max_batch=max_batch))
+    server.start()
+    answers: list = [None] * len(prompts)
+
+    def ask(i):
+        try:
+            client = GenerationClient(server.host, server.port)
+            try:
+                answers[i] = client.generate(prompts[i],
+                                             max_new_tokens=new_tokens)
+            finally:
+                client.close()
+        except Exception as e:  # re-raised on the main thread below
+            answers[i] = e
+
+    threads = [threading.Thread(target=ask, args=(i,), daemon=True)
+               for i in range(len(prompts))]
+    try:
+        for th in threads:
+            th.start()
+        for th in threads:
+            th.join(timeout=900)
+    finally:
+        server.stop()
+    stats = server.stats()
+    for a in answers:
+        if isinstance(a, Exception):
+            raise a
+    _check(not any(th.is_alive() for th in threads)
+           and all(a is not None for a in answers),
+           "serve: a request never answered")
+
+    first_diff = []
+    for p, a in zip(prompts, answers):
+        oracle = np.asarray(generate(spec, params, p[None], new_tokens))
+        oracle = oracle[0, len(p):]
+        diff = np.nonzero(np.asarray(a) != oracle)[0] \
+            if len(a) == len(oracle) else np.array([0])
+        first_diff.append(int(diff[0]) if len(diff) else None)
+    try:
+        socket.create_connection((server.host, server.port), 1).close()
+        stopped = False
+    except OSError:
+        stopped = True          # nobody listens there any more
+    line = _report(
+        "serve", t0, requests=len(prompts), prompt_lens=list(prompt_lens),
+        new_tokens=new_tokens, completed=stats["completed"],
+        mean_batch_occupancy=round(stats["mean_batch_occupancy"], 2),
+        first_token_differing_from_dense_generate=first_diff,
+        server_stopped=stopped, blocks_in_use=stats["blocks_in_use"],
+        params_on=sorted(str(d) for d in _device_set(params)),
+    )
+    _check(stats["completed"] == len(prompts),
+           f"serve: {stats['completed']} of {len(prompts)} completed")
+    _check(first_diff == [None] * len(prompts),
+           f"serve: token streams differ from dense greedy generate at "
+           f"{first_diff} (index of first differing new token per request)")
+    _check(stopped and stats["blocks_in_use"] == 0,
+           "serve: server did not stop cleanly or leaked cache blocks")
+    return line
+
+
+# ---------------------------------------------------------------------------
+# four chips: one SPMD replica per chip, against its one-device twin
+# ---------------------------------------------------------------------------
+
+
+def _rel_l2(tree, ref) -> float:
+    """``|tree - ref|_2 / |ref|_2`` over all leaves as one vector."""
+    import jax
+
+    flat = lambda t: np.concatenate([np.ravel(x) for x in jax.tree.leaves(t)])
+    a, b = flat(tree), flat(ref)
+    return float(np.linalg.norm(a - b) / np.linalg.norm(b))
+
+
+#: adag4's optimizers: (learning rate, worst relative loss difference from
+#: the one-device run, relative L2 of the centers from it or None = not held)
+ADAG4_OPTIMIZERS = {"adam": (1e-3, 2e-2, None), "sgd": (1e-2, 2e-3, 2e-3)}
+
+
+def adag4(*, n_train=8192, n_test=1024, batch_size=128, window=4, epochs=2,
+          workers=4, optimizers=("adam", "sgd"),
+          min_accuracy=ADAG_CPU_ACCURACY - 0.02):
+    """The ``adag`` job with one worker per chip on ``get_mesh(workers)``
+    against the same job with the workers stacked on ONE device, and the
+    stacked-worker state must really span ``workers`` distinct devices.
+
+    The collective backend is deterministic (one program twice is bitwise
+    equal), but these are two programs — ``workers`` replicas vmapped on a
+    chip, one replica per chip — and on a TPU at default precision they
+    differ in the last bits of a gradient. Adam's first steps are sign-like
+    (``lr * g / |g|``), so such a bit in a near-zero gradient becomes a
+    whole ``lr`` in the weight: under Adam only the loss curve and the
+    accuracy are held equal. The same job under plain SGD, whose response to
+    rounding is linear, is what holds the CENTERS to float tolerance."""
+    import jax
+
+    from distkeras_tpu.datasets import mnist
+    from distkeras_tpu.parallel.mesh import get_mesh
+
+    t0 = time.perf_counter()
+    train, test = mnist(n_train=n_train, n_test=n_test)
+    meshes = {"spread": get_mesh(workers),
+              "stacked": get_mesh(workers, devices=jax.devices()[:1])}
+    runs = {}
+    for opt in optimizers:
+        for name, mesh in meshes.items():
+            t = _adag_trainer(workers, mesh, batch_size, window, epochs,
+                              (opt, ADAG4_OPTIMIZERS[opt][0]))
+            params = t.train(train, shuffle=True)
+            runs[opt, name] = (t, params, t.get_history().losses())
+    t, params, _ = runs[optimizers[0], "spread"]
+    acc = _accuracy(t, params, test)
+    loss_err, center_err = {}, {}
+    for opt in optimizers:
+        (_, p, l), (_, p1, l1) = runs[opt, "spread"], runs[opt, "stacked"]
+        loss_err[opt] = max(abs(a - b) / abs(b) for a, b in zip(l, l1))
+        center_err[opt] = _rel_l2(p, p1)
+    state = t._build_engine().init_state(*t.spec.init_np(t.seed))
+    shards = [
+        sorted((str(s.device), tuple(s.data.shape))
+               for s in leaf.addressable_shards)
+        for leaf in jax.tree.leaves(state.workers)
+    ]
+    line = _report(
+        "adag4", t0, workers=workers, accuracy=acc,
+        losses={f"{opt}.{name}": [round(v, 5) for v in runs[opt, name][2]]
+                for opt in optimizers for name in meshes},
+        worst_rel_loss_diff_vs_stacked=loss_err,
+        center_rel_l2_vs_stacked=center_err,
+        worker_state_shards=shards[0],
+    )
+    _check(acc >= min_accuracy, f"adag4: accuracy {acc:.4f}")
+    for opt in optimizers:
+        _, loss_tol, center_tol = ADAG4_OPTIMIZERS[opt]
+        _check(loss_err[opt] <= loss_tol,
+               f"adag4: {opt} loss curve differs from the one-device run "
+               f"by {loss_err[opt]:.3g} (relative), tolerance {loss_tol:g}")
+        _check(center_tol is None or center_err[opt] <= center_tol,
+               f"adag4: {opt} centers differ from the one-device run by "
+               f"{center_err[opt]:.3g} (relative L2), tolerance {center_tol}")
+    for sh in shards:
+        _check(len({dev for dev, _ in sh}) == workers
+               and all(shape[0] == 1 for _, shape in sh),
+               f"adag4: stacked-worker leaf not one worker per device: {sh}")
+    return line
+
+
+def lm4(*, vocab=16384, maxlen=2048, dim=1024, heads=8, depth=8, ce_chunk=512,
+        batch=8, steps=4, dp=4):
+    """The ``lm`` model under ``MeshTrainer(mesh_shape={"dp": dp},
+    parameter_sharding="fsdp")`` against ``{"dp": 1}`` on the same batches:
+    losses must agree to bf16 tolerance, every sharded parameter's shards
+    must sit on ``dp`` devices, and the compiled step must gather the
+    parameters and scatter the gradients."""
+    import jax
+
+    t0 = time.perf_counter()
+    spec = _lm_spec(vocab, maxlen, dim, heads, depth, ce_chunk)
+    ds = _token_dataset(vocab, maxlen, batch * steps)
+    sharded = _lm_trainer(spec, {"dp": dp}, "fsdp", batch, 1)
+    sharded.train(ds)
+    losses = sharded.get_history().losses()
+    peak = _peak_bytes(sharded.mesh.devices.flat)
+    single = _lm_trainer(spec, {"dp": 1}, "megatron", batch, 1)
+    single.train(ds)
+    losses1 = single.get_history().losses()
+
+    compiled, params = _compiled_step(sharded, spec.init_np(SEED), ds, batch)
+    text = compiled.as_text()
+    collectives = {op: text.count(f" {op}(") + text.count(f" {op}-start(")
+                   for op in ("all-gather", "reduce-scatter", "all-reduce")}
+    n_sharded, small = 0, 0
+    for leaf in jax.tree.leaves(params):
+        devs = {s.device for s in leaf.addressable_shards}
+        _check(len(devs) == dp, f"lm4: a parameter sits on {len(devs)} devices")
+        if leaf.addressable_shards[0].data.size * dp == leaf.size:
+            n_sharded += 1
+        else:
+            small += 1          # below fsdp's size floor: replicated
+    line = _report(
+        "lm4", t0, dp=dp, losses=[round(v, 4) for v in losses],
+        losses_one_device=[round(v, 4) for v in losses1],
+        params_sharded=n_sharded, params_replicated_small=small,
+        collectives_in_step=collectives,
+        kernel_calls_in_step=text.count(KERNEL_CALL),
+        peak_bytes_in_use=peak,
+    )
+    _check(len(losses) == len(losses1) == steps
+           and all(math.isfinite(v) for v in losses), "lm4: bad loss count")
+    worst = max(abs(a - b) / abs(b) for a, b in zip(losses, losses1))
+    _check(worst <= 1e-2, f"lm4: losses differ from one device by {worst:.3g}")
+    _check(n_sharded > 0 and collectives["all-gather"] > 0
+           and collectives["reduce-scatter"] + collectives["all-reduce"] > 0,
+           f"lm4: no sharded parameter or no collective: {collectives}")
+    return line
+
+
+# ---------------------------------------------------------------------------
+
+
+def main(argv=None) -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1,
+                    help="4: run only the four-chip phases (adag4, lm4)")
+    args = ap.parse_args(argv)
+
+    import jax
+
+    devices = jax.devices()
+    first = devices[0]
+    if first.platform != "tpu" or len(devices) < args.chips:
+        print(f"chip_smoke: needs {args.chips} TPU chip(s); JAX found "
+              f"{len(devices)} x {first.platform} ({first.device_kind})",
+              file=sys.stderr)
+        return 2
+
+    from distkeras_tpu.utils import enable_compilation_cache
+
+    cache_dir = enable_compilation_cache()
+    cache = {"hits": 0, "misses": 0}
+
+    def count(event, **_):
+        for k in cache:
+            if event == f"/jax/compilation_cache/cache_{k}":
+                cache[k] += 1
+
+    jax.monitoring.register_event_listener(count)
+
+    for phase in ((adag4, lm4) if args.chips == 4
+                  else (kernels, adag, lm, serve)):
+        phase()
+    print(json.dumps({"phase": "cache", "dir": cache_dir, **cache}),
+          flush=True)
+    print(json.dumps({"ok": True, "device": {
+        "platform": first.platform, "kind": first.device_kind,
+        "count": len(devices)}}), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -12,6 +12,7 @@ back to the pure-Python paths.
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -21,19 +22,28 @@ _SRC = os.path.join(_HERE, "dkps.cpp")
 _BUILD_DIR = os.environ.get(
     "DISTKERAS_NATIVE_BUILD_DIR", os.path.join(_HERE, "_build")
 )
-_SO = os.path.join(_BUILD_DIR, "libdkps.so")
 
 _lock = threading.Lock()
 _cached: ctypes.CDLL | None = None
 _failed: str | None = None
 
 
-def _build() -> str | None:
-    """Compile dkps.cpp → libdkps.so if missing/stale; return error or None."""
+def _so_path() -> str:
+    """The built library's path, keyed by a hash of the SOURCE'S CONTENTS:
+    a copy of the tree can reorder mtimes, and a library built from another
+    tree's source must never load in place of this one's."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(_BUILD_DIR, f"libdkps-{digest}.so")
+
+
+def _build(so: str) -> str | None:
+    """Compile dkps.cpp → ``so`` unless that file is already there; return
+    error or None."""
     os.makedirs(_BUILD_DIR, exist_ok=True)
-    if os.path.exists(_SO) and os.path.getmtime(_SO) >= os.path.getmtime(_SRC):
+    if os.path.exists(so):
         return None
-    tmp = _SO + f".tmp.{os.getpid()}"
+    tmp = so + f".tmp.{os.getpid()}"
     cmd = [
         "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
         "-o", tmp, _SRC,
@@ -46,7 +56,7 @@ def _build() -> str | None:
         return f"g++ unavailable: {e}"
     if proc.returncode != 0:
         return f"g++ failed: {proc.stderr[-2000:]}"
-    os.replace(tmp, _SO)  # atomic: concurrent builders race benignly
+    os.replace(tmp, so)  # atomic: concurrent builders race benignly
     return None
 
 
@@ -189,11 +199,12 @@ def load_dkps(required: bool = False) -> ctypes.CDLL | None:
     with _lock:
         if _cached is not None:
             return _cached
+        so = _so_path()
         if _failed is None:
-            _failed = _build() or ""
+            _failed = _build(so) or ""
         if _failed:
             if required:
                 raise RuntimeError(f"cannot build libdkps: {_failed}")
             return None
-        _cached = _bind(ctypes.CDLL(_SO))
+        _cached = _bind(ctypes.CDLL(so))
         return _cached

@@ -2,6 +2,10 @@
 (``ops.pallas_kernels.fused_adam``, selectable as
 ``worker_optimizer="fused_adam"``)."""
 
+import contextlib
+import contextvars
+import importlib
+
 from distkeras_tpu.ops import losses, metrics
 from distkeras_tpu.ops.losses import get_loss
 from distkeras_tpu.ops.metrics import accuracy
@@ -9,16 +13,96 @@ from distkeras_tpu.ops.metrics import accuracy
 
 def __getattr__(name):
     # pallas modules import jax.experimental.pallas; keep them lazy so plain
-    # loss/metric users never pay for it
-    if name == "pallas_kernels":
-        from distkeras_tpu.ops import pallas_kernels
-
-        return pallas_kernels
-    if name == "quant":
-        from distkeras_tpu.ops import quant
-
-        return quant
+    # loss/metric users never pay for it. import_module, not `from … import`:
+    # the latter asks this hook for the attribute first and recurses.
+    if name in ("pallas_kernels", "quant"):
+        return importlib.import_module(f"{__name__}.{name}")
     raise AttributeError(f"module 'distkeras_tpu.ops' has no attribute {name!r}")
 
 
-__all__ = ["losses", "metrics", "get_loss", "accuracy"]
+def native_kernels() -> bool:
+    """True when Pallas kernels compile for the chip, False when they run in
+    the interpreter — THE place the ops ask which backend they are on. It
+    only ever picks how a kernel runs and what ``"auto"`` means; a kernel
+    that was asked for by name is never swapped for its reference here."""
+    import jax
+
+    return jax.default_backend() == "tpu"
+
+
+def interpreted(interpret: bool | None) -> bool:
+    """A kernel's ``interpret`` argument resolved: as given, or by default
+    "compiled on a TPU, interpreter elsewhere"."""
+    return not native_kernels() if interpret is None else bool(interpret)
+
+
+#: (mesh, batch axis) of the SPMD step being traced — see kernel_mesh()
+_KERNEL_MESH: contextvars.ContextVar = contextvars.ContextVar(
+    "distkeras_kernel_mesh", default=None)
+
+
+@contextlib.contextmanager
+def kernel_mesh(mesh, batch_axis: str):
+    """While a program over ``mesh`` is being TRACED, tell the kernels which
+    mesh axis its batches are split on. The compiler partitions XLA ops by
+    itself but refuses a Mosaic kernel inside a jit over more than one
+    device ("Mosaic kernels cannot be automatically partitioned. Please wrap
+    the call in a shard_map") — which interpret mode on a virtual CPU mesh
+    never shows. An engine that jits over a mesh enters this around the
+    model's forward AND backward; :func:`on_each_device` does the wrapping."""
+    token = _KERNEL_MESH.set((mesh, batch_axis))
+    try:
+        yield
+    finally:
+        _KERNEL_MESH.reset(token)
+
+
+def on_each_device(kernel, *arrays):
+    """``kernel(*arrays)`` for a kernel that is independent across dim 0 of
+    every array (``None`` entries pass through): under :func:`kernel_mesh`,
+    a ``shard_map`` in which each device runs it on its own rows — split on
+    the batch axis, whole along every other mesh axis; with no mesh
+    declared, on a one-device mesh, or inside a region that is already
+    manual (a strategy's own ``shard_map``), the plain call."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    declared = _KERNEL_MESH.get()
+    if (declared is None or declared[0].size == 1
+            or jax.sharding.get_abstract_mesh().manual_axes):
+        return kernel(*arrays)
+    mesh, batch_axis = declared
+    # a mesh without that axis (tp only): every device runs the whole batch
+    rows = P(batch_axis if batch_axis in mesh.axis_names else None)
+    return jax.shard_map(
+        kernel, mesh=mesh,
+        in_specs=tuple(None if a is None else rows for a in arrays),
+        out_specs=rows, check_vma=False,
+    )(*arrays)
+
+
+def kernel_impl(op: str, impl: str = "auto", **dims) -> str:
+    """Which implementation a call to ``op`` with these dims will take.
+
+    ``kernel_impl("attention", L=2048)`` → ``"flash"`` | ``"reference"``;
+    ``kernel_impl("lstm_scan", B=64, H=512)`` and
+    ``kernel_impl("q_matmul", k=2048, n=8192)`` → ``"pallas"`` |
+    ``"xla"``. The dispatchers themselves (``flash_attention.attention``,
+    ``recurrent.lstm_scan``, ``quant.q_matmul``) decide through the same
+    functions, so what this returns is what runs — the answer a smoke run or
+    a test asserts on instead of trusting that ``"auto"`` found the chip.
+    """
+    resolvers = {
+        "attention": ("flash_attention", "attention_impl"),
+        "lstm_scan": ("recurrent", "lstm_impl"),
+        "q_matmul": ("quant", "q_matmul_impl"),
+    }
+    if op not in resolvers:
+        raise ValueError(f"unknown op {op!r}; one of {sorted(resolvers)}")
+    module, fn = resolvers[op]
+    mod = importlib.import_module(f"{__name__}.{module}")
+    return getattr(mod, fn)(impl, **dims)
+
+
+__all__ = ["losses", "metrics", "get_loss", "accuracy", "interpreted",
+           "kernel_impl", "kernel_mesh", "native_kernels", "on_each_device"]

@@ -44,6 +44,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distkeras_tpu import ops
+
 _NEG = -1e9  # matches parallel.sequence: finite mask keeps softmax NaN-free
 
 BLOCK_Q = 128   # q rows per grid step
@@ -207,10 +209,6 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc, *,
         l = jnp.maximum(l_s[:], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
         lse_ref[0] = m_s[:] + jnp.log(l)
-
-
-def _interpret_default():
-    return jax.default_backend() != "tpu"
 
 
 def _pick_block_q(L):
@@ -666,30 +664,45 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
+# A Mosaic kernel cannot be partitioned by the compiler (see
+# ``ops.on_each_device``), and attention is independent across the batch: the
+# forward and the backward each run through it, every array batch-major in
+# dim 0 — q/k/v/out/g ``[B, …]``, the mask ``[B, L]``, lse ``[B·H, L]``.
+
+
+def _forward(q, k, v, key_mask, scale, causal, interpret, window):
+    return ops.on_each_device(
+        functools.partial(_fa_forward, scale=scale, causal=causal,
+                          interpret=interpret, window=window),
+        q, k, v, key_mask,
+    )
+
+
+def _backward(q, k, v, key_mask, out, lse, g, scale, causal, interpret,
+              window):
+    return ops.on_each_device(
+        functools.partial(_fa_backward, scale=scale, causal=causal,
+                          interpret=interpret, window=window),
+        q, k, v, key_mask, out, lse, g,
+    )
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _flash_core(q, k, v, key_mask, causal, scale, interpret, window):
-    out, _ = _fa_forward(
-        q, k, v, key_mask, scale=scale, causal=causal, interpret=interpret,
-        window=window,
-    )
+    out, _ = _forward(q, k, v, key_mask, scale, causal, interpret, window)
     return out
 
 
 def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window):
-    out, lse = _fa_forward(
-        q, k, v, key_mask, scale=scale, causal=causal, interpret=interpret,
-        window=window,
-    )
+    out, lse = _forward(q, k, v, key_mask, scale, causal, interpret, window)
     # saving `out` adds no memory under jit: it aliases the primal output
     return out, (q, k, v, key_mask, out, lse)
 
 
 def _fa_bwd(causal, scale, interpret, window, res, g):
     q, k, v, key_mask, out, lse = res
-    dq, dk, dv = _fa_backward(
-        q, k, v, key_mask, out, lse, g,
-        scale=scale, causal=causal, interpret=interpret, window=window,
-    )
+    dq, dk, dv = _backward(q, k, v, key_mask, out, lse, g, scale, causal,
+                           interpret, window)
     dmask = None if key_mask is None else jnp.zeros_like(key_mask)
     return dq, dk, dv, dmask
 
@@ -721,9 +734,25 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
     return _flash_core(
         q, k, v, key_mask, bool(causal),
         float(scale if scale is not None else q.shape[-1] ** -0.5),
-        _interpret_default() if interpret is None else bool(interpret),
+        ops.interpreted(interpret),
         _canonical_window(window, q.shape[1]),
     )
+
+
+def attention_impl(impl: str = "auto", *, L: int) -> str:
+    """``"flash"`` or ``"reference"``: what :func:`attention` runs for a
+    length-``L`` call (``ops.kernel_impl("attention", …)`` is the public
+    door). A named implementation is returned as asked; ``"auto"`` is the
+    kernel only when it compiles natively AND ``L`` is a tile multiple —
+    interpret mode off-TPU is for testing, not speed."""
+    if impl not in ("flash", "reference", "auto"):
+        raise ValueError(
+            f"unknown attention impl {impl!r}; use 'flash', 'reference', "
+            f"or 'auto'"
+        )
+    if impl != "auto":
+        return impl
+    return "reference" if L % BLOCK_Q or not ops.native_kernels() else "flash"
 
 
 def attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
@@ -731,25 +760,14 @@ def attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
     """Dispatch between the Pallas kernel and the XLA reference.
 
     ``impl``: ``"flash"`` forces the kernel (requires ``L % 128 == 0``),
-    ``"reference"`` the XLA path, ``"auto"`` uses the kernel only when
-    running natively on TPU AND the shapes are tile-friendly — interpret
-    mode off-TPU is for testing, not speed. ``key_mask`` is treated as a
-    static-presence argument (its values are traced, its presence is not).
-    ``window``: sliding-window (local) attention span — see
-    :func:`flash_attention`.
+    ``"reference"`` the XLA path, ``"auto"`` is decided by
+    :func:`attention_impl`. ``key_mask`` is treated as a static-presence
+    argument (its values are traced, its presence is not). ``window``:
+    sliding-window (local) attention span — see :func:`flash_attention`.
     """
     from distkeras_tpu.parallel.sequence import attention_reference
 
-    if impl not in ("flash", "reference", "auto"):
-        raise ValueError(
-            f"unknown attention impl {impl!r}; use 'flash', 'reference', "
-            f"or 'auto'"
-        )
-    L = q.shape[1]
-    if impl == "reference" or (
-        impl == "auto"
-        and (L % BLOCK_Q or jax.default_backend() != "tpu")
-    ):
+    if attention_impl(impl, L=q.shape[1]) == "reference":
         return attention_reference(q, k, v, causal=causal, scale=scale,
                                    key_mask=key_mask, window=window)
     return flash_attention(q, k, v, causal, scale, key_mask, window=window)

@@ -29,6 +29,8 @@ import optax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from distkeras_tpu import ops
+
 _LANES = 128          # TPU lane width (last dim of every tile)
 _BLOCK_ROWS = 256     # rows per grid step: 256×128 f32 = 128 KiB/buffer in VMEM
 
@@ -96,11 +98,6 @@ def fused_adam(learning_rate: float, b1: float = 0.9, b2: float = 0.999,
     """
     lr = float(learning_rate)
 
-    def _interp():
-        if interpret is not None:
-            return bool(interpret)
-        return jax.default_backend() != "tpu"
-
     def init(params):
         zeros = lambda p: jnp.zeros_like(p, dtype=jnp.float32)
         return FusedAdamState(
@@ -121,7 +118,7 @@ def fused_adam(learning_rate: float, b1: float = 0.9, b2: float = 0.999,
         g_leaves, treedef = jax.tree.flatten(updates)
         m_leaves = treedef.flatten_up_to(state.mu)
         v_leaves = treedef.flatten_up_to(state.nu)
-        interp = _interp()
+        interp = ops.interpreted(interpret)
         new_m, new_v, u = [], [], []
         for g, m, v in zip(g_leaves, m_leaves, v_leaves):
             mi, vi, ui = _adam_leaf(

@@ -37,7 +37,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from distkeras_tpu import ops
+
 _LANES = 128
+_MAX_K = 8192   # deepest contraction one full-depth tile pair still fits
+#: bytes the kernel's tiles may take: Mosaic's default scoped-VMEM limit is
+#: 16 MiB on a v5e (the smallest of the supported chips); the rest is headroom
+_VMEM_BUDGET = 15 * 2 ** 20
 
 
 class QTensor(NamedTuple):
@@ -123,48 +129,79 @@ def _q_matmul_pallas(x2, q, scale, *, bm, bn, out_dtype, interpret):
     return out[:m]
 
 
+def _pick_tiles(m: int, k: int, n: int, x_bytes: int, o_bytes: int):
+    """``(bm, bn)`` for a full-depth ``[bm, K] @ [K, bn]`` output tile.
+
+    Start from the widest tiles (256 rows × 512 lanes) and halve whichever
+    input tile takes more VMEM until the working set fits the budget. What
+    the compiler allocates, read off its own refusals for a v5e: both input
+    tiles and the output tile, double-buffered — at K = 8192 in bf16 the old
+    fixed 256 × 512 choice came to 8 + 8 + 0.5 MiB against the 16 MiB limit,
+    so every prefill of more than 240 rows was refused — and for some tile
+    shapes one more ``[bm, K]`` buffer (256 × 256 was refused at 16.27 MiB
+    where its tiles sum to 12.25). The budget always counts that buffer. The
+    smallest tiles (16 × 128) take 3.6 MiB at K = 8192 in f32, so every
+    shape the ``K <= 8192`` guard admits fits.
+    """
+    def lane_tile(upto):   # widest multiple of 128 that divides N
+        return max(c for c in range(_LANES, upto + 1, _LANES) if n % c == 0)
+
+    bm, bn = min(_pad_to(max(m, 1), 16), 256), lane_tile(min(n, 512))
+    while True:
+        x_tile, q_tile = 3 * bm * k * x_bytes, 2 * k * bn
+        if x_tile + q_tile + 2 * bm * bn * o_bytes <= _VMEM_BUDGET:
+            return bm, bn
+        if bm > 16 and (x_tile >= q_tile or bn == _LANES):
+            bm = _pad_to(bm // 2, 16)
+        else:
+            bn = lane_tile(bn - _LANES)
+
+
+def q_matmul_impl(impl: str = "auto", *, k: int, n: int) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`q_matmul` runs for a
+    ``[K, N]`` weight (``ops.kernel_impl("q_matmul", …)`` is the public
+    door). ``"auto"`` is the kernel whenever its tiling constraints hold
+    (K and N multiples of 128, K small enough for a full-depth VMEM tile)
+    — on every backend: off-TPU the kernel runs in the interpreter."""
+    if impl not in ("auto", "pallas", "xla"):
+        raise ValueError(f"impl must be 'auto', 'pallas', or 'xla', "
+                         f"got {impl!r}")
+    tileable = k % _LANES == 0 and n % _LANES == 0 and k <= _MAX_K
+    if impl == "auto":
+        return "pallas" if tileable else "xla"
+    if impl == "pallas" and not tileable:
+        raise ValueError(
+            f"impl='pallas' needs K, N multiples of {_LANES} and "
+            f"K <= {_MAX_K}; got K={k}, N={n} (use impl='auto' to fall back)"
+        )
+    return impl
+
+
 def q_matmul(x, qt: QTensor, *, impl: str = "auto", out_dtype=None,
              interpret: bool | None = None):
     """``x [..., K] @ dequant(qt) [K, N] → [..., N]``.
 
     ``impl``: ``"pallas"`` (fused in-VMEM dequant kernel), ``"xla"``
-    (widen-in-graph fallback), or ``"auto"`` — the kernel whenever its
-    tiling constraints hold (K and N multiples of 128, K small enough for
-    a full-depth VMEM tile). ``interpret`` defaults to "kernel on TPU,
-    interpreter elsewhere" so CI exercises the same code path on CPU.
+    (widen-in-graph fallback), or ``"auto"`` — see :func:`q_matmul_impl`.
+    ``interpret`` defaults to "kernel on TPU, interpreter elsewhere" so CI
+    exercises the same code path on CPU.
     """
-    if impl not in ("auto", "pallas", "xla"):
-        raise ValueError(f"impl must be 'auto', 'pallas', or 'xla', "
-                         f"got {impl!r}")
     k, n = qt.q.shape
     if x.shape[-1] != k:
         raise ValueError(f"x trailing dim {x.shape[-1]} != weight rows {k}")
     out_dtype = out_dtype or x.dtype
-    tileable = (k % _LANES == 0 and n % _LANES == 0 and k <= 8192)
-    if impl == "auto":
-        impl = "pallas" if tileable else "xla"
-    if impl == "xla":
+    if q_matmul_impl(impl, k=k, n=n) == "xla":
         return _q_matmul_xla(x, qt, out_dtype)
-    if not tileable:
-        raise ValueError(
-            f"impl='pallas' needs K, N multiples of {_LANES} and K <= 8192; "
-            f"got K={k}, N={n} (use impl='auto' to fall back)"
-        )
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     lead = x.shape[:-1]
     m = 1
     for d in lead:
         m *= d
     x2 = x.reshape(m, k)
-    # one output tile spans the full contraction: K<=8192 bf16 rows fit a
-    # [bm, K] + [K, bn] VMEM working set comfortably inside 16 MiB
-    bm = min(_pad_to(max(m, 1), 16), 256)
-    bn = min(n, 512)
-    while n % bn:
-        bn //= 2
+    bm, bn = _pick_tiles(m, k, n, jnp.dtype(x.dtype).itemsize,
+                         jnp.dtype(out_dtype).itemsize)
     out = _q_matmul_pallas(x2, qt.q, qt.scale, bm=bm, bn=bn,
-                           out_dtype=out_dtype, interpret=bool(interpret))
+                           out_dtype=out_dtype,
+                           interpret=ops.interpreted(interpret))
     return out.reshape(*lead, n)
 
 

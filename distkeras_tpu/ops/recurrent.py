@@ -46,7 +46,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from distkeras_tpu.ops.flash_attention import _interpret_default
+from distkeras_tpu import ops
 
 #: timesteps unrolled per grid step (largest divisor of T from this ladder)
 CHUNK = 8
@@ -288,6 +288,21 @@ def lstm_scan_reference(gates_x, wh):
     return jnp.moveaxis(outs, 0, 1)
 
 
+def lstm_impl(impl: str = "auto", *, B: int, H: int) -> str:
+    """``"pallas"`` or ``"xla"``: what :func:`lstm_scan` runs for batch
+    ``B`` and hidden width ``H`` (``ops.kernel_impl("lstm_scan", …)`` is the
+    public door). A named implementation is returned as asked; ``"auto"``
+    is the kernel only when it compiles natively with tile-friendly shapes
+    (H a multiple of 128, B of 8)."""
+    if impl not in ("pallas", "xla", "auto"):
+        raise ValueError(
+            f"unknown lstm impl {impl!r}; use 'pallas', 'xla', or 'auto'"
+        )
+    if impl != "auto":
+        return impl
+    return "xla" if H % 128 or B % 8 or not ops.native_kernels() else "pallas"
+
+
 def lstm_scan(gates_x, wh, impl: str = "auto",
               interpret: bool | None = None):
     """Run the LSTM recurrence over pre-projected gate inputs.
@@ -297,22 +312,13 @@ def lstm_scan(gates_x, wh, impl: str = "auto",
     ``hs`` [B, T, H] in ``gates_x.dtype``. Differentiable in both arguments.
 
     ``impl``: ``"pallas"`` forces the fused kernel, ``"xla"`` the
-    ``lax.scan`` reference, ``"auto"`` uses the kernel only when running
-    natively on TPU with tile-friendly shapes (H a multiple of 128, B of 8).
+    ``lax.scan`` reference, ``"auto"`` is decided by :func:`lstm_impl`.
     """
-    if impl not in ("pallas", "xla", "auto"):
-        raise ValueError(
-            f"unknown lstm impl {impl!r}; use 'pallas', 'xla', or 'auto'"
-        )
     B, T, H4 = gates_x.shape
-    H = H4 // 4
-    if impl == "xla" or (
-        impl == "auto"
-        and (H % 128 or B % 8 or jax.default_backend() != "tpu")
-    ):
+    if lstm_impl(impl, B=B, H=H4 // 4) == "xla":
         return lstm_scan_reference(gates_x, wh)
     hs = _lstm_core(
         jnp.moveaxis(gates_x, 1, 0), wh,
-        _interpret_default() if interpret is None else bool(interpret),
+        ops.interpreted(interpret),
     )
     return jnp.moveaxis(hs, 0, 1)

@@ -75,7 +75,9 @@ def _dispatch_combine(gate_logits, num_experts: int, capacity: int,
     pos = jnp.moveaxis(pos_cm.reshape(top_k, t), 0, 1)        # [t, k]
     keep = (pos < capacity).astype(jnp.float32)               # [t, k]
 
-    pos_oh = jax.nn.one_hot(pos, capacity, dtype=jnp.float32)  # [t, k, C]
+    # pos holds exact small integers in f32; one_hot wants an integer index
+    pos_oh = jax.nn.one_hot(pos.astype(jnp.int32), capacity,
+                            dtype=jnp.float32)                # [t, k, C]
     # [t, k, E, C] → sum over choices
     dispatch = jnp.einsum("tke,tkc,tk->tec", oh, pos_oh, keep)
     combine = jnp.einsum(

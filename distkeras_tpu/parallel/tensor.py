@@ -25,6 +25,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distkeras_tpu.ops import kernel_mesh
 from distkeras_tpu.parallel.mesh import put_global
 
 
@@ -249,7 +250,10 @@ class SPMDEngine:
             return (loss_sum / A, nt), grads
 
         def step(params, nt, opt_state, batch):
-            (loss, new_nt), grads = grads_of(params, nt, batch)
+            # forward AND backward are traced in here: a Pallas kernel in
+            # the model runs per device on its own rows of the dp split
+            with kernel_mesh(mesh, dp_axis):
+                (loss, new_nt), grads = grads_of(params, nt, batch)
             updates, opt_state = tx.update(grads, opt_state, params)
             params = optax.apply_updates(params, updates)
             # pin the output layout so donation reuses the input buffers

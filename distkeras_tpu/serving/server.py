@@ -354,6 +354,13 @@ class GenerationServer:
         self._withdraw_registration()
         self._running = False
         if self._server_sock is not None:
+            # shutdown first: close() alone does not wake an accept()
+            # blocked in the accept thread, and the socket would go on
+            # listening until the next connection arrived
+            try:
+                self._server_sock.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass
             try:
                 self._server_sock.close()
             except OSError:

@@ -160,27 +160,25 @@ def json_default(o):
 # ---------------------------------------------------------------------------
 
 
-def enable_compilation_cache(directory: str | None = None,
-                             min_compile_secs: float = 1.0) -> str:
+def enable_compilation_cache() -> str:
     """Turn on JAX's persistent compilation cache for this process.
 
     First-compile latency is the dominant interactive cost on TPU (tens of
-    seconds per trainer program — SCALING.md); with the cache, identical
-    programs (same model/config/shape) skip XLA compilation on every later
-    run. Call once before training; returns the cache directory.
-    Precedence: explicit argument > ``JAX_COMPILATION_CACHE_DIR`` (JAX's
-    own env var) > a tmp-dir default. The CI conftest uses this helper too.
+    seconds per trainer program); with the cache, identical programs (same
+    model/config/shape) skip XLA compilation on every later run. Call once,
+    before the first compile; returns the cache directory. The place is a
+    deployment setting, so it comes from outside: ``JAX_COMPILATION_CACHE_DIR``
+    (JAX's own variable) when set, and no other directory then; otherwise
+    ``.jax_cache`` beside the package — the checkout root. The path is part
+    of nothing that changes between runs, so a second run finds the first
+    one's programs. (The test suite keeps the cache off — tests/conftest.py.)
     """
-    import tempfile
-
-    directory = directory or os.environ.get(
-        "JAX_COMPILATION_CACHE_DIR",
-        os.path.join(tempfile.gettempdir(), "distkeras-jax-cache"),
+    directory = os.environ.get("JAX_COMPILATION_CACHE_DIR") or os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache",
     )
-    jax.config.update("jax_compilation_cache_dir", str(directory))
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      float(min_compile_secs))
-    return str(directory)
+    jax.config.update("jax_compilation_cache_dir", directory)
+    return directory
 
 
 # ---------------------------------------------------------------------------

@@ -6,6 +6,7 @@ import sys
 from pathlib import Path
 
 import numpy as np
+import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
 
@@ -256,8 +257,11 @@ def test_regress_load_trajectory_parses_parsed_and_tail(tmp_path):
 def test_regress_bench_smoke_clean_and_synthetic_slowdown(tmp_path):
     """--regress end to end at toy scale: an unmodified measurement
     passes against its own clean repeats; the synthetic-slowdown seam
-    (a REAL injected sleep) is flagged. Empty glob -> the clean repeats
-    are the whole baseline, exactly the trajectory-seeding path."""
+    (a REAL injected sleep) is flagged. The trajectory holds one record
+    of another family, so the clean repeats are the exchange baseline."""
+    (tmp_path / "BENCH_r01.json").write_text(json.dumps(
+        {"parsed": {"config": "lm_train", "tokens_per_sec": 100.0}}
+    ))
     # rel_slack loosened to 35% for the in-suite smoke (ISSUE 14
     # jitter-hardening): the suite's own load jitters this box well
     # past the guard's 12% default (which CI runs with the step alone)
@@ -268,16 +272,16 @@ def test_regress_bench_smoke_clean_and_synthetic_slowdown(tmp_path):
     # injection came back −34% in-suite, INSIDE the widened slack).
     rec = bench.run_regress_bench(
         repeats=2, seconds=0.3, n_params=16_384, slowdown=0.0,
-        glob_pat="NO_SUCH_BENCH_*.json", root=str(tmp_path),
+        glob_pat="BENCH_*.json", root=str(tmp_path),
         rel_slack=0.35,
     )
     assert rec["verdict"] == "ok", rec["checks"]
-    assert rec["trajectory_files"] == 0
+    assert rec["trajectory_files"] == 1
     keys = {c["key"] for c in rec["checks"]}
     assert "fused_rounds_per_sec" in keys
     slow = bench.run_regress_bench(
         repeats=2, seconds=0.3, n_params=16_384, slowdown=2.0,
-        glob_pat="NO_SUCH_BENCH_*.json", root=str(tmp_path),
+        glob_pat="BENCH_*.json", root=str(tmp_path),
         rel_slack=0.35,
     )
     assert slow["verdict"] == "regression", slow["checks"]
@@ -287,6 +291,14 @@ def test_regress_bench_smoke_clean_and_synthetic_slowdown(tmp_path):
     # legs (the pipelined leg may hide part of it in its overlap) —
     # at least one rounds/s leg must be flagged
     assert any(k.endswith("_rounds_per_sec") for k in flagged), flagged
+
+
+def test_regress_empty_trajectory_is_an_error(tmp_path):
+    """No history to compare against is an error that says so — never an
+    "ok" verdict from a run compared with itself — and it is raised
+    before anything is measured."""
+    with pytest.raises(FileNotFoundError, match="empty trajectory"):
+        bench.run_regress_bench(glob_pat="BENCH_*.json", root=str(tmp_path))
 
 
 def test_analytic_flop_models():
@@ -318,7 +330,8 @@ def test_peak_flops_by_device_kind():
     assert bench.peak_flops(Fake("TPU v5p")) == 459e12
     assert bench.peak_flops(Fake("TPU v6e")) == 918e12
     assert bench.peak_flops(Fake("TPU v4")) == 275e12
-    assert bench.peak_flops(Fake("TPU vNext")) == 197e12  # unknown default
+    with pytest.raises(ValueError, match="TPU vNext"):
+        bench.peak_flops(Fake("TPU vNext"))  # unknown: an error, no default
 
     class Cpu:
         platform = "cpu"

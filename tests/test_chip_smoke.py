@@ -1,0 +1,97 @@
+"""chip_smoke.py rehearsed on the CPU mesh: every phase function at a tiny
+size (Pallas kernels in interpret mode), so a wrong path, argument or check
+in the script costs no chip time — and the device gate itself: on a platform
+that is not a TPU, ``main`` exits non-zero before any phase and prints no
+``"ok": true`` line.
+"""
+
+import json
+import sys
+from pathlib import Path
+
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent))
+
+import chip_smoke
+
+LM_TINY = dict(vocab=256, maxlen=128, dim=64, heads=2, depth=1, ce_chunk=64,
+               batch=4, steps=2)
+
+
+def test_kernels_phase_interpret_mode():
+    line = chip_smoke.kernels(
+        attn=(1, 128, 2, 64), qmm=((8, 128, 256), (40, 256, 128)),
+        adam=(40, 33), lstm=(8, 5, 128), interpret=True,
+    )
+    assert line["phase"] == "kernels" and line["interpret"] is True
+    assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
+                                     "fused_adam.update2"}
+    # off the chip "auto" keeps the references for attention and the LSTM —
+    # the phase reports it, and only a native run insists on the kernels
+    assert line["auto"] == {"attention": "reference", "lstm_scan": "xla",
+                            "q_matmul": "pallas"}
+
+
+def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
+    """The comparison has teeth: a kernel that is off its reference fails
+    the phase (after the line with every measured error is printed)."""
+    from distkeras_tpu.ops import quant
+
+    monkeypatch.setattr(
+        chip_smoke, "TOL", {"bfloat16": 0.0, "float32": 0.0}
+    )
+    monkeypatch.setattr(quant, "_q_matmul_xla",
+                        lambda x, qt, dt: (x @ qt.q.astype(x.dtype)) * 1.5)
+    with pytest.raises(chip_smoke.SmokeFailure, match="off its reference"):
+        chip_smoke.kernels(attn=(1, 128, 1, 64), qmm=((8, 128, 128),),
+                           adam=(8, 16), lstm=(8, 2, 128), interpret=True)
+
+
+def test_adag_phase_tiny():
+    line = chip_smoke.adag(n_train=512, n_test=128, batch_size=16, window=1,
+                           epochs=3, num_workers=2, min_accuracy=0.5)
+    assert line["windows"] == 48 and len(line["state_devices"]) == 2
+    assert line["loss_last"] < 0.5 * line["loss_first"]
+
+
+def test_lm_phase_tiny():
+    # interpret-mode kernels leave no custom call in the compiled step
+    line = chip_smoke.lm(**LM_TINY, epochs=2, kernel_calls=0)
+    assert line["steps"] == 4
+    assert abs(line["losses"][0] - line["plain_f32_first_loss"]) < 0.06
+
+
+def test_serve_phase_tiny():
+    line = chip_smoke.serve(vocab=64, maxlen=64, dim=32, heads=4, depth=2,
+                            kv_heads=1, prompt_lens=(5, 8, 13, 16),
+                            new_tokens=8, max_batch=4)
+    assert line["completed"] == 4 and line["server_stopped"]
+    assert line["first_token_differing_from_dense_generate"] == [None] * 4
+
+
+def test_adag4_phase_tiny():
+    line = chip_smoke.adag4(n_train=256, n_test=64, batch_size=16,
+                            window=1, epochs=1, optimizers=("sgd",),
+                            min_accuracy=0.0)
+    devices = {dev for dev, _ in line["worker_state_shards"]}
+    assert len(devices) == 4
+    # on the CPU the two programs share their arithmetic to the bit
+    assert line["center_rel_l2_vs_stacked"] == {"sgd": 0.0}
+
+
+def test_lm4_phase_tiny():
+    line = chip_smoke.lm4(**LM_TINY)
+    assert line["params_sharded"] > 0
+    assert line["collectives_in_step"]["all-gather"] > 0
+
+
+def test_main_refuses_a_platform_that_is_not_tpu(capsys):
+    """JAX is held to the CPU here: exit non-zero before any phase, say
+    what was found, and print nothing a driver could read as a result."""
+    assert chip_smoke.main([]) != 0
+    assert chip_smoke.main(["--chips", "4"]) != 0
+    out, err = capsys.readouterr()
+    assert "cpu" in err and '"ok"' not in out and '"phase"' not in out
+    for line in out.splitlines():
+        assert not json.loads(line).get("ok")

@@ -12,8 +12,9 @@ REPO = pathlib.Path(__file__).resolve().parent.parent
 
 
 def run_example(script: str, *args):
-    """Run an example on the forced virtual 8-CPU mesh (even if a TPU
-    plugin is importable); shared by every example test."""
+    """Run an example in a child on the forced virtual 8-CPU mesh
+    (JAX_PLATFORMS=cpu: a child never wants the chip); shared by every
+    example test."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["JAX_PLATFORMS"] = "cpu"

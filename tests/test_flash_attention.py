@@ -541,3 +541,57 @@ def test_gqa_head_divisibility_validated(rng):
         flash_attention(q, kv, kv)
     with pytest.raises(ValueError, match="multiple of kv heads"):
         attention_reference(q, kv, kv)
+
+
+# -- one kernel per device under a mesh (ops.kernel_mesh) ----------------------
+
+
+@pytest.mark.parametrize("masked,stacked", [(False, False), (True, False),
+                                            (False, True)])
+def test_kernel_runs_per_device_under_a_declared_mesh(rng, masked, stacked):
+    """A Mosaic kernel inside a jit over several chips is refused by the
+    compiler unless it sits in a shard_map — which a virtual CPU mesh never
+    shows. Under ``kernel_mesh`` the forward AND the backward each become a
+    shard_map over the batch split (every other mesh axis replicated), with
+    the same values and gradients as the reference; with no mesh declared
+    the program holds no shard_map at all. ``stacked`` adds a vmapped
+    worker axis on top."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distkeras_tpu.ops import kernel_mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    Bm = 4
+    lead = (2,) if stacked else ()
+    mk = lambda h: jnp.asarray(
+        rng.normal(size=lead + (Bm, 128, h, 32)).astype(np.float32))
+    q, k, v, cot = mk(4), mk(2), mk(2), mk(4)
+    mask = None
+    if masked:
+        mask = np.ones((Bm, 128), np.float32)
+        mask[:, 100:] = 0.0
+
+    def loss(fn):
+        def one(q, k, v, cot):
+            return jnp.sum(fn(q, k, v, causal=True, key_mask=mask) * cot)
+        f = jax.vmap(one) if stacked else one
+        return lambda q, k, v: jnp.sum(f(q, k, v, cot))
+
+    def declared(q, k, v):
+        with kernel_mesh(mesh, "dp"):
+            return jax.value_and_grad(loss(flash_attention), (0, 1, 2))(
+                q, k, v)
+
+    spec = P(None, "dp") if stacked else P("dp")
+    put = lambda x: jax.device_put(x, NamedSharding(mesh, spec))
+    with jax.default_matmul_precision("highest"):
+        got = jax.jit(declared)(put(q), put(k), put(v))
+        want = jax.value_and_grad(loss(attention_reference), (0, 1, 2))(
+            q, k, v)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=5e-3, atol=1e-3)
+    assert str(jax.make_jaxpr(declared)(q, k, v)).count("shard_map") >= 2
+    plain = jax.make_jaxpr(jax.value_and_grad(loss(flash_attention),
+                                              (0, 1, 2)))(q, k, v)
+    assert "shard_map" not in str(plain) and "pallas_call" in str(plain)

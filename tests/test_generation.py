@@ -401,10 +401,24 @@ def test_windowed_lm_generates(lm):
     assert (outw != out_full).any()
 
 
+def test_flash_lm_takes_the_kernel_on_any_backend():
+    """attn_impl='flash' is the Pallas kernel whenever the length is a tile
+    multiple — also here on the CPU (interpret mode). The backend never
+    swaps it for the reference: only a ragged length does."""
+    module = transformer_lm(vocab=VOCAB, maxlen=128, dim=DIM, heads=HEADS,
+                            depth=1, dtype=jnp.float32,
+                            attn_impl="flash").module
+    fwd = lambda L: str(jax.make_jaxpr(
+        lambda t: module.init(jax.random.PRNGKey(0), t)
+    )(jnp.zeros((1, L), jnp.int32)))
+    assert "pallas_call" in fwd(128)
+    assert "pallas_call" not in fwd(100)
+
+
 def test_flash_lm_accepts_ragged_prompt():
-    """attn_impl='flash' on the LM family dispatches as 'auto': a prompt
-    whose length is not a tile multiple must prefill (falling back to the
-    XLA path) instead of erroring."""
+    """attn_impl='flash' on the LM family: a prompt whose length is not a
+    tile multiple must prefill (on the XLA reference path) instead of
+    erroring."""
     spec = transformer_lm(vocab=VOCAB, maxlen=MAXLEN, dim=DIM, heads=HEADS,
                           depth=DEPTH, dtype=jnp.float32, attn_impl="flash")
     params, _ = spec.init_np(0)

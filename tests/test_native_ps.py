@@ -30,6 +30,27 @@ pytestmark = pytest.mark.skipif(
 )
 
 
+def test_built_library_is_keyed_by_source_contents(tmp_path, monkeypatch):
+    """The library file's name carries a hash of dkps.cpp's CONTENTS: a
+    copied tree can reorder mtimes, and a library built from another
+    tree's source must not load in place of this one's."""
+    import os
+    import shutil
+
+    from distkeras_tpu import native
+
+    so = native._so_path()
+    assert os.path.exists(so), "load_dkps() built it at import of this file"
+    src = tmp_path / "dkps.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_SRC", str(src))
+    assert native._so_path() == so                 # same bytes, same key
+    os.utime(src, (0, 0))
+    assert native._so_path() == so                 # mtime plays no part
+    src.write_bytes(src.read_bytes() + b"\n// edited\n")
+    assert native._so_path() != so                 # other source, other file
+
+
 def make_server(center, rule, num_workers, ema_decay=None):
     from distkeras_tpu.native_ps import NativeSocketParameterServer
 

@@ -99,3 +99,48 @@ def test_fused_adam_vs_adam_trainer_equivalence():
     for a, b in zip(jax.tree.leaves(p1), jax.tree.leaves(p2)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-4, atol=1e-5)
+
+
+# -- which implementation runs (ops.kernel_impl) ------------------------------
+
+
+@pytest.mark.parametrize("op,impl,dims,native,want", [
+    # a kernel asked for by name is the kernel, on any backend
+    ("attention", "flash", dict(L=2048), False, "flash"),
+    ("lstm_scan", "pallas", dict(B=64, H=512), False, "pallas"),
+    ("q_matmul", "pallas", dict(k=8192, n=2048), False, "pallas"),
+    # "auto": the kernel only natively and on tile-friendly shapes
+    ("attention", "auto", dict(L=2048), True, "flash"),
+    ("attention", "auto", dict(L=2048), False, "reference"),
+    ("attention", "auto", dict(L=100), True, "reference"),
+    ("lstm_scan", "auto", dict(B=64, H=512), True, "pallas"),
+    ("lstm_scan", "auto", dict(B=64, H=512), False, "xla"),
+    ("lstm_scan", "auto", dict(B=4, H=16), True, "xla"),
+    # q_matmul's "auto" never looks at the backend (interpreter off-TPU)
+    ("q_matmul", "auto", dict(k=2048, n=8192), False, "pallas"),
+    ("q_matmul", "auto", dict(k=100, n=8192), True, "xla"),
+    ("q_matmul", "auto", dict(k=16384, n=128), True, "xla"),
+])
+def test_kernel_impl_reports_what_runs(monkeypatch, op, impl, dims, native,
+                                       want):
+    """The one observable answer to "which implementation will this call
+    take" — the dispatchers decide through the same resolvers, so a chip
+    smoke run asserts on this instead of trusting that "auto" found the
+    chip."""
+    from distkeras_tpu import ops
+
+    monkeypatch.setattr(ops, "native_kernels", lambda: native)
+    assert ops.kernel_impl(op, impl, **dims) == want
+
+
+def test_kernel_impl_rejects_unknown_op_and_impl():
+    from distkeras_tpu import ops
+
+    with pytest.raises(ValueError, match="unknown op"):
+        ops.kernel_impl("conv", L=128)
+    with pytest.raises(ValueError, match="attention impl"):
+        ops.kernel_impl("attention", "warp", L=128)
+    # lazy kernel modules resolve as attributes (Python 3.12's
+    # `from pkg import sub` asks the package hook first)
+    assert ops.quant.q_matmul is not None
+    assert ops.pallas_kernels.fused_adam is fused_adam

@@ -1,0 +1,251 @@
+"""Compiles for a DESCRIBED TPU v5e — the only file that describes the chip.
+
+The TPU's compiler is installed here and compiles for a chip that is
+described, not attached; it refuses what the chip would refuse (a kernel over
+the scoped-VMEM limit, a block that breaks the tiling rule, a program that
+does not fit 16 GB) where interpret mode accepts everything. These are the
+kernels of the main path at the shapes their callers use, a second or two
+each, and the whole config-9 LM train step — so a later PR that breaks one
+finds out here, at no chip time. Nothing runs: results are chip_smoke.py's
+business.
+
+The topology is described inside a module-scoped fixture and nowhere else:
+only one process may load the TPU's library, every xdist worker imports every
+test file, and a file that touched the library at import would leave the
+workers with different tests to collect. The persistent compile cache is off
+for the whole suite (conftest.py): these executables cannot be read back
+without the chip.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+BF16, F32, I8 = jnp.bfloat16, jnp.float32, jnp.int8
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # or it logs under /tmp
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# -- the kernels, one compile each -------------------------------------------
+
+
+def _flash(shape, *, kv_heads=None, dtype=BF16, causal=True, window=None,
+           masked=False, backward=True):
+    """(fn, argument shapes) for flash attention at q ``shape``."""
+    from distkeras_tpu.ops.flash_attention import flash_attention
+
+    B, L, H, D = shape
+    kv = (B, L, kv_heads or H, D)
+    args = [(shape, dtype), (kv, dtype), (kv, dtype)]
+    if masked:
+        args.append(((B, L), F32))
+
+    def fwd(q, k, v, mask=None):
+        return flash_attention(q, k, v, causal=causal, key_mask=mask,
+                               window=window, interpret=False)
+
+    if not backward:
+        return fwd, args
+
+    def fwd_bwd(q, k, v, mask=None):
+        loss = lambda q, k, v: jnp.sum(fwd(q, k, v, mask).astype(F32))
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    return fwd_bwd, args
+
+
+def _lstm(B, T, H, workers=None):
+    from distkeras_tpu.ops.recurrent import lstm_scan
+
+    def fwd_bwd(gx, wh):
+        loss = lambda gx, wh: jnp.sum(lstm_scan(
+            gx, wh, impl="pallas", interpret=False).astype(F32))
+        return jax.grad(loss, argnums=(0, 1))(gx, wh)
+
+    args = [((B, T, 4 * H), BF16), ((H, 4 * H), F32)]
+    if workers is None:
+        return fwd_bwd, args
+    return jax.vmap(fwd_bwd), [((workers,) + s, d) for s, d in args]
+
+
+def _adam(shape):
+    from distkeras_tpu.ops.pallas_kernels import fused_adam
+
+    tx = fused_adam(1e-3, interpret=False)
+
+    def update(g, p):
+        return tx.update({"w": g}, tx.init({"w": p}))
+
+    return update, [(shape, F32), (shape, F32)]
+
+
+def _q_matmul(m, k, n, dtype=BF16):
+    from distkeras_tpu.ops.quant import QTensor, q_matmul
+
+    def fn(x, q, s):
+        return q_matmul(x, QTensor(q, s), impl="pallas", interpret=False)
+
+    return fn, [((m, k), dtype), ((k, n), I8), ((n,), F32)]
+
+
+KERNELS = {
+    # flash attention: the config-9 training shape, then each variant a
+    # model in the repo calls it with
+    "flash-fwd-causal-bf16-8x2048x8x128":
+        lambda: _flash((8, 2048, 8, 128), backward=False),
+    "flash-fwdbwd-causal-bf16-8x2048x8x128":
+        lambda: _flash((8, 2048, 8, 128)),
+    "flash-fwdbwd-mqa-kv1": lambda: _flash((8, 2048, 8, 128), kv_heads=1),
+    "flash-fwdbwd-window512": lambda: _flash((8, 2048, 8, 128), window=512),
+    "flash-fwdbwd-keymask-noncausal-d64":
+        lambda: _flash((8, 2048, 8, 64), causal=False, masked=True),
+    "flash-prefill-mqa-8x128x16x128":
+        lambda: _flash((8, 128, 16, 128), kv_heads=1, backward=False),
+    "flash-fwdbwd-f32-L16384":
+        lambda: _flash((1, 16384, 8, 64), dtype=F32),
+    # fused LSTM scan: the IMDB config's batches, the stacked-worker vmap,
+    # and chip_smoke's shape
+    **{f"lstm-fwdbwd-T200-H128-B{b}": (lambda b=b: _lstm(b, 200, 128))
+       for b in (32, 64, 128, 256)},
+    "lstm-fwdbwd-vmap4-T200-H128-B64": lambda: _lstm(64, 200, 128, workers=4),
+    "lstm-fwdbwd-T200-H512-B64": lambda: _lstm(64, 200, 512),
+    "fused-adam-16384x1024": lambda: _adam((16384, 1024)),
+    # q_matmul: every projection of the 400M decoder (dim 2048, 16 heads,
+    # MQA, mlp_ratio 4, vocab 16384) at the decode batch m = 8 …
+    **{f"q_matmul-8x{k}x{n}": (lambda k=k, n=n: _q_matmul(8, k, n))
+       for k, n in ((2048, 2304), (2048, 2048), (2048, 8192), (8192, 2048),
+                    (2048, 16384))},
+    # … and its MLP down-projection under a 1024-token prefill: the fixed
+    # 256 x 512 tiles came to 16.5 MiB against the 16 MiB scoped-VMEM limit
+    # and the compiler refused it (any prefill of more than 240 rows)
+    "q_matmul-1024x8192x2048": lambda: _q_matmul(1024, 8192, 2048),
+    # the deepest, widest f32 call the K <= 8192 guard admits
+    "q_matmul-f32-4096x8192x8192":
+        lambda: _q_matmul(4096, 8192, 8192, dtype=F32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNELS))
+def test_kernel_compiles_for_v5e(one_chip, case):
+    fn, args = KERNELS[case]()
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    assert "tpu_custom_call" in text, "no Pallas kernel in the program"
+
+
+# -- the whole LM train step ---------------------------------------------------
+
+
+@pytest.mark.parametrize("dp,sharding", [(1, "megatron"), (4, "fsdp")])
+def test_lm_train_step_compiles_with_its_kernels(topo, monkeypatch, dp,
+                                                 sharding):
+    """The config-9 step (vocab 16384, L 2048, dim 1024 x 8 layers, bf16,
+    flash + RoPE + fused CE, adam) as ``MeshTrainer`` builds it, for one
+    described chip and — ZeRO-3 sharded, global batch 8 — for the 2x2 host:
+    24 kernel calls (flash forward, dq, dk/dv in each of 8 layers) and
+    temporaries that leave room in 16 GB. On four chips each kernel must sit
+    in a shard_map on its device's 2 rows (the compiler refuses to partition
+    a Mosaic kernel itself) between the parameter all-gathers.
+    ``attn_impl="flash"`` means the kernel on any backend; HOW it lowers
+    still follows the backend the process runs on, so the native lowering is
+    steered on here — in the test, not through an option of the program."""
+    import chip_smoke
+    from distkeras_tpu import ops
+    from distkeras_tpu.trainers import MeshTrainer
+
+    monkeypatch.setattr(ops, "native_kernels", lambda: True)
+    B, L = 8, 2048
+    spec = chip_smoke._lm_spec(vocab=16384, maxlen=L, dim=1024, heads=8,
+                               depth=8, ce_chunk=512)
+    mesh = Mesh(np.asarray(topo.devices[:dp]), ("dp",))
+    trainer = MeshTrainer(spec, worker_optimizer="adam", learning_rate=1e-4,
+                          mesh=mesh, parameter_sharding=sharding,
+                          batch_size=B)
+    engine, _, _ = trainer._build_engine()
+
+    def placed(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
+            tree, shardings)
+
+    rep = NamedSharding(mesh, P())
+    params, nt = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
+    engine._resolve_specs(params)
+    engine._build_step()
+    tokens = jax.ShapeDtypeStruct((B, L), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp")))
+    compiled = engine._step.lower(
+        placed(params, jax.tree.map(lambda s: NamedSharding(mesh, s),
+                                    engine.param_specs)),
+        placed(nt, jax.tree.map(lambda _: rep, nt)),
+        placed(jax.eval_shape(engine.optimizer.init, params),
+               engine._opt_shardings(params)),
+        (tokens, tokens),
+    ).compile()
+
+    text = compiled.as_text()
+    assert text.count(chip_smoke.KERNEL_CALL) == 24
+    assert (" all-gather(" in text) == (dp > 1)
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 8 * 2 ** 30, mem
+    assert mem.argument_size_in_bytes < 2 * 2 ** 30 / dp, mem
+
+
+# -- the serving steps -----------------------------------------------------------
+
+
+@pytest.mark.parametrize("step", ["prefill-128", "decode"])
+def test_serving_step_compiles_for_v5e(one_chip, monkeypatch, step):
+    """The 400M MQA decoder's engine programs at full width (vocab 16384,
+    dim 2048, 16 heads / 1 KV head, 8 layers, bf16, 8 rows of 1024): the
+    batched prefill of a 128-token prompt — flash attention inside, one call
+    per layer — and the greedy paged decode step over an 18-block table."""
+    import chip_smoke
+    from distkeras_tpu import ops
+    from distkeras_tpu.models import transformer_lm
+    from distkeras_tpu.serving import GenerationEngine
+
+    monkeypatch.setattr(ops, "native_kernels", lambda: True)
+    spec = transformer_lm(vocab=16384, maxlen=1024, dim=2048, heads=16,
+                          depth=8, kv_heads=1, dtype=BF16, attn_impl="flash",
+                          pos_embedding="rope")
+    shaped = lambda tree: jax.tree.map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        tree)
+    arr = lambda dtype, *shape: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=one_chip)
+    params = shaped(jax.eval_shape(spec.init, jax.random.PRNGKey(0))[0])
+    engine = GenerationEngine(spec, None, max_batch=8)
+    k, v = shaped(engine.cache.k_pools), shaped(engine.cache.v_pools)
+    i32, f32 = jnp.int32, F32
+    if step == "decode":
+        lowered = engine._decode_fn_greedy.lower(
+            params, k, v, arr(i32, 8), arr(i32, 8, 18), arr(i32, 8),
+            arr(i32, 8))
+        kernels = 0          # the paged gather + attention are plain XLA
+    else:
+        lowered = engine._make_prefill().lower(
+            params, None, k, v, (), (), arr(i32, 1, 128), arr(i32, 1, 128),
+            arr(i32, 1), arr(f32, 1), arr(i32, 1), arr(f32, 1),
+            arr(jnp.bool_, 1), arr(i32, 1))
+        kernels = 8
+    compiled = lowered.compile()
+    assert compiled.as_text().count(chip_smoke.KERNEL_CALL) == kernels
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 * 2 ** 30

@@ -1,5 +1,6 @@
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from distkeras_tpu import utils
 
@@ -57,24 +58,30 @@ def test_count_params():
     assert utils.tree_count_params(t) == 10
 
 
-def test_enable_compilation_cache(tmp_path, monkeypatch):
-    """The helper points JAX's persistent cache where asked (explicit arg >
-    JAX_COMPILATION_CACHE_DIR env > tmp default) and the config keys exist
-    in this JAX version."""
+@pytest.mark.parametrize("from_env", [True, False])
+def test_enable_compilation_cache(tmp_path, monkeypatch, from_env):
+    """The cache is placed from outside: JAX_COMPILATION_CACHE_DIR when set
+    — that directory and no other — else ``.jax_cache`` at the checkout
+    root, computed from the package's location. Nothing in the path moves
+    between calls (no temp dir, pid or time), or a second run would never
+    hit the first one's programs."""
+    import pathlib
+
     import jax
 
     from distkeras_tpu.utils import enable_compilation_cache
 
+    if from_env:
+        want = str(tmp_path / "from_env")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", want)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        want = str(pathlib.Path(utils.__file__).resolve().parent.parent
+                   / ".jax_cache")
     before = jax.config.jax_compilation_cache_dir
     try:
-        got = enable_compilation_cache(str(tmp_path / "explicit"))
-        assert got == str(tmp_path / "explicit")
-        assert jax.config.jax_compilation_cache_dir == got
-
-        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
-                           str(tmp_path / "from_env"))
-        assert enable_compilation_cache() == str(tmp_path / "from_env")
-        assert jax.config.jax_persistent_cache_min_compile_time_secs == 1.0
+        assert enable_compilation_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+        assert enable_compilation_cache() == want
     finally:
-        # restore the conftest-configured cache for the rest of the suite
-        enable_compilation_cache(before)
+        jax.config.update("jax_compilation_cache_dir", before)
