@@ -614,20 +614,15 @@ def main(argv=None) -> int:
     from distkeras_tpu.utils import enable_compilation_cache
 
     cache_dir = enable_compilation_cache()
-    cache = {"hits": 0, "misses": 0}
-
-    def count(event, **_):
-        for k in cache:
-            if event == f"/jax/compilation_cache/cache_{k}":
-                cache[k] += 1
-
-    jax.monitoring.register_event_listener(count)
+    from distkeras_tpu.observability import trace
 
     for phase in ((adag4, lm4) if args.chips == 4
                   else (kernels, adag, lm, serve)):
         phase()
-    print(json.dumps({"phase": "cache", "dir": cache_dir, **cache}),
-          flush=True)
+    counts = trace.jax_counts()
+    print(json.dumps({"phase": "cache", "dir": cache_dir,
+                      "hits": counts["cache_hits"],
+                      "misses": counts["cache_misses"]}), flush=True)
     print(json.dumps({"ok": True, "device": {
         "platform": first.platform, "kind": first.device_kind,
         "count": len(devices)}}), flush=True)

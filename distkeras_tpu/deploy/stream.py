@@ -38,7 +38,6 @@ import threading
 from typing import Callable
 
 from distkeras_tpu import networking
-from distkeras_tpu.observability import trace as _trace
 
 __all__ = [
     "ModelSnapshot",
@@ -274,12 +273,10 @@ class ReadReplica:
                 rec_type = recs[0][0]
                 with self._lock:
                     self._records += 1
-                    with _trace.span("deploy.apply",
-                                     args={"shard": self.shard_id}):
-                        _wal.replay_record(
-                            self._state, rec_type, recs[0][1],
-                            self.rule, self.num_workers, self.ema_decay,
-                        )
+                    _wal.replay_record(
+                        self._state, rec_type, recs[0][1],
+                        self.rule, self.num_workers, self.ema_decay,
+                    )
                     self._forward_locked(head, body)
                     if self.on_apply is not None:
                         self.on_apply(self, rec_type, self._state)
@@ -325,9 +322,8 @@ class ReadReplica:
         if sock is None:
             return
         try:
-            with _trace.span("deploy.forward"):
-                sock.sendall(head)
-                sock.sendall(body)
+            sock.sendall(head)
+            sock.sendall(body)
         except OSError:
             self._successor_sock = None
             self._n_forward_drops += 1
@@ -543,9 +539,6 @@ class WeightStreamer:
                 epochs = {e for _, e in ready.values() if e is not None}
                 epoch = min(epochs) if epochs else None
             if self.store.publish(version, tree, epoch=epoch):
-                _trace.instant("deploy.snapshot", cat="deploy",
-                               args={"version": version,
-                                     "epoch": -1 if epoch is None else epoch})
                 if self._report is not None:
                     try:
                         self._report(version)

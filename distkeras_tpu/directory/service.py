@@ -52,7 +52,6 @@ import time
 from typing import Any, Callable
 
 from distkeras_tpu import networking
-from distkeras_tpu.observability import trace as _trace
 from distkeras_tpu.resilience import wal as _wal
 
 __all__ = [
@@ -690,15 +689,12 @@ class DirectoryServer:
                     )
                     continue
                 if action == "publish":
-                    with _trace.span("directory.publish",
-                                     args={"role": msg.get("role"),
-                                           "key": msg.get("key")}):
-                        reply = self.publish(
-                            msg["role"], msg["key"], msg["host"],
-                            msg["port"], epoch=int(msg.get("epoch", 0)),
-                            meta=msg.get("meta"),
-                            ttl=msg.get("ttl", ...),
-                        )
+                    reply = self.publish(
+                        msg["role"], msg["key"], msg["host"],
+                        msg["port"], epoch=int(msg.get("epoch", 0)),
+                        meta=msg.get("meta"),
+                        ttl=msg.get("ttl", ...),
+                    )
                     networking.send_data(conn, reply)
                 elif action == "renew":
                     networking.send_data(
@@ -801,8 +797,7 @@ class StandbyDirectoryServer(DirectoryServer):
                         return True  # promoted: this stream is history
                     self._repl_records += 1
                     with self._lock:
-                        with _trace.span("directory.chain_apply"):
-                            self.state.apply(recs[0][0], recs[0][1])
+                        self.state.apply(recs[0][0], recs[0][1])
                         if self._wal is not None:
                             self._wal.append(head + body)
                             self._records_since_snapshot += 1
@@ -830,26 +825,25 @@ class StandbyDirectoryServer(DirectoryServer):
         flushes and FINs in bounded time), stamp the bumped fence epoch
         (durably — the promoted history must outrank the corpse's), and
         re-arm every lease."""
-        with _trace.span("directory.promote", args={"epoch": int(epoch)}):
-            deadline = time.monotonic() + float(drain_timeout)
-            last = -1
-            while time.monotonic() < deadline:
-                with self._repl_lock:
-                    streaming = self._repl_streaming
-                    applied = self._repl_records
-                if not streaming or applied == last:
-                    break
-                last = applied
-                time.sleep(0.05)
+        deadline = time.monotonic() + float(drain_timeout)
+        last = -1
+        while time.monotonic() < deadline:
             with self._repl_lock:
-                with self._lock:
-                    if int(epoch) > self.state.fence_epoch:
-                        self._apply_and_log(
-                            _wal.REC_DIR_FENCE,
-                            (int(epoch), self.state.version + 1),
-                        )
-                    self._rearm_all_leases()
-                self.is_standby = False
-                self.promoted_ = True
-            if self._wal is not None:
-                self._wal.sync()
+                streaming = self._repl_streaming
+                applied = self._repl_records
+            if not streaming or applied == last:
+                break
+            last = applied
+            time.sleep(0.05)
+        with self._repl_lock:
+            with self._lock:
+                if int(epoch) > self.state.fence_epoch:
+                    self._apply_and_log(
+                        _wal.REC_DIR_FENCE,
+                        (int(epoch), self.state.version + 1),
+                    )
+                self._rearm_all_leases()
+            self.is_standby = False
+            self.promoted_ = True
+        if self._wal is not None:
+            self._wal.sync()

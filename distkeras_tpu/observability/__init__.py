@@ -8,7 +8,13 @@ SLO-aware scheduling, shm transport) are debugged against:
   trace-event JSON loadable in Perfetto, with a correlation id
   (worker id + seqno, or serving request id) stitching one EXCHANGE
   across the worker thread, the PS handler, the WAL flusher, chain
-  replicas, and the native C++ server ring.
+  replicas, and the native C++ server ring. At the boundaries that
+  feed the chip (``MeshTrainer.train``, the serving engine's loop) the
+  same ``span()`` call has two more sinks: the PROFILER SINK
+  (``profile=True``: a ``jax.profiler.TraceAnnotation`` on the device
+  trace's clock) and the RUN LOG (``log=True``; ``trace.run_log()``:
+  set-up phases, epoch ends and every JAX trace, lower and compile,
+  kept with tracing off).
 - :mod:`distkeras_tpu.observability.metrics` — a typed registry
   normalizing ``ps.stats()`` / serving / WAL counters into named
   metrics with Prometheus text + JSON snapshot exporters, served live
@@ -36,7 +42,9 @@ SLO-aware scheduling, shm transport) are debugged against:
 - ``python -m distkeras_tpu.observability`` — ``dump`` / ``tail`` a
   live server's metrics, emit the ``health`` snapshot, ``health
   --watch`` a live server's alert transitions, or ``analyze`` a saved
-  trace into the bottleneck report.
+  trace into the bottleneck report, or list its longest ``steps``
+  (``train.step`` / ``serve.step`` with their children, totals by
+  program key).
 
 Trainer knobs: ``trace=True`` (enable), ``trace_dir=`` (write the
 timeline file, path lands in ``trainer.trace_path_``),

@@ -9,6 +9,8 @@ Usage::
         [--host H --port P] [--watch [--interval 2] [--count 0]]
     python -m distkeras_tpu.observability analyze <trace.json[.gz]> \\
         [--series <dump.json[.gz]>] [--json]
+    python -m distkeras_tpu.observability steps <trace.json[.gz]> \\
+        [--top 10] [--after SECONDS] [--json]
 
 ``dump``/``tail`` speak the ``metrics`` wire action both the
 ``SocketParameterServer`` and the ``GenerationServer`` serve (the framed
@@ -27,6 +29,11 @@ attribution, and the typed regime verdict with knob-keyed
 recommendations. ``--json`` prints the full report document (the CI
 artifact); the default is the human-readable summary. Exit code 2 when
 the verdict is degraded (the trace dropped spans), 0 otherwise.
+
+``steps`` (ISSUE 25) lists the longest ``train.step`` / ``serve.step``
+spans of a saved trace with the spans inside each (a ``serve.chunk`` with
+its rows, padded rows and program key) and totals by span and program
+key: whether a slow run is one long stall or many slow steps.
 
 ``health --watch`` (ISSUE 13) polls a live server's ``metrics`` action
 on ``--interval`` and prints alert TRANSITIONS as JSON lines: the
@@ -163,6 +170,23 @@ def _cmd_analyze(args) -> int:
     return 2 if report["degraded"] else 0
 
 
+def _cmd_steps(args) -> int:
+    from distkeras_tpu.observability.analyze import (
+        format_steps, load_trace, step_report,
+    )
+
+    try:
+        events, _ = load_trace(args.trace)
+    except (OSError, ValueError, KeyError) as e:
+        raise SystemExit(
+            f"steps: cannot read {args.trace!r}: {type(e).__name__}: {e}"
+        ) from e
+    report = step_report(events, top=args.top, after_s=args.after)
+    print(json.dumps(report, indent=2) if args.json
+          else format_steps(report))
+    return 0
+
+
 def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(
         prog="python -m distkeras_tpu.observability",
@@ -217,6 +241,19 @@ def main(argv: list[str] | None = None) -> int:
     p.add_argument("--json", action="store_true",
                    help="full report document instead of the summary")
     p.set_defaults(fn=_cmd_analyze)
+
+    p = sub.add_parser(
+        "steps",
+        help="the longest train.step / serve.step spans of a saved "
+             "trace with their children, and totals by program key",
+    )
+    p.add_argument("trace", help="Chrome trace file from trace.save()")
+    p.add_argument("--top", type=int, default=10)
+    p.add_argument("--after", type=float, default=0.0,
+                   help="leave out steps that began before this many "
+                        "seconds on the trace's clock (the 'at' column)")
+    p.add_argument("--json", action="store_true")
+    p.set_defaults(fn=_cmd_steps)
 
     args = ap.parse_args(argv)
     if args.cmd == "health" and args.wal_dir is None \

@@ -862,12 +862,10 @@ def _serving_report(spans: list[dict]) -> dict | None:
     dominant = (max(buckets, key=lambda k: buckets[k])
                 if tot else "decode")
     # batch occupancy: duration-weighted mean rows in flight over the
-    # decode-step spans (the satellite's rows arg; "batch" is the
-    # PR 11-era name of the same number)
+    # decode-step spans (the satellite's rows arg)
     wsum = rsum = 0.0
     for e in decode_steps:
-        args = e.get("args") or {}
-        rows = args.get("rows", args.get("batch"))
+        rows = (e.get("args") or {}).get("rows")
         if rows is None or e["dur_ns"] <= 0:
             continue
         wsum += e["dur_ns"]
@@ -910,6 +908,83 @@ def _counter_summary(events: list[dict], *, store=None,
         if alerts:
             out["alerts"] = alerts
     return out
+
+
+STEP_SPANS = ("train.step", "serve.step")
+
+
+def step_report(events: list[dict], top: int = 10,
+                after_s: float = 0.0) -> dict:
+    """The operator's look at the step loops (ISSUE 25): the ``top``
+    longest ``train.step`` / ``serve.step`` spans, each with the spans
+    that lie inside it on its thread (``serve.chunk`` with its rows and
+    program key, ``serve.decode_step`` ...), and every span under a step
+    totalled by name and program key — whether a slow window is one long
+    stall or many slow steps, and in which program. ``after_s`` leaves
+    out the steps that began earlier on the trace's clock (warm-up, where
+    a step is a compile)."""
+    steps = sorted((e for e in events if e["name"] in STEP_SPANS
+                    and e["t0_ns"] >= after_s * 1e9),
+                   key=lambda e: -e["dur_ns"])
+    by_tid: dict[int, list[dict]] = {}
+    for e in events:
+        # the loop's own spans: a request's (``serve.prefill``, one copy
+        # of the chunk's interval a request) carry a corr
+        if (e["cat"] != "__counter__" and e["corr"] is None
+                and e["name"] not in STEP_SPANS):
+            by_tid.setdefault(e["tid"], []).append(e)
+
+    def inside(step):
+        t0, t1 = step["t0_ns"], step["t0_ns"] + step["dur_ns"]
+        return [e for e in by_tid.get(step["tid"], ())
+                if t0 <= e["t0_ns"] and e["t0_ns"] + e["dur_ns"] <= t1]
+
+    totals: dict[tuple, list] = {}
+    longest = []
+    for rank, step in enumerate(steps):
+        children = inside(step)
+        for e in [step] + children:
+            key = (e["name"], (e.get("args") or {}).get("key"))
+            rec = totals.setdefault(key, [0, 0, 0])
+            rec[0] += 1
+            rec[1] += e["dur_ns"]
+            rec[2] = max(rec[2], e["dur_ns"])
+        if rank < top:
+            longest.append({
+                "name": step["name"], "t0_ns": step["t0_ns"],
+                "dur_ns": step["dur_ns"], "args": step.get("args"),
+                "children": [{"name": c["name"], "t0_ns": c["t0_ns"],
+                              "dur_ns": c["dur_ns"], "args": c.get("args")}
+                             for c in children]})
+    return {
+        "steps": len(steps),
+        "longest": longest,
+        "totals": [{"name": n, "key": k, "count": c, "total_ns": t,
+                    "max_ns": m}
+                   for (n, k), (c, t, m) in sorted(
+                       totals.items(), key=lambda kv: -kv[1][1])],
+    }
+
+
+def format_steps(report: dict) -> str:
+    def args(a):
+        return " ".join(f"{k}={v}" for k, v in (a or {}).items())
+
+    out = [f"{report['steps']} step spans; the "
+           f"{len(report['longest'])} longest:"]
+    for s in report["longest"]:
+        out.append(f"  {s['name']:<18}{s['dur_ns'] / 1e6:10.3f} ms  "
+                   f"at {s['t0_ns'] / 1e9:.6f} s  {args(s['args'])}")
+        for c in s["children"]:
+            out.append(f"    {c['name']:<18}{c['dur_ns'] / 1e6:10.3f} ms  "
+                       f"{args(c['args'])}")
+    out.append("totals by span and program key:")
+    for t in report["totals"]:
+        name = t["name"] + (f" {t['key']}" if t["key"] else "")
+        out.append(f"  {name:<34}{t['count']:7d} x {t['total_ns'] / 1e9:10.4f}"
+                   f" s  mean {t['total_ns'] / t['count'] / 1e6:9.3f} ms  "
+                   f"max {t['max_ns'] / 1e6:9.3f} ms")
+    return "\n".join(out)
 
 
 def analyze_trace(path: str, series_path: str | None = None,

@@ -295,6 +295,12 @@ _SERVING_SCHEMA: tuple[tuple[str, str, str, str], ...] = (
      "copy-on-write block copies (partial-block divergence)"),
     ("preemptions", "dk_serve_preemptions_total", "counter",
      "running rows preempted for higher-SLO admissions"),
+    ("chunk_rows", "dk_serve_chunk_rows_total", "counter",
+     "rows fed through chunked-prefill steps"),
+    ("chunk_rows_padded", "dk_serve_chunk_rows_padded_total", "counter",
+     "rows those steps ran, padded to a power of two"),
+    ("programs_built", "dk_serve_programs_built", "gauge",
+     "prefill and chunk programs and decode widths built so far"),
 )
 
 

@@ -35,6 +35,28 @@ Design constraints, in order:
    worker-side exchange span and the PS-side fold/WAL-append spans share
    one id across threads, processes, and (via the seqno) the C++ wire.
 
+Two more sinks sit behind the same ``span()`` call, for the boundaries
+that feed the chip (the trainers' step loops, the serving engine's loop):
+
+- ``profile=True``: the span is also a ``jax.profiler.TraceAnnotation``
+  (``step=`` makes it a ``StepTraceAnnotation``), its ``args`` the
+  annotation's keywords. Whether a profiler session runs is the only
+  switch, and JAX tests it in C++; the annotation lands in the trace's
+  ``/host:CPU`` plane, on the device lines' own clock.
+- ``log=True``: the span is kept in the RUN LOG whether or not
+  ``enable()`` was called: one bounded process-wide list
+  (:data:`RUN_LOG_SIZE` entries, oldest dropped and counted in
+  ``dropped_spans()``) of the event dicts ``events()`` returns, read with
+  :func:`run_log`. For boundaries crossed once a run or once an epoch
+  (set-up phases, epoch ends, the closing fetch), never a step or a
+  request. A listener registered here turns JAX's own trace, lower and
+  compile durations into run-log events ``jax.trace``, ``jax.lower`` and
+  ``jax.compile`` (``args["fun"]`` names the program) and the compile
+  cache's hits and misses into :func:`jax_counts`. Of the traces only
+  the outermost of a program is kept, and none under a millisecond
+  (:data:`MIN_TRACE_NS`): eager ``add``s and key folds are traced by the
+  thousand, 14 µs each, and would turn the log over; they are counted.
+
 Sampling: ``enable(sample=0.1)`` keeps a deterministic ~10% of spans
 (counter-based, per thread — no RNG on the hot path). ``corr``
 propagation is never sampled out, only span recording is.
@@ -42,6 +64,7 @@ propagation is never sampled out, only span recording is.
 
 from __future__ import annotations
 
+import collections
 import gzip
 import json
 import os
@@ -49,10 +72,13 @@ import threading
 import time
 from typing import Any
 
+import jax
+
 __all__ = [
     "enable", "disable", "enabled", "span", "record", "instant",
     "counter", "set_corr", "current_corr", "add_events", "events",
-    "save", "rotate_files", "dropped_spans", "live_dropped",
+    "save", "rotate_files", "dropped_spans", "live_dropped", "run_log",
+    "jax_counts",
 ]
 
 #: category marking a ring entry as a sampled counter value rather than
@@ -113,6 +139,50 @@ class _Span:
         corr = self.corr if self.corr is not None else st.corr
         tr._record(st, self.name, self.cat, corr, self.t0, t1 - self.t0,
                    self.args)
+        return False
+
+
+class _BoundarySpan:
+    """A span at a boundary that feeds the chip: a profiler annotation
+    (``profile``), a run-log entry (``log``), else a ring entry while
+    tracing is on. One clock read at each end; no synchronisation."""
+
+    __slots__ = ("name", "cat", "corr", "args", "log", "t0", "t1", "_ann")
+
+    def __init__(self, name, cat, corr, args, profile, log, step):
+        self.name = name
+        self.cat = cat
+        self.corr = corr
+        if step is not None:
+            args = {**(args or {}), "step_num": step}
+        self.args = args
+        self.log = log
+        self._ann = None
+        if profile:
+            kind = (jax.profiler.TraceAnnotation if step is None
+                    else jax.profiler.StepTraceAnnotation)
+            self._ann = kind(name, **(args or {}))
+
+    def __enter__(self):
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.t1 = t1 = time.perf_counter_ns()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        if self.log:
+            _log_event(self.name, self.cat, self.corr, self.t0,
+                       t1 - self.t0, self.args)
+        else:
+            tr = _tracer
+            if tr is not None:
+                st = tr._state()
+                corr = self.corr if self.corr is not None else st.corr
+                tr._record(st, self.name, self.cat, corr, self.t0,
+                           t1 - self.t0, self.args)
         return False
 
 
@@ -249,7 +319,7 @@ def dropped_spans() -> int:
     nothing ever overflowed; monotone otherwise."""
     tr = _tracer
     live = tr.dropped() if tr is not None else 0
-    return _dropped_retired + live
+    return _dropped_retired + live + _run_log_dropped
 
 
 def live_dropped() -> int:
@@ -261,10 +331,14 @@ def live_dropped() -> int:
 
 
 def span(name: str, cat: str = "", corr: str | None = None,
-         args: dict | None = None):
+         args: dict | None = None, *, profile: bool = False,
+         log: bool = False, step: int | None = None):
     """Open a span: ``with trace.span("ps.fold"): ...``. Returns the
     shared no-op singleton when tracing is off — the off-mode call is
-    allocation-free."""
+    allocation-free. ``profile``/``log``/``step`` pick the profiler sink
+    and the run log (module doc); such a span lives with tracing off."""
+    if profile or log:
+        return _BoundarySpan(name, cat, corr, args, profile, log, step)
     tr = _tracer
     if tr is None:
         return _NOOP_SPAN
@@ -361,6 +435,99 @@ def events(min_end_ns: int | None = None) -> list[dict]:
     return tr.events(min_end_ns)
 
 
+#: entries the run log keeps; the oldest goes when one more arrives
+RUN_LOG_SIZE = 4096
+
+_run_log: collections.deque = collections.deque(maxlen=RUN_LOG_SIZE)
+_run_log_dropped = 0
+_run_log_lock = threading.Lock()
+#: a ``jax.trace`` shorter than this is counted, not kept
+MIN_TRACE_NS = 1_000_000
+
+_jax_counts = {"cache_hits": 0, "cache_misses": 0, "short_traces": 0,
+               "short_trace_ns": 0}
+_CACHE_EVENTS = {
+    "/jax/compilation_cache/cache_hits": ("cache_hits", "hit"),
+    "/jax/compilation_cache/cache_misses": ("cache_misses", "miss"),
+}
+_jax_seen = threading.local()
+
+
+def _log_event(name, cat, corr, t0_ns, dur_ns, args) -> None:
+    global _run_log_dropped
+    ev = {"name": name, "cat": cat, "corr": corr, "t0_ns": t0_ns,
+          "dur_ns": dur_ns, "tid": threading.get_native_id(),
+          "tname": threading.current_thread().name, "args": args}
+    with _run_log_lock:
+        if len(_run_log) == RUN_LOG_SIZE:
+            _run_log_dropped += 1
+        _run_log.append(ev)
+
+
+def run_log() -> list[dict]:
+    """The run log, oldest first: every ``log=True`` span and every JAX
+    trace, lower and compile of this process, tracing on or off."""
+    with _run_log_lock:
+        return list(_run_log)
+
+
+def jax_counts() -> dict:
+    """Over the process: ``cache_hits`` and ``cache_misses`` of JAX's
+    persistent compile cache, and the ``short_traces`` (with their
+    ``short_trace_ns``) that the run log counted instead of keeping."""
+    return dict(_jax_counts)
+
+
+_JAX_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_JAX_DURATIONS = {
+    _JAX_TRACE: "jax.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "jax.lower",
+    "/jax/core/compile/backend_compile_duration": "jax.compile",
+}
+
+
+def _on_jax_scalar(event, value, **_):
+    # JAX reports a duration event's start as a scalar; a jitted function
+    # called while another is traced is traced inside it
+    if event == _JAX_TRACE:
+        _jax_seen.depth = getattr(_jax_seen, "depth", 0) + 1
+
+
+def _on_jax_duration(event, duration_secs, fun_name=None, **_):
+    name = _JAX_DURATIONS.get(event)
+    if name is None:
+        return
+    args = {"fun": fun_name}
+    if name == "jax.trace":
+        # only the outermost trace is kept: a 24-layer step traces
+        # thousands of jitted ``add``s and ``multiply``s inside its own
+        _jax_seen.depth = depth = max(getattr(_jax_seen, "depth", 1) - 1, 0)
+        if depth:
+            return
+        if duration_secs * 1e9 < MIN_TRACE_NS:
+            _jax_counts["short_traces"] += 1
+            _jax_counts["short_trace_ns"] += int(duration_secs * 1e9)
+            return
+    elif name == "jax.compile":
+        # the cache's event fires inside the compile it answers
+        args["cache"] = getattr(_jax_seen, "cache", None)
+        _jax_seen.cache = None
+    dur = int(duration_secs * 1e9)
+    _log_event(name, "jax", None, time.perf_counter_ns() - dur, dur, args)
+
+
+def _on_jax_event(event, **_):
+    kind = _CACHE_EVENTS.get(event)
+    if kind is not None:
+        _jax_counts[kind[0]] += 1
+        _jax_seen.cache = kind[1]
+
+
+jax.monitoring.register_scalar_listener(_on_jax_scalar)
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
+jax.monitoring.register_event_listener(_on_jax_event)
+
+
 def open_maybe_gz(path: str):
     """Open a JSON document that may be gzipped — sniffed by magic
     bytes, not suffix, so rotated/renamed files read transparently.
@@ -399,7 +566,8 @@ def rotate_files(path: str, max_bytes: int, keep: int = 3) -> None:
 
 def save(path: str, max_bytes: int | None = None, keep: int = 3) -> str:
     """Write everything recorded so far as Chrome trace-event JSON
-    (``{"traceEvents": [...]}``, complete-event ``ph: "X"`` records with
+    (the ring's events and the run log's;
+    ``{"traceEvents": [...]}``, complete-event ``ph: "X"`` records with
     µs timestamps, counter samples as ``ph: "C"`` tracks) — drag the
     file into https://ui.perfetto.dev or ``chrome://tracing``. A path
     ending in ``.gz`` is gzip-compressed (the long-run growth fix;
@@ -413,7 +581,7 @@ def save(path: str, max_bytes: int | None = None, keep: int = 3) -> str:
     tr = _tracer
     if tr is None:
         raise RuntimeError("tracing is not enabled: nothing to save")
-    evs = tr.events()
+    evs = sorted(tr.events() + run_log(), key=lambda e: e["t0_ns"])
     pid = os.getpid()
     out: list[dict[str, Any]] = [{
         "name": "process_name", "ph": "M", "pid": pid, "tid": 0,

@@ -326,6 +326,7 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
         out_shape=out_shape,
         scratch_shapes=scratch,
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     out = jnp.moveaxis(o.reshape(B, H, L, D), 1, 2)
     return out, lse[..., 0]
@@ -563,6 +564,7 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dq",
     )(*dq_args)
 
     # dk/dv: k blocks on the parallel axis, q innermost; under GQA the
@@ -613,6 +615,7 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
         scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
                         pltpu.VMEM((bk, D), jnp.float32)],
         interpret=interpret,
+        name="flash_dkv",
     )(*dkv_args)
 
     def unbh(x):  # [B·h, L, D] → [B, L, h, D]

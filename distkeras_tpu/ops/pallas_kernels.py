@@ -73,6 +73,7 @@ def _adam_leaf(g, m, v, bc, *, lr, b1, b2, eps, interpret):
         out_specs=[blk, blk, blk],
         out_shape=[out, out, out],
         interpret=interpret,
+        name="fused_adam",
     )(bc, prep(g), prep(m), prep(v))
 
     def unprep(x):

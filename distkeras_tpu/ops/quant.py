@@ -125,6 +125,7 @@ def _q_matmul_pallas(x2, q, scale, *, bm, bn, out_dtype, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), out_dtype),
         interpret=interpret,
+        name="q_matmul",
     )(xp, q, scale.reshape(1, n))
     return out[:m]
 

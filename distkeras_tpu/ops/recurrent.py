@@ -200,6 +200,7 @@ def _fwd(gx_t, wh, interpret, save_c: bool = True):
             pltpu.VMEM((B, H), jnp.float32),  # c carry
         ],
         interpret=interpret,
+        name="lstm_scan_fwd",
     )(gx_t, wh)
     return (out[0], out[1]) if save_c else (out[0], None)
 
@@ -241,6 +242,7 @@ def _bwd(gx_t, wh, hs, cs, dhs, interpret):
             pltpu.VMEM((H, H4), jnp.float32),  # dwh accumulator
         ],
         interpret=interpret,
+        name="lstm_scan_bwd",
     )(gx_t, wh, hs, hs, cs, cs, dhs)
     return dgx, dwh
 

@@ -170,8 +170,11 @@ class SPMDEngine:
         nt = jax.tree.map(lambda x: put_global(x, rep), nt)
         # moments/accumulators inherit the params' layout (with FSDP specs
         # this IS ZeRO optimizer-state partitioning); scalars replicate
+        def train_init_state(params):
+            return self.optimizer.init(params)
+
         opt_state = jax.jit(
-            self.optimizer.init, out_shardings=self._opt_shardings(params)
+            train_init_state, out_shardings=self._opt_shardings(params)
         )(params)
         self._build_step()
         return params, nt, opt_state
@@ -249,7 +252,10 @@ class SPMDEngine:
             grads = jax.tree.map(lambda g: g / A, acc)
             return (loss_sum / A, nt), grads
 
-        def step(params, nt, opt_state, batch):
+        # the functions' names are the programs' in a profiler trace
+        # (``jit_train_step`` on its ``XLA Modules`` line) and in the run
+        # log's ``jax.compile`` entries
+        def train_step(params, nt, opt_state, batch):
             # forward AND backward are traced in here: a Pallas kernel in
             # the model runs per device on its own rows of the dp split
             with kernel_mesh(mesh, dp_axis):
@@ -265,8 +271,8 @@ class SPMDEngine:
             )
             return params, new_nt, opt_state, loss
 
-        self._step_fn = step
-        self._step = jax.jit(step, donate_argnums=(0, 2))
+        self._step_fn = train_step
+        self._step = jax.jit(train_step, donate_argnums=(0, 2))
         self._resident = None
 
     def _check_batch(self, B: int):
@@ -330,7 +336,8 @@ class SPMDEngine:
         mesh, dp_axis = self.mesh, self.dp_axis
         step = self._step_fn
 
-        def resident_fn(params, nt, opt_state, staged, key, do_shuffle, B):
+        def train_epoch_resident(params, nt, opt_state, staged, key,
+                                 do_shuffle, B):
             rows = staged[0].shape[0]
             S = rows // B
             if do_shuffle:
@@ -355,7 +362,8 @@ class SPMDEngine:
             return params, nt, opt_state, losses
 
         self._resident = jax.jit(
-            resident_fn, donate_argnums=(0, 2), static_argnums=(5, 6)
+            train_epoch_resident, donate_argnums=(0, 2),
+            static_argnums=(5, 6),
         )
 
 

@@ -856,17 +856,12 @@ class ParameterServer:
 
     def _drain_folds_locked(self, batch: list[_FoldWork]) -> None:
         """Fold one drained batch — call holding the center lock. Every
-        item is processed (its ``done`` event always set), exceptions
-        are carried per item to the submitting thread, and the batch
-        span makes K-folds-per-acquisition visible on the timeline."""
+        item is processed (its ``done`` event always set) and exceptions
+        are carried per item to the submitting thread; K folds under one
+        acquisition show as ``batched_folds`` in stats."""
         batched = len(batch) >= 2
-        if batched:
-            with _trace.span("ps.fold_batch", args={"k": len(batch)}):
-                for work in batch:
-                    work.batched = True
-                    self._fold_one_locked(work)
-            return
         for work in batch:
+            work.batched = batched
             self._fold_one_locked(work)
 
     def _fold_one_locked(self, work: _FoldWork) -> None:
@@ -1085,7 +1080,6 @@ class ParameterServer:
         joiner's very next ``pull`` records its pull-version, so its
         first DynSGD commit is priced at the true small τ. Returns the
         admission record the wire action answers with."""
-        _trace.instant("ps.join", corr=f"w{worker_id}")
         self._registry.register(worker_id)
         with self._stats_lock:
             self._drained_wids.discard(worker_id)
@@ -1105,8 +1099,6 @@ class ParameterServer:
         path) plus the elastic counters — ``timeout=True`` records a
         drain whose deadline lapsed (the force-drain path; eviction
         remains the backstop for the abandoned worker)."""
-        _trace.instant("ps.drain", corr=f"w{worker_id}",
-                       args={"timeout": bool(timeout)})
         self.deregister_worker(worker_id)
         with self._stats_lock:
             if worker_id in self._drained_wids:
@@ -1735,14 +1727,13 @@ class SocketParameterServer(ParameterServer):
         redundant O(model) pass here) and counts the pull only once the
         reply is fully sent — delivered-traffic semantics, matching the
         compressed path and the native server."""
-        with _trace.span("ps.pull"):
-            snap, _ = self._begin_pull(worker_id, compressed=False)
-            self._begin_reply()
-            try:
-                networking.send_data(conn, {"weights": snap})
-                self._count(pulls=1, bytes_out=self._center_nbytes)
-            finally:
-                self._end_reply()
+        snap, _ = self._begin_pull(worker_id, compressed=False)
+        self._begin_reply()
+        try:
+            networking.send_data(conn, {"weights": snap})
+            self._count(pulls=1, bytes_out=self._center_nbytes)
+        finally:
+            self._end_reply()
 
     def _serve_exchange(self, conn, msg, raw: bytes) -> None:
         """Wire variant of the fused ``exchange``: fold + fused pull
@@ -1807,22 +1798,21 @@ class SocketParameterServer(ParameterServer):
         bounded phantom-pull behavior instead of corrupting the newer
         encode's residual. The center-lock section is the same O(1)
         version-record + snapshot grab as ``pull``."""
-        with _trace.span("ps.pull_int8"):
-            snap, st = self._begin_pull(worker_id, compressed=True)
+        snap, st = self._begin_pull(worker_id, compressed=True)
+        with st.lock:
+            blob, nbytes = self._encode_pull(st, snap)
+            epoch = st.epoch
+        self._begin_reply()
+        try:
+            networking.send_data(conn, {"weights": blob})
+            self._count(compressed_pulls=1, bytes_out=nbytes)
+        except (ConnectionError, OSError):
             with st.lock:
-                blob, nbytes = self._encode_pull(st, snap)
-                epoch = st.epoch
-            self._begin_reply()
-            try:
-                networking.send_data(conn, {"weights": blob})
-                self._count(compressed_pulls=1, bytes_out=nbytes)
-            except (ConnectionError, OSError):
-                with st.lock:
-                    if st.epoch == epoch:
-                        self._rollback_encode_locked(st, snap, blob)
-                raise
-            finally:
-                self._end_reply()
+                if st.epoch == epoch:
+                    self._rollback_encode_locked(st, snap, blob)
+            raise
+        finally:
+            self._end_reply()
 
     def stop(self) -> None:
         """Shut down, unblocking ``accept`` via the reference's self-connect
@@ -2004,11 +1994,10 @@ class StandbySocketParameterServer(SocketParameterServer):
                     if not self.is_standby:
                         return True  # promoted: this stream is history
                     self._repl_records += 1
-                    with _trace.span("ps.chain_apply"):
-                        _wal.replay_record(
-                            self._repl_state, recs[0][0], recs[0][1],
-                            self.rule, self.num_workers, self.ema_decay,
-                        )
+                    _wal.replay_record(
+                        self._repl_state, recs[0][0], recs[0][1],
+                        self.rule, self.num_workers, self.ema_decay,
+                    )
                     # chain replication (distkeras_tpu/sharding): a middle
                     # link forwards the RAW frame to its own successor
                     # after applying it — under the same lock, so the
@@ -2032,9 +2021,8 @@ class StandbySocketParameterServer(SocketParameterServer):
         if sock is None:
             return
         try:
-            with _trace.span("ps.chain_forward"):
-                sock.sendall(head)
-                sock.sendall(body)
+            sock.sendall(head)
+            sock.sendall(body)
         except OSError:
             self._replica_sock = None
             self._n_standby_drops += 1
@@ -2098,8 +2086,7 @@ class StandbySocketParameterServer(SocketParameterServer):
         grace — closes the gap. (A zombie's post-promotion folds belong
         to the superseded history anyway; fencing rejects their clients'
         next commits.)"""
-        with _trace.span("ps.promote", args={"epoch": int(epoch)}):
-            self._promote_impl(epoch, drain_timeout)
+        self._promote_impl(epoch, drain_timeout)
 
     def _promote_impl(self, epoch: int, drain_timeout: float) -> None:
         deadline = time.monotonic() + float(drain_timeout)

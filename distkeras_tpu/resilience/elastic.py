@@ -494,11 +494,7 @@ class ElasticCoordinator:
             self._next_id += 1
             self.joined += 1
             self.join_log.append({"worker": wid, "reason": reason})
-        from distkeras_tpu.observability import trace as _trace
-
-        with _trace.span("elastic.join", corr=f"w{wid}",
-                         args={"reason": reason}):
-            self._admit(wid, joiner=True)
+        self._admit(wid, joiner=True)
         return wid
 
     def request_preempt(self, worker_id: int,
@@ -527,11 +523,7 @@ class ElasticCoordinator:
         return True
 
     def _drain(self, worker_id: int, reason: str) -> None:
-        from distkeras_tpu.observability import trace as _trace
-
-        with _trace.span("elastic.drain", corr=f"w{worker_id}",
-                         args={"reason": reason}):
-            self._drain_impl(worker_id)
+        self._drain_impl(worker_id)
 
     def _drain_impl(self, worker_id: int) -> None:
         t = self._threads[worker_id]

@@ -163,7 +163,7 @@ class Request:
         self.new_tokens: list[int] = []
         self.state = "queued"
         self.error: str | None = None
-        self.t_submit = time.monotonic()
+        self.t_submit = time.perf_counter()
         self.t_admit: float | None = None
         self.t_done: float | None = None
         self.prefill_s: float | None = None
@@ -357,6 +357,7 @@ class GenerationEngine:
             "occupancy_sum": 0,
             "spec_rounds": 0, "spec_proposed": 0, "spec_accepted": 0,
             "swaps": 0, "refilled": 0,
+            "chunk_rows": 0, "chunk_rows_padded": 0,
         }
         if self.admission == "slo":
             self.stats_["preemptions"] = 0
@@ -370,6 +371,8 @@ class GenerationEngine:
         self._retired: deque = deque(maxlen=2048)
 
         self._decode_fn, self._decode_fn_greedy = self._make_decode()
+        self._decode_widths: set[int] = set()
+        self._step_num = 0
         self._prefill_fns: dict[int, object] = {}
         self._spec_fn = self._make_spec() if self._draft_module else None
 
@@ -380,8 +383,11 @@ class GenerationEngine:
 
         module, bs = self._module, self.block_size
 
-        def fn(params, k_pools, v_pools, tok, tables, write_slot, positions,
-               temp, top_k, top_p, greedy, seeds):
+        # the functions' names are the programs' in a profiler trace
+        # (``jit_serve_decode`` on its ``XLA Modules`` line) and in the
+        # run log's ``jax.compile`` entries
+        def serve_decode(params, k_pools, v_pools, tok, tables, write_slot,
+                         positions, temp, top_k, top_p, greedy, seeds):
             logits, k_pools, v_pools = module.apply(
                 {"params": params}, tok, k_pools, v_pools, tables,
                 write_slot, positions, bs,
@@ -397,8 +403,8 @@ class GenerationEngine:
 
         # all-greedy fast path: serving batches are frequently pure-greedy
         # and the per-row warp costs two [B, vocab] sorts per token
-        def fn_greedy(params, k_pools, v_pools, tok, tables, write_slot,
-                      positions):
+        def serve_decode_greedy(params, k_pools, v_pools, tok, tables,
+                                write_slot, positions):
             logits, k_pools, v_pools = module.apply(
                 {"params": params}, tok, k_pools, v_pools, tables,
                 write_slot, positions, bs,
@@ -408,16 +414,17 @@ class GenerationEngine:
                              axis=-1).astype(jnp.int32)
             return nxt, k_pools, v_pools
 
-        return (jax.jit(fn, donate_argnums=(1, 2)),
-                jax.jit(fn_greedy, donate_argnums=(1, 2)))
+        return (jax.jit(serve_decode, donate_argnums=(1, 2)),
+                jax.jit(serve_decode_greedy, donate_argnums=(1, 2)))
 
     def _make_prefill(self):
         from distkeras_tpu.models.lm import TransformerLM
 
         module, dm = self._module, self._draft_module
 
-        def fn(params, d_params, k_pools, v_pools, dk_pools, dv_pools,
-               prompts, row_slots, lp, temp, top_k, top_p, greedy, seeds):
+        def serve_prefill(params, d_params, k_pools, v_pools, dk_pools,
+                          dv_pools, prompts, row_slots, lp, temp, top_k,
+                          top_p, greedy, seeds):
             logits, kvs = module.apply(
                 {"params": params}, prompts,
                 method=TransformerLM.prefill_raw,
@@ -444,7 +451,7 @@ class GenerationEngine:
             tok = sample_rows(last, keys, temp, top_k, top_p, greedy)
             return tok, k_pools, v_pools, dk_pools, dv_pools
 
-        return jax.jit(fn, donate_argnums=(2, 3, 4, 5))
+        return jax.jit(serve_prefill, donate_argnums=(2, 3, 4, 5))
 
     def _make_chunk(self):
         """The front-door prefill program: one ``paged_extend_rows`` pass
@@ -460,9 +467,9 @@ class GenerationEngine:
 
         module, bs = self._module, self.block_size
 
-        def fn(params, k_pools, v_pools, tokens, tables, write_slots,
-               positions, last_idx, temp, top_k, top_p, greedy, seeds,
-               sample_pos):
+        def serve_chunk(params, k_pools, v_pools, tokens, tables,
+                        write_slots, positions, last_idx, temp, top_k,
+                        top_p, greedy, seeds, sample_pos):
             logits, k_pools, v_pools = module.apply(
                 {"params": params}, tokens, k_pools, v_pools, tables,
                 write_slots, positions, bs,
@@ -477,7 +484,7 @@ class GenerationEngine:
             tok = sample_rows(last, keys, temp, top_k, top_p, greedy)
             return tok, k_pools, v_pools
 
-        return jax.jit(fn, donate_argnums=(1, 2))
+        return jax.jit(serve_chunk, donate_argnums=(1, 2))
 
     def _make_spec(self):
         from distkeras_tpu.models.lm import TransformerLM
@@ -485,8 +492,8 @@ class GenerationEngine:
         module, dm, K = self._module, self._draft_module, self.spec_tokens
         bs = self.block_size
 
-        def fn(params, d_params, k, v, dk, dv, tok, tables, positions,
-               write_slots):
+        def serve_spec(params, d_params, k, v, dk, dv, tok, tables,
+                       positions, write_slots):
             def draft_step(carry, xs):
                 t, dkp, dvp = carry
                 i, ws = xs
@@ -520,7 +527,7 @@ class GenerationEngine:
             a_row = jnp.sum(jnp.cumprod(match, axis=1), axis=1)
             return props, g, a_row, k, v, dk, dv
 
-        return jax.jit(fn, donate_argnums=(2, 3, 4, 5))
+        return jax.jit(serve_spec, donate_argnums=(2, 3, 4, 5))
 
     # -- client surface ------------------------------------------------------
 
@@ -611,7 +618,6 @@ class GenerationEngine:
         # correlation id (carried in the wire frame), so this enqueue
         # mark, the queued/prefill spans, and the final serve.request
         # span stitch one request across threads
-        _trace.instant("serve.enqueue", corr=req.id)
         return req
 
     def cancel(self, request: Request) -> None:
@@ -711,8 +717,6 @@ class GenerationEngine:
         self.model_version = version
         self._staged_swap = None
         self.stats_["swaps"] += 1
-        _trace.instant("serve.swap", cat="deploy",
-                       args={"version": version, "policy": policy})
 
     # -- the scheduler loop --------------------------------------------------
 
@@ -720,7 +724,7 @@ class GenerationEngine:
                   error: str | None = None) -> None:
         req.state = state
         req.error = error
-        req.t_done = time.monotonic()
+        req.t_done = time.perf_counter()
         key = {"done": "completed", "cancelled": "cancelled",
                "failed": "failed"}[state]
         self.stats_[key] += 1
@@ -743,8 +747,7 @@ class GenerationEngine:
             "model_version": req.model_version,
         })
         if _trace.enabled():
-            # whole-lifetime span (submit → retire); time.monotonic and
-            # the tracer's perf_counter share CLOCK_MONOTONIC on Linux
+            # whole-lifetime span (submit → retire), on the tracer's clock
             _trace.record(
                 "serve.request", int(req.t_submit * 1e9),
                 int(req.t_done * 1e9), corr=req.id,
@@ -829,7 +832,7 @@ class GenerationEngine:
             self._tables[b, :need] = blocks
             self._batch_dirty = True
             head.state = "running"
-            head.t_admit = time.monotonic()
+            head.t_admit = time.perf_counter()
             head.model_version = self.model_version
             self.stats_["admitted"] += 1
             if _trace.enabled():
@@ -948,8 +951,6 @@ class GenerationEngine:
         victim_id = self._slots[b].request.id
         self._evacuate_row(b, reset_tokens=False)
         self.stats_["preemptions"] += 1
-        _trace.instant("serve.preempt", corr=victim_id,
-                       args={"for": req.id})
         return True
 
     def _install_row(self, b: int, req: Request, res) -> None:
@@ -985,7 +986,7 @@ class GenerationEngine:
         self._slots[b] = slot
         self._batch_dirty = True
         req.state = "running"
-        req.t_admit = time.monotonic()
+        req.t_admit = time.perf_counter()
         req.model_version = self.model_version
         self.stats_["admitted"] += 1
         if _trace.enabled():
@@ -1126,62 +1127,69 @@ class GenerationEngine:
                        + self._slots[b].next_pos for b in rows)
         nb = min(self._nb_per_seq,
                  2 * math.ceil(math.ceil(need_pos / bs) / 2))
-        tokens = np.zeros((npad, Tpad), np.int32)
-        tables = np.zeros((npad, nb), np.int32)
-        # pad rows / pad positions write the scratch block's slots —
-        # garbage nobody reads, same trick as the legacy prefill buckets
-        write_slots = np.tile((np.arange(Tpad) % bs).astype(np.int32),
-                              (npad, 1))
-        positions = np.zeros((npad,), np.int32)
-        last_idx = np.zeros((npad,), np.int32)
-        sample_pos = np.zeros((npad,), np.int32)
-        temp = np.zeros((npad,), np.float32)
-        top_k = np.full((npad,), vocab, np.int32)
-        top_p = np.ones((npad,), np.float32)
-        greedy = np.ones((npad,), bool)
-        seeds = np.zeros((npad,), np.int32)
-        t_real = []
-        for i, b in enumerate(rows):
-            s = self._slots[b]
-            r = s.request
-            t = min(Tpad, s.feed_len - s.next_pos)
-            t_real.append(t)
-            tokens[i, :t] = s.feed[s.next_pos: s.next_pos + t]
-            tables[i] = self._tables[b, :nb]
-            pos = s.next_pos + np.arange(t)
-            write_slots[i, :t] = tables[i, pos // bs] * bs + pos % bs
-            positions[i] = s.next_pos
-            last_idx[i] = min(max(s.feed_len - 1 - s.next_pos, 0),
-                              Tpad - 1)
-            sample_pos[i] = s.feed_len   # == lp for fresh requests: the
-            temp[i] = r.temperature      # key matches _make_prefill
-            if r.top_k is not None:
-                top_k[i] = r.top_k
-            if r.top_p is not None:
-                top_p[i] = r.top_p
-            greedy[i] = r.greedy
-            seeds[i] = r.seed
         key = (Tpad, npad, nb)
-        if key not in self._chunk_fns:
-            self._chunk_fns[key] = self._make_chunk()
-        c = self.cache
-        t_pf = time.perf_counter_ns()
-        tok, c.k_pools, c.v_pools = self._chunk_fns[key](
-            self._params, c.k_pools, c.v_pools, jnp.asarray(tokens),
-            jnp.asarray(tables), jnp.asarray(write_slots),
-            jnp.asarray(positions), jnp.asarray(last_idx),
-            jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
-            jnp.asarray(greedy), jnp.asarray(seeds),
-            jnp.asarray(sample_pos),
-        )
-        tok = np.asarray(jax.device_get(tok))
-        t1_pf = time.perf_counter_ns()
+        # from before the host builds the arrays to after the token fetch
+        with _trace.span("serve.chunk", cat="serve", profile=True,
+                         args={"rows": n, "padded_rows": npad,
+                               "tpad": Tpad, "width": nb,
+                               "key": str(key)}) as chunk:
+            tokens = np.zeros((npad, Tpad), np.int32)
+            tables = np.zeros((npad, nb), np.int32)
+            # pad rows / pad positions write the scratch block's slots —
+            # garbage nobody reads, same trick as the legacy prefill buckets
+            write_slots = np.tile((np.arange(Tpad) % bs).astype(np.int32),
+                                  (npad, 1))
+            positions = np.zeros((npad,), np.int32)
+            last_idx = np.zeros((npad,), np.int32)
+            sample_pos = np.zeros((npad,), np.int32)
+            temp = np.zeros((npad,), np.float32)
+            top_k = np.full((npad,), vocab, np.int32)
+            top_p = np.ones((npad,), np.float32)
+            greedy = np.ones((npad,), bool)
+            seeds = np.zeros((npad,), np.int32)
+            t_real = []
+            for i, b in enumerate(rows):
+                s = self._slots[b]
+                r = s.request
+                t = min(Tpad, s.feed_len - s.next_pos)
+                t_real.append(t)
+                tokens[i, :t] = s.feed[s.next_pos: s.next_pos + t]
+                tables[i] = self._tables[b, :nb]
+                pos = s.next_pos + np.arange(t)
+                write_slots[i, :t] = tables[i, pos // bs] * bs + pos % bs
+                positions[i] = s.next_pos
+                last_idx[i] = min(max(s.feed_len - 1 - s.next_pos, 0),
+                                  Tpad - 1)
+                sample_pos[i] = s.feed_len   # == lp for fresh requests: the
+                temp[i] = r.temperature      # key matches _make_prefill
+                if r.top_k is not None:
+                    top_k[i] = r.top_k
+                if r.top_p is not None:
+                    top_p[i] = r.top_p
+                greedy[i] = r.greedy
+                seeds[i] = r.seed
+            if key not in self._chunk_fns:
+                self._chunk_fns[key] = self._make_chunk()
+            c = self.cache
+            tok, c.k_pools, c.v_pools = self._chunk_fns[key](
+                self._params, c.k_pools, c.v_pools, jnp.asarray(tokens),
+                jnp.asarray(tables), jnp.asarray(write_slots),
+                jnp.asarray(positions), jnp.asarray(last_idx),
+                jnp.asarray(temp), jnp.asarray(top_k), jnp.asarray(top_p),
+                jnp.asarray(greedy), jnp.asarray(seeds),
+                jnp.asarray(sample_pos),
+            )
+            tok = np.asarray(jax.device_get(tok))
+        t_pf, t1_pf = chunk.t0, chunk.t1
         with self._wake:
+            self.stats_["chunk_rows"] += n
+            self.stats_["chunk_rows_padded"] += npad
             for i, b in enumerate(rows):
                 s = self._slots[b]
                 r = s.request
                 r.prefill_s = (r.prefill_s or 0.0) + (t1_pf - t_pf) / 1e9
                 if _trace.enabled():
+                    # the step's one chunk interval, once a request
                     _trace.record("serve.prefill", t_pf, t1_pf, corr=r.id,
                                   args={"rows": n, "chunk": int(t_real[i]),
                                         "pos": int(s.next_pos)})
@@ -1234,13 +1242,21 @@ class GenerationEngine:
         """One scheduler iteration: retire cancellations, admit + prefill,
         one batched decode (or speculative) step. Returns whether any work
         was done — the loop thread sleeps on False."""
+        self._step_num += 1
+        with _trace.span("serve.step", cat="serve", profile=True,
+                         step=self._step_num):
+            return self._step()
+
+    def _step(self) -> bool:
         with self._wake:
-            for b, slot in enumerate(self._slots):
-                if slot is not None and slot.request._cancelled:
-                    self._retire(b, "cancelled", "cancelled by client")
-            self._apply_swap_locked()
-            admitted = (self._admit_frontdoor() if self._frontdoor
-                        else self._admit())
+            with _trace.span("serve.retire", cat="serve", profile=True):
+                for b, slot in enumerate(self._slots):
+                    if slot is not None and slot.request._cancelled:
+                        self._retire(b, "cancelled", "cancelled by client")
+                self._apply_swap_locked()
+            with _trace.span("serve.admit", cat="serve", profile=True):
+                admitted = (self._admit_frontdoor() if self._frontdoor
+                            else self._admit())
         worked = bool(admitted)
         if self._frontdoor:
             self._apply_cows()
@@ -1259,10 +1275,9 @@ class GenerationEngine:
         if not active:
             return worked
         # rows-in-flight rides the span (ISSUE 14): the analyzer's
-        # batch-occupancy input ("batch" kept for older readers)
-        _args = ({"batch": len(active), "rows": len(active)}
-                 if _trace.enabled() else None)
-        with _trace.span("serve.decode_step", args=_args):
+        # batch-occupancy input
+        with _trace.span("serve.decode_step", cat="serve", profile=True,
+                         args={"rows": len(active)}):
             if self._spec_fn is not None:
                 self._spec_step(active)
             else:
@@ -1315,6 +1330,7 @@ class GenerationEngine:
         of step shapes, not one per length."""
         nb = min(self._nb_per_seq,
                  2 * math.ceil(math.ceil(need_pos / self.block_size) / 2))
+        self._decode_widths.add(nb)
         if nb not in self._dev_tables_by_width:
             self._dev_tables_by_width[nb] = jnp.asarray(
                 self._tables[:, :nb]
@@ -1454,8 +1470,8 @@ class GenerationEngine:
 
     def drain(self, timeout: float = 30.0) -> bool:
         """Wait until every accepted request has retired."""
-        deadline = time.monotonic() + timeout
-        while time.monotonic() < deadline:
+        deadline = time.perf_counter() + timeout
+        while time.perf_counter() < deadline:
             with self._lock:
                 if self._idle():
                     return True
@@ -1507,6 +1523,9 @@ class GenerationEngine:
             s["blocks_in_use"] = self.allocator.used_blocks
             s["blocks_free"] = self.allocator.free_blocks
             s["blocks_high_water"] = self.allocator.high_water
+            s["programs_built"] = (len(self._chunk_fns)
+                                   + len(self._prefill_fns)
+                                   + len(self._decode_widths))
             s["mean_batch_occupancy"] = (
                 round(s["occupancy_sum"] / s["steps"], 3)
                 if s["steps"] else 0.0

@@ -1056,35 +1056,33 @@ class ShmParameterServer(SocketParameterServer):
         """Bulk-lane pull reply: the immutable center snapshot's leaves
         written ONCE into the ring (no pickle pass); counters land after
         delivery — the same delivered-traffic semantics as TCP."""
-        with _trace.span("ps.pull"):
-            snap, _ = self._begin_pull(worker_id, compressed=False)
-            self._begin_reply()
-            try:
-                conn.send_msg({"weights": snap}, bulk=True)
-                self._count(pulls=1, bytes_out=self._center_nbytes)
-            finally:
-                self._end_reply()
+        snap, _ = self._begin_pull(worker_id, compressed=False)
+        self._begin_reply()
+        try:
+            conn.send_msg({"weights": snap}, bulk=True)
+            self._count(pulls=1, bytes_out=self._center_nbytes)
+        finally:
+            self._end_reply()
 
     def _serve_compressed_pull_shm(self, conn: _ShmConn,
                                    worker_id: int) -> None:
         """int8 error-feedback pull with the dropped-reply residual
         rollback (epoch-guarded, same as the socket/native lanes)."""
-        with _trace.span("ps.pull_int8"):
-            snap, st = self._begin_pull(worker_id, compressed=True)
+        snap, st = self._begin_pull(worker_id, compressed=True)
+        with st.lock:
+            blob, nbytes = self._encode_pull(st, snap)
+            epoch = st.epoch
+        self._begin_reply()
+        try:
+            conn.send_msg({"weights": blob}, bulk=True)
+            self._count(compressed_pulls=1, bytes_out=nbytes)
+        except (ConnectionError, OSError):
             with st.lock:
-                blob, nbytes = self._encode_pull(st, snap)
-                epoch = st.epoch
-            self._begin_reply()
-            try:
-                conn.send_msg({"weights": blob}, bulk=True)
-                self._count(compressed_pulls=1, bytes_out=nbytes)
-            except (ConnectionError, OSError):
-                with st.lock:
-                    if st.epoch == epoch:
-                        self._rollback_encode_locked(st, snap, blob)
-                raise
-            finally:
-                self._end_reply()
+                if st.epoch == epoch:
+                    self._rollback_encode_locked(st, snap, blob)
+            raise
+        finally:
+            self._end_reply()
 
     def _serve_exchange_shm(self, conn: _ShmConn, msg: dict,
                             raw: bytes | None) -> None:

@@ -41,6 +41,7 @@ import optax
 from distkeras_tpu import utils
 from distkeras_tpu.data import Dataset, padded_chunks, prefetch_to_device
 from distkeras_tpu.model import ModelSpec, from_keras, keras_weights_to_model
+from distkeras_tpu.observability import trace as _trace
 from distkeras_tpu.ops.losses import get_loss
 from distkeras_tpu.parallel.local_sgd import LocalSGDEngine
 from distkeras_tpu.parallel.merge_rules import (
@@ -230,6 +231,14 @@ def _drain(x):
     """
     jax.block_until_ready(x)
     jax.tree.map(np.asarray, x)
+
+
+def _phase(name, **args):
+    """A span of ``MeshTrainer.train`` crossed once a run or once an epoch:
+    kept in the run log, tracing on or off, and an annotation in a profiler
+    slice (``observability.trace``). It adds no synchronisation."""
+    return _trace.span(name, cat="train", args=args or None, profile=True,
+                       log=True)
 
 
 def _profile_trace_ctx(profile_dir):
@@ -1843,7 +1852,8 @@ class MeshTrainer(Trainer):
         ](self.spec, self.loss_fn, self.mesh, **kwargs)
         # one init serves both the specs derivation and (via the cache)
         # train()'s fresh-start state — no duplicate Flax init
-        self._init_cache = self.spec.init_np(self.seed)
+        with _phase("train.init_weights"):
+            self._init_cache = self.spec.init_np(self.seed)
         specs = (self.param_specs if self.param_specs is not None
                  else specs_for(to_engine(self._init_cache[0])))
         engine = SPMDEngine(
@@ -1871,12 +1881,11 @@ class MeshTrainer(Trainer):
         # (_profile_trace_ctx) and global-array eval batches (_Validator).
         ds = self._coerce_dataset(dataset)
         cols = self.features_col + [self.label_col]
-        engine, to_engine, from_engine = self._build_engine()
-        validator = self._make_validator()
+        with _phase("train.build_engine"):
+            engine, to_engine, from_engine = self._build_engine()
+            validator = self._make_validator()
 
         def run_validation(epoch):
-            if validator is None:
-                return
             if self.strategy == "spmd":
                 # engine layout == model layout: score the sharded params
                 # in place — the jitted eval compiles over their mesh
@@ -1906,18 +1915,43 @@ class MeshTrainer(Trainer):
             from distkeras_tpu import checkpoint as ckpt
 
             if ckpt.latest_step(self.checkpoint_dir) is not None:
-                payload, _ = ckpt.restore_checkpoint(self.checkpoint_dir)
-                restored = payload
-                start_epoch = int(payload["epoch"]) + 1
+                with _phase("train.init_weights", source="checkpoint"):
+                    restored, _ = ckpt.restore_checkpoint(
+                        self.checkpoint_dir)
+                start_epoch = int(restored["epoch"]) + 1
         if restored is not None:
-            params, nt, opt = engine.place_state(
-                restored["params"], restored["nt"], restored["opt"]
-            )
+            with _phase("train.init_state"):
+                params, nt, opt = engine.place_state(
+                    restored["params"], restored["nt"], restored["opt"]
+                )
         else:
-            p0, nt0 = (self._init_cache if getattr(self, "_init_cache", None)
-                       else self.spec.init_np(self.seed))
-            params, nt, opt = engine.init_state(to_engine(p0), nt0)
+            if not getattr(self, "_init_cache", None):
+                with _phase("train.init_weights"):
+                    self._init_cache = self.spec.init_np(self.seed)
+            p0, nt0 = self._init_cache
+            with _phase("train.init_state"):
+                params, nt, opt = engine.init_state(to_engine(p0), nt0)
         self._init_cache = None
+
+        def end_epoch(epoch, rows, steps, t0, fetch, ready=None):
+            """From the last step's return to the loop's next turn;
+            ``fetch`` is what ``log_metrics`` syncs on (None: no sync)."""
+            with _phase("train.epoch_end", epoch=epoch) as sp:
+                # an epoch that was handed no batch leaves no entry
+                sp.log = bool(steps)
+                if fetch is not None:
+                    with _phase("train.drain"):
+                        jax.block_until_ready(ready)
+                        _drain(fetch)
+                    with _phase("train.log_metrics"):
+                        self._epoch_metrics(epoch, rows, steps,
+                                            time.perf_counter() - t0)
+                if validator is not None:
+                    with _phase("train.validate"):
+                        run_validation(epoch)
+                if self.checkpoint_dir:
+                    with _phase("train.checkpoint"):
+                        self._maybe_checkpoint(params, nt, opt, epoch)
 
         use_resident = {
             "stream": False, "resident": True,
@@ -1937,74 +1971,82 @@ class MeshTrainer(Trainer):
         self.record_training_start()
         with ctx:
             if use_resident:
-                staged = engine.stage_epoch(tuple(ds[c] for c in cols))
+                with _phase("train.stage_epoch"):
+                    staged = engine.stage_epoch(tuple(ds[c] for c in cols))
                 rows = (staged[0].shape[0] // self.batch_size) \
                     * self.batch_size
+                steps = rows // self.batch_size
                 for epoch in range(start_epoch, self.num_epoch):
-                    seed = (self.seed + epoch) if shuffle else None
-                    t0 = time.perf_counter() if self.log_metrics else 0.0
-                    params, nt, opt, losses = engine.run_epoch_resident(
-                        params, nt, opt, staged, self.batch_size, seed
-                    )
-                    self.history.append(losses=losses, epoch=epoch)
-                    if self.log_metrics:
+                    with _phase("train.epoch", epoch=epoch, steps=steps):
+                        seed = (self.seed + epoch) if shuffle else None
+                        t0 = time.perf_counter() if self.log_metrics else 0.0
+                        params, nt, opt, losses = engine.run_epoch_resident(
+                            params, nt, opt, staged, self.batch_size, seed
+                        )
+                        self.history.append(losses=losses, epoch=epoch)
                         # params too: loss scalars can stream back before
                         # the epoch's update compute drains
-                        jax.block_until_ready(params)
-                        _drain(losses)
-                        self._epoch_metrics(
-                            epoch, rows, rows // self.batch_size,
-                            time.perf_counter() - t0,
-                        )
-                    run_validation(epoch)
-                    self._maybe_checkpoint(params, nt, opt, epoch)
+                        end_epoch(epoch, rows, steps, t0,
+                                  losses if self.log_metrics else None,
+                                  ready=params)
             else:
+                step_num = 0
                 for epoch in range(start_epoch, self.num_epoch):
-                    seed = (self.seed + epoch) if shuffle else None
-                    t0 = time.perf_counter() if self.log_metrics else 0.0
-                    n_steps = 0
-                    batch_iter = ds.batches(self.batch_size, cols, seed=seed)
-                    if self.prefetch:
-                        batch_iter = prefetch_to_device(
-                            batch_iter, engine.place_batch,
-                            depth=self.prefetch,
-                        )
-                    for b in batch_iter:
-                        params, nt, opt, loss = engine.run_step(
-                            params, nt, opt, b
-                        )
-                        if ema_step is not None:
-                            ema = ema_step(ema, params)
-                        self.history.append(loss=loss, epoch=epoch)
-                        n_steps += 1
-                    if self.log_metrics and n_steps:
-                        _drain(loss)
-                        self._epoch_metrics(
-                            epoch, n_steps * self.batch_size, n_steps,
-                            time.perf_counter() - t0,
-                        )
-                    run_validation(epoch)
-                    self._maybe_checkpoint(params, nt, opt, epoch)
-        jax.block_until_ready(jax.tree.leaves(params)[0])
-        self._finish_checkpoints()
-        self.record_training_end()
-        self._materialize_history()
-        if jax.process_count() > 1:
-            # gather sharded leaves to host: under jax.distributed some
-            # shards live on devices this controller cannot address
-            from jax.experimental import multihost_utils
+                    with _phase("train.epoch", epoch=epoch) as ep:
+                        seed = (self.seed + epoch) if shuffle else None
+                        t0 = time.perf_counter() if self.log_metrics else 0.0
+                        n_steps = 0
+                        with _trace.span("train.input", profile=True):
+                            batch_iter = ds.batches(self.batch_size, cols,
+                                                    seed=seed)
+                            if self.prefetch:
+                                batch_iter = prefetch_to_device(
+                                    batch_iter, engine.place_batch,
+                                    depth=self.prefetch,
+                                )
+                            batch_iter = iter(batch_iter)
+                        while True:
+                            with _trace.span("train.input", profile=True):
+                                b = next(batch_iter, None)
+                            if b is None:
+                                break
+                            with _trace.span("train.step", profile=True,
+                                             step=step_num):
+                                params, nt, opt, loss = engine.run_step(
+                                    params, nt, opt, b
+                                )
+                            if ema_step is not None:
+                                ema = ema_step(ema, params)
+                            self.history.append(loss=loss, epoch=epoch)
+                            n_steps += 1
+                            step_num += 1
+                        ep.args["steps"] = n_steps
+                        ep.log = bool(n_steps)
+                        end_epoch(epoch, n_steps * self.batch_size, n_steps,
+                                  t0, loss if self.log_metrics and n_steps
+                                  else None)
+        with _phase("train.finish"):
+            jax.block_until_ready(jax.tree.leaves(params)[0])
+            self._finish_checkpoints()
+            self.record_training_end()
+            self._materialize_history()
+        with _phase("train.fetch_params"):
+            if jax.process_count() > 1:
+                # gather sharded leaves to host: under jax.distributed some
+                # shards live on devices this controller cannot address
+                from jax.experimental import multihost_utils
 
-            params = multihost_utils.process_allgather(params, tiled=True)
+                params = multihost_utils.process_allgather(params, tiled=True)
+                if ema is not None:
+                    ema = multihost_utils.process_allgather(ema, tiled=True)
             if ema is not None:
-                ema = multihost_utils.process_allgather(ema, tiled=True)
-        if ema is not None:
-            self.ema_params_ = from_engine(
-                jax.tree.map(np.asarray, jax.device_get(ema))
-            )
-        return self._finalize(
-            from_engine(jax.tree.map(np.asarray, jax.device_get(params))),
-            jax.tree.map(np.asarray, jax.device_get(nt)),
-        )
+                self.ema_params_ = from_engine(
+                    jax.tree.map(np.asarray, jax.device_get(ema))
+                )
+            host_params = from_engine(
+                jax.tree.map(np.asarray, jax.device_get(params)))
+            host_nt = jax.tree.map(np.asarray, jax.device_get(nt))
+        return self._finalize(host_params, host_nt)
 
     def _maybe_checkpoint(self, params, nt, opt, epoch: int):
         if not self.checkpoint_dir:

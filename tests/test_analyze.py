@@ -231,8 +231,7 @@ def test_serving_report_and_queue_regime():
         ev("serve.request", 5, 95, corr="r2", tid=5,
            args={"state": "done"}),
         ev("serve.queued", 5, 60, corr="r2", tid=5),
-        ev("serve.decode_step", 80, 5, tid=5, args={"rows": 4,
-                                                    "batch": 4}),
+        ev("serve.decode_step", 80, 5, tid=5, args={"rows": 4}),
         ev("serve.decode_step", 85, 15, tid=5, args={"rows": 8}),
     ]
     rep = an.analyze_events(evs, host_cores=8)

@@ -150,6 +150,26 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     assert "tpu_custom_call" in text, "no Pallas kernel in the program"
 
 
+NAMED = {
+    "flash-fwdbwd-keymask-noncausal-d64": ("flash_fwd", "flash_dq",
+                                           "flash_dkv"),
+    "lstm-fwdbwd-T200-H128-B32": ("lstm_scan_fwd", "lstm_scan_bwd"),
+    "fused-adam-16384x1024": ("fused_adam",),
+    "q_matmul-8x2048x2048": ("q_matmul",),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NAMED))
+def test_kernel_carries_its_name_for_v5e(one_chip, case):
+    """``pallas_call(name=...)`` survives into the compiled program: it is
+    what a profiler trace of the chip shows for the kernel's operation."""
+    fn, args = KERNELS[case]()
+    shapes = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    text = jax.jit(fn).lower(*shapes).compile().as_text()
+    for name in NAMED[case]:
+        assert name in text, f"{name} is not in the compiled program"
+
+
 # -- the whole LM train step ---------------------------------------------------
 
 
@@ -202,6 +222,10 @@ def test_lm_train_step_compiles_with_its_kernels(topo, monkeypatch, dp,
 
     text = compiled.as_text()
     assert text.count(chip_smoke.KERNEL_CALL) == 24
+    # what a trace of the chip names: the program, and each kernel 8 times
+    assert "HloModule jit_train_step" in text
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert text.count(name) >= 8, name
     assert (" all-gather(" in text) == (dp > 1)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8 * 2 ** 30, mem
