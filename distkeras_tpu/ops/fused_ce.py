@@ -12,12 +12,30 @@ each chunk's ``[chunk, V]`` logits live only for one scan step, XLA fuses
 the matmul with the log-sum-exp that consumes it, and the full logits
 tensor never exists in HBM — forward *or* backward.
 
-The backward is a :func:`jax.custom_vjp` that recomputes each chunk's
-logits from the saved ``hidden`` (the flash-attention trade: FLOPs for
-HBM), forms ``dlogits = softmax − onehot`` chunk-locally, and accumulates
-``d_kernel`` in an f32 carry. Peak extra memory is
-``O(chunk · V)`` activations + one f32 kernel-shaped accumulator, instead
-of ``O(N · V)``.
+The backward is a :func:`jax.custom_vjp` that walks the **vocabulary**,
+not the rows. The forward saves each row's log-sum-exp (``[N]`` f32), so
+the backward needs no softmax reduction of its own: for a tile of ``Vb``
+vocabulary columns it recomputes ``hidden @ kernel[:, tile]`` (the
+flash-attention trade: FLOPs for HBM), forms
+``dlogits = (exp(logits − lse) − onehot) · mask · g/Σmask`` tile-locally,
+writes ``d_kernel[:, tile] = hiddenᵀ @ dlogits`` (and ``d_bias[tile]``)
+**once, in the kernel's dtype, as the loop's output**, and adds
+``dlogits @ kernel[:, tile]ᵀ`` into the loop's only carry, the ``[N, D]``
+f32 hidden gradient. A loop over row chunks would have to carry the
+kernel's gradient instead — an f32 ``[D, V]`` array read and written once
+a chunk, which at V = 256k is most of the step's memory traffic.
+
+The tile width follows from the shapes: with ``steps = ⌈N / chunk⌉`` row
+chunks in the forward, ``Vb = 128 · ⌈⌈V / steps⌉ / 128⌉`` (the forward's
+``chunk × V`` logits budget spread over all ``N`` rows, rounded up to
+whole 128-lane tiles) and the backward makes ``tiles = ⌈V / Vb⌉`` steps
+(:func:`_vocab_tiles`); the kernel is zero-padded to ``tiles · Vb``
+columns and the padded columns are masked to ``p = 0``, so they get no
+gradient and give none. When one row chunk holds every row
+(``N ≤ chunk``), or ``Vb ≥ V``, there is one tile of width ``V`` and no
+padding. Peak extra memory is ``O(chunk · V)`` activations (``O(N · Vb)``
+in the backward — the same size) plus the ``[N, D]`` f32 carry, instead of
+``O(N · V)``.
 
 This is a compiler-level fusion, not a Pallas kernel, on purpose: the
 chunk matmul ``[chunk, D] · [D, V]`` is exactly MXU-shaped, and XLA already
@@ -44,27 +62,45 @@ import jax.numpy as jnp
 import numpy as np
 
 
+def _cdiv(a: int, b: int) -> int:
+    return -(-a // b)
+
+
 def _chunk_rows(n: int, chunk: int) -> tuple[int, int]:
     """Number of scan steps and padded row count."""
-    steps = max(1, -(-n // chunk))
+    steps = max(1, _cdiv(n, chunk))
     return steps, steps * chunk
 
 
-def _pad_to(x, rows):
-    n = x.shape[0]
-    if n == rows:
+def _vocab_tiles(n: int, v: int, chunk: int) -> tuple[int, int]:
+    """Number of backward tiles and their width ``Vb`` (module docstring).
+
+    ``Vb = 128 · ⌈⌈V / steps⌉ / 128⌉`` with ``steps = ⌈N / chunk⌉`` and
+    ``tiles = ⌈V / Vb⌉``; one tile of width ``V`` when ``Vb ≥ V``.
+    """
+    steps, _ = _chunk_rows(n, chunk)
+    vb = 128 * _cdiv(_cdiv(v, steps), 128)
+    if vb >= v:
+        return 1, v
+    return _cdiv(v, vb), vb
+
+
+def _pad_to(x, size, axis=0):
+    n = x.shape[axis]
+    if n == size:
         return x
-    pad = [(0, rows - n)] + [(0, 0)] * (x.ndim - 1)
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, size - n)
     return jnp.pad(x, pad)
 
 
-def _chunk_logits(h_c, kernel, bias):
-    """One chunk's logits in f32: ``[chunk, D] @ [D, V] (+ bias)``.
+def _logits(h, kernel, bias):
+    """``h @ kernel (+ bias)`` in f32: ``[rows, D] @ [D, cols]``.
 
     The matmul runs in the params' dtype (bf16 on TPU → MXU) with f32
     accumulation; the softmax math downstream is all f32.
     """
-    logits = jnp.dot(h_c, kernel, preferred_element_type=jnp.float32)
+    logits = jnp.dot(h, kernel, preferred_element_type=jnp.float32)
     logits = logits.astype(jnp.float32)
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
@@ -85,65 +121,71 @@ def _fused_ce_fwd(hidden, kernel, bias, labels, mask, chunk):
     m = _pad_to(mask, rows).reshape(steps, chunk)
 
     def body(total, args):
-        h_c, lab_c, m_c = args
-        logits = _chunk_logits(h_c, kernel, bias)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, lab_c[:, None], axis=-1)[:, 0]
-        return total + jnp.sum((lse - picked) * m_c), None
+        with jax.named_scope("fused_ce_fwd"):
+            h_c, lab_c, m_c = args
+            logits = _logits(h_c, kernel, bias)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            picked = jnp.take_along_axis(logits, lab_c[:, None], axis=-1)[:, 0]
+            nll = lse - picked
+            return total + jnp.sum(nll * m_c), (lse, nll)
 
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (h, lab, m))
+    total, (lse, nll) = jax.lax.scan(
+        body, jnp.zeros((), jnp.float32), (h, lab, m))
     msum = jnp.sum(mask)
     denom = jnp.maximum(msum, 1.0)
-    return total / denom, (hidden, kernel, bias, labels, mask, total, msum)
+    res = (hidden, kernel, bias, labels, mask, total, msum,
+           lse.reshape(rows)[:n], nll.reshape(rows)[:n])
+    return total / denom, res
 
 
 def _fused_ce_bwd(chunk, res, g):
-    hidden, kernel, bias, labels, mask, total, msum = res
+    hidden, kernel, bias, labels, mask, total, msum, lse, nll = res
     n, d = hidden.shape
-    steps, rows = _chunk_rows(n, chunk)
-    h = _pad_to(hidden, rows).reshape(steps, chunk, d)
-    lab = _pad_to(labels, rows).reshape(steps, chunk)
-    m = _pad_to(mask, rows).reshape(steps, chunk)
     v = kernel.shape[1]
+    tiles, vb = _vocab_tiles(n, v, chunk)
     denom = jnp.maximum(msum, 1.0)
-    scale = g / denom
+    weight = mask * (g / denom)
+    padded = tiles * vb > v
+    # [D, V] → [tiles, D, Vb]; zero columns past V, masked out of p below
+    k = _pad_to(kernel, tiles * vb, axis=1).reshape(d, tiles, vb)
+    xs = (
+        jnp.arange(tiles, dtype=jnp.int32) * vb,
+        k.transpose(1, 0, 2),
+        None if bias is None else _pad_to(bias, tiles * vb).reshape(tiles, vb),
+    )
 
-    def body(carry, args):
-        dk, db = carry
-        h_c, lab_c, m_c = args
-        logits = _chunk_logits(h_c, kernel, bias)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        picked = jnp.take_along_axis(logits, lab_c[:, None], axis=-1)[:, 0]
-        p = jax.nn.softmax(logits, axis=-1)
-        dlogits = p - jax.nn.one_hot(lab_c, v, dtype=p.dtype)
-        dlogits = dlogits * (m_c * scale)[:, None]
-        # dh in the hidden dtype (bf16 matmul on the MXU), dk accumulated f32
-        dh_c = jnp.dot(
-            dlogits.astype(hidden.dtype), kernel.T,
-            preferred_element_type=jnp.float32,
-        ).astype(hidden.dtype)
-        dk = dk + jnp.dot(
-            h_c.T, dlogits.astype(hidden.dtype),
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.float32)
-        if bias is not None:  # no [V] carry/reduction for bias-free heads
-            db = db + jnp.sum(dlogits, axis=0)
-        return (dk, db), (dh_c, lse - picked)
+    def body(dh, args):
+        with jax.named_scope("fused_ce_bwd"):
+            first, k_b, bias_b = args
+            col = first + jnp.arange(vb, dtype=jnp.int32)
+            logits = _logits(hidden, k_b, bias_b)
+            p = jnp.exp(logits - lse[:, None])
+            if padded:
+                p = jnp.where(col < v, p, 0.0)
+            onehot = (labels[:, None] == col).astype(p.dtype)
+            dlogits = (p - onehot) * weight[:, None]
+            # both products in the hidden dtype (bf16 → MXU), f32 accumulation
+            dl = dlogits.astype(hidden.dtype)
+            dh = dh + jnp.dot(dl, k_b.T, preferred_element_type=jnp.float32)
+            dk_b = jnp.dot(
+                hidden.T, dl, preferred_element_type=jnp.float32,
+            ).astype(kernel.dtype)
+            # no [Vb] reduction for bias-free heads
+            db_b = (None if bias is None
+                    else jnp.sum(dlogits, axis=0).astype(bias.dtype))
+            return dh, (dk_b, db_b)
 
-    zero_db = (jnp.zeros((), jnp.float32) if bias is None
-               else jnp.zeros((v,), jnp.float32))
-    zero = (jnp.zeros((d, v), jnp.float32), zero_db)
-    (dk, db), (dh, nll) = jax.lax.scan(body, zero, (h, lab, m))
-    dh = dh.reshape(rows, d)[:n]
+    dh, (dk, db) = jax.lax.scan(body, jnp.zeros((n, d), jnp.float32), xs)
+    dk = dk.transpose(1, 0, 2).reshape(d, tiles * vb)[:, :v]
+    dbias = None if bias is None else db.reshape(tiles * vb)[:v]
     # loss = T/D with T = Σ nll_i·m_i, D = max(Σm, 1):
     # ∂loss/∂m_i = nll_i/D − T·[Σm > 1]/D² — the same weights a caller
     # differentiating the unfused masked mean would get
     ddenom = jnp.where(msum > 1.0, 1.0, 0.0)
-    dmask = g * (nll.reshape(rows)[:n] / denom - total * ddenom / denom**2)
-    dbias = None if bias is None else db.astype(bias.dtype)
+    dmask = g * (nll / denom - total * ddenom / denom**2)
     return (
-        dh,
-        dk.astype(kernel.dtype),
+        dh.astype(hidden.dtype),
+        dk,
         dbias,
         np.zeros(labels.shape, dtype=jax.dtypes.float0),
         dmask.astype(mask.dtype),
@@ -170,7 +212,8 @@ def chunked_softmax_cross_entropy(hidden, labels, kernel, bias=None, *,
       bias: optional ``[V]`` head bias.
       mask: optional ``[N]`` validity weights; loss is
         ``sum(nll · mask) / max(sum(mask), 1)``. Default: all rows valid.
-      chunk: rows per scan step — peak logits memory is ``chunk × V`` f32.
+      chunk: rows per scan step — peak logits memory is ``chunk × V`` f32
+        (the backward's ``[N, Vb]`` vocabulary tile is sized to the same).
     """
     hidden = jnp.asarray(hidden)
     if hidden.ndim != 2:

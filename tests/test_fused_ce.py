@@ -118,6 +118,151 @@ def test_bf16_params_close_to_f32_oracle(rng):
     assert float(rel.max()) <= 5e-2 * float(np.abs(np.asarray(gr)).max()) + 1e-4
 
 
+# every edge of the backward's vocabulary tiling (``_vocab_tiles``): V not a
+# multiple of 128 lanes or of the tile, N not a multiple of ``chunk``, one
+# tile (``N <= chunk``), with and without a bias, both dtypes
+_TILING_CASES = [
+    # n, d, v, chunk, bias, dtype
+    (37, 16, 101, 8, True, "float32"),      # vb 128 >= V: one tile
+    (37, 16, 101, 64, False, "float32"),    # N <= chunk: one tile
+    (96, 16, 1000, 16, True, "float32"),    # 6 chunks -> vb 256, 4 tiles
+    (96, 16, 1000, 16, False, "bfloat16"),
+    (100, 8, 1000, 16, True, "bfloat16"),   # N % chunk != 0: 7 chunks, 4 tiles
+    (512, 32, 1000, 64, False, "float32"),  # vb 128, 8 tiles, 24 padded cols
+    (64, 8, 4099, 8, True, "float32"),      # vb 640, 7 tiles, 381 padded cols
+    (64, 8, 4099, 8, False, "bfloat16"),
+    (50, 8, 4099, 7, True, "bfloat16"),     # 8 chunks -> vb 640, ragged rows
+    (48, 8, 256, 24, True, "float32"),      # V a multiple of the tile: no pad
+    (16, 8, 4099, 16, True, "float32"),     # N == chunk at a wide V: one tile
+]
+
+
+@pytest.mark.parametrize("n,d,v,chunk,use_bias,dtype", _TILING_CASES)
+def test_value_and_all_gradients_across_tiling_edges(rng, n, d, v, chunk,
+                                                     use_bias, dtype):
+    """Loss and the gradients of hidden, kernel, bias and mask against the
+    unfused oracle (float32 operands); a label in the last real column and
+    masked rows in every case."""
+    h, y, w, b = _problem(rng, n=n, d=d, v=v)
+    y[0] = y[n - 1] = v - 1
+    mask = rng.uniform(0.2, 1.0, size=n).astype(np.float32)
+    mask[rng.uniform(size=n) < 0.25] = 0.0
+    mask[0], mask[1] = 1.0, 0.0
+    low = jnp.dtype(dtype)
+    # the oracle sees the operands as the op does (rounded once), in float32
+    h32 = jnp.asarray(h).astype(low).astype(jnp.float32)
+    w32 = jnp.asarray(w).astype(low).astype(jnp.float32)
+    bias = jnp.asarray(b) if use_bias else None
+
+    def fused(h, w, b, m):
+        return chunked_softmax_cross_entropy(h, y, w, b, mask=m, chunk=chunk)
+
+    def oracle(h, w, b, m):
+        return _oracle(h, y, w, b, mask=m)
+
+    argnums = (0, 1, 2, 3) if use_bias else (0, 1, 3)
+    lf, gf = jax.value_and_grad(fused, argnums)(
+        h32.astype(low), w32.astype(low), bias, jnp.asarray(mask))
+    lr, gr = jax.value_and_grad(oracle, argnums)(
+        h32, w32, bias, jnp.asarray(mask))
+    exact = low == jnp.float32
+    np.testing.assert_allclose(float(lf), float(lr),
+                               rtol=1e-6 if exact else 1e-5)
+    assert gf[0].dtype == low and gf[1].dtype == low
+    assert gf[0].shape == (n, d) and gf[1].shape == (d, v)
+    for a, e in zip(gf, gr):
+        a, e = np.asarray(a, np.float32), np.asarray(e)
+        # bf16: dlogits and the written gradients are rounded to 8 bits
+        tol = 1e-7 if exact else 2e-2 * float(np.abs(e).max())
+        np.testing.assert_allclose(a, e, rtol=2e-5 if exact else 2e-2,
+                                   atol=tol)
+    assert np.all(np.asarray(gf[0], np.float32)[mask == 0.0] == 0.0)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+def _count(jaxpr, *names):
+    return sum(e.primitive.name in names for e in _eqns(jaxpr))
+
+
+def test_backward_walks_vocabulary_tiles_and_carries_no_kernel(rng):
+    """The finding of PERF.md §6 (PR 26), held structurally: the backward is
+    one loop over ``_vocab_tiles`` vocabulary tiles whose only carry is the
+    ``[N, D]`` float32 hidden gradient — no loop carries a kernel-shaped
+    array — and the log-sum-exp comes from the forward, so a tile holds one
+    logits product and the two gradient products, not a second logits pass."""
+    from distkeras_tpu.ops.fused_ce import _vocab_tiles
+
+    n, d, v, chunk = 512, 32, 1000, 64
+    assert _vocab_tiles(n, v, chunk) == (8, 128)
+    # the benchmark's shape, and a head that fits one row chunk
+    assert _vocab_tiles(16384, 256008, 256) == (63, 4096)
+    assert _vocab_tiles(200, 50000, 256) == (1, 50000)
+    h, y, w, _ = _problem(rng, n=n, d=d, v=v)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda h, w: chunked_softmax_cross_entropy(h, y, w, None,
+                                                   chunk=chunk),
+        argnums=(0, 1)))(h, w).jaxpr
+    loops = [e for e in _eqns(jaxpr) if e.primitive.name in ("scan", "while")]
+    assert [e.primitive.name for e in loops] == ["scan", "scan"]
+    fwd, bwd = loops
+    assert fwd.params["length"] == n // chunk
+    assert bwd.params["length"] == _vocab_tiles(n, v, chunk)[0]
+
+    def carries(eqn):
+        k, c = eqn.params["num_consts"], eqn.params["num_carry"]
+        return [tuple(x.aval.shape) for x in eqn.invars[k:k + c]]
+
+    assert carries(fwd) == [()]
+    assert carries(bwd) == [(n, d)]
+    for eqn in loops:
+        for shape in carries(eqn):
+            assert int(np.prod(shape)) < d * v, shape
+    # d_kernel leaves the loop as its stacked output, one tile a step
+    assert (8, d, 128) in [tuple(x.aval.shape) for x in bwd.outvars]
+    # one logits product in the forward; logits, d_hidden, d_kernel in a tile
+    assert _count(fwd.params["jaxpr"].jaxpr, "dot_general") == 1
+    assert _count(bwd.params["jaxpr"].jaxpr, "dot_general") == 3
+    assert _count(jaxpr, "dot_general") == 4
+    # the backward takes no log-sum-exp of its own
+    assert _count(bwd.params["jaxpr"].jaxpr, "reduce_max") == 0
+    # the compiled step's op_name metadata says whose operations these are
+    for eqn, scope in ((fwd, "fused_ce_fwd"), (bwd, "fused_ce_bwd")):
+        dots = [e for e in eqn.params["jaxpr"].jaxpr.eqns
+                if e.primitive.name == "dot_general"]
+        assert all(scope in str(e.source_info.name_stack) for e in dots)
+
+
+def test_sharded_vocabulary_and_rows_give_the_same_gradients(rng):
+    """The megatron layout shards the head over its vocabulary and data
+    parallelism shards the rows: the backward slices both by tile inside one
+    jit, and GSPMD must keep value and gradients what one device computes."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    n, d, v, chunk = 96, 16, 1000, 16          # 4 tiles of 256, 24 padded
+    h, y, w, _ = _problem(rng, n=n, d=d, v=v)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    hs = jax.device_put(h, NamedSharding(mesh, P("dp", None)))
+    ws = jax.device_put(w, NamedSharding(mesh, P(None, "tp")))
+
+    def fused(h, w):
+        return chunked_softmax_cross_entropy(h, y, w, None, chunk=chunk)
+
+    ls, gs = jax.jit(jax.value_and_grad(fused, argnums=(0, 1)))(hs, ws)
+    lr, gr = jax.value_and_grad(
+        lambda h, w: _oracle(h, y, w, None), argnums=(0, 1))(h, w)
+    np.testing.assert_allclose(float(ls), float(lr), rtol=1e-6)
+    for a, e in zip(gs, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(e),
+                                   rtol=2e-5, atol=1e-7)
+
+
 def test_shape_validation(rng):
     h, y, w, b = _problem(rng, n=8, d=4, v=11)
     with pytest.raises(ValueError, match="rows, dim"):
