@@ -6,7 +6,7 @@ a user calls, at the full width of models the repo supports, and checks what
 comes out by the repo's own means. Run it through the chip tool from the root
 of a checkout:
 
-    python3 chip_smoke.py              # one chip: kernels, adag, lm, serve
+    python3 chip_smoke.py              # one chip: kernels, adag, lm, moe, serve
     python3 chip_smoke.py --chips 4    # four chips: adag4, lm4 (and no other)
 
 Each phase prints one JSON line (its name, seconds, what it checked); the last
@@ -362,6 +362,95 @@ def lm(*, vocab=16384, maxlen=2048, dim=1024, heads=8, depth=8, ce_chunk=512,
 
 
 # ---------------------------------------------------------------------------
+# moe: MeshTrainer on the ZAYA1 block (CCA + dropless routed experts)
+# ---------------------------------------------------------------------------
+
+
+def _zaya_spec(vocab, maxlen, dim, heads, kv_heads, depth, zaya, ce_chunk,
+               plain=False):
+    """``_lm_spec`` with ZAYA1 blocks: the kernel path under remat, or its
+    float32 twin (reference attention, unfused loss)."""
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models import transformer_lm
+
+    kw = dict(vocab=vocab, maxlen=maxlen, dim=dim, heads=heads,
+              kv_heads=kv_heads, depth=depth, pos_embedding="rope",
+              tie_embeddings=True, zaya=zaya)
+    if plain:
+        return transformer_lm(dtype=jnp.float32, attn_impl="reference",
+                              fused_ce=False, **kw)
+    return transformer_lm(dtype=jnp.bfloat16, attn_impl="flash",
+                          fused_ce=True, ce_chunk=ce_chunk, remat=True, **kw)
+
+
+def moe(*, vocab=8192, maxlen=1024, dim=512, heads=4, kv_heads=2, depth=2,
+        head_dim=128, router_dim=128, experts=8, experts_held=(0, 4),
+        expert_dim=512, ce_chunk=256, batch=4, steps=4, epochs=2,
+        kernel_calls=30):
+    """``MeshTrainer(...).train`` on ``transformer_lm(zaya=...)`` in bf16 with
+    flash attention, the fused cross-entropy and remat, holding half the
+    router's experts: every loss finite, the first equal to the plain float32
+    model's on the same batch and parameters to bf16 tolerance, the compiled
+    step holding ``kernel_calls`` kernel calls (flash attention's and the
+    grouped expert products'), and the counters the trainer fetched adding
+    up to every token once a layer."""
+    import jax
+
+    from distkeras_tpu.models import ZayaDims
+    from distkeras_tpu.models.lm import moe_tokens
+    from distkeras_tpu.ops import get_loss
+
+    t0 = time.perf_counter()
+    dims = ZayaDims(head_dim=head_dim, router_dim=router_dim, experts=experts,
+                    experts_held=tuple(experts_held), expert_dim=expert_dim)
+    model = (vocab, maxlen, dim, heads, kv_heads, depth, dims, ce_chunk)
+    spec, plain = _zaya_spec(*model), _zaya_spec(*model, plain=True)
+    ds = _token_dataset(vocab, maxlen, batch * steps)
+    from distkeras_tpu.trainers import MeshTrainer
+
+    # the benchmark cell's path: streamed batches, the loss and the
+    # counters fetched at each epoch's end
+    trainer = MeshTrainer(
+        spec, loss="sparse_softmax_cross_entropy", worker_optimizer="adam",
+        learning_rate=1e-4, mesh_shape={"dp": 1}, batch_size=batch,
+        num_epoch=epochs, input_mode="stream", log_metrics=True, seed=SEED)
+    trainer.train(ds)
+    losses = trainer.get_history().losses()
+    routed = moe_tokens(trainer.counters_)
+
+    p0, nt0 = spec.init_np(SEED)
+    compiled, _ = _compiled_step(trainer, (p0, nt0), ds, batch)
+    calls = compiled.as_text().count(KERNEL_CALL)
+    x, y = ds["features"][:batch], ds["label"][:batch]
+    loss_fn = get_loss("sparse_softmax_cross_entropy")
+    with jax.default_matmul_precision("highest"):
+        want = float(jax.jit(
+            # in training mode, as the step: the routers balance their bias
+            lambda p, nt, x, y: loss_fn(y, plain.apply(p, nt, x, True)[0])
+        )(p0, nt0, x, y))
+
+    first, count = experts_held
+    line = _report(
+        "moe", t0, steps=len(losses), losses=[round(v, 4) for v in losses],
+        plain_f32_first_loss=want, kernel_calls_in_step=calls,
+        tokens_by_layer_and_expert=routed.tolist(),
+        held_share=float(routed[:, first:first + count].sum() / routed.sum()),
+    )
+    _check(len(losses) == steps * epochs,
+           f"moe: {len(losses)} losses for {steps * epochs} steps")
+    _check(all(math.isfinite(v) for v in losses), "moe: non-finite loss")
+    _check(abs(losses[0] - want) <= 1e-2 * abs(want),
+           f"moe: first loss {losses[0]:.5f} vs plain float32 {want:.5f}")
+    _check(calls == kernel_calls,
+           f"moe: {calls} kernel calls in the compiled step, expected "
+           f"{kernel_calls}")
+    _check(routed.sum(1).tolist() == [batch * steps * epochs * maxlen] * depth,
+           f"moe: the counters hold {routed.sum(1).tolist()} tokens a layer")
+    return line
+
+
+# ---------------------------------------------------------------------------
 # serve: GenerationEngine behind GenerationServer, four concurrent clients
 # ---------------------------------------------------------------------------
 
@@ -617,7 +706,7 @@ def main(argv=None) -> int:
     from distkeras_tpu.observability import trace
 
     for phase in ((adag4, lm4) if args.chips == 4
-                  else (kernels, adag, lm, serve)):
+                  else (kernels, adag, lm, moe, serve)):
         phase()
     counts = trace.jax_counts()
     print(json.dumps({"phase": "cache", "dir": cache_dir,

@@ -33,6 +33,7 @@ from distkeras_tpu.models.moe import (
 )
 from distkeras_tpu.models.lm import (
     TransformerLM,
+    ZayaDims,
     beam_search,
     generate,
     speculative_generate,
@@ -60,7 +61,7 @@ __all__ = [
     "pipelined_transformer_forward",
     "sequence_parallel_transformer_forward",
     "MoETransformerClassifier", "moe_transformer_classifier",
-    "TransformerLM", "transformer_lm", "generate", "beam_search",
+    "TransformerLM", "ZayaDims", "transformer_lm", "generate", "beam_search",
     "speculative_generate",
     "next_token_dataset", "quantize_lm",
 ]

@@ -30,6 +30,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 
 from distkeras_tpu.model import ModelSpec, from_flax
 from distkeras_tpu.models.transformer import sincos_positions
@@ -367,6 +368,303 @@ class DecoderBlock(nn.Module):
         return self._mlp(x), k_pool, v_pool
 
 
+@dataclasses.dataclass(frozen=True)
+class ZayaDims:
+    """The sizes of a ZAYA1 block (:class:`ZayaBlock`) that ``dim``, ``heads``
+    and ``kv_heads`` do not give. Defaults are ZAYA1-8B's published ones."""
+
+    head_dim: int = 128
+    #: kernel lengths of CCA's two causal convolutions over the sequence
+    #: (``cca_time0`` depthwise, ``cca_time1`` grouped by head)
+    conv_kernels: tuple = (2, 2)
+    rotary_fraction: float = 0.5      # leading share of a head that is rotated
+    rope_base: float = 5e6
+    router_dim: int = 256
+    experts: int = 16                 # the router's width
+    #: ``(first, count)``: the experts this model holds of all ``experts``
+    #: (an ``ep`` rank's share); ``None`` holds them all
+    experts_held: tuple | None = None
+    expert_dim: int = 2048
+    norm_eps: float = 1e-5
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.experts)
+
+
+def _delay(x, n: int):
+    """``x`` [B, S, C] moved ``n`` positions later, zeros in front."""
+    if not n:
+        return x
+    return jnp.pad(x, ((0, 0), (n, 0), (0, 0)))[:, : x.shape[1]]
+
+
+def _causal_depthwise(x, w):
+    """``out[t] = sum_j w[j] * x[t - (k-1) + j]``: one filter ``w[:, c]`` a
+    channel, left-padded with zeros."""
+    k = w.shape[0]
+    return sum(_delay(x, k - 1 - j) * w[j] for j in range(k))
+
+
+def _causal_grouped(x, w):
+    """The same over ``w`` [k, heads, dh, dh]: each head's channels mix among
+    themselves."""
+    k, heads, dh, _ = w.shape
+    B, S, _ = x.shape
+    return sum(
+        jnp.einsum("bshd,hde->bshe",
+                   _delay(x, k - 1 - j).reshape(B, S, heads, dh), w[j])
+        for j in range(k)).reshape(B, S, heads * dh)
+
+
+def _rescaled(mod, x, y):
+    """``(a * x + b) + (g * y + e)``: a ZAYA sublayer's learned scaling of
+    the residual stream ``x`` and of its own result ``y``."""
+    def vec(name, init):
+        return mod.param(name, init, (x.shape[-1],), jnp.float32)
+
+    a, b = vec("res_scale", nn.initializers.ones), vec("res_bias", nn.initializers.zeros)
+    g, e = vec("out_scale", nn.initializers.ones), vec("out_bias", nn.initializers.zeros)
+    return (a * x + b) + (g * y.astype(jnp.float32) + e)
+
+
+#: sweeps over the experts by which a training step balances a router's bias
+_BALANCE_SWEEPS = 2
+
+
+def _balanced_bias(p, bias, sweeps: int = _BALANCE_SWEEPS):
+    """The router's balancing bias after ``sweeps`` sweeps over the experts
+    from ``bias`` ``[E]``: in turn each expert's bias goes where exactly
+    ``T // E`` of ``p`` ``[T, E]``'s rows prefer it to the best of the others
+    (half-way between the two rows at that cut), the others' held; the mean
+    is taken off at the end. One sort of ``T`` numbers an expert a sweep.
+
+    A stand-in: ZAYA1's own balancing rule is a training procedure that its
+    ``config.json`` does not give. Without any, Adam on random weights sends
+    nearly every token of a layer to a few experts within tens of steps."""
+    T, E = p.shape
+    k = T // E
+
+    def one(i, b):
+        e = i % E
+        others = jnp.where(jnp.arange(E) == e, -jnp.inf, p + b)
+        margin = jnp.sort(jnp.max(others, axis=-1) - p[:, e])
+        return b.at[e].set(0.5 * (margin[k - 1] + margin[k]))
+
+    bias = jax.lax.fori_loop(0, sweeps * E, one, bias)
+    return bias - jnp.mean(bias)
+
+
+class CCAttention(nn.Module):
+    """ZAYA1's attention sublayer, compressed convolutional attention:
+    projections into a latent of ``heads`` / ``kv_heads`` heads of
+    ``z.head_dim``, two causal convolutions over the sequence on q and on k,
+    a value whose second half is the previous token's, the q-k mean,
+    L2-normalised q and k with a learned temperature a key head, rotary on
+    the leading ``z.rotary_fraction`` of each head, flash attention."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    z: ZayaDims
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "reference"
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from distkeras_tpu.ops.flash_attention import BLOCK_Q, attention
+
+        z, f32 = self.z, jnp.float32
+        B, S, _ = x.shape
+        H, K, dh = self.heads, self.kv_heads, z.head_dim
+        G = H // K
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        lecun = nn.initializers.lecun_normal()
+        h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
+        h = h.astype(self.dtype)
+        q0 = dense(H * dh, name="q")(h)
+        k0 = dense(K * dh, name="k")(h)
+        # the first half of the K/V heads carries this token's value, the
+        # second half the previous token's
+        v = jnp.concatenate(
+            [dense(K * dh // 2, name="v_now")(h),
+             _delay(dense(K * dh // 2, name="v_prev")(h), 1)], axis=-1)
+        t0, t1 = z.conv_kernels
+        conv = {
+            "q0": self.param("conv_q0", lecun, (t0, H * dh), f32),
+            "q1": self.param("conv_q1", lecun, (t1, H, dh, dh), f32),
+            "k0": self.param("conv_k0", lecun, (t0, K * dh), f32),
+            "k1": self.param("conv_k1", lecun, (t1, K, dh, dh), f32),
+        }
+        conv = {n: w.astype(self.dtype) for n, w in conv.items()}
+        with jax.named_scope("cca_conv"):
+            cq = _causal_grouped(_causal_depthwise(q0, conv["q0"]), conv["q1"])
+            ck = _causal_grouped(_causal_depthwise(k0, conv["k0"]), conv["k1"])
+        # the q-k mean: a query head is averaged with its key head, a key
+        # head with the mean of its group's query heads
+        qh = q0.astype(f32).reshape(B, S, K, G, dh)
+        kh = k0.astype(f32).reshape(B, S, K, 1, dh)
+        q = cq.astype(f32).reshape(B, S, K, G, dh) + (qh + kh) / 2
+        k = ck.astype(f32).reshape(B, S, K, dh) \
+            + (kh[:, :, :, 0] + jnp.mean(qh, axis=3)) / 2
+        tau = self.param("tau", nn.initializers.ones, (K,), f32)
+
+        def unit(a):
+            return a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True)) \
+                * dh ** 0.5
+
+        q = unit(q).reshape(B, S, H, dh)
+        k = unit(k) * tau[:, None]
+        rot = int(dh * z.rotary_fraction)
+        angles = jnp.asarray(rope_angles(S, rot, z.rope_base))
+        q, k = (jnp.concatenate([apply_rope(a[..., :rot], angles),
+                                 a[..., rot:]], -1) for a in (q, k))
+        impl = self.attn_impl
+        if impl == "flash" and S % BLOCK_Q:
+            impl = "reference"
+        o = attention(q.astype(self.dtype), k.astype(self.dtype),
+                      v.reshape(B, S, K, dh), causal=True, key_mask=mask,
+                      impl=impl)
+        o = dense(self.dim, name="out")(
+            o.reshape(B, S, H * dh).astype(self.dtype))
+        return _rescaled(self, x, o)
+
+
+class RoutedExperts(nn.Module):
+    """ZAYA1's expert sublayer: a float32 router MLP over a state ``r`` that
+    passes from layer to layer (``r = h Wd + gamma * r``), top-1 of all
+    ``z.experts``, and the held SwiGLU experts through
+    :func:`parallel.expert.dropless_experts`. ``__call__(x, r) -> (x, r)``.
+
+    The ``counters`` collection holds what no gradient reaches:
+    ``router_bias`` (float32 ``[experts]``), added to the probabilities
+    before the top-1 choice, and ``moe_tokens`` (int32 ``[experts]``). With
+    the collection mutable (a training step), ``router_bias`` is first
+    balanced on the step's own tokens (:func:`_balanced_bias` from where the
+    step before left it; the step routes with the result and leaves it for
+    the next), and ``moe_tokens`` is increased by the tokens routed to each
+    expert. Outside training the bias is used as it stands. With
+    ``intermediates`` mutable, ``moe_chosen`` holds every token's expert."""
+
+    dim: int
+    z: ZayaDims
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, r):
+        from distkeras_tpu.parallel.expert import dropless_experts
+
+        z, f32 = self.z, jnp.float32
+        B, S, d = x.shape
+        E, R, F = z.experts, z.router_dim, z.expert_dim
+        first, count = z.held
+        lecun = nn.initializers.lecun_normal()
+        # the router runs in float32 (``HIGHEST``: true float32 on a TPU)
+        rdense = functools.partial(nn.Dense, use_bias=False, dtype=f32,
+                                   precision=jax.lax.Precision.HIGHEST)
+        h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
+        gamma = self.param("router_gamma", nn.initializers.ones, (R,), f32)
+        r = rdense(R, name="router_down")(h) + gamma * r
+        s = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln_router")(r)
+        s = nn.gelu(rdense(R, name="router_w1")(s))
+        s = nn.gelu(rdense(R, name="router_w2")(s))
+        p = jax.nn.softmax(rdense(E, name="router_w3")(s), axis=-1)
+        # the balancing bias is state, not a parameter: argmax passes it no
+        # gradient, and a training step balances it on its own tokens
+        bias = self.variable("counters", "router_bias",
+                             lambda: jnp.zeros((E,), f32))
+        counting = self.is_mutable_collection("counters") \
+            and not self.is_initializing()
+        b = bias.value
+        if counting:
+            with jax.named_scope("moe_balance"):
+                # kept over remat: the backward pass does not sort again
+                b = checkpoint_name(_balanced_bias(
+                    jax.lax.stop_gradient(p).reshape(B * S, E), b),
+                    "router_bias")
+        chosen = jnp.argmax(p + b, axis=-1)
+        self.sow("intermediates", "moe_chosen", chosen)
+        weight = jnp.take_along_axis(p, chosen[..., None], -1)[..., 0]
+        w_in = self.param("experts_in", lecun, (count, d, 2 * F), f32)
+        w_out = self.param("experts_out", lecun, (count, F, d), f32)
+        y, tokens = dropless_experts(
+            h.astype(self.dtype).reshape(B * S, d),
+            chosen.reshape(B * S).astype(jnp.int32), weight.reshape(B * S),
+            w_in, w_out, experts=(first, count), total=E)
+        seen = self.variable("counters", "moe_tokens",
+                             lambda: jnp.zeros((E,), jnp.int32))
+        if counting:
+            seen.value = seen.value + tokens
+            bias.value = b
+        return _rescaled(self, x, y.reshape(B, S, d)), r
+
+
+class ZayaBlock(nn.Module):
+    """ZAYA1's layer: :class:`CCAttention`, then :class:`RoutedExperts`,
+    whose router state ``r`` travels beside the residual stream ``x``:
+    ``__call__`` takes and returns ``(x, r)``.
+
+    Training only: CCA's cache would hold a convolution tail and a shifted
+    value beside K/V, and the experts are not on the paged path, so the
+    serving entry points raise.
+    """
+
+    dim: int
+    heads: int
+    kv_heads: int
+    z: ZayaDims
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "reference"
+
+    def setup(self):
+        self.cca = CCAttention(self.dim, self.heads, self.kv_heads, self.z,
+                               self.dtype, self.attn_impl)
+        self.moe = RoutedExperts(self.dim, self.z, self.dtype)
+
+    def __call__(self, x, r, mask=None, training: bool = False):
+        return self.moe(self.cca(x, mask), r)
+
+    def _no_serving(self, *_, **__):
+        raise NotImplementedError(
+            "ZayaBlock has no serving path: CCA's cache would hold a "
+            "convolution tail and a shifted value beside compressed K/V, "
+            "and the dropless experts are not on the paged path")
+
+    prefill = step = extend = paged_extend = _no_serving
+
+
+def _check_zaya_options(zaya: ZayaDims, *, quant, attn_window, pos_embedding,
+                        kv_heads):
+    """Raise for an option the ZAYA block cannot honour; none is ignored."""
+    refused = {
+        "quant=True (quantize_lm knows the dense block's matrices only)":
+            quant,
+        "attn_window (CCA attends to every earlier position)":
+            attn_window is not None,
+        "pos_embedding other than 'rope' (CCA rotates q and k itself)":
+            pos_embedding != "rope",
+        "kv_heads=None or odd (the value's halves are this token's and the "
+        "previous one's)": not kv_heads or kv_heads % 2,
+        "an odd rotary width": int(zaya.head_dim * zaya.rotary_fraction) % 2,
+    }
+    for what, hit in refused.items():
+        if hit:
+            raise ValueError(f"the ZAYA block cannot honour {what}")
+
+
+def moe_tokens(counters) -> np.ndarray | None:
+    """The tokens routed to each expert of each ZAYA layer, ``[layers,
+    experts]``, from a model's counters by path (``{"blocks_<i>/moe/
+    moe_tokens": counts}``, as ``MeshTrainer.counters_`` and a history
+    record's ``counters`` hold them); ``None`` where there are none."""
+    rows = {int(path.split("/")[0].removeprefix("blocks_")): counts
+            for path, counts in (counters or {}).items()
+            if path.endswith("/moe/moe_tokens")}
+    if not rows:
+        return None
+    return np.stack([np.asarray(rows[i], np.int64) for i in sorted(rows)])
+
+
 class TransformerLM(nn.Module):
     """Token sequence → next-token logits ``[B, L, vocab]`` (training), with
     ``prefill``/``decode_step`` methods for cached autoregressive decoding."""
@@ -395,6 +693,10 @@ class TransformerLM(nn.Module):
     #: logits = hidden @ embedding.T — V·dim fewer parameters, and the
     #: embedding receives both input- and output-side gradients
     tie_embeddings: bool = False
+    #: the block type: ``None`` is the dense pre-LN GELU :class:`DecoderBlock`;
+    #: a :class:`ZayaDims` makes every layer a :class:`ZayaBlock` (RMSNorm,
+    #: CCA, routed experts) and the head's norm an RMSNorm
+    zaya: ZayaDims | None = None
 
     def setup(self):
         if self.kv_heads is not None and self.heads % self.kv_heads:
@@ -413,6 +715,9 @@ class TransformerLM(nn.Module):
                 f"{self.dim // self.heads}"
             )
         self.embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
+        if self.zaya is not None:
+            self._setup_zaya()
+            return
         # nn.remat preserves the params tree (blocks_i names unchanged) and
         # transforms __call__ only — prefill/step run through the same
         # parameters un-rematted, which is exactly right for decode
@@ -432,6 +737,26 @@ class TransformerLM(nn.Module):
         if not self.tie_embeddings:
             head = QDense if self.quant else nn.Dense
             self.lm_head = head(self.vocab, dtype=self.dtype)
+
+    def _setup_zaya(self):
+        _check_zaya_options(self.zaya, quant=self.quant,
+                            attn_window=self.attn_window,
+                            pos_embedding=self.pos_embedding,
+                            kv_heads=self.kv_heads)
+        block_cls = (nn.remat(
+            ZayaBlock, static_argnums=(4,),
+            policy=jax.checkpoint_policies.save_only_these_names(
+                "router_bias")) if self.remat else ZayaBlock)
+        self.blocks = [
+            block_cls(dim=self.dim, heads=self.heads, kv_heads=self.kv_heads,
+                      z=self.zaya, dtype=self.dtype, attn_impl=self.attn_impl)
+            for _ in range(self.depth)
+        ]
+        self.ln_head = nn.RMSNorm(epsilon=self.zaya.norm_eps,
+                                  dtype=jnp.float32)
+        if not self.tie_embeddings:
+            self.lm_head = nn.Dense(self.vocab, use_bias=False,
+                                    dtype=self.dtype)
 
     def _embed_at(self, tokens, pos0: int | jax.Array = 0):
         """Embed ``tokens`` occupying positions ``pos0 .. pos0+L``."""
@@ -468,6 +793,12 @@ class TransformerLM(nn.Module):
         applies ``lm_head`` chunk-by-chunk, so the ``[B, L, vocab]`` logits
         tensor never materializes (``ops/fused_ce.py``)."""
         x = self._embed_at(tokens)
+        if self.zaya is not None:
+            # the router state passes from layer to layer beside x
+            r = jnp.zeros(x.shape[:2] + (self.zaya.router_dim,), jnp.float32)
+            for blk in self.blocks:
+                x, r = blk(x, r, mask, training)
+            return self.ln_head(x)
         for blk in self.blocks:
             x = blk(x, mask, training)
         return self.ln_head(x)
@@ -1316,7 +1647,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
                    attn_window=None, kv_heads=None,
                    pos_embedding="sincos", fused_ce=False,
                    ce_chunk=256, remat=False,
-                   tie_embeddings=False) -> ModelSpec:
+                   tie_embeddings=False, zaya=None) -> ModelSpec:
     """Causal-LM ModelSpec. Train with ``loss="sparse_softmax_cross_entropy"``
     on ``features=tokens [B, L]`` / ``label=tokens shifted left [B, L]``
     (see :func:`next_token_dataset`); decode with :func:`generate`.
@@ -1336,25 +1667,47 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
     ``tie_embeddings=True`` shares the token embedding with the output
     head (V·dim fewer parameters; the head matmul contracts against the
     embedding table, so int8 ``quantize_lm`` leaves the head in the
-    trained dtype)."""
+    trained dtype).
+    ``zaya=ZayaDims(...)`` makes every layer a :class:`ZayaBlock` (ZAYA1:
+    compressed convolutional attention and dropless top-1 experts behind a
+    router MLP; ``heads`` / ``kv_heads`` heads of ``zaya.head_dim``,
+    ``pos_embedding="rope"``). It trains like the dense model, with or without
+    ``fused_ce`` and ``remat``; the model's state then holds
+    ``counters/blocks_<i>/moe/moe_tokens``, int32 ``[experts]``, to which every
+    training step adds the tokens it routed to each expert, and
+    ``.../router_bias``, the router's balancing bias, which every training
+    step balances on its own tokens (:func:`moe_tokens` reads the counters by
+    layer). An option the
+    block cannot honour (``attn_window``, another ``pos_embedding``) and the
+    serving entry points raise."""
+    if zaya is not None:
+        # here, by name, and not at the module's first trace
+        _check_zaya_options(zaya, quant=False, attn_window=attn_window,
+                            pos_embedding=pos_embedding, kv_heads=kv_heads)
     module = TransformerLM(
         vocab=vocab, maxlen=maxlen, dim=dim, heads=heads, depth=depth,
         dtype=dtype, attn_impl=attn_impl, attn_window=attn_window,
         kv_heads=kv_heads, pos_embedding=pos_embedding, remat=remat,
-        tie_embeddings=tie_embeddings,
+        tie_embeddings=tie_embeddings, zaya=zaya,
     )
     example = jnp.zeros((1, maxlen), jnp.int32)
-    spec = from_flax(module, example, name="transformer_lm")
+    spec = from_flax(module, example, name="transformer_lm",
+                     mutable_collections=("batch_stats", "counters"))
     if fused_ce:
         from distkeras_tpu.ops.fused_ce import chunked_softmax_cross_entropy
 
         chunk = int(ce_chunk)
 
         def fused(params, state, x, y, training, mask=None):
+            counting = training and "counters" in state
             h = module.apply(
                 {"params": params, **state}, x, training=training,
                 method=TransformerLM.hidden,
+                mutable=["counters"] if counting else False,
             )
+            if counting:
+                h, counted = h
+                state = {**state, **counted}
             b_, l_, d_ = h.shape
             token_mask = None
             if mask is not None:
@@ -1372,7 +1725,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
                 bias = None
             else:
                 kernel = params["lm_head"]["kernel"].astype(module.dtype)
-                bias = params["lm_head"]["bias"]
+                bias = params["lm_head"].get("bias")
             loss = chunked_softmax_cross_entropy(
                 h.astype(module.dtype).reshape(b_ * l_, d_),
                 jnp.reshape(y, (b_ * l_,)),

@@ -65,6 +65,7 @@ from distkeras_tpu.observability.metrics import (
     ps_metrics,
     serving_metrics,
     trace_metrics,
+    training_metrics,
 )
 from distkeras_tpu.observability.timeseries import Scraper, TimeSeriesStore
 from distkeras_tpu.observability.watch import (
@@ -75,7 +76,7 @@ from distkeras_tpu.observability.watch import (
 
 __all__ = [
     "trace", "timeseries", "watch", "analyze", "MetricsRegistry", "ps_metrics",
-    "serving_metrics", "phase_metrics", "trace_metrics",
+    "serving_metrics", "phase_metrics", "trace_metrics", "training_metrics",
     "health_snapshot", "TimeSeriesStore", "Scraper", "Watchdog",
     "Watchtower", "default_rules",
 ]

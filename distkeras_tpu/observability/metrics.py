@@ -376,6 +376,22 @@ def serving_metrics(stats: dict, labels: dict | None = None,
     return reg
 
 
+def training_metrics(moe_tokens, labels: dict | None = None,
+                     registry: MetricsRegistry | None = None,
+                     ) -> MetricsRegistry:
+    """``models.lm.moe_tokens(trainer.counters_)`` (``[layers, experts]``:
+    the tokens a run's steps routed to each expert, counted by the model on
+    the device) as ``dk_train_moe_tokens_total{layer,expert}``."""
+    reg = registry if registry is not None else MetricsRegistry()
+    for layer, row in enumerate(moe_tokens):
+        for expert, n in enumerate(row):
+            reg.counter("dk_train_moe_tokens_total", int(n),
+                        {**(labels or {}), "layer": str(layer),
+                         "expert": str(expert)},
+                        "tokens routed to an expert of a layer")
+    return reg
+
+
 def trace_metrics(registry: MetricsRegistry | None = None,
                   labels: dict | None = None) -> MetricsRegistry:
     """The flight recorder's own health as metrics: whether tracing is

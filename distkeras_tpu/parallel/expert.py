@@ -17,6 +17,11 @@ Everything is differentiable: gradients flow through the combine weights
 (softmax probabilities), the standard straight-through-free MoE training
 path. Equality with the single-device oracle is pinned by
 tests/test_expert_parallel.py on an 8-device mesh.
+
+:func:`dropless_experts` is the decoder's expert layer (``models/lm.py``
+``ZayaBlock``): no capacity and no ``[tokens, experts, capacity]`` array. It
+is told which experts it holds, sorts the tokens by chosen expert and runs
+one grouped product a projection over the held experts' stacked weights.
 """
 
 from __future__ import annotations
@@ -188,3 +193,74 @@ def moe_mlp(params, x, mesh: Mesh, axis: str = "ep", top_k: int = 1,
         # GSPMD there (shard_map reshards the manual axis as needed)
         x = put_global(x, NamedSharding(mesh, P(axis)))
     return fn(params, x)
+
+
+@jax.custom_vjp
+def _permute_rows(x, perm, inverse):
+    """``x[perm]`` for a permutation ``perm`` whose inverse is ``inverse``: the
+    backward pass is the gather ``g[inverse]``, never a scatter-add."""
+    return x[perm]
+
+
+def _permute_rows_fwd(x, perm, inverse):
+    return x[perm], (perm, inverse)
+
+
+def _permute_rows_bwd(res, g):
+    perm, inverse = res
+    return g[inverse], None, None
+
+
+_permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
+
+
+def dropless_experts(x, expert, weight, w_in, w_out, *, experts, total):
+    """Top-1 SwiGLU experts without capacity: every token routed to a held
+    expert is computed, none is dropped.
+
+    ``x`` [T, d] tokens; ``expert`` int32 [T], each token's chosen expert out
+    of ``total``; ``weight`` float32 [T], what the chosen expert's result is
+    multiplied by (the router's probability). ``experts=(first, count)``:
+    this layer holds experts ``first .. first + count - 1`` — ``w_in``
+    ``[count, d, 2 f]`` (gate and up side by side) and ``w_out``
+    ``[count, f, d]``. A token whose expert is not held gets 0: on an ``ep``
+    axis that is another chip's part of the sum.
+
+    Tokens are sorted by held expert (stable; absent experts' tokens last),
+    each projection is ONE ``jax.lax.ragged_dot`` over the stacked weights
+    (a grouped matmul kernel on TPU), and the result is put back in token
+    order. Rows past the last group are masked on the way in and on the way
+    out, so nothing depends on what the grouped product leaves there.
+
+    Returns ``(y [T, d], tokens int32 [total])``: ``tokens[e]`` counts the
+    tokens routed to expert ``e`` of all ``total``, held or not.
+    """
+    first, count = experts
+    if not (0 <= first and count >= 1 and first + count <= total):
+        raise ValueError(f"experts={experts!r} is not a range of {total}")
+    if w_in.shape[0] != count or w_out.shape[0] != count:
+        raise ValueError(
+            f"experts={experts!r} but the weights hold {w_in.shape[0]} and "
+            f"{w_out.shape[0]} experts")
+    T = x.shape[0]
+    with jax.named_scope("moe_route"):
+        tokens = jnp.bincount(expert, length=total).astype(jnp.int32)
+        local = expert - first
+        held = (local >= 0) & (local < count)
+        key = jnp.where(held, local, count)          # absent experts sort last
+        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        inverse = jnp.zeros((T,), jnp.int32).at[order].set(
+            jnp.arange(T, dtype=jnp.int32), unique_indices=True)
+        sizes = tokens[first:first + count]
+        live = (jnp.arange(T) < jnp.sum(sizes))[:, None]
+        xs = jnp.where(live, _permute_rows(x, order, inverse), 0)
+    with jax.named_scope("moe_experts"):
+        gate, up = jnp.split(jax.lax.ragged_dot(xs, w_in.astype(x.dtype), sizes),
+                             2, axis=-1)
+        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_out.astype(x.dtype),
+                                sizes)
+    with jax.named_scope("moe_route"):
+        ys = jnp.where(live, ys, 0)
+        y = _permute_rows(ys, inverse, order)
+        y = y * jnp.where(held, weight, 0.0).astype(jnp.float32)[:, None]
+    return y, tokens

@@ -241,6 +241,23 @@ def _phase(name, **args):
                        log=True)
 
 
+def _counters(nt):
+    """What a model's training steps have added so far to the int32 leaves of
+    its state's ``counters`` collection, by path (``{"blocks_0/moe/moe_tokens":
+    int64 array, ...}``), or ``None`` for a model that counts nothing. The
+    device's int32 is read as unsigned, so a difference of two readings is
+    right modulo 2**32."""
+    from flax.traverse_util import flatten_dict
+
+    tree = nt.get("counters") if isinstance(nt, dict) else None
+    counts = {path: leaf for path, leaf in flatten_dict(tree or {}, sep="/").items()
+              if leaf.dtype == np.int32}
+    if not counts:
+        return None
+    return {path: np.asarray(leaf).view(np.uint32).astype(np.int64)
+            for path, leaf in jax.device_get(counts).items()}
+
+
 def _profile_trace_ctx(profile_dir):
     """``jax.profiler.trace`` context for a training run (or a no-op).
 
@@ -1911,6 +1928,7 @@ class MeshTrainer(Trainer):
 
         start_epoch = 0
         restored = None
+        self._counters_seen = self.counters_ = None
         if self.checkpoint_dir and self.resume:
             from distkeras_tpu import checkpoint as ckpt
 
@@ -1919,6 +1937,7 @@ class MeshTrainer(Trainer):
                     restored, _ = ckpt.restore_checkpoint(
                         self.checkpoint_dir)
                 start_epoch = int(restored["epoch"]) + 1
+                self._counters_seen = _counters(restored["nt"])
         if restored is not None:
             with _phase("train.init_state"):
                 params, nt, opt = engine.place_state(
@@ -1943,6 +1962,7 @@ class MeshTrainer(Trainer):
                     with _phase("train.drain"):
                         jax.block_until_ready(ready)
                         _drain(fetch)
+                        self._record_counters(epoch, nt)
                     with _phase("train.log_metrics"):
                         self._epoch_metrics(epoch, rows, steps,
                                             time.perf_counter() - t0)
@@ -2047,6 +2067,28 @@ class MeshTrainer(Trainer):
                 jax.tree.map(np.asarray, jax.device_get(params)))
             host_nt = jax.tree.map(np.asarray, jax.device_get(nt))
         return self._finalize(host_params, host_nt)
+
+    def _record_counters(self, epoch: int, nt):
+        """Fetch the model's counters where the loss was just fetched (the
+        step added to them on the device; nothing is synchronised for them)
+        and record what the epoch added, by path: in the history
+        (``counters``), in the run log (``train.counters``, args ``epoch``
+        and ``counts``) and, summed over this run, in ``counters_``."""
+        with _phase("train.counters", epoch=epoch) as sp:
+            total = _counters(nt)
+            sp.log = total is not None
+            if total is None:
+                return
+            seen = self._counters_seen or {}
+            new = {path: (n - seen[path]) % 2 ** 32 if path in seen else n
+                   for path, n in total.items()}
+            self._counters_seen = total
+            run = self.counters_ or {}
+            self.counters_ = {path: run.get(path, 0) + n
+                              for path, n in new.items()}
+            lists = {path: n.tolist() for path, n in new.items()}
+            sp.args["counts"] = lists
+            self.history.append(epoch=epoch, counters=lists)
 
     def _maybe_checkpoint(self, params, nt, opt, epoch: int):
         if not self.checkpoint_dir:

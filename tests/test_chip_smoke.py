@@ -62,6 +62,16 @@ def test_lm_phase_tiny():
     assert abs(line["losses"][0] - line["plain_f32_first_loss"]) < 0.06
 
 
+def test_moe_phase_tiny():
+    line = chip_smoke.moe(vocab=256, maxlen=128, dim=64, heads=4, kv_heads=2,
+                          depth=2, head_dim=16, router_dim=16, experts=4,
+                          experts_held=(0, 2), expert_dim=64, ce_chunk=64,
+                          batch=2, steps=2, epochs=2, kernel_calls=0)
+    assert line["steps"] == 4
+    assert abs(line["losses"][0] - line["plain_f32_first_loss"]) < 0.06
+    assert 0.0 < line["held_share"] < 1.0
+
+
 def test_serve_phase_tiny():
     line = chip_smoke.serve(vocab=64, maxlen=64, dim=32, heads=4, depth=2,
                             kv_heads=1, prompt_lens=(5, 8, 13, 16),

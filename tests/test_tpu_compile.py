@@ -232,6 +232,64 @@ def test_lm_train_step_compiles_with_its_kernels(topo, monkeypatch, dp,
     assert mem.argument_size_in_bytes < 2 * 2 ** 30 / dp, mem
 
 
+def test_zaya_train_step_compiles_with_its_kernels(topo, monkeypatch):
+    """``chip_smoke.py``'s ``moe`` step (ZAYA1 blocks: 4 query / 2 key-value
+    heads of 128 under CCA, 8 experts of which 4 are held, remat, fused CE)
+    for one described chip: in each of 2 layers the three flash kernels (the
+    forward twice under remat), and the dropless expert layer's grouped
+    products as the TPU compiler's own ``ragged-dot`` kernels, 8 a layer
+    (gate-and-up and down: forward twice, the gradient to the rows, the
+    gradient to the weights) beside the kernels that lay out their groups.
+    No ``[tokens, experts, capacity]`` array is in the program."""
+    import inspect
+
+    import chip_smoke
+    from distkeras_tpu import ops
+    from distkeras_tpu.models import ZayaDims
+    from distkeras_tpu.trainers import MeshTrainer
+
+    monkeypatch.setattr(ops, "native_kernels", lambda: True)
+    size = {k: v.default for k, v in
+            inspect.signature(chip_smoke.moe).parameters.items()}
+    dims = ZayaDims(head_dim=size["head_dim"], router_dim=size["router_dim"],
+                    experts=size["experts"],
+                    experts_held=size["experts_held"],
+                    expert_dim=size["expert_dim"])
+    spec = chip_smoke._zaya_spec(
+        size["vocab"], size["maxlen"], size["dim"], size["heads"],
+        size["kv_heads"], size["depth"], dims, size["ce_chunk"])
+    B, L = size["batch"], size["maxlen"]
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    trainer = MeshTrainer(spec, loss="sparse_softmax_cross_entropy",
+                          worker_optimizer="adam", learning_rate=1e-4,
+                          mesh=mesh, batch_size=B)
+    engine, _, _ = trainer._build_engine()
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+            tree)
+
+    params, nt = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
+    engine._resolve_specs(params)
+    engine._build_step()
+    tokens = jax.ShapeDtypeStruct((B, L), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp")))
+    text = engine._step.lower(
+        placed(params), placed(nt),
+        placed(jax.eval_shape(engine.optimizer.init, params)),
+        (tokens, tokens)).compile().as_text()
+    depth = size["depth"]
+    assert text.count("ragged-dot-none") >= 8 * depth
+    for name, n in (("flash_fwd", 2), ("flash_dq", 1), ("flash_dkv", 1)):
+        assert text.count(f"%{name}") >= n * depth, name
+    assert text.count(chip_smoke.KERNEL_CALL) == size["kernel_calls"]
+    T, E = B * L, size["experts"]
+    assert f"[{T},{E},{T}]" not in text and f"[{T},{E}," not in text.replace(
+        f"[{T},{E}]", "")
+
+
 # -- the serving steps -----------------------------------------------------------
 
 
