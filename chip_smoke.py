@@ -87,13 +87,84 @@ def _peak_bytes(devices) -> list:
 # ---------------------------------------------------------------------------
 
 
+def _device_ms_by_kernel(run, names, calls: int) -> dict:
+    """``{name: ms a call}`` on the chip for the kernels whose operations'
+    names start with one of ``names``, from a profiler trace of ``run()``
+    (which makes ``calls`` calls of each); None where the trace holds none."""
+    import tempfile
+
+    import jax
+
+    from benchmark import xplane
+
+    with tempfile.TemporaryDirectory() as d:
+        with jax.profiler.trace(d):
+            run()
+        planes = xplane.device_planes(xplane.read_planes(xplane.trace_file(d)))
+    ns = dict.fromkeys(names, 0.0)
+    for lines in planes.values():
+        for event, _, dur in lines[xplane.OPS_LINE]:
+            stem = xplane.short_name(event).split(".")[0]
+            if stem in ns:
+                ns[stem] += dur
+    return {k: round(v / 1e6 / calls, 4) if v else None
+            for k, v in ns.items()}
+
+
+def _flash_times(timed, interpret, reps=3) -> list:
+    """The three flash kernels at each ``(B, L, H, D, Hkv)`` of ``timed``,
+    causal and not: ms a call on the chip (None in interpret mode: a CPU gives
+    no device time) beside what ``band_census`` counts for that length: grid
+    steps a head and how many are idle, computed over band pairs, and the
+    share of the computed pairs that run with no mask."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops.flash_attention import band_census, flash_attention
+
+    names = ("flash_fwd", "flash_dq", "flash_dkv")
+    out = []
+    for B, L, H, D, Hkv in timed:
+        keys = jax.random.split(jax.random.PRNGKey(SEED + L), 4)
+        q, g = (jax.random.normal(k, (B, L, H, D), jnp.bfloat16)
+                for k in keys[:2])
+        k, v = (jax.random.normal(k, (B, L, Hkv, D), jnp.bfloat16)
+                for k in keys[2:])
+        for causal in (True, False):
+            def fwd_bwd(q, k, v, g):
+                o, vjp = jax.vjp(lambda q, k, v: flash_attention(
+                    q, k, v, causal=causal, interpret=interpret), q, k, v)
+                return (o,) + vjp(g)
+            step = jax.jit(fwd_bwd)
+            jax.block_until_ready(step(q, k, v, g))      # compiles
+            ms = dict.fromkeys(names)
+            if not interpret:
+                ms = _device_ms_by_kernel(
+                    lambda: jax.block_until_ready(
+                        [step(q, k, v, g) for _ in range(reps)]),
+                    names, reps)
+            census = band_census(L, causal=causal)
+            out.append({"shape": [B, L, H, D, Hkv], "causal": causal, **{
+                n: {"ms": ms[n], "steps": c["steps"],
+                    "steps_idle": c["steps_idle"],
+                    "computed_over_band": round(c["computed_over_band"], 4),
+                    "unmasked_share": round(c["pairs_unmasked"] / (
+                        c["pairs_unmasked"] + c["pairs_masked"]), 4)}
+                for n, c in census.items()}})
+    return out
+
+
 def kernels(*, attn=(8, 2048, 8, 128),
             qmm=((8, 2048, 8192), (1024, 8192, 2048)),
-            adam=(16384, 1024), lstm=(64, 200, 512), interpret=False):
+            adam=(16384, 1024), lstm=(64, 200, 512), interpret=False,
+            timed=((8, 2048, 16, 64, 16), (8, 4096, 8, 128, 2))):
     """flash attention fwd+bwd (causal, bf16), ``q_matmul`` (bf16 × int8),
     fused Adam (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real
     call shape with ``interpret`` EXPLICIT, against ``attention_reference``,
-    ``_q_matmul_xla``, ``optax.adam`` and ``lstm_scan_reference``."""
+    ``_q_matmul_xla``, ``optax.adam`` and ``lstm_scan_reference``. Then the
+    three flash kernels timed on the chip at ``timed``, the two benchmark
+    cells' ``(B, L, H, D, Hkv)``: what a computed pair costs with and
+    without the mask, beside what ``band_census`` says they compute."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -181,7 +252,8 @@ def kernels(*, attn=(8, 2048, 8, 128),
         "q_matmul": kernel_impl("q_matmul", k=qmm[0][1], n=qmm[0][2]),
     }
     line = _report("kernels", t0, interpret=bool(interpret), auto=auto,
-                   norm_err={k: e for k, (e, _) in errs.items()})
+                   norm_err={k: e for k, (e, _) in errs.items()},
+                   flash=_flash_times(timed, interpret))
     for name, (e, dtype) in errs.items():
         # wh's gradient is f32 but flows through the bf16 recurrence
         tol = TOL["bfloat16"] if name.startswith("lstm") else TOL[dtype]

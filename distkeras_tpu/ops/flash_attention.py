@@ -25,9 +25,17 @@ then a dq kernel (grid ``(batch·head, q-blocks, k-blocks)``, k innermost,
 ``dq += ds @ k``) and a dk/dv kernel (grid ``(batch·head, k-blocks,
 q-blocks)``, q innermost, ``dk += dsᵀ @ q``, ``dv += pᵀ @ dO``) each
 rebuild their probability tile from the saved lse and fold into VMEM
-accumulators. Causal tiles that cannot contribute are skipped on both
-sides of the diagonal (dq skips above, dk/dv below). ``_attention_bwd_math``
-keeps the plain-XLA gradient identities as the small-shape oracle.
+accumulators. ``_attention_bwd_math`` keeps the plain-XLA gradient identities
+as the small-shape oracle.
+
+The band (causal diagonal and/or sliding window) is paid for at its own
+grain, not the grid's: a grid step holds a tall tile (a step costs about a
+microsecond whatever it computes) and decides from its tile's offset alone
+what to run — nothing for a tile outside the band (its index map names the
+block already held, so it copies nothing either), one body with no mask for
+a tile wholly inside, and for a tile the band's edge crosses the pieces
+``_band_plan`` laid out while tracing. ``band_census`` counts, from the same
+index arithmetic, what that comes to at a length.
 
 On TPU the kernel compiles natively; elsewhere (the 8-device CPU mesh in CI)
 it runs in Pallas interpret mode, so the SAME code path is oracle-tested
@@ -74,6 +82,25 @@ def _last_k_tile(iq, nk, *, block_q, block_k, causal, window):
     return last
 
 
+def _first_q_tile(jk, *, block_q, block_k, causal, window):
+    """First q tile that can see k tile ``jk``: the causal diagonal and/or
+    the lower edge of the window band (0 when unrestricted)."""
+    if causal:
+        return (jk * block_k) // block_q
+    if window is not None:
+        return jnp.maximum(0, (jk * block_k - window + 1) // block_q)
+    return 0
+
+
+def _last_q_tile(jk, nq, *, block_q, block_k, window):
+    """Last q tile inside k tile ``jk``'s band (``nq - 1`` unwindowed)."""
+    if window is None:
+        return nq - 1
+    return jnp.minimum(
+        nq - 1, (jk * block_k + block_k - 1 + window - 1) // block_q
+    )
+
+
 def band_predicate(q_pos, k_pos, causal, window):
     """THE causal/sliding-window validity predicate, shared by the kernels
     (both orientations), the XLA backward oracle, and
@@ -94,16 +121,144 @@ def band_predicate(q_pos, k_pos, causal, window):
     return valid
 
 
-def _band_valid(iq, kt, *, block_q, block_k, causal, window):
-    """[bq, bk] tile of :func:`band_predicate` for q tile ``iq`` × k tile
-    ``kt`` (None when everything is valid)."""
-    q_pos = iq * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 0
-    )
-    k_pos = kt * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_q, block_k), 1
-    )
+def _band_valid(q0, k0, rows, cols, causal, window):
+    """[rows, cols] tile of :func:`band_predicate` for the queries from ``q0``
+    and the keys from ``k0`` (None when everything is valid)."""
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     return band_predicate(q_pos, k_pos, causal, window)
+
+
+def _band_valid_t(q0, k0, rows, cols, causal, window):
+    """Transposed [cols, rows] tile (keys down, queries across) of
+    :func:`band_predicate`: what the dk/dv kernel masks with."""
+    k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (cols, rows), 0)
+    q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (cols, rows), 1)
+    return band_predicate(q_pos, k_pos, causal, window)
+
+
+def _piece_valid(band, keys, shape):
+    """What a body masks with: its band tile (None off the band's edge) and
+    its slice of the key mask (None without one), or None for no mask."""
+    if keys is None:
+        return band
+    keys = jnp.broadcast_to(keys.astype(jnp.float32) > 0.5, shape)
+    return keys if band is None else (band & keys)
+
+
+# The grain of the band. A grid step holds a tall [block_q, block_k] tile
+# (the per-step overhead the ladders below were chosen for), but the band's
+# edge is a diagonal, so a step decides from its scalar tile indices what it
+# runs. Whether a tile lies inside the band depends only on how far its first
+# query is past its first key, ``d = q0 - k0``, and a grid has few such
+# offsets, so everything but the test of ``d`` is decided while tracing:
+#
+# - a tile wholly inside the band takes ONE body with no mask at all;
+# - a tile outside it is left out (and fetches nothing: the index maps are
+#   clamped to the last tile that contributes);
+# - for each offset at which the band's edge crosses the tile, the tile is
+#   halved (its longer side, both when square) down to ``_FINE`` rows and keys
+#   and each piece decided again: outside, inside (no mask), or on the edge
+#   (today's masked body). The pieces of one offset run back to back under
+#   one test of ``d``.
+#
+# What cutting is worth differs by kernel (v5e, PR 28, chip_smoke's kernels
+# leg): dq and dk/dv pay by the (query, key) pair, so they run the pieces;
+# the forward pays by the row of every body it folds into the online softmax
+# (its two row reductions and the [rows, 1] statistics: 83 % of a 512 x 1024
+# body, the same at head 64 and 128), so it runs one masked body over the
+# columns the band touches and never two bodies over the same rows.
+_FINE = 256
+_WIDEST = 1024   # keys a body of dq or dk/dv covers at most
+
+
+def _chunk_band(d, rows, cols, causal, window):
+    """``(some, every)``: whether some / every (query, key) pair of a
+    ``[rows, cols]`` chunk lies inside the band, when the chunk's first query
+    is ``d`` positions past its first key. The band is an interval of
+    ``q - k`` around 0 and a chunk holds every ``q - k`` between its top-right
+    and its bottom-left corner, so :func:`band_predicate` at those two
+    corners decides both. Python ints while tracing, scalars in a kernel."""
+    if not causal and window is None:
+        return True, True
+    lo, hi = d - (cols - 1), d + rows - 1     # top-right, bottom-left
+    in_lo = band_predicate(lo, 0, causal, window)
+    in_hi = band_predicate(hi, 0, causal, window)
+    return in_lo | in_hi | ((lo < 0) & (hi > 0)), in_lo & in_hi
+
+
+def _band_pieces(d, rows, cols, causal, window, r=0, c=0):
+    """The bodies a ``[rows, cols]`` tile at offset ``d`` takes, as a list of
+    ``(r, c, rows, cols, edge)``: the piece at ``(r, c)`` of the tile, under
+    the band's mask if ``edge``. Static: ``d`` is a Python int."""
+    some, every = _chunk_band(d + r - c, rows, cols, causal, window)
+    if not some:
+        return []
+    if every:
+        return [(r, c, rows, cols, False)]
+    halved = lambda n: n // 2 if n % (2 * _FINE) == 0 else n
+    hr = halved(rows) if rows >= cols else rows
+    hc = halved(cols) if cols >= rows else cols
+    if (hr, hc) == (rows, cols):
+        return [(r, c, rows, cols, True)]
+    return [piece for rr in range(r, r + rows, hr)
+            for cc in range(c, c + cols, hc)
+            for piece in _band_pieces(d, hr, hc, causal, window, rr, cc)]
+
+
+def _band_plan(L, tiles, causal, window, one_body=False):
+    """``((d, pieces), ...)``: for every offset ``d = q0 - k0`` of the
+    length-``L`` grid whose ``[bq, bk]`` tile (``tiles``) touches the band,
+    the pieces that tile runs, cut down to ``_FINE``. ``one_body`` (the
+    forward): the one body over the bounding box of what :func:`_band_pieces`
+    keeps of the tile, masked unless that is the whole tile. Else (dq, dk/dv,
+    which pay by the pair): those pieces themselves, of each ``_WIDEST`` keys
+    of the tile apart, so that a wide tile's float32 ``[rows, keys]``
+    temporaries stay what they were at 1024 keys a step."""
+    bq, bk = tiles
+    plan = []
+    for d in sorted({i * bq - j * bk
+                     for i in range(L // bq) for j in range(L // bk)}):
+        if one_body:
+            pieces = _band_pieces(d, bq, bk, causal, window)
+            if len(pieces) > 1:
+                r0 = min(r for r, *_ in pieces)
+                c0 = min(c for _, c, *_ in pieces)
+                r1 = max(r + rows for r, _, rows, _, _ in pieces)
+                c1 = max(c + cols for _, c, _, cols, _ in pieces)
+                pieces = [(r0, c0, r1 - r0, c1 - c0, True)]
+        else:
+            wide = min(bk, _WIDEST)
+            pieces = [piece for c in range(0, bk, wide)
+                      for piece in _band_pieces(d, bq, wide, causal, window,
+                                                0, c)]
+        if pieces:
+            plan.append((d, tuple(pieces)))
+    return tuple(plan)
+
+
+def _run_band(fold, d, live, rows, cols, plan, causal, window):
+    """One grid step's bodies, ``fold(r, c, rows, cols, edge)`` each: the
+    pieces the plan has for the step's offset ``d``. The tiles wholly inside
+    the band all run the same unmasked pieces, under one test of the tile's
+    corners; each offset at which the band's edge crosses has its own pieces
+    under a test of ``d``; a tile outside the band runs nothing."""
+    inside = {pieces for at, pieces in plan
+              if _chunk_band(at, rows, cols, causal, window)[1]}
+    assert len(inside) <= 1, inside
+    for pieces in inside:
+        _, every = _chunk_band(d, rows, cols, causal, window)
+
+        @pl.when(live & every)
+        def _():
+            for piece in pieces:
+                fold(*piece)
+    for at, pieces in plan:
+        if pieces not in inside:
+            @pl.when(live & (d == at))
+            def _():
+                for piece in pieces:
+                    fold(*piece)
 
 
 def _num_band_tiles(n_tiles, span, block):
@@ -114,50 +269,103 @@ def _num_band_tiles(n_tiles, span, block):
 
 def _restricted_k_axis(nk, bq, bk, causal, window):
     """(nkt, k_tile(iq, j)) for the forward/dq grids: the static size of the
-    k axis and the index map from (q tile, band step) → real k tile. With no
-    window the axis is the full nk and the map is the identity on j; with a
-    window only the tiles the band can touch are visited (and DMA'd), so
-    compute and bandwidth are O(L·window) — clamped duplicate tiles at the
-    sequence end are guarded off in-kernel by ``kt <= last_k``."""
+    k axis and the index map from (q tile, band step) → real k tile. With a
+    window only the tiles the band can touch are visited, so compute and
+    bandwidth are O(L·window). The map is clamped to the q tile's last
+    contributing k tile: a step past it (guarded off in-kernel by
+    ``kt <= last_k``: above the causal diagonal, or past the sequence end)
+    names the block already held and copies nothing."""
     if window is None:
-        return nk, (lambda i, j: j)
-    span = bq + window - 1 if causal else bq + 2 * window - 2
+        nkt = nk
+    else:
+        span = bq + window - 1 if causal else bq + 2 * window - 2
+        nkt = _num_band_tiles(nk, span, bk)
 
     def k_tile(i, j):
         fk = _first_k_tile(i, block_q=bq, block_k=bk, window=window)
-        return jnp.minimum(fk + j, nk - 1)
+        return jnp.minimum(fk + j, _last_k_tile(
+            i, nk, block_q=bq, block_k=bk, causal=causal, window=window))
 
-    return _num_band_tiles(nk, span, bk), k_tile
+    return nkt, k_tile
 
 
 def _restricted_q_axis(nq, bq, bk, causal, window):
     """(nqt, q_tile(jk, i)) for the dkv grid — the transposed mirror of
-    :func:`_restricted_k_axis`."""
+    :func:`_restricted_k_axis`: band steps count from the first q tile that
+    sees the k tile, and the steps past the last one are clamped to it."""
     if window is None:
-        return nq, (lambda j, i: i)
-    span = bk + window - 1 if causal else bk + 2 * window - 2
+        nqt = nq
+    else:
+        span = bk + window - 1 if causal else bk + 2 * window - 2
+        nqt = _num_band_tiles(nq, span, bq)
 
     def q_tile(j, i):
         fq = _first_q_tile(j, block_q=bq, block_k=bk, causal=causal,
                            window=window)
-        return jnp.minimum(fq + i, nq - 1)
+        return jnp.minimum(fq + i, _last_q_tile(
+            j, nq, block_q=bq, block_k=bk, window=window))
 
-    return _num_band_tiles(nq, span, bq), q_tile
+    return nqt, q_tile
 
 
-def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc, *,
-               scale, causal, block_q, block_k, window=None, nk=None,
-               km_ref=None):
-    """One (bh, iq, jk) step: fold a [bq, bk] score tile into the online
-    softmax state; finalize on this q block's last contributing k step.
+def _k_step(iq, jk, nk, *, block_q, block_k, causal, window):
+    """``(kt, last_k)`` of step ``(iq, jk)`` of the forward's and dq's grids:
+    the k tile the step stands on (``jk`` counts from the first tile of q
+    tile ``iq``'s band) and the last tile that contributes to ``iq``. The step
+    is live iff ``kt <= last_k``. Python ints give ints (the census), program
+    ids scalars (the kernels)."""
+    kt = _first_k_tile(iq, block_q=block_q, block_k=block_k,
+                       window=window) + jk
+    return kt, _last_k_tile(iq, nk, block_q=block_q, block_k=block_k,
+                            causal=causal, window=window)
 
-    With ``window`` set the grid's k axis is restricted to the band (the
-    BlockSpec index map only loads in-band tiles), so ``jk`` counts tiles
-    from the band start: the real k tile is ``first_k + jk``."""
+
+def _q_step(jk, iq, nq, *, block_q, block_k, causal, window):
+    """``(qt, last_q)`` of step ``(jk, iq)`` of dk/dv's grid: the transposed
+    mirror of :func:`_k_step`, live iff ``qt <= last_q``."""
+    qt = _first_q_tile(jk, block_q=block_q, block_k=block_k, causal=causal,
+                       window=window) + iq
+    return qt, _last_q_tile(jk, nq, block_q=block_q, block_k=block_k,
+                            window=window)
+
+
+def _grid_steps(L, tiles, causal, window, transposed=False):
+    """``(qt, kt, live)`` of every step one head's grid takes at length ``L``,
+    in the grid's order: the forward's and dq's (k innermost), or dk/dv's
+    (``transposed``: q innermost), from the axes and the step rule the
+    kernels themselves run."""
+    bq, bk = tiles
+    nq, nk = L // bq, L // bk
+    kw = dict(block_q=bq, block_k=bk, causal=causal, window=window)
+    if transposed:
+        nqt, _ = _restricted_q_axis(nq, bq, bk, causal, window)
+        for jk in range(nk):
+            for i in range(nqt):
+                qt, last = (int(x) for x in _q_step(jk, i, nq, **kw))
+                yield qt, jk, qt <= last
+    else:
+        nkt, _ = _restricted_k_axis(nk, bq, bk, causal, window)
+        for iq in range(nq):
+            for j in range(nkt):
+                kt, last = (int(x) for x in _k_step(iq, j, nk, **kw))
+                yield iq, kt, kt <= last
+
+
+def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
+               plan, window, nk):
+    """One (bh, iq, jk) step: fold the step's [bq, bk] score tile, piece by
+    piece (:func:`_run_band`), into the online softmax state; finalize on
+    this q block's last contributing k step.
+
+    The grid's k axis is restricted to the band (the BlockSpec index map only
+    loads in-band tiles), so ``jk`` counts tiles from the band start: the
+    real k tile is ``first_k + jk``."""
+    if len(rest) == 6:
+        km_ref, o_ref, lse_ref, m_s, l_s, acc = rest
+    else:
+        km_ref, (o_ref, lse_ref, m_s, l_s, acc) = None, rest
     iq = pl.program_id(1)
     jk = pl.program_id(2)
-    if nk is None:
-        nk = pl.num_programs(2)
 
     @pl.when(jk == 0)
     def _():
@@ -167,42 +375,43 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc, *,
 
     # under causal/window masking, k tiles outside the band contribute
     # nothing — the restricted grid never visits tiles below the band, and
-    # the guards below skip tiles past its end (≈2× at long causal context)
-    kt = _first_k_tile(iq, block_q=block_q, block_k=block_k,
-                       window=window) + jk
-    last_k = _last_k_tile(iq, nk, block_q=block_q, block_k=block_k,
-                          causal=causal, window=window)
+    # the guard skips the steps past its end (≈2× at long causal context)
+    kt, last_k = _k_step(iq, jk, nk, block_q=block_q, block_k=block_k,
+                         causal=causal, window=window)
+    q0, k0 = iq * block_q, kt * block_k
 
-    @pl.when(kt <= last_k)
-    def _():
-        q = q_ref[0].astype(jnp.float32) * scale        # [bq, D]
-        k = k_ref[0].astype(jnp.float32)                # [bk, D]
-        v = v_ref[0].astype(jnp.float32)                # [bk, D]
+    def fold(r, c, rows, cols, edge):
+        rs, cs = pl.ds(r, rows), pl.ds(c, cols)
+        q = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, D]
+        k = k_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
+        v = v_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                # [bq, bk]
-        valid = _band_valid(iq, kt, block_q=block_q, block_k=block_k,
-                            causal=causal, window=window)
-        if km_ref is not None:
-            km = km_ref[0].astype(jnp.float32) > 0.5     # [1, bk]
-            km = jnp.broadcast_to(km, s.shape)
-            valid = km if valid is None else (valid & km)
+        )                                                # [rows, cols]
+        valid = _piece_valid(
+            _band_valid(q0 + r, k0 + c, rows, cols, causal, window)
+            if edge else None,
+            None if km_ref is None else km_ref[0, :, cs],     # [1, cols]
+            s.shape)
         if valid is not None:
             s = jnp.where(valid, s, _NEG)
 
-        m_prev = m_s[:]                                  # [bq, 1]
+        m_prev = m_s[rs, :]                              # [rows, 1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
-        corr = jnp.exp(m_prev - m_new)                   # [bq, 1]
-        l_s[:] = l_s[:] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc[:] = acc[:] * corr + jax.lax.dot_general(
+        corr = jnp.exp(m_prev - m_new)                   # [rows, 1]
+        l_s[rs, :] = l_s[rs, :] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        acc[rs, :] = acc[rs, :] * corr + jax.lax.dot_general(
             p, v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
-        m_s[:] = m_new
+        m_s[rs, :] = m_new
+
+    _run_band(fold, q0 - k0, kt <= last_k, block_q, block_k, plan, causal,
+              window)
 
     @pl.when(kt == last_k)
     def _():
@@ -212,30 +421,85 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc, *,
 
 
 def _pick_block_q(L):
-    """q tile height: taller q tiles amortize per-grid-step pipeline
-    overhead and cut the number of (m, l, acc) rescale passes. Round 5
-    re-measured the ladder on a v5e DOWN to L = 1024 (fwd+bwd, causal):
-    512-row tiles win 1.5× at L = 2048 for BOTH D=64 (thin heads — the
-    VERDICT r4 #4 gap: the per-step overhead, not the 64-wide MXU
-    contraction, was the recoverable part) and D=128, matching the
-    2.0–2.1× already measured at L ≥ 8192 (SCALING.md flash table).
-    Gated at L >= 1024 — exactly the measured range: L = 512 would get a
-    single 512-row tile (a config no measurement covered), so it keeps
-    the default ladder, as do lengths that aren't 512-multiples
-    (tile rule)."""
+    """q tile height: 512 rows at every ``L >= 1024`` that 512 divides, else
+    ``BLOCK_Q``. A grid step costs about a microsecond whatever it computes
+    (v5e, PR 28: a step whose body is guarded off takes 0.8-1.3 us), so tall
+    tiles win: at 8 x 2048 x 16 heads of 64, not causal, forward + dq + dk/dv
+    take 7.65 ms a call at 512 x 1024 and 12.46 ms at 256 x 512. 1024 rows
+    gain another 5 % where they fit and do not fit the scoped VMEM at head
+    128 or without a band (the whole-tile body's float32 [rows, keys]
+    temporaries), so 512 it stays. L = 512 keeps the default ladder, as do
+    lengths that aren't 512-multiples (tile rule)."""
     return 512 if L >= 1024 and L % 512 == 0 else BLOCK_Q
 
 
 def _pick_block_k(L):
-    """k tile width: largest tile-aligned block that divides L (128 always
-    does); 1024 whenever L allows it (same round-5 measurement as
-    _pick_block_q — fewer, wider k steps beat the old 512 ladder at every
-    L ≥ 1024 tried). Every (bq, bk) combination keeps bk % bq == 0 or
-    bq % bk == 0, which the backward's causal tile-skipping index math
-    relies on."""
-    if L % 1024 == 0:
-        return 1024
-    return next(c for c in (BLOCK_K, 384, 256, 128) if L % c == 0)
+    """k tile width: the largest of 2048, 1024, ``BLOCK_K``, 384, 256, 128
+    that divides L (128 always does). Wider is fewer grid steps, and since
+    the band is cut inside a step (:func:`_band_plan`) a wide tile no longer
+    computes what lies outside it. v5e, PR 28, ms a call forward + dq + dk/dv,
+    causal: 8 x 2048 x 16 heads of 64: 7.24 at the parent (whole 512 x 1024
+    tiles under the mask), 5.97 with the band cut inside 1024-key steps, 4.98
+    at 2048 keys; 8 x 4096 x 8 heads of 128 over 2 key-value heads: 10.98,
+    9.97, 8.92. Not causal 7.65 -> 7.18 and 14.96 -> 14.19. The 1024-key
+    figures are a sweep's (``chip_smoke.py``'s kernels leg on this tree reads
+    the others); in that sweep dq and dk/dv ran bodies as wide as the tile
+    and head 128 read 9.09 at 2048 keys, before their bodies were held to
+    ``_WIDEST`` keys. 4096 keys a step fit no better (dk/dv four times slower
+    at head 128). Every (bq, bk) combination keeps bk % bq == 0 or
+    bq % bk == 0, which the tile index math relies on."""
+    return next(c for c in (2048, 1024, BLOCK_K, 384, 256, 128) if L % c == 0)
+
+
+def band_census(L, causal=False, window=None, masked=False):
+    """What the three kernels do at length ``L``, for one head, counted from
+    the index arithmetic they run (:func:`_grid_steps`: their grids' axes and
+    step rule; their :func:`_band_plan`) and from nothing measured:
+    per kernel a dict of
+
+    - ``steps``, ``steps_idle``: grid steps, and those of them the guard
+      turns off (they fetch nothing either: the index maps are clamped);
+    - ``bodies_unmasked`` / ``bodies_masked`` and ``pairs_unmasked`` /
+      ``pairs_masked``: the bodies that run without and with a mask (every
+      body is masked under a key mask: ``masked``) and the (query, key)
+      pairs they compute; ``pairs_skipped``: the rest of the grid's tiles;
+    - ``pairs_band``: the pairs :func:`band_predicate` admits, and
+      ``computed_over_band``: computed pairs over those (1.0 is the floor).
+
+    Causal, at the 512 x 2048 tiles of L = 2048 and 4096: dq and dk/dv
+    compute 1.125 and 1.062 times the band, 78 % and 88 % of it with no
+    mask; the forward 1.25 and 1.125 times, none and 44 % of it with no mask
+    (whole 512 x 1024 tiles under the mask before PR 28: 1.50 and 1.25)."""
+    import numpy as np
+
+    window = _canonical_window(window, L)
+    tiles = bq, bk = _tiles(L)
+    pairs_band = L * L
+    if causal or window is not None:      # a q tile's rows at a time
+        pairs_band = sum(int(band_predicate(
+            np.arange(q0, q0 + bq)[:, None], np.arange(L)[None, :], causal,
+            window).sum()) for q0 in range(0, L, bq))
+
+    def count(transposed, one_body):
+        plan = dict(_band_plan(L, tiles, causal, window, one_body))
+        out = dict(steps=0, steps_idle=0, bodies_unmasked=0, bodies_masked=0,
+                   pairs_unmasked=0, pairs_masked=0)
+        for qt, kt, live in _grid_steps(L, tiles, causal, window, transposed):
+            out["steps"] += 1
+            out["steps_idle"] += not live
+            pieces = plan.get(qt * bq - kt * bk, ()) if live else ()
+            for _, _, rows, cols, edge in pieces:
+                kind = "masked" if edge or masked else "unmasked"
+                out["bodies_" + kind] += 1
+                out["pairs_" + kind] += rows * cols
+        computed = out["pairs_unmasked"] + out["pairs_masked"]
+        out["pairs_skipped"] = out["steps"] * bq * bk - computed
+        out["pairs_band"] = pairs_band
+        out["computed_over_band"] = computed / pairs_band
+        return out
+
+    return {"flash_fwd": count(False, True), "flash_dq": count(False, False),
+            "flash_dkv": count(True, False)}
 
 
 def _gqa_groups(q, k):
@@ -257,21 +521,44 @@ def _kv_row(b, H, Hkv):
     return (b // H) * Hkv + (b % H) // (H // Hkv)
 
 
+def _tiles(L):
+    """The grid's ``(block_q, block_k)`` at length ``L``."""
+    return _pick_block_q(L), _pick_block_k(L)
+
+
 def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
                 window=None):
     """q [B, L, H, D], k/v [B, L, Hkv, D] with Hkv | H (grouped-query
     attention reads shared K/V heads straight from the index maps — no
     repeated-KV materialization), + key_mask [B, L] →
     (out [B, L, H, D], lse)."""
-    B, L, H, D = q.shape
-    Hkv = k.shape[2]
+    L = q.shape[1]
     _gqa_groups(q, k)
     if L % BLOCK_Q:
         raise ValueError(
             f"sequence length {L} must be a multiple of {BLOCK_Q}"
         )
-    bq = _pick_block_q(L)
-    bk = _pick_block_k(L)
+    return _fwd_call(q, k, v, key_mask, tiles=_tiles(L), scale=scale,
+                     causal=causal, interpret=interpret, window=window)
+
+
+# The two launchers are jitted on their own: a model calls them once a layer
+# with the same shapes, and tracing and lowering the kernels' bodies (a score
+# of them in a causal kernel) is then paid once a program, not once a layer:
+# v5e, PR 28, XGLM-564M's 24 layers trace and lower in 14.2 s so and in 92.7 s
+# without (23.2 s when a kernel had one body). It is not free: under it the
+# TPU compiler keeps dk and not dv in its fast memory space, and the fusion
+# that reads dq, dk and dv takes 0.2 ms a layer more (PERF.md section 6).
+# The tiles are an argument so that the choice is part of the cache's key;
+# the band's grain (``_FINE``, ``_WIDEST``) is read when a launcher traces.
+_STATIC = ("tiles", "scale", "causal", "interpret", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window):
+    B, L, H, D = q.shape
+    Hkv = k.shape[2]
+    bq, bk = tiles
 
     def bh(x):  # [B, L, h, D] → [B·h, L, D]
         h = x.shape[2]
@@ -299,25 +586,18 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
     ]
     in_specs = [qspec, kvspec, kvspec]
     args = [bh(q), bh(k), bh(v)]
-    if key_mask is None:
-        kernel = functools.partial(
-            _fa_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-            window=window, nk=nk,
-        )
-    else:
-        H_ = H
+    if key_mask is not None:
         # mask ships as [B, 1, L] so its block obeys the (8, 128) tile rule
         in_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H_, 0,
+            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H, 0,
                                                       k_tile(i, j)))
         )
         args.append(key_mask.astype(jnp.float32)[:, None, :])
-
-        def kernel(q_ref, k_ref, v_ref, km_ref, o_ref, lse_ref,
-                   m_s, l_s, acc):
-            _fa_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_s, l_s, acc,
-                       scale=scale, causal=causal, block_q=bq, block_k=bk,
-                       window=window, nk=nk, km_ref=km_ref)
+    kernel = functools.partial(
+        _fa_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
+        plan=_band_plan(L, tiles, causal, window, one_body=True),
+        window=window, nk=nk,
+    )
 
     o, lse = pl.pallas_call(
         kernel, grid=grid,
@@ -333,104 +613,74 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
-                      scale, causal, block_q, block_k, window=None, nk=None):
-    """One (bh, iq, jk) step: rebuild the [bq, bk] probability tile from the
-    saved lse and fold ``ds @ k`` into the dq accumulator; write on this q
-    block's last contributing k step."""
+                      scale, causal, block_q, block_k, plan, window, nk):
+    """One (bh, iq, jk) step: rebuild the step's [bq, bk] probability tile
+    from the saved lse, piece by piece (:func:`_run_band`), and fold
+    ``ds @ k`` into the dq accumulator; write on this q block's last
+    contributing k step."""
     if len(rest) == 3:
         km_ref, dq_ref, acc = rest
     else:
         km_ref, (dq_ref, acc) = None, rest
     iq = pl.program_id(1)
     jk = pl.program_id(2)
-    if nk is None:
-        nk = pl.num_programs(2)
 
     @pl.when(jk == 0)
     def _():
         acc[:] = jnp.zeros_like(acc)
 
-    kt = _first_k_tile(iq, block_q=block_q, block_k=block_k,
-                       window=window) + jk
-    last_k = _last_k_tile(iq, nk, block_q=block_q, block_k=block_k,
-                          causal=causal, window=window)
+    kt, last_k = _k_step(iq, jk, nk, block_q=block_q, block_k=block_k,
+                         causal=causal, window=window)
+    q0, k0 = iq * block_q, kt * block_k
 
-    @pl.when(kt <= last_k)
-    def _():
-        qs = q_ref[0].astype(jnp.float32) * scale       # [bq, D]
-        kk = k_ref[0].astype(jnp.float32)               # [bk, D]
-        vv = v_ref[0].astype(jnp.float32)               # [bk, D]
-        gg = g_ref[0].astype(jnp.float32)               # [bq, D]
+    def fold(r, c, rows, cols, edge):
+        rs, cs = pl.ds(r, rows), pl.ds(c, cols)
+        qs = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, D]
+        kk = k_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
+        vv = v_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
+        gg = g_ref[0, rs, :].astype(jnp.float32)          # [rows, D]
         s = jax.lax.dot_general(
             qs, kk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                # [bq, bk]
-        valid = _band_valid(iq, kt, block_q=block_q, block_k=block_k,
-                            causal=causal, window=window)
-        if km_ref is not None:
-            km = km_ref[0].astype(jnp.float32) > 0.5     # [1, bk]
-            km = jnp.broadcast_to(km, s.shape)
-            valid = km if valid is None else (valid & km)
+        )                                                 # [rows, cols]
+        valid = _piece_valid(
+            _band_valid(q0 + r, k0 + c, rows, cols, causal, window)
+            if edge else None,
+            None if km_ref is None else km_ref[0, :, cs],     # [1, cols]
+            s.shape)
         if valid is not None:
             s = jnp.where(valid, s, _NEG)
-        p = jnp.exp(s - lse_ref[0])                      # lse [bq, 1]
+        p = jnp.exp(s - lse_ref[0, rs, :])                # lse [rows, 1]
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
         dp = jax.lax.dot_general(
             gg, vv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                # [bq, bk]
-        ds = p * (dp - d_ref[0])                         # delta [bq, 1]
-        acc[:] += jax.lax.dot_general(
+        )                                                 # [rows, cols]
+        ds = p * (dp - d_ref[0, rs, :])                   # delta [rows, 1]
+        acc[rs, :] += jax.lax.dot_general(
             ds, kk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
+
+    _run_band(fold, q0 - k0, kt <= last_k, block_q, block_k, plan, causal,
+              window)
 
     @pl.when(kt == last_k)
     def _():
         dq_ref[0] = acc[:].astype(dq_ref.dtype)
 
 
-def _first_q_tile(jk, *, block_q, block_k, causal, window):
-    """First q tile that can see k tile ``jk``: the causal diagonal and/or
-    the lower edge of the window band (0 when unrestricted)."""
-    if causal:
-        return (jk * block_k) // block_q
-    if window is not None:
-        return jnp.maximum(0, (jk * block_k - window + 1) // block_q)
-    return 0
-
-
-def _last_q_tile(jk, nq, *, block_q, block_k, window):
-    """Last q tile inside k tile ``jk``'s band (``nq - 1`` unwindowed)."""
-    if window is None:
-        return nq - 1
-    return jnp.minimum(
-        nq - 1, (jk * block_k + block_k - 1 + window - 1) // block_q
-    )
-
-
-def _band_valid_t(jk, qt, *, block_q, block_k, causal, window):
-    """Transposed [bk, bq] tile of :func:`band_predicate` for k tile ``jk``
-    × q tile ``qt``."""
-    k_pos = jk * block_k + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 0
-    )
-    q_pos = qt * block_q + jax.lax.broadcasted_iota(
-        jnp.int32, (block_k, block_q), 1
-    )
-    return band_predicate(q_pos, k_pos, causal, window)
-
-
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
-                       scale, causal, block_q, block_k, window=None,
-                       nq=None, gqa_groups=None):
+                       scale, causal, block_q, block_k, plan, window, nq,
+                       gqa_groups=None):
     """One (bh, jk, iq) step — or (b·hkv, jk, gg, iq) under grouped-query
     attention, where the extra ``gg`` axis walks the q heads sharing this
     k/v head and the dk/dv accumulators run across the whole group:
-    rebuild the transposed [bk, bq] probability tile and fold ``pᵀ @ dO``
-    / ``dsᵀ @ q`` into the dv/dk accumulators; write on the group's last
-    contributing q step."""
+    rebuild the step's transposed [bk, bq] probability tile, piece by piece
+    (:func:`_run_band`), and fold ``pᵀ @ dO`` / ``dsᵀ @ q`` into the
+    dv/dk accumulators; write on the group's last contributing q step.
+    ``iq`` counts q tiles from the first that sees this k tile."""
     if len(rest) == 5:
         km_ref, dk_ref, dv_ref, dk_acc, dv_acc = rest
     else:
@@ -440,13 +690,10 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
     if gqa_groups is None:
         last_g = None
         iq = pl.program_id(2)
-        if nq is None:
-            nq = pl.num_programs(2)
         first_step = iq == 0
     else:
         grp = pl.program_id(2)  # in-group q head (gg names the dO tile)
         iq = pl.program_id(3)
-        assert nq is not None
         first_step = (grp == 0) & (iq == 0)
         last_g = grp == gqa_groups - 1
 
@@ -455,52 +702,46 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
         dk_acc[:] = jnp.zeros_like(dk_acc)
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
-    first_q = _first_q_tile(jk, block_q=block_q, block_k=block_k,
-                            causal=causal, window=window)
-    if window is None:
-        # full grid: iq is the real q tile, skip those before the band
-        qt = iq
-        last_q = nq - 1
-    else:
-        # restricted grid: iq counts tiles from the band start
-        qt = first_q + iq
-        last_q = _last_q_tile(jk, nq, block_q=block_q, block_k=block_k,
-                              window=window)
+    qt, last_q = _q_step(jk, iq, nq, block_q=block_q, block_k=block_k,
+                         causal=causal, window=window)
+    q0, k0 = qt * block_q, jk * block_k
 
-    @pl.when((qt >= first_q) & (qt <= last_q))
-    def _():
-        qs = q_ref[0].astype(jnp.float32) * scale       # [bq, D]
-        kk = k_ref[0].astype(jnp.float32)               # [bk, D]
-        vv = v_ref[0].astype(jnp.float32)               # [bk, D]
-        gg = g_ref[0].astype(jnp.float32)               # [bq, D]
+    def fold(r, c, rows, cols, edge):
+        rs, cs = pl.ds(r, rows), pl.ds(c, cols)
+        qs = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, D]
+        kk = k_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
+        vv = v_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
+        gg = g_ref[0, rs, :].astype(jnp.float32)          # [rows, D]
         st = jax.lax.dot_general(
             kk, qs, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                # [bk, bq]
-        valid = _band_valid_t(jk, qt, block_q=block_q, block_k=block_k,
-                              causal=causal, window=window)
-        if km_ref is not None:
-            km = km_ref[0].astype(jnp.float32) > 0.5     # [bk, 1]
-            km = jnp.broadcast_to(km, st.shape)
-            valid = km if valid is None else (valid & km)
+        )                                                 # [cols, rows]
+        valid = _piece_valid(
+            _band_valid_t(q0 + r, k0 + c, rows, cols, causal, window)
+            if edge else None,
+            None if km_ref is None else km_ref[0, cs, :],     # [cols, 1]
+            st.shape)
         if valid is not None:
             st = jnp.where(valid, st, _NEG)
-        pt = jnp.exp(st - lse_ref[0])                    # lse [1, bq]
+        pt = jnp.exp(st - lse_ref[0, :, rs])              # lse [1, rows]
         if valid is not None:
             pt = jnp.where(valid, pt, 0.0)
-        dv_acc[:] += jax.lax.dot_general(
+        dv_acc[cs, :] += jax.lax.dot_general(
             pt, gg, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
         dpt = jax.lax.dot_general(
             vv, gg, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )                                                # [bk, bq]
-        dst = pt * (dpt - d_ref[0])                      # delta [1, bq]
-        dk_acc[:] += jax.lax.dot_general(
+        )                                                 # [cols, rows]
+        dst = pt * (dpt - d_ref[0, :, rs])                # delta [1, rows]
+        dk_acc[cs, :] += jax.lax.dot_general(
             dst, qs, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )
+
+    _run_band(fold, q0 - k0, qt <= last_q, block_q, block_k, plan, causal,
+              window)
 
     write = qt == last_q if last_g is None else ((qt == last_q) & last_g)
 
@@ -518,11 +759,19 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
     shared heads through the index maps and the dkv grid gains a group
     axis whose accumulators sum the whole group — dk/dv come out
     Hkv-wide, no repeated-KV tensors anywhere."""
+    return _bwd_call(q, k, v, key_mask, out, lse, g, tiles=_tiles(q.shape[1]),
+                     scale=scale, causal=causal, interpret=interpret,
+                     window=window)
+
+
+@functools.partial(jax.jit, static_argnames=_STATIC)
+def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
+              interpret, window):
     B, L, H, D = q.shape
     Hkv = k.shape[2]
     groups = _gqa_groups(q, k)
-    bq = _pick_block_q(L)
-    bk = _pick_block_k(L)  # same ladders as the forward — keep in lockstep
+    bq, bk = tiles  # the forward's: one ladder
+    plan = _band_plan(L, tiles, causal, window)
 
     def bh(x):  # [B, L, h, D] → [B·h, L, D]
         h = x.shape[2]
@@ -534,7 +783,6 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
                     axis=-1)
     lse_col, d_col = lse[..., None], delta[..., None]      # [B·H, L, 1]
     lse_row, d_row = lse[:, None, :], delta[:, None, :]    # [B·H, 1, L]
-    H_ = H
     nk, nq = L // bk, L // bq
     # same restricted band axes as the forward (one shared builder, so the
     # forward and backward grids cannot drift apart)
@@ -551,13 +799,14 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
     dq_args = [qb, kb, vb, gb, lse_col, d_col]
     if key_mask is not None:
         dq_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H_, 0,
+            pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H, 0,
                                                       k_tile(i, j)))
         )
         dq_args.append(key_mask.astype(jnp.float32)[:, None, :])
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, window=window, nk=nk),
+                          block_q=bq, block_k=bk, plan=plan, window=window,
+                          nk=nk),
         grid=(B * H, nq, nkt),
         in_specs=dq_specs,
         out_specs=qspec,
@@ -583,7 +832,7 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
         rowspec = pl.BlockSpec(
             (1, 1, bq), lambda b, j, i: (b, 0, q_tile(j, i))
         )
-        kmspec = pl.BlockSpec((1, bk, 1), lambda b, j, i: (b // H_, j, 0))
+        kmspec = pl.BlockSpec((1, bk, 1), lambda b, j, i: (b // H, j, 0))
     else:
         grid = (B * Hkv, nk, groups, nqt)
         kvspec = pl.BlockSpec((1, bk, D), lambda b, j, gg, i: (b, j, 0))
@@ -605,8 +854,8 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
         dkv_args.append(key_mask.astype(jnp.float32)[..., None])
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
-                          block_q=bq, block_k=bk, window=window, nq=nq,
-                          gqa_groups=None if groups == 1 else groups),
+                          block_q=bq, block_k=bk, plan=plan, window=window,
+                          nq=nq, gqa_groups=None if groups == 1 else groups),
         grid=grid,
         in_specs=dkv_specs,
         out_specs=[kvspec, kvspec],

@@ -23,10 +23,19 @@ def test_kernels_phase_interpret_mode():
     line = chip_smoke.kernels(
         attn=(1, 128, 2, 64), qmm=((8, 128, 256), (40, 256, 128)),
         adam=(40, 33), lstm=(8, 5, 128), interpret=True,
+        timed=((1, 256, 2, 64, 1),),
     )
     assert line["phase"] == "kernels" and line["interpret"] is True
     assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
                                      "fused_adam.update2"}
+    # the timed leg: no device time off the chip, the static census beside it
+    causal, full = line["flash"]
+    assert causal["causal"] and causal["flash_dkv"]["ms"] is None
+    assert causal["flash_dq"]["computed_over_band"] > 1.0
+    for name in ("flash_fwd", "flash_dq", "flash_dkv"):
+        assert full[name]["computed_over_band"] == 1.0
+        assert full[name]["unmasked_share"] == 1.0
+        assert full[name]["steps_idle"] == 0
     # off the chip "auto" keeps the references for attention and the LSTM —
     # the phase reports it, and only a native run insists on the kernels
     assert line["auto"] == {"attention": "reference", "lstm_scan": "xla",
@@ -45,7 +54,8 @@ def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
                         lambda x, qt, dt: (x @ qt.q.astype(x.dtype)) * 1.5)
     with pytest.raises(chip_smoke.SmokeFailure, match="off its reference"):
         chip_smoke.kernels(attn=(1, 128, 1, 64), qmm=((8, 128, 128),),
-                           adam=(8, 16), lstm=(8, 2, 128), interpret=True)
+                           adam=(8, 16), lstm=(8, 2, 128), interpret=True,
+                           timed=())
 
 
 def test_adag_phase_tiny():

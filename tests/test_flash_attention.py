@@ -245,26 +245,27 @@ def test_auto_dispatch_falls_back_on_ragged_length(rng):
 
 
 def test_block_ladders_scale_with_length():
-    """Blocks scale with L (1.5x fwd+bwd at L=2048, 2x at L>=8192, v5e):
-    the only combos the ladders can produce are (512, 1024), (512, 512),
-    and (128, 384|256|128) — keeping the backward's divisibility
-    assumption (bk % bq == 0 or bq % bk == 0) true by construction."""
+    """Blocks scale with L: 512 rows by the widest of 2048 | 1024 | 512 keys
+    that divides L from L = 1024 up (v5e, PR 28: a grid step costs about a
+    microsecond, and the band is cut inside a step, so a wide tile computes
+    nothing outside it), (128, 512|384|256|128) below and at lengths 512
+    does not divide — keeping the tile index math's divisibility assumption
+    (bk % bq == 0 or bq % bk == 0) true by construction."""
     from distkeras_tpu.ops.flash_attention import _pick_block_k, _pick_block_q
 
-    # round-5 re-measure: 512/1024 wins at EVERY L >= 1024 that allows it
-    # (1.5x at L=2048 for both D=64 and D=128 — the thin-head gap's
-    # recoverable part was per-step overhead, not MXU width)
     assert (_pick_block_q(1024), _pick_block_k(1024)) == (512, 1024)
-    assert (_pick_block_q(2048), _pick_block_k(2048)) == (512, 1024)
-    assert (_pick_block_q(4096), _pick_block_k(4096)) == (512, 1024)
-    assert (_pick_block_q(8192), _pick_block_k(8192)) == (512, 1024)
-    assert (_pick_block_q(16384), _pick_block_k(16384)) == (512, 1024)
+    assert (_pick_block_q(1536), _pick_block_k(1536)) == (512, 512)
+    assert (_pick_block_q(2048), _pick_block_k(2048)) == (512, 2048)
+    assert (_pick_block_q(3072), _pick_block_k(3072)) == (512, 1024)
+    assert (_pick_block_q(4096), _pick_block_k(4096)) == (512, 2048)
+    assert (_pick_block_q(8192), _pick_block_k(8192)) == (512, 2048)
+    assert (_pick_block_q(16384), _pick_block_k(16384)) == (512, 2048)
     # non-512-multiples keep the small-tile fallbacks
     assert (_pick_block_q(4480), _pick_block_k(4480)) == (128, 128)
     assert (_pick_block_q(256), _pick_block_k(256)) == (128, 256)
-    # L = 512 is BELOW the measured range (round 5 stopped at 1024): a
-    # 512-row tile there would be a single-tile config no measurement
-    # covered, so the gate keeps the default ladder
+    # L = 512 is BELOW the measured range: a 512-row tile there would be a
+    # single-tile config no measurement covered, so the gate keeps the
+    # default ladder
     assert (_pick_block_q(512), _pick_block_k(512)) == (128, 512)
     for L in (512, 1024, 2048, 4096, 4480, 8192, 8320, 16384):
         bq, bk = _pick_block_q(L), _pick_block_k(L)
@@ -274,7 +275,7 @@ def test_block_ladders_scale_with_length():
 
 @pytest.mark.parametrize("causal", [False, True])
 def test_large_block_path_matches_reference(rng, causal):
-    """The L>=4096 (512, 512) tile path, end to end in interpret mode:
+    """The L = 4096 (512, 2048) tile path, end to end in interpret mode:
     forward and all three gradients vs the XLA oracle (the native-chip
     equality at L=4k/8k/16k is in SCALING.md; this pins the same code path
     in CI)."""
@@ -595,3 +596,204 @@ def test_kernel_runs_per_device_under_a_declared_mesh(rng, masked, stacked):
     plain = jax.make_jaxpr(jax.value_and_grad(loss(flash_attention),
                                               (0, 1, 2)))(q, k, v)
     assert "shard_map" not in str(plain) and "pallas_call" in str(plain)
+
+
+# ---------------------------------------------------------------------------
+# The band's grain: what a step runs is decided from its tile's offset
+# ---------------------------------------------------------------------------
+
+# (L, causal, window, key mask) -> for forward and for dq = dk/dv: computed
+# over band pairs, the share of the computed pairs with no mask, idle steps
+CENSUS = {
+    "causal-2048": ((2048, True, None, False),
+                    (1.2494, 0.0, 0), (1.1245, 0.7778, 0)),
+    "causal-4096": ((4096, True, None, False),
+                    (1.1247, 0.4444, 4), (1.0622, 0.8824, 4)),
+    "full-2048": ((2048, False, None, False), (1.0, 1.0, 0), (1.0, 1.0, 0)),
+    "causal-window512-2048": ((2048, True, 512, False),
+                              (1.9994, 0.0, 0), (1.4996, 0.3333, 0)),
+    "bidirectional-window512-2048": ((2048, False, 512, False),
+                                     (1.4298, 0.0, 0), (1.2153, 0.6471, 0)),
+    "full-keymask-2048": ((2048, False, None, True),
+                          (1.0, 0.0, 0), (1.0, 0.0, 0)),
+    "causal-keymask-2048": ((2048, True, None, True),
+                            (1.2494, 0.0, 0), (1.1245, 0.0, 0)),
+    # the fallback ladders: (128, 256) cut no finer, (128, 128) tiles
+    "causal-256": ((256, True, None, False),
+                   (1.9922, 0.0, 0), (1.9922, 0.0, 0)),
+    "causal-4480": ((4480, True, None, False),
+                    (1.0283, 0.9444, 595), (1.0283, 0.9444, 595)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(CENSUS))
+def test_band_census_counts_what_the_kernels_run(case):
+    """The static counter, from the kernels' own index arithmetic: at the
+    benchmark's two lengths no kernel computes more than 1.25 / 1.125 times
+    the causal band (1.50 / 1.25 when whole tiles ran under the mask), dq
+    and dk/dv run 78 % / 88 % of their pairs with no mask, no tile runs a
+    mask without a band or key mask, every body runs one under a key mask,
+    and the grid's pairs are all accounted for."""
+    from distkeras_tpu.ops.flash_attention import _tiles, band_census
+
+    (L, causal, window, masked), fwd, bwd = CENSUS[case]
+    census = band_census(L, causal=causal, window=window, masked=masked)
+    assert sorted(census) == ["flash_dkv", "flash_dq", "flash_fwd"]
+    bq, bk = _tiles(L)
+    for name, c in census.items():
+        ratio, unmasked, idle = fwd if name == "flash_fwd" else bwd
+        computed = c["pairs_unmasked"] + c["pairs_masked"]
+        assert computed + c["pairs_skipped"] == c["steps"] * bq * bk, name
+        assert computed >= c["pairs_band"], name
+        assert c["computed_over_band"] == pytest.approx(ratio, abs=1e-4), name
+        assert c["pairs_unmasked"] / computed == pytest.approx(
+            unmasked, abs=1e-4), name
+        assert c["steps_idle"] == idle, name
+        assert (c["bodies_unmasked"] == 0) == (unmasked == 0.0), name
+    if case in ("causal-2048", "causal-4096"):
+        limit = 1.25 if L == 2048 else 1.125
+        assert all(c["computed_over_band"] <= limit for c in census.values())
+
+
+def _band_case(rng, case):
+    """(q, k, v, kwargs) at L = 1024 for one of the band cases below."""
+    heads, kv_heads, D = (2, 2, 64) if case.startswith("mha64") else (4, 1, 128)
+    Lb = 1024
+    mk = lambda h: rng.normal(0, 1, size=(1, Lb, h, D)).astype(np.float32)
+    q, k, v = mk(heads), mk(kv_heads), mk(kv_heads)
+    kind = case.split("-", 1)[1]
+    kw = {"causal": kind != "bidirectional-window"}
+    if "window" in kind:
+        kw["window"] = 300
+    if kind == "keymask":
+        mask = np.ones((1, Lb), np.float32)
+        mask[:, :40] = 0.0       # queries 0..39 see no key at all
+        mask[:, 700:760] = 0.0
+        kw["key_mask"] = mask
+    return q, k, v, kw
+
+
+BAND_CASES = [f"{h}-{k}" for h in ("mha64", "gqa128")
+              for k in ("causal", "causal-window", "bidirectional-window",
+                        "keymask")]
+
+
+@pytest.fixture
+def small_band_tiles(monkeypatch):
+    """256 x 512 tiles cut down to 128, bodies of dq and dk/dv 256 keys wide
+    at most: at L = 1024 a causal call then holds, in its first grid step
+    AND its last, a piece left out, a piece with no mask and a piece under
+    the mask (and tiles wholly inside and wholly outside the band between)."""
+    from distkeras_tpu.ops import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_pick_block_q", lambda L: 256)
+    monkeypatch.setattr(fa, "_pick_block_k", lambda L: 512)
+    monkeypatch.setattr(fa, "_FINE", 128)
+    monkeypatch.setattr(fa, "_WIDEST", 256)
+    plan = dict(fa._band_plan(1024, fa._tiles(1024), True, None))
+    first, last = plan[0], plan[256]     # steps (0, 0) and (3, 1)
+    for pieces in (first, last):
+        edges = [edge for *_, edge in pieces]
+        assert True in edges and False in edges
+        assert sum(r * c for _, _, r, c, _ in pieces) < 256 * 512
+    # the launchers read the grain when they trace: nothing traced at
+    # another grain may answer for these tiles, here or after
+    for launcher in (fa._fwd_call, fa._bwd_call):
+        launcher.clear_cache()
+    yield fa
+    for launcher in (fa._fwd_call, fa._bwd_call):
+        launcher.clear_cache()
+
+
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_band_census_is_what_the_kernels_run(rng, case, small_band_tiles,
+                                             monkeypatch):
+    """The census against the kernels at run time: every body a kernel's grid
+    steps actually execute (interpret mode, counted through ``_run_band``'s
+    ``fold``) is a body ``band_census`` counted, by kind and by pairs, for the
+    forward, dq and dk/dv alike: a guard or an index map changed in a kernel
+    and not in the census fails here."""
+    fa = small_band_tiles
+    q, k, v, kw = _band_case(rng, case)
+    ran = []
+    real = fa._run_band
+
+    def counting(fold, *rest):
+        bodies = []           # one list a kernel, in the order they trace
+        ran.append(bodies)
+
+        def counted(r, c, rows, cols, edge):
+            jax.debug.callback(lambda: bodies.append((rows * cols, edge)))
+            fold(r, c, rows, cols, edge)
+        real(counted, *rest)
+
+    monkeypatch.setattr(fa, "_run_band", counting)
+    jax.block_until_ready(jax.grad(
+        lambda q, k, v: jnp.sum(fa.flash_attention(q, k, v, **kw)),
+        argnums=(0, 1, 2))(q, k, v))
+    jax.effects_barrier()
+    census = fa.band_census(q.shape[1], causal=kw["causal"],
+                            window=kw.get("window"),
+                            masked="key_mask" in kw)
+    heads = q.shape[0] * q.shape[2]
+    assert len(ran) == 3
+    for name, bodies in zip(("flash_fwd", "flash_dq", "flash_dkv"), ran):
+        c = census[name]
+        masked = [n for n, edge in bodies if edge or "key_mask" in kw]
+        assert len(bodies) - len(masked) == heads * c["bodies_unmasked"], name
+        assert len(masked) == heads * c["bodies_masked"], name
+        assert sum(n for n, _ in bodies) == heads * (
+            c["pairs_unmasked"] + c["pairs_masked"]), name
+        assert c["steps_idle"] > 0 or "window" in kw, name
+
+
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_band_pieces_forward_and_lse_match_reference(rng, case,
+                                                     small_band_tiles):
+    fa = small_band_tiles
+    q, k, v, kw = _band_case(rng, case)
+    scale = q.shape[-1] ** -0.5
+    with jax.default_matmul_precision("highest"):
+        out, lse = fa._fa_forward(
+            q, k, v, kw.get("key_mask"), scale=scale, causal=kw["causal"],
+            interpret=True, window=kw.get("window"))
+        ref = attention_reference(q, k, v, **kw)
+        groups = q.shape[2] // k.shape[2]
+        s = jnp.einsum("bqhd,bkhd->bhqk", q * scale,
+                       jnp.repeat(k, groups, axis=2))
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=2e-4, atol=2e-5)
+    Lb = q.shape[1]
+    valid = fa.band_predicate(np.arange(Lb)[:, None], np.arange(Lb)[None, :],
+                              kw["causal"], kw.get("window"))
+    valid = np.broadcast_to(valid, s.shape)
+    if "key_mask" in kw:
+        valid = valid & (kw["key_mask"][:, None, None, :] > 0.5)
+    want = jax.scipy.special.logsumexp(jnp.where(valid, s, -jnp.inf), axis=-1)
+    seen = valid.any(-1)                     # rows with a key to attend to
+    got = np.asarray(lse).reshape(want.shape)
+    np.testing.assert_allclose(got[seen], np.asarray(want)[seen],
+                               rtol=2e-5, atol=2e-5)
+    if "key_mask" in kw:
+        assert not seen.all()
+        dead = np.asarray(out)[:, :40]
+        np.testing.assert_allclose(dead, np.zeros_like(dead), atol=1e-6)
+
+
+@pytest.mark.parametrize("case", BAND_CASES)
+def test_band_pieces_gradients_match_reference(rng, case, small_band_tiles):
+    fa = small_band_tiles
+    q, k, v, kw = _band_case(rng, case)
+    cot = rng.normal(size=q.shape).astype(np.float32)
+    with jax.default_matmul_precision("highest"):
+        g = jax.grad(
+            lambda q, k, v: jnp.sum(fa.flash_attention(q, k, v, **kw) * cot),
+            argnums=(0, 1, 2))(q, k, v)
+        r = jax.grad(
+            lambda q, k, v: jnp.sum(attention_reference(q, k, v, **kw) * cot),
+            argnums=(0, 1, 2))(q, k, v)
+    assert g[1].shape == k.shape and g[2].shape == v.shape
+    for name, gg, rr in zip(("dq", "dk", "dv"), g, r):
+        assert np.isfinite(np.asarray(gg)).all(), name
+        np.testing.assert_allclose(np.asarray(gg), np.asarray(rr),
+                                   rtol=5e-3, atol=1e-3, err_msg=name)

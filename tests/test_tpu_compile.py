@@ -112,6 +112,20 @@ KERNELS = {
         lambda: _flash((8, 2048, 8, 128), backward=False),
     "flash-fwdbwd-causal-bf16-8x2048x8x128":
         lambda: _flash((8, 2048, 8, 128)),
+    # the two benchmark cells' own calls: xglm-564m.train (16 heads of 64)
+    # and zaya1-8b.train (8 query heads over 2 key-value heads of 128)
+    "flash-fwd-causal-bf16-8x2048x16x64":
+        lambda: _flash((8, 2048, 16, 64), backward=False),
+    "flash-fwdbwd-causal-bf16-8x2048x16x64":
+        lambda: _flash((8, 2048, 16, 64)),
+    "flash-fwd-causal-gqa-kv2-8x4096x8x128":
+        lambda: _flash((8, 4096, 8, 128), kv_heads=2, backward=False),
+    "flash-fwdbwd-causal-gqa-kv2-8x4096x8x128":
+        lambda: _flash((8, 4096, 8, 128), kv_heads=2),
+    # the heaviest body of the 2048-key ladder: the forward's 512 x 2048
+    # float32 tile under the band AND a key mask, at head 128
+    "flash-fwdbwd-causal-keymask-8x2048x8x128":
+        lambda: _flash((8, 2048, 8, 128), masked=True),
     "flash-fwdbwd-mqa-kv1": lambda: _flash((8, 2048, 8, 128), kv_heads=1),
     "flash-fwdbwd-window512": lambda: _flash((8, 2048, 8, 128), window=512),
     "flash-fwdbwd-keymask-noncausal-d64":
@@ -153,6 +167,10 @@ def test_kernel_compiles_for_v5e(one_chip, case):
 NAMED = {
     "flash-fwdbwd-keymask-noncausal-d64": ("flash_fwd", "flash_dq",
                                            "flash_dkv"),
+    "flash-fwdbwd-causal-bf16-8x2048x16x64": ("flash_fwd", "flash_dq",
+                                              "flash_dkv"),
+    "flash-fwdbwd-causal-gqa-kv2-8x4096x8x128": ("flash_fwd", "flash_dq",
+                                                 "flash_dkv"),
     "lstm-fwdbwd-T200-H128-B32": ("lstm_scan_fwd", "lstm_scan_bwd"),
     "fused-adam-16384x1024": ("fused_adam",),
     "q_matmul-8x2048x2048": ("q_matmul",),
