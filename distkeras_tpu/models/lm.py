@@ -1408,10 +1408,10 @@ def speculative_generate(target, target_params, draft, draft_params, prompt,
     matmul may round a tie one ulp differently than the single-token
     step, after which the two streams are different-but-equally-valid
     greedy decodes. The test suite asserts bitwise equality on f32
-    models, where ties have measure zero; the bench's bf16 legs fall
+    models, where ties have measure zero; a bf16 comparison has to fall
     back to an argmax-within-two-ulps check when streams differ (one
-    true ulp is the measured drift of plain greedy itself against a
-    full-forward oracle).)
+    true ulp is the drift of plain greedy itself against a full-forward
+    oracle).)
 
     ``temperature>0`` is the paper's rejection-sampling scheme: the draft
     SAMPLES each proposal from its warped distribution ``q``; proposal

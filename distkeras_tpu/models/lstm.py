@@ -10,11 +10,10 @@ the final matmul.
 TPU note — hoisted input projection: the input half of the LSTM's gate math
 (``x_t @ W_x`` for every t) has no sequential dependence, so it runs as ONE
 big ``[B·T, E] @ [E, 4H]`` matmul before the scan (MXU-friendly), leaving
-only the recurrent ``h @ W_h`` inside the ``lax.scan``. On a bare jitted
-train step this measured ~1.25× over ``nn.RNN(OptimizedLSTMCell)`` (B=64,
-T=200, 128/128, v5e); through the window-scan engine the two are within
-chip run-to-run variance — kept for the simpler code and the microbench
-win. Cell state stays f32; gates/hidden compute in ``dtype``.
+only the recurrent ``h @ W_h`` inside the ``lax.scan``. Kept for the
+simpler code; no benchmark cell times it against
+``nn.RNN(OptimizedLSTMCell)``. Cell state stays f32; gates/hidden compute
+in ``dtype``.
 """
 
 from __future__ import annotations

@@ -1,10 +1,10 @@
 """SRU sequence classifier — the recurrence that isn't latency-bound.
 
-SCALING.md's LSTM roofline analysis ends at an irreducible ~21 µs/step
-sequential-chain latency: every LSTM timestep needs ``h_{t-1}`` through a
-matmul, so a T=200 sequence is 200 dependent MXU dispatches no kernel can
-parallelize away — "the leftover levers are architectural (QRNN/SRU-style
-recurrences that break the dependency)". This module is that lever.
+An LSTM's sequential chain has a latency floor: every timestep needs
+``h_{t-1}`` through a matmul, so a T=200 sequence is 200 dependent MXU
+dispatches no kernel can parallelize away. The levers left are
+architectural (QRNN/SRU-style recurrences that break the dependency), and
+this module is that lever.
 
 The Simple Recurrent Unit (Lei et al. 2018, "Simple Recurrent Units for
 Highly Parallelizable Recurrence") moves ALL matmuls out of the recurrence:
@@ -20,7 +20,7 @@ parallel depth on the VPU — one fused program, no per-step dispatch, no
 h→matmul dependency — while the MXU sees a single big time-parallel
 projection. Same classifier interface as ``models.lstm`` (padded tokens +
 mask, masked-mean pooling), so it drops into the IMDB BASELINE config
-unchanged; measured throughput vs the LSTM is in SCALING.md.
+unchanged; its throughput against the LSTM is not measured on the chip.
 
 No reference counterpart (the Spark-era reference topped out at a Keras
 LSTM — SURVEY.md §2b.2); this is the beyond-parity answer to its slowest

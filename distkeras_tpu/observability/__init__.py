@@ -52,9 +52,6 @@ timeline file, path lands in ``trainer.trace_path_``),
 ``watch_rules=`` / ``watch_dir=`` / ``scrape_interval=`` /
 ``watch_hook=`` run the watchtower over a training run (alerts land in
 ``trainer.watch_alerts_``, the dump path in ``trainer.watch_path_``).
-``bench.py`` legs take ``--trace-dir`` and record ``trace_path`` in
-their stdout JSON; ``bench.py --regress`` is the trajectory-enforcing
-perf-regression guard.
 """
 
 from distkeras_tpu.observability import analyze, timeseries, trace, watch

@@ -2,7 +2,7 @@
 
 PR 11's flight recorder answers "what happened" and PR 13's watchtower
 "is it healthy right now"; this module answers the question every perf
-PR in this repo had to answer by hand-reading bench legs: **why is this
+PR in this repo had to answer by hand: **why is this
 run slow, and which knob fixes it**. It is strictly post-hoc: it
 consumes the span streams the recorder already captured (in-memory
 ``trace.events()`` or a saved Chrome-trace file) plus, optionally, the
@@ -48,8 +48,7 @@ The machinery, bottom up:
 
 Surfaces: ``python -m distkeras_tpu.observability analyze <trace.json>
 [--series <dump.json>] [--json]`` (both files may be gzipped), the
-trainer knob ``analyze=True`` (→ ``trainer.analysis_``), ``bench.py
---trace-dir`` legs stamping the verdict into their records, and
+trainer knob ``analyze=True`` (→ ``trainer.analysis_``), and
 :func:`regime_source` feeding ``analyze.regime_code`` into the
 watchtower store so ``watch.BottleneckShiftRule`` can fire when the
 dominant regime changes mid-run.

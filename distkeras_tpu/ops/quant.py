@@ -8,9 +8,9 @@ Why weight-only, and why a Pallas kernel:
 
 - **Autoregressive decode is HBM-bandwidth-bound.** Every decode step
   streams every weight matrix once to multiply a tiny ``[B, 1, d]``
-  activation. Int8 weights halve the bytes per step, which is directly
-  ~2× decode throughput for the weight-dominated regime (small batch,
-  cache smaller than the weights).
+  activation. Int8 weights halve the bytes per step, which is what bounds
+  a step in the weight-dominated regime (small batch, cache smaller than
+  the weights).
 - **The dequant must happen AFTER the HBM read.** An XLA-level
   ``q.astype(bf16) * scale`` before the matmul is loop-invariant inside
   the decode ``lax.scan`` — the compiler may hoist it and materialize a

@@ -5,8 +5,8 @@ step on CPU executors (reference ``distkeras/examples`` IMDB config —
 SURVEY.md §2b #19 / BASELINE config 5). The rebuild's XLA ``lax.scan`` path
 (:mod:`distkeras_tpu.models.lstm`) is bounded not by matmul FLOPs but by
 per-step overheads: each of the T sequential steps round-trips the h/c
-carries through HBM and launches a tiny [B,H]·[H,4H] contraction
-(SCALING.md's roofline paragraph for BASELINE config 5). This kernel runs
+carries through HBM and launches a tiny [B,H]·[H,4H] contraction.
+This kernel runs
 the WHOLE scan as one Pallas grid:
 
 - grid ``(T/K,)`` with ``K`` timesteps unrolled per grid step — TPU grid

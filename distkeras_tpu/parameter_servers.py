@@ -532,9 +532,8 @@ class ParameterServer:
         add → absmax → divide → rint → dequant-subtract sequence in f32;
         the old clip pass was a provable no-op, see below), but runs in
         preallocated per-worker scratch: one int8 output allocation per
-        float leaf instead of ~10 model-sized temporaries — most of the
-        measured single-stream speedup comes from here, the rest from
-        pulls no longer serializing behind the center lock.
+        float leaf instead of ~10 model-sized temporaries, and pulls no
+        longer serialize behind the center lock.
         """
         import jax
 

@@ -6,8 +6,8 @@ you. The TPU-native PS stack owns its transport, so it owns its chaos too:
 :class:`FaultPlan` is a seeded plan of wire faults (drops, delays,
 op-count partitions) plus kill-at-window worker faults, installed behind
 the ``networking._fault_hook`` seam and the ``AsyncWorker`` window loop.
-Tests and ``bench.py --chaos`` drive the same plan, so the chaos an
-integration test proves survivable is the chaos the benchmark measures.
+The chaos tests (``tests/test_resilience.py``, ``test_ps_durability.py``,
+``test_elastic.py``) drive it.
 
 Determinism: every wire-fault decision comes from one ``Philox``-seeded
 generator consumed under a lock in call order, and worker kills key on

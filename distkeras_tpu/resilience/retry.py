@@ -305,7 +305,7 @@ class ResilientPSClient:
         # commit whose very first attempt folded server-side before the
         # ack died leaves commits == logical + 1 — possible only in runs
         # that lost a worker mid-commit, which the oracle's consumers
-        # (chaos tests, --chaos bench) don't tolerate silently anyway.
+        # (the chaos tests) don't tolerate silently anyway.
         self._wire_seq += 1
         seq = self._seq_epoch + self._wire_seq
         if _trace.enabled():

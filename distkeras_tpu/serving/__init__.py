@@ -1,7 +1,7 @@
 """Serving tier: continuous-batching generation over a block-paged KV cache.
 
 The decode stack (KV cache, GQA/MQA, sliding-window, beam, speculative,
-int8 — SCALING.md) served one request at a time through
+int8) served one request at a time through
 ``GeneratorPredictor``; this package is the millions-of-users front end on
 top of it:
 
@@ -28,8 +28,9 @@ top of it:
   (``ServerBusyError``), mid-stream death detection that frees the dead
   client's blocks, and graceful drain.
 
-Benchmark: ``bench.py --serve`` (Poisson open-loop load, throughput vs
-p50/p99, vs the sequential ``GeneratorPredictor`` baseline).
+Benchmark: ``benchmark/drivers/serve.py`` drives client -> server -> engine
+under a closed-loop mix; its cell is not in ``BENCHMARK.json`` yet (PERF.md
+section 7), so this tier has no number on the chip.
 """
 
 from distkeras_tpu.serving.frontdoor import (  # noqa: F401

@@ -1291,8 +1291,8 @@ class GenerationEngine:
         """Rebuild the per-batch device arrays — ONLY when the batch
         composition changed (admission/retire), never per token: the slot
         map and sampling params are constants of a batch lineup, and
-        rebuilding + re-uploading them each step was measurable per-step
-        overhead on the 1-core bench host."""
+        rebuilding + re-uploading them each step is host work a step does
+        not need."""
         if not self._batch_dirty:
             return
         B = self.max_batch

@@ -1,7 +1,6 @@
 """Shared-memory ring-buffer transport — the colocated fast lane (ISSUE 12).
 
-Every bench record since PR 6 carries a ``host_cores`` honesty field
-because the socket/native wires serialize behind syscalls, kernel socket
+The socket/native wires serialize behind syscalls, kernel socket
 copies, and pickle passes that the colocated regime (workers and PS on one
 host — CI, single-VM, the single-TPU-slice deployment) never needed.
 This module attacks that constant factor: ``ps_transport="shm"`` moves

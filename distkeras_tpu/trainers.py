@@ -829,8 +829,8 @@ class DistributedTrainer(Trainer):
         #   window boundaries; lease_timeout (default 5× the interval)
         #   controls stale-worker eviction, surfaced in ps.stats() and fed
         #   into DynSGD staleness accounting.
-        # - fault_plan: a resilience.FaultPlan injected into the run (tests
-        #   and bench.py --chaos; install()ed by the caller for wire
+        # - fault_plan: a resilience.FaultPlan injected into the run (the chaos
+        #   tests; install()ed by the caller for wire
         #   faults, kill-at-window faults hook the worker loop here).
         self.worker_restart_budget = int(worker_restart_budget)
         if self.worker_restart_budget < 0:

@@ -884,7 +884,7 @@ def run_async_training(trainer, ds, shuffle: bool):
     external_host = getattr(trainer, "ps_host", None)
     offset = int(getattr(trainer, "worker_id_offset", 0))
     # Flight recorder (ISSUE 11): trace=True / trace_dir= turn on the
-    # span recorder for this run (idempotent when a caller — bench.py —
+    # span recorder for this run (idempotent when a caller
     # already enabled it; we only disable what we enabled). The timeline
     # lands in trace_dir as Chrome trace-event JSON, path stashed on
     # trainer.trace_path_.

@@ -5,11 +5,10 @@ The reference (2016-era Spark/Keras) had no long-context story at all
 
 1. **flash attention** (`attn_impl="flash"`, Pallas) — O(block²) on-chip
    score memory for BOTH forward and backward (blockwise dq/dk/dv from the
-   saved log-sum-exp); bf16 fwd+bwd is 1.2–2.3× the XLA path at L=2k–16k,
-   and on one v5e chip it TRAINS at L=16k where XLA fails (SCALING.md).
+   saved log-sum-exp); what it buys a training step on the chip is in
+   PERF.md (``flash_roofline``).
 2. **rematerialization** (`remat=True`) — `jax.checkpoint` per encoder
-   block: 4.4× less activation memory on the XLA attention path (measured
-   via compiled memory analysis, SCALING.md).
+   block: activations are recomputed in the backward pass and not kept.
 3. **sequence parallelism** — the whole forward+backward in one `shard_map`
    with activations sharded along L (`sequence_parallel_transformer_forward`):
    per-chip activation memory O(L/N), so context scales with the mesh.

@@ -276,9 +276,9 @@ def test_block_ladders_scale_with_length():
 @pytest.mark.parametrize("causal", [False, True])
 def test_large_block_path_matches_reference(rng, causal):
     """The L = 4096 (512, 2048) tile path, end to end in interpret mode:
-    forward and all three gradients vs the XLA oracle (the native-chip
-    equality at L=4k/8k/16k is in SCALING.md; this pins the same code path
-    in CI)."""
+    forward and all three gradients vs the XLA oracle (``chip_smoke.py``'s
+    ``kernels`` phase holds the native kernels to XLA on the chip; this pins
+    the same code path in CI)."""
     Lbig = 4096
     q = rng.normal(0, 1, size=(1, Lbig, 1, 64)).astype(np.float32)
     k = rng.normal(0, 1, size=(1, Lbig, 1, 64)).astype(np.float32)

@@ -197,6 +197,31 @@ def _run_engine(spec, params, jobs, **eng_kw):
     return eng, [np.asarray(r.result(0)) for r in reqs]
 
 
+def test_prefix_hit_rate_is_nought_cold_and_most_of_a_warm_wave(lm):
+    """A cold cache serves its first request from nothing; a second wave
+    behind the same system prompt maps the cached blocks and prefills only
+    its tails. (The hit rate is by tokens, over the engine's life.)"""
+    spec, params = lm
+    jobs = _jobs(np.random.default_rng(29), 5, sys_len=16, tail=3, max_new=2)
+    eng = GenerationEngine(spec, params, max_batch=4, block_size=8,
+                           max_queue=64, prefix_cache=True)
+    eng.submit(jobs[0][0], **jobs[0][1])
+    eng.run_until_idle()
+    cold = eng.stats()
+    assert cold["prefix_hit_rate"] == 0.0 and cold["prefix_hit_tokens"] == 0
+    reqs = [eng.submit(p, **kw) for p, kw in jobs[1:]]
+    eng.run_until_idle()
+    assert all(r.result(0).shape == (2,) for r in reqs)
+    warm = eng.stats()
+    wave_tokens = warm["prefix_prompt_tokens"] - cold["prefix_prompt_tokens"]
+    assert wave_tokens == sum(len(p) for p, _ in jobs[1:])
+    # both whole blocks of the 16-token system prompt, for each of the four
+    assert warm["prefix_hit_tokens"] / wave_tokens >= 0.5
+    assert eng.prefix_hit_rate() == warm["prefix_hit_rate"] > 0.0
+    eng.flush_prefix_cache()
+    assert eng.stats()["blocks_in_use"] == 0
+
+
 def test_frontdoor_bit_identical_to_cache_off(lm):
     """Every front-door knob combination — prefix cache (COW included),
     chunked prefill at a non-block-aligned chunk, SLO admission — serves

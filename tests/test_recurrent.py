@@ -36,8 +36,8 @@ def pallas_scan(gx, wh):
 def test_forward_matches_reference(rng, dtype):
     """bf16 is the production default path (LSTMClassifier dtype). In f32
     the kernel matches the XLA scan to float tolerance; in bf16 the two
-    agree to the bf16 rounding floor here (on the chip, where XLA keeps
-    excess precision, they are measured bit-exact — SCALING.md)."""
+    agree to the bf16 rounding floor here (``chip_smoke.py``'s ``kernels``
+    phase compares them on the chip)."""
     gx, wh = make_inputs(rng, dtype=dtype)
     out = pallas_scan(gx, wh)
     ref = lstm_scan_reference(gx, wh)
