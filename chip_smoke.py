@@ -6,7 +6,7 @@ a user calls, at the full width of models the repo supports, and checks what
 comes out by the repo's own means. Run it through the chip tool from the root
 of a checkout:
 
-    python3 chip_smoke.py              # one chip: kernels, adag, lm, moe, serve
+    python3 chip_smoke.py              # one chip: kernels, loss, adag, lm, moe, serve
     python3 chip_smoke.py --chips 4    # four chips: adag4, lm4 (and no other)
 
 Each phase prints one JSON line (its name, seconds, what it checked); the last
@@ -87,10 +87,10 @@ def _peak_bytes(devices) -> list:
 # ---------------------------------------------------------------------------
 
 
-def _device_ms_by_kernel(run, names, calls: int) -> dict:
-    """``{name: ms a call}`` on the chip for the kernels whose operations'
-    names start with one of ``names``, from a profiler trace of ``run()``
-    (which makes ``calls`` calls of each); None where the trace holds none."""
+def _device_ms_by_op(run, calls: int) -> dict:
+    """``{operation: ms a call}`` on the chip, from a profiler trace of
+    ``run()`` (which makes ``calls`` calls): every operation's own time, what
+    it contains taken out (a ``while`` is not charged its body's)."""
     import tempfile
 
     import jax
@@ -101,14 +101,23 @@ def _device_ms_by_kernel(run, names, calls: int) -> dict:
         with jax.profiler.trace(d):
             run()
         planes = xplane.device_planes(xplane.read_planes(xplane.trace_file(d)))
-    ns = dict.fromkeys(names, 0.0)
+    ns: dict[str, float] = {}
     for lines in planes.values():
-        for event, _, dur in lines[xplane.OPS_LINE]:
-            stem = xplane.short_name(event).split(".")[0]
-            if stem in ns:
-                ns[stem] += dur
-    return {k: round(v / 1e6 / calls, 4) if v else None
-            for k, v in ns.items()}
+        for event, _, _, own in xplane.self_times(lines[xplane.OPS_LINE]):
+            name = xplane.short_name(event)
+            ns[name] = ns.get(name, 0.0) + own
+    return {k: v / 1e6 / calls for k, v in ns.items()}
+
+
+def _device_ms_by_kernel(run, names, calls: int) -> dict:
+    """``{name: ms a call}`` for the kernels whose operations' names start
+    with one of ``names``; None where the trace holds none."""
+    ms = dict.fromkeys(names, 0.0)
+    for op, v in _device_ms_by_op(run, calls).items():
+        stem = op.split(".")[0]
+        if stem in ms:
+            ms[stem] += v
+    return {k: round(v, 4) if v else None for k, v in ms.items()}
 
 
 def _flash_times(timed, interpret, reps=3) -> list:
@@ -263,6 +272,70 @@ def kernels(*, attn=(8, 2048, 8, 128),
         _check(auto == {"attention": "flash", "lstm_scan": "pallas",
                         "q_matmul": "pallas"},
                f"kernels: 'auto' does not pick the kernels here: {auto}")
+    return line
+
+
+# ---------------------------------------------------------------------------
+# loss: the fused cross-entropy alone, at the benchmark cells' shapes
+# ---------------------------------------------------------------------------
+
+
+def loss(*, shapes=((32768, 2048, 32784, 256), (16384, 1024, 256008, 256)),
+         timed=True, reps=3, check_rows=128):
+    """``value_and_grad`` of ``chunked_softmax_cross_entropy`` alone at each
+    ``(N, D, V, chunk)`` of ``shapes`` (bf16 hidden and head; the two
+    benchmark cells' by default): the static count of what its backward runs
+    (``_vocab_tiles``: tiles, their width, padded columns, bytes of the
+    float32 ``[N, D]`` carry read and written a call), ms a call and the four
+    longest operations on the chip (None where ``timed`` is off: a CPU gives
+    no device time), and the hidden gradient of the first ``check_rows``
+    rows against the plain softmax formula on those rows."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.ops import fused_ce
+
+    t0 = time.perf_counter()
+    out = []
+    for n, d, v, chunk in shapes:
+        kh, kw, ky = jax.random.split(jax.random.PRNGKey(SEED + v), 3)
+        h = jax.random.normal(kh, (n, d), jnp.bfloat16)
+        w = (0.02 * jax.random.normal(kw, (d, v))).astype(jnp.bfloat16)
+        y = jax.random.randint(ky, (n,), 0, v, jnp.int32)
+        step = jax.jit(jax.value_and_grad(
+            lambda h, w, y: fused_ce.chunked_softmax_cross_entropy(
+                h, y, w, chunk=chunk), argnums=(0, 1)))
+        value, (dh, _) = jax.block_until_ready(step(h, w, y))   # compiles
+
+        def plain_dh(h, w, y):
+            logits = jnp.dot(h, w, preferred_element_type=jnp.float32)
+            p = jax.nn.softmax(logits, axis=-1)
+            dl = (p - jax.nn.one_hot(y, v, dtype=p.dtype)) / n
+            return jnp.dot(dl.astype(h.dtype), w.T,
+                           preferred_element_type=jnp.float32)
+
+        err = _norm_err(dh[:check_rows], jax.jit(plain_dh)(
+            h[:check_rows], w, y[:check_rows]))
+        ms = ops = None
+        if timed:
+            by_op = _device_ms_by_op(
+                lambda: jax.block_until_ready(
+                    [step(h, w, y) for _ in range(reps)]), reps)
+            ms = round(sum(by_op.values()), 3)
+            ops = {k: round(by_op[k], 3)
+                   for k in sorted(by_op, key=by_op.get, reverse=True)[:4]}
+        tiles, vb = fused_ce._vocab_tiles(n, v, chunk)
+        out.append({"shape": [n, d, v, chunk], "tiles": tiles, "vb": vb,
+                    "padded_columns": tiles * vb - v,
+                    "carry_bytes_moved": 8 * n * d * tiles,
+                    "loss": float(value), "dh_norm_err": err,
+                    "ms": ms, "ops": ops})
+    line = _report("loss", t0, timed=bool(timed), shapes=out)
+    for o in out:
+        _check(math.isfinite(o["loss"]), f"loss: {o['loss']} at {o['shape']}")
+        _check(o["dh_norm_err"] <= TOL["bfloat16"],
+               f"loss: d_hidden off the plain formula by "
+               f"{o['dh_norm_err']:.3g} at {o['shape']}")
     return line
 
 
@@ -778,7 +851,7 @@ def main(argv=None) -> int:
     from distkeras_tpu.observability import trace
 
     for phase in ((adag4, lm4) if args.chips == 4
-                  else (kernels, adag, lm, moe, serve)):
+                  else (kernels, loss, adag, lm, moe, serve)):
         phase()
     counts = trace.jax_counts()
     print(json.dumps({"phase": "cache", "dir": cache_dir,

@@ -25,17 +25,32 @@ f32 hidden gradient. A loop over row chunks would have to carry the
 kernel's gradient instead — an f32 ``[D, V]`` array read and written once
 a chunk, which at V = 256k is most of the step's memory traffic.
 
-The tile width follows from the shapes: with ``steps = ⌈N / chunk⌉`` row
-chunks in the forward, ``Vb = 128 · ⌈⌈V / steps⌉ / 128⌉`` (the forward's
-``chunk × V`` logits budget spread over all ``N`` rows, rounded up to
-whole 128-lane tiles) and the backward makes ``tiles = ⌈V / Vb⌉`` steps
-(:func:`_vocab_tiles`); the kernel is zero-padded to ``tiles · Vb``
-columns and the padded columns are masked to ``p = 0``, so they get no
-gradient and give none. When one row chunk holds every row
-(``N ≤ chunk``), or ``Vb ≥ V``, there is one tile of width ``V`` and no
-padding. Peak extra memory is ``O(chunk · V)`` activations (``O(N · Vb)``
-in the backward — the same size) plus the ``[N, D]`` f32 carry, instead of
-``O(N · V)``.
+The tile width follows from what a tile costs. Every tile reads and
+writes the whole carry, ``8 · N · D`` bytes, to add a product of
+``2 · N · D · Vb`` FLOPs to it, so below ``Vb = 4 ·`` (the chip's FLOPs a
+byte) — 962 columns on a v5e, whatever ``N`` and ``D`` are — the
+``d_hidden`` product waits for HBM and not for the matrix unit. The width
+aimed at is therefore the larger of the forward's budget spread over all
+rows (``128 · ⌈⌈V / steps⌉ / 128⌉`` with ``steps = ⌈N / chunk⌉`` row
+chunks: ``chunk`` still widens the tile where it asks for more) and
+:data:`_BWD_TILE_COLUMNS`; then the tiles are evened out so that padding
+stays under 128 columns a tile: ``tiles = ⌈V / width⌉``,
+``Vb = 128 · ⌈⌈V / tiles⌉ / 128⌉`` (:func:`_vocab_tiles`, whose docstring
+has the sweep measured on the chip). The kernel is zero-padded to
+``tiles · Vb`` columns and the padded columns are masked to ``p = 0``, so
+they get no gradient and give none. When one row chunk holds every row
+(``N ≤ chunk``), or ``V`` is no wider than the width aimed at, there is
+one tile of width ``V`` and no padding.
+
+Peak extra memory: ``O(chunk · V)`` activations in the forward; in the
+backward ``O(N · max(Vb_budget, _BWD_TILE_COLUMNS))`` — a tile's bf16
+``dlogits`` (``N · Vb · 2`` bytes: 243 MB at 32,768 rows of 3,712
+columns, 134 MB at 16,384 of 4,096; three times that where the compiler
+keeps a float32 tile beside it) — plus the ``[N, D]`` f32 carry, instead
+of ``O(N · V)``. The backward's share no longer shrinks with ``chunk``
+below the floor: a caller with far more rows than these pays in
+proportion (a row-blocked inner loop would bound it, at the price of a
+kernel-shaped carry; not built).
 
 This is a compiler-level fusion, not a Pallas kernel, on purpose: the
 chunk matmul ``[chunk, D] · [D, V]`` is exactly MXU-shaped, and XLA already
@@ -72,17 +87,66 @@ def _chunk_rows(n: int, chunk: int) -> tuple[int, int]:
     return steps, steps * chunk
 
 
+#: Least width (columns) the backward aims at for a vocabulary tile. A tile
+#: moves the ``[N, D]`` float32 carry through HBM once each way, ``8 N D``
+#: bytes, for a ``d_hidden`` product of ``2 N D Vb`` FLOPs: the two take
+#: the same time at ``Vb = 4 x`` the chip's FLOPs a byte, which is
+#: ``4 x 197e12 / 819e9 = 962`` columns on a TPU v5e for any ``N`` and
+#: ``D``. 4096, about four times that, leaves the carry's traffic under a
+#: quarter of the product's time, and is the width measured at 91-97 % of peak
+#: (:func:`_vocab_tiles` has the sweep). A multiple of 128 lanes.
+_BWD_TILE_COLUMNS = 4096
+
+
 def _vocab_tiles(n: int, v: int, chunk: int) -> tuple[int, int]:
     """Number of backward tiles and their width ``Vb`` (module docstring).
 
-    ``Vb = 128 · ⌈⌈V / steps⌉ / 128⌉`` with ``steps = ⌈N / chunk⌉`` and
-    ``tiles = ⌈V / Vb⌉``; one tile of width ``V`` when ``Vb ≥ V``.
+    The width aimed at is the larger of the forward's budget,
+    ``128 · ⌈⌈V / steps⌉ / 128⌉`` with ``steps = ⌈N / chunk⌉``, and
+    :data:`_BWD_TILE_COLUMNS`; ``tiles = ⌈V / width⌉`` and the tiles are
+    evened, ``Vb = 128 · ⌈⌈V / tiles⌉ / 128⌉``, so that fewer than 128
+    columns a tile are padding and ``Vb`` is over half the width aimed at.
+    One tile of width ``V`` when the width aimed at is ``V`` or more.
+
+    v5e, PR 30: the loss's ``value_and_grad`` alone (bf16, chunk 256) with
+    the width forced, ms a call by operation from a profiler trace: the
+    product added into the carry / logits to ``dlogits`` / ``d_kernel`` /
+    the whole call. 32768 x 2048 x 32784 (ZAYA1's cut, where the budget
+    alone gave the first)::
+
+        86 x   384   70.19  24.10  24.48   150.5
+        33 x  1024   27.67  24.53  25.83   109.7
+        17 x  2048   28.11  25.25  27.36   112.4
+        12 x  2816   24.53  27.56  27.27   111.0
+         9 x  3712   24.66  24.92  25.57   106.8   <- the rule
+         8 x  4224   30.38  25.82  27.25   115.1
+         5 x  6656   27.04  24.09  25.54   108.3
+         3 x 10944   28.41  23.81  23.65   107.1
+
+    16384 x 1024 x 256008 (XGLM)::
+
+        63 x  4096   47.56  45.65  47.45   249.1   <- the rule, and before
+        32 x  8064   46.58  46.53  45.87   243.4
+
+    A product is 4.43-4.48 TFLOP at the first shape (22.5 ms at the 197
+    TFLOP/s peak) and 8.66 at the second (44.0 ms); the forward's 28.58 and
+    80.7 ms are in the whole call. 384 columns pay for the carry's traffic
+    (46 GB a call); from 1,024 up the three products lie within a few ms
+    of one another and of the peak, and which width is quickest follows how
+    the compiler tiles each product more than the carry: the rule's
+    9 x 3712 read the least of the eight, the next wider the most. The
+    ``exp``, one-hot and weights stay in the logits product's fusion, whose
+    output is the bf16 ``dlogits`` tile (the compiled step's text at 3,712;
+    that operation's time at every width). XGLM's 8,064 read 2.3 % under
+    its 4,096, 3.9 ms of it in the forward, which this rule does not touch:
+    not taken, that cell is the control.
     """
     steps, _ = _chunk_rows(n, chunk)
-    vb = 128 * _cdiv(_cdiv(v, steps), 128)
-    if vb >= v:
+    width = max(128 * _cdiv(_cdiv(v, steps), 128), _BWD_TILE_COLUMNS)
+    if width >= v:
         return 1, v
-    return _cdiv(v, vb), vb
+    tiles = _cdiv(v, width)
+    return tiles, 128 * _cdiv(_cdiv(v, tiles), 128)
 
 
 def _pad_to(x, size, axis=0):
@@ -213,7 +277,9 @@ def chunked_softmax_cross_entropy(hidden, labels, kernel, bias=None, *,
       mask: optional ``[N]`` validity weights; loss is
         ``sum(nll · mask) / max(sum(mask), 1)``. Default: all rows valid.
       chunk: rows per scan step — peak logits memory is ``chunk × V`` f32
-        (the backward's ``[N, Vb]`` vocabulary tile is sized to the same).
+        (the backward's ``[N, Vb]`` vocabulary tile is cut from the same
+        budget where that is wider than what hides the carry's traffic:
+        module docstring).
     """
     hidden = jnp.asarray(hidden)
     if hidden.ndim != 2:

@@ -58,6 +58,24 @@ def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
                            timed=())
 
 
+def test_loss_phase_tiny():
+    """The fused loss alone: the static count of its backward's tiles beside
+    (off the chip) no device time, and d_hidden held to the plain formula."""
+    line = chip_smoke.loss(shapes=((96, 8, 9000, 16), (40, 8, 300, 64)),
+                           timed=False, check_rows=32)
+    assert line["phase"] == "loss" and line["timed"] is False
+    several, one = line["shapes"]
+    assert set(several) == {"shape", "tiles", "vb", "padded_columns",
+                            "carry_bytes_moved", "loss", "dh_norm_err", "ms",
+                            "ops"}
+    assert (several["tiles"], several["vb"]) == (3, 3072)
+    assert several["padded_columns"] == 216
+    assert several["carry_bytes_moved"] == 3 * 8 * 96 * 8
+    assert (one["tiles"], one["vb"], one["padded_columns"]) == (1, 300, 0)
+    assert several["ms"] is None and several["ops"] is None
+    assert several["dh_norm_err"] < 2e-2 and one["dh_norm_err"] < 2e-2
+
+
 def test_adag_phase_tiny():
     line = chip_smoke.adag(n_train=512, n_test=128, batch_size=16, window=1,
                            epochs=3, num_workers=2, min_accuracy=0.5)

@@ -118,31 +118,36 @@ def test_bf16_params_close_to_f32_oracle(rng):
     assert float(rel.max()) <= 5e-2 * float(np.abs(np.asarray(gr)).max()) + 1e-4
 
 
-# every edge of the backward's vocabulary tiling (``_vocab_tiles``): V not a
-# multiple of 128 lanes or of the tile, N not a multiple of ``chunk``, one
-# tile (``N <= chunk``), with and without a bias, both dtypes
+# every edge of the backward's vocabulary tiling (``_vocab_tiles``; the width
+# aimed at is 4096 or the forward's budget, whichever is larger): several
+# tiles with padded columns, V a multiple of the tile, N not a multiple of
+# ``chunk``, one tile (``N <= chunk``, or V no wider than the floor), with
+# and without a bias, both dtypes
 _TILING_CASES = [
-    # n, d, v, chunk, bias, dtype
-    (37, 16, 101, 8, True, "float32"),      # vb 128 >= V: one tile
-    (37, 16, 101, 64, False, "float32"),    # N <= chunk: one tile
-    (96, 16, 1000, 16, True, "float32"),    # 6 chunks -> vb 256, 4 tiles
-    (96, 16, 1000, 16, False, "bfloat16"),
-    (100, 8, 1000, 16, True, "bfloat16"),   # N % chunk != 0: 7 chunks, 4 tiles
-    (512, 32, 1000, 64, False, "float32"),  # vb 128, 8 tiles, 24 padded cols
-    (64, 8, 4099, 8, True, "float32"),      # vb 640, 7 tiles, 381 padded cols
-    (64, 8, 4099, 8, False, "bfloat16"),
-    (50, 8, 4099, 7, True, "bfloat16"),     # 8 chunks -> vb 640, ragged rows
-    (48, 8, 256, 24, True, "float32"),      # V a multiple of the tile: no pad
-    (16, 8, 4099, 16, True, "float32"),     # N == chunk at a wide V: one tile
+    # n, d, v, chunk, bias, dtype, (tiles, width) that _vocab_tiles gives
+    (37, 16, 101, 8, True, "float32", (1, 101)),      # V <= floor: one tile
+    (37, 16, 101, 64, False, "float32", (1, 101)),    # N <= chunk too
+    (96, 8, 9000, 16, True, "float32", (3, 3072)),    # 216 padded columns
+    (96, 8, 9000, 16, False, "bfloat16", (3, 3072)),
+    (90, 8, 9000, 16, True, "bfloat16", (3, 3072)),   # N % chunk != 0
+    (32, 8, 12300, 16, False, "float32", (2, 6272)),  # budget over the floor
+    (64, 8, 13000, 8, True, "float32", (4, 3328)),    # 312 padded columns
+    (64, 8, 13000, 8, False, "bfloat16", (4, 3328)),
+    (50, 8, 13000, 7, True, "bfloat16", (4, 3328)),   # 8 ragged row chunks
+    (48, 8, 8192, 24, True, "float32", (2, 4096)),    # V = tiles x width
+    (16, 8, 4099, 16, True, "float32", (1, 4099)),    # N == chunk, wide V
 ]
 
 
-@pytest.mark.parametrize("n,d,v,chunk,use_bias,dtype", _TILING_CASES)
+@pytest.mark.parametrize("n,d,v,chunk,use_bias,dtype,tiling", _TILING_CASES)
 def test_value_and_all_gradients_across_tiling_edges(rng, n, d, v, chunk,
-                                                     use_bias, dtype):
+                                                     use_bias, dtype, tiling):
     """Loss and the gradients of hidden, kernel, bias and mask against the
     unfused oracle (float32 operands); a label in the last real column and
-    masked rows in every case."""
+    masked rows in every case; the case reaches the edge it names."""
+    from distkeras_tpu.ops.fused_ce import _vocab_tiles
+
+    assert _vocab_tiles(n, v, chunk) == tiling
     h, y, w, b = _problem(rng, n=n, d=d, v=v)
     y[0] = y[n - 1] = v - 1
     mask = rng.uniform(0.2, 1.0, size=n).astype(np.float32)
@@ -179,6 +184,41 @@ def test_value_and_all_gradients_across_tiling_edges(rng, n, d, v, chunk,
     assert np.all(np.asarray(gf[0], np.float32)[mask == 0.0] == 0.0)
 
 
+def _budget_rule(n, v, chunk):
+    """``_vocab_tiles`` as it was before PR 30: the forward's budget alone."""
+    from distkeras_tpu.ops.fused_ce import _cdiv
+
+    vb = 128 * _cdiv(_cdiv(v, max(1, _cdiv(n, chunk))), 128)
+    return (1, v) if vb >= v else (_cdiv(v, vb), vb)
+
+
+@pytest.mark.parametrize("chunk", [64, 256])
+@pytest.mark.parametrize("v", [1000, 4096, 4097, 9000, 32784, 151936, 256008])
+@pytest.mark.parametrize("n", [96, 4096, 32768])
+def test_vocab_tiles_properties(n, v, chunk):
+    """The tiles cover V with under 128 padded columns a tile and none wholly
+    padded; a tile is whole 128-lane groups unless it is the only one; where
+    V is wider than the floor a tile is wider than half of it (so above the
+    962 columns at which the carry's traffic equals the product, where the
+    budget rule gave 384 at ZAYA's cut); and the loop never makes more passes
+    over the carry than the budget rule alone did."""
+    from distkeras_tpu.ops.fused_ce import _BWD_TILE_COLUMNS, _vocab_tiles
+
+    tiles, vb = _vocab_tiles(n, v, chunk)
+    old_tiles, old_vb = _budget_rule(n, v, chunk)
+    assert _BWD_TILE_COLUMNS % 128 == 0
+    assert tiles * vb >= v > (tiles - 1) * vb
+    assert tiles * vb - v < 128 * tiles
+    assert tiles <= old_tiles
+    if n <= chunk or v <= _BWD_TILE_COLUMNS:
+        assert (tiles, vb) == (1, v)
+    if tiles == 1:
+        assert vb == v
+    else:
+        assert vb % 128 == 0
+        assert vb > max(_BWD_TILE_COLUMNS, old_vb) // 2 and vb > 962
+
+
 def _eqns(jaxpr):
     """Every equation of a jaxpr, sub-jaxprs included."""
     for eqn in jaxpr.eqns:
@@ -199,11 +239,15 @@ def test_backward_walks_vocabulary_tiles_and_carries_no_kernel(rng):
     logits product and the two gradient products, not a second logits pass."""
     from distkeras_tpu.ops.fused_ce import _vocab_tiles
 
-    n, d, v, chunk = 512, 32, 1000, 64
-    assert _vocab_tiles(n, v, chunk) == (8, 128)
-    # the benchmark's shape, and a head that fits one row chunk
+    n, d, v, chunk = 128, 8, 9000, 16
+    assert _vocab_tiles(n, v, chunk) == (3, 3072)
+    # the benchmark's two cells (xglm-564m.train's head, where the budget
+    # asks for the floor's width itself; zaya1-8b.train's cut, where it asks
+    # for 384 columns), a head that fits one row chunk, one under the floor
     assert _vocab_tiles(16384, 256008, 256) == (63, 4096)
+    assert _vocab_tiles(32768, 32784, 256) == (9, 3712)
     assert _vocab_tiles(200, 50000, 256) == (1, 50000)
+    assert _vocab_tiles(512, 1000, 64) == (1, 1000)
     h, y, w, _ = _problem(rng, n=n, d=d, v=v)
     jaxpr = jax.make_jaxpr(jax.grad(
         lambda h, w: chunked_softmax_cross_entropy(h, y, w, None,
@@ -225,7 +269,7 @@ def test_backward_walks_vocabulary_tiles_and_carries_no_kernel(rng):
         for shape in carries(eqn):
             assert int(np.prod(shape)) < d * v, shape
     # d_kernel leaves the loop as its stacked output, one tile a step
-    assert (8, d, 128) in [tuple(x.aval.shape) for x in bwd.outvars]
+    assert (3, d, 3072) in [tuple(x.aval.shape) for x in bwd.outvars]
     # one logits product in the forward; logits, d_hidden, d_kernel in a tile
     assert _count(fwd.params["jaxpr"].jaxpr, "dot_general") == 1
     assert _count(bwd.params["jaxpr"].jaxpr, "dot_general") == 3
@@ -245,7 +289,7 @@ def test_sharded_vocabulary_and_rows_give_the_same_gradients(rng):
     jit, and GSPMD must keep value and gradients what one device computes."""
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    n, d, v, chunk = 96, 16, 1000, 16          # 4 tiles of 256, 24 padded
+    n, d, v, chunk = 96, 8, 9000, 16           # 3 tiles of 3072, 216 padded
     h, y, w, _ = _problem(rng, n=n, d=d, v=v)
     mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
     hs = jax.device_put(h, NamedSharding(mesh, P("dp", None)))
