@@ -122,10 +122,13 @@ def _device_ms_by_kernel(run, names, calls: int) -> dict:
 
 def _flash_times(timed, interpret, reps=3) -> list:
     """The three flash kernels at each ``(B, L, H, D, Hkv)`` of ``timed``,
-    causal and not: ms a call on the chip (None in interpret mode: a CPU gives
-    no device time) beside what ``band_census`` counts for that length: grid
-    steps a head and how many are idle, computed over band pairs, and the
-    share of the computed pairs that run with no mask."""
+    causal and not — or, for an entry ``(B, L, H, D, Hkv, G)``, under the
+    block-diffusion mask over blocks of ``G`` (``L`` the stream's length: a
+    noised and a clean copy of rows of ``L // 2``): ms a call on the chip
+    (None in interpret mode: a CPU gives no device time) beside what
+    ``band_census`` counts for that length: grid steps a head and how many
+    are idle, computed over band pairs, and the share of the computed pairs
+    that run with no mask."""
     import jax
     import jax.numpy as jnp
 
@@ -133,16 +136,18 @@ def _flash_times(timed, interpret, reps=3) -> list:
 
     names = ("flash_fwd", "flash_dq", "flash_dkv")
     out = []
-    for B, L, H, D, Hkv in timed:
+    for B, L, H, D, Hkv, *block in timed:
         keys = jax.random.split(jax.random.PRNGKey(SEED + L), 4)
         q, g = (jax.random.normal(k, (B, L, H, D), jnp.bfloat16)
                 for k in keys[:2])
         k, v = (jax.random.normal(k, (B, L, Hkv, D), jnp.bfloat16)
                 for k in keys[2:])
-        for causal in (True, False):
+        # one mask an entry: the block-diffusion one, or causal and none
+        for mask in ([dict(block_diffusion=block[0])] if block
+                     else [dict(causal=True), dict(causal=False)]):
             def fwd_bwd(q, k, v, g):
                 o, vjp = jax.vjp(lambda q, k, v: flash_attention(
-                    q, k, v, causal=causal, interpret=interpret), q, k, v)
+                    q, k, v, interpret=interpret, **mask), q, k, v)
                 return (o,) + vjp(g)
             step = jax.jit(fwd_bwd)
             jax.block_until_ready(step(q, k, v, g))      # compiles
@@ -152,8 +157,8 @@ def _flash_times(timed, interpret, reps=3) -> list:
                     lambda: jax.block_until_ready(
                         [step(q, k, v, g) for _ in range(reps)]),
                     names, reps)
-            census = band_census(L, causal=causal)
-            out.append({"shape": [B, L, H, D, Hkv], "causal": causal, **{
+            census = band_census(L, **mask)
+            out.append({"shape": [B, L, H, D, Hkv], **mask, **{
                 n: {"ms": ms[n], "steps": c["steps"],
                     "steps_idle": c["steps_idle"],
                     "computed_over_band": round(c["computed_over_band"], 4),
@@ -166,14 +171,17 @@ def _flash_times(timed, interpret, reps=3) -> list:
 def kernels(*, attn=(8, 2048, 8, 128),
             qmm=((8, 2048, 8192), (1024, 8192, 2048)),
             adam=(16384, 1024), lstm=(64, 200, 512), interpret=False,
-            timed=((8, 2048, 16, 64, 16), (8, 4096, 8, 128, 2))):
-    """flash attention fwd+bwd (causal, bf16), ``q_matmul`` (bf16 × int8),
-    fused Adam (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real
-    call shape with ``interpret`` EXPLICIT, against ``attention_reference``,
+            timed=((8, 2048, 16, 64, 16), (8, 4096, 8, 128, 2),
+                   (4, 8192, 32, 128, 4, 4)), block=4):
+    """flash attention fwd+bwd (causal, and under the block-diffusion mask
+    over blocks of ``block``; bf16), ``q_matmul`` (bf16 × int8), fused Adam
+    (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real call shape
+    with ``interpret`` EXPLICIT, against ``attention_reference``,
     ``_q_matmul_xla``, ``optax.adam`` and ``lstm_scan_reference``. Then the
-    three flash kernels timed on the chip at ``timed``, the two benchmark
-    cells' ``(B, L, H, D, Hkv)``: what a computed pair costs with and
-    without the mask, beside what ``band_census`` says they compute."""
+    three flash kernels timed on the chip at ``timed``, the benchmark cells'
+    ``(B, L, H, D, Hkv)`` (the third with its block length: the
+    block-diffusion call): what a computed pair costs with and without the
+    mask, beside what ``band_census`` says they compute."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -213,6 +221,16 @@ def kernels(*, attn=(8, 2048, 8, 128),
             q, k, v, causal=True), (q, k, v), g),
         ("out", "dq", "dk", "dv"),
     )
+    if (L // 2) % 128 == 0:      # the same arrays as a noised and a clean copy
+        compare(
+            "flash_bd",
+            fwd_bwd(lambda q, k, v: flash_attention(
+                q, k, v, block_diffusion=block, interpret=interpret),
+                (q, k, v), g),
+            fwd_bwd(lambda q, k, v: attention_reference(
+                q, k, v, block_diffusion=block), (q, k, v), g),
+            ("out", "dq", "dk", "dv"),
+        )
 
     for m, kk, n in qmm:
         x = jax.random.normal(next(keys), (m, kk), bf16)
@@ -532,14 +550,18 @@ def _zaya_spec(vocab, maxlen, dim, heads, kv_heads, depth, zaya, ce_chunk,
 def moe(*, vocab=8192, maxlen=1024, dim=512, heads=4, kv_heads=2, depth=2,
         head_dim=128, router_dim=128, experts=8, experts_held=(0, 4),
         expert_dim=512, ce_chunk=256, batch=4, steps=4, epochs=2,
-        kernel_calls=30):
+        kernel_calls=30, top_k=(4096, 512, 256, 32, 8, 8)):
     """``MeshTrainer(...).train`` on ``transformer_lm(zaya=...)`` in bf16 with
     flash attention, the fused cross-entropy and remat, holding half the
     router's experts: every loss finite, the first equal to the plain float32
     model's on the same batch and parameters to bf16 tolerance, the compiled
     step holding ``kernel_calls`` kernel calls (flash attention's and the
     grouped expert products'), and the counters the trainer fetched adding
-    up to every token once a layer."""
+    up to every token once a layer. Then ``dropless_experts`` alone at
+    ``top_k = (tokens, dim, expert_dim, experts, held, k)``: the ``k``
+    largest of a random router renormalised, the first ``held`` experts held,
+    in chunks of ``held_rows``, against every held expert applied to every
+    token under its one-hot weight."""
     import jax
 
     from distkeras_tpu.models import ZayaDims
@@ -576,12 +598,19 @@ def moe(*, vocab=8192, maxlen=1024, dim=512, heads=4, kv_heads=2, depth=2,
         )(p0, nt0, x, y))
 
     first, count = experts_held
+    top = _top_k_experts(*top_k)
     line = _report(
         "moe", t0, steps=len(losses), losses=[round(v, 4) for v in losses],
         plain_f32_first_loss=want, kernel_calls_in_step=calls,
         tokens_by_layer_and_expert=routed.tolist(),
         held_share=float(routed[:, first:first + count].sum() / routed.sum()),
+        top_k=top,
     )
+    _check(top["norm_err"] <= TOL["bfloat16"],
+           f"moe: top-{top_k[-1]} dropless experts off the one-hot sum by "
+           f"{top['norm_err']:.3g} (normalized)")
+    _check(top["pairs"] == top_k[0] * top_k[-1],
+           f"moe: top-k counted {top['pairs']} pairs")
     _check(len(losses) == steps * epochs,
            f"moe: {len(losses)} losses for {steps * epochs} steps")
     _check(all(math.isfinite(v) for v in losses), "moe: non-finite loss")
@@ -593,6 +622,48 @@ def moe(*, vocab=8192, maxlen=1024, dim=512, heads=4, kv_heads=2, depth=2,
     _check(routed.sum(1).tolist() == [batch * steps * epochs * maxlen] * depth,
            f"moe: the counters hold {routed.sum(1).tolist()} tokens a layer")
     return line
+
+
+def _top_k_experts(tokens, dim, expert_dim, experts, held, k) -> dict:
+    """``dropless_experts`` on ``[tokens, k]`` pairs (bf16) against the plain
+    one-hot sum in float32: the error, the pairs counted and held, and the
+    rows a chunk holds."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models import SdarDims
+    from distkeras_tpu.models.lm import held_rows
+    from distkeras_tpu.parallel.expert import dropless_experts
+
+    ks = jax.random.split(jax.random.PRNGKey(SEED + k), 4)
+    x = jax.random.normal(ks[0], (tokens, dim), jnp.bfloat16)
+    w_in = jax.random.normal(ks[1], (held, dim, 2 * expert_dim)) * dim ** -0.5
+    w_out = jax.random.normal(ks[2], (held, expert_dim, dim)) \
+        * expert_dim ** -0.5
+    top, chosen = jax.lax.top_k(
+        jax.nn.softmax(jax.random.normal(ks[3], (tokens, experts))), k)
+    weight = top / top.sum(-1, keepdims=True)
+    rows = held_rows(tokens, SdarDims(experts=experts, experts_per_token=k,
+                                      experts_held=(0, held)))
+    got, pairs = jax.jit(lambda x, a, b: dropless_experts(
+        x, chosen.astype(jnp.int32), weight, a, b, experts=(0, held),
+        total=experts, rows=rows))(x, w_in, w_out)
+
+    def plain(x, w_in, w_out):
+        y = jnp.zeros(x.shape, jnp.float32)
+        for j in range(held):
+            gu = x @ w_in[j]
+            out = (jax.nn.silu(gu[:, :expert_dim]) * gu[:, expert_dim:]) \
+                @ w_out[j]
+            y = y + jnp.sum(jnp.where(chosen == j, weight, 0.0), -1,
+                            keepdims=True) * out
+        return y
+
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(plain)(x.astype(jnp.float32), w_in, w_out)
+    return {"k": k, "rows_a_chunk": list(rows), "pairs": int(pairs.sum()),
+            "pairs_held": int(pairs[:held].sum()),
+            "norm_err": _norm_err(got, want)}
 
 
 # ---------------------------------------------------------------------------

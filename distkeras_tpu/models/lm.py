@@ -44,6 +44,13 @@ def rope_angles(maxlen: int, head_dim: int, base: float = 10000.0):
     return (np.arange(maxlen)[:, None] * inv[None, :]).astype(np.float32)
 
 
+def rope_angles_at(positions, head_dim: int, base: float = 10000.0):
+    """:func:`rope_angles` at the ``positions`` given (a vector, in any order
+    and with repeats) instead of ``0 .. maxlen - 1``."""
+    inv = base ** (-np.arange(0, head_dim, 2) / head_dim)
+    return (np.asarray(positions)[:, None] * inv[None, :]).astype(np.float32)
+
+
 def apply_rope(x, angles):
     """Rotate feature pairs of ``x`` [..., L, H, Dh] by per-position
     ``angles`` [L, Dh//2] (pairing (x[2i], x[2i+1]), rotation in f32, cast
@@ -530,25 +537,111 @@ class CCAttention(nn.Module):
         return _rescaled(self, x, o)
 
 
+def _zaya_router(mod, h, r):
+    """ZAYA1's router, a part of :class:`RoutedExperts` (its parameters and
+    state are ``mod``'s own): a float32 MLP over a state ``r`` that passes
+    from layer to layer (``r = h Wd + gamma * r``), top-1 of ``p + bias``.
+    The balancing bias is state, not a parameter: argmax passes it no
+    gradient, and a training step (the ``counters`` collection mutable) first
+    balances it on its own tokens (:func:`_balanced_bias`) and leaves the
+    result for the next. Returns ``(chosen [B, S], weight [B, S], r)``."""
+    z, f32 = mod.z, jnp.float32
+    B, S, _ = h.shape
+    E, R = z.experts, z.router_dim
+    # the router runs in float32 (``HIGHEST``: true float32 on a TPU)
+    rdense = functools.partial(nn.Dense, use_bias=False, dtype=f32,
+                               precision=jax.lax.Precision.HIGHEST)
+    gamma = mod.param("router_gamma", nn.initializers.ones, (R,), f32)
+    r = rdense(R, name="router_down")(h) + gamma * r
+    s = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln_router")(r)
+    s = nn.gelu(rdense(R, name="router_w1")(s))
+    s = nn.gelu(rdense(R, name="router_w2")(s))
+    p = jax.nn.softmax(rdense(E, name="router_w3")(s), axis=-1)
+    bias = mod.variable("counters", "router_bias",
+                        lambda: jnp.zeros((E,), f32))
+    b = bias.value
+    if mod.counting():
+        with jax.named_scope("moe_balance"):
+            # kept over remat: the backward pass does not sort again
+            b = checkpoint_name(_balanced_bias(
+                jax.lax.stop_gradient(p).reshape(B * S, E), b),
+                "router_bias")
+        bias.value = b
+    chosen = jnp.argmax(p + b, axis=-1)
+    return chosen, jnp.take_along_axis(p, chosen[..., None], -1)[..., 0], r
+
+
+def _topk_router(mod, h, r):
+    """A linear softmax router, a part of :class:`RoutedExperts`: float32
+    ``p = softmax(h Wr)``, the ``z.experts_per_token`` largest, their
+    probabilities renormalised to sum to 1 over all of them, held or not. No
+    bias, no state (``r`` passes through). Returns ``(chosen [B, S, k],
+    weight [B, S, k], r)``."""
+    z = mod.z
+    logits = nn.Dense(z.experts, use_bias=False, dtype=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST, name="router")(h)
+    top, chosen = jax.lax.top_k(jax.nn.softmax(logits, axis=-1),
+                                z.experts_per_token)
+    return chosen, top / jnp.sum(top, axis=-1, keepdims=True), r
+
+
+def _added(mod, x, y):
+    """The plain residual: ``x + y``."""
+    return x + y.astype(jnp.float32)
+
+
+#: rows the first chunk of the top-k expert layer holds over the balanced
+#: load's. A chunk costs its rows whatever share of them is held, so up to
+#: this margin a step's time does not follow the router; past it a layer pays
+#: for its excess. A layer's held share wanders with the seed, the batch and
+#: training (0.05 to 0.22 where an even router gives 0.125, over 0.172 in one
+#: layer and epoch of thirty; v5e, PR 31)
+HELD_ROWS_MARGIN = 1.375
+#: and the rows a later chunk holds: what a load over the first chunk costs
+#: follows its excess (a gather, two products and a scatter-add of this many
+#: rows a time)
+HELD_ROWS_LATER = 4096
+
+
+def held_rows(tokens: int, z) -> tuple:
+    """``(first, later)``: the chunks of (token, expert) pairs a top-k layer
+    computes at a time (``dropless_experts(rows=...)``). The first holds
+    :data:`HELD_ROWS_MARGIN` times the held experts' share of ``tokens * k``
+    pairs under an even router, a multiple of 512, at most every pair; the
+    later ones :data:`HELD_ROWS_LATER`."""
+    pairs = tokens * z.experts_per_token
+    even = pairs * z.held[1] / z.experts
+    first = min(pairs, 512 * int(np.ceil(HELD_ROWS_MARGIN * even / 512)))
+    return first, min(HELD_ROWS_LATER, pairs)
+
+
 class RoutedExperts(nn.Module):
-    """ZAYA1's expert sublayer: a float32 router MLP over a state ``r`` that
-    passes from layer to layer (``r = h Wd + gamma * r``), top-1 of all
-    ``z.experts``, and the held SwiGLU experts through
-    :func:`parallel.expert.dropless_experts`. ``__call__(x, r) -> (x, r)``.
+    """An expert sublayer: RMSNorm, a router (``router``, a part: a function
+    ``(module, h, r) -> (chosen, weight, r)`` that makes its parameters and
+    state in this module), the held SwiGLU experts through
+    :func:`parallel.expert.dropless_experts`, and the residual (``join``, a
+    part: ``(module, x, y) -> x``). ``__call__(x, r) -> (x, r)``; ``r`` is
+    the router's state beside the residual stream, or None. The defaults are
+    ZAYA1's: :func:`_zaya_router` (top-1) and the learned scaling
+    :func:`_rescaled`; :func:`_topk_router` and :func:`_added` make the
+    top-k layer of a plain pre-norm block.
 
     The ``counters`` collection holds what no gradient reaches:
-    ``router_bias`` (float32 ``[experts]``), added to the probabilities
-    before the top-1 choice, and ``moe_tokens`` (int32 ``[experts]``). With
-    the collection mutable (a training step), ``router_bias`` is first
-    balanced on the step's own tokens (:func:`_balanced_bias` from where the
-    step before left it; the step routes with the result and leaves it for
-    the next), and ``moe_tokens`` is increased by the tokens routed to each
-    expert. Outside training the bias is used as it stands. With
-    ``intermediates`` mutable, ``moe_chosen`` holds every token's expert."""
+    ``moe_tokens`` (int32 ``[experts]``), increased in a training step (the
+    collection mutable) by the (token, expert) pairs routed to each expert,
+    held or not, and whatever the router keeps there (ZAYA1's
+    ``router_bias``). With ``intermediates`` mutable, ``moe_chosen`` holds
+    every token's expert(s)."""
 
     dim: int
-    z: ZayaDims
+    z: object
     dtype: jnp.dtype = jnp.bfloat16
+    router: object = _zaya_router
+    join: object = _rescaled
+
+    def counting(self) -> bool:
+        return self.is_mutable_collection("counters") \
+            and not self.is_initializing()
 
     @nn.compact
     def __call__(self, x, r):
@@ -556,50 +649,59 @@ class RoutedExperts(nn.Module):
 
         z, f32 = self.z, jnp.float32
         B, S, d = x.shape
-        E, R, F = z.experts, z.router_dim, z.expert_dim
+        E, F = z.experts, z.expert_dim
         first, count = z.held
         lecun = nn.initializers.lecun_normal()
-        # the router runs in float32 (``HIGHEST``: true float32 on a TPU)
-        rdense = functools.partial(nn.Dense, use_bias=False, dtype=f32,
-                                   precision=jax.lax.Precision.HIGHEST)
         h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
-        gamma = self.param("router_gamma", nn.initializers.ones, (R,), f32)
-        r = rdense(R, name="router_down")(h) + gamma * r
-        s = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln_router")(r)
-        s = nn.gelu(rdense(R, name="router_w1")(s))
-        s = nn.gelu(rdense(R, name="router_w2")(s))
-        p = jax.nn.softmax(rdense(E, name="router_w3")(s), axis=-1)
-        # the balancing bias is state, not a parameter: argmax passes it no
-        # gradient, and a training step balances it on its own tokens
-        bias = self.variable("counters", "router_bias",
-                             lambda: jnp.zeros((E,), f32))
-        counting = self.is_mutable_collection("counters") \
-            and not self.is_initializing()
-        b = bias.value
-        if counting:
-            with jax.named_scope("moe_balance"):
-                # kept over remat: the backward pass does not sort again
-                b = checkpoint_name(_balanced_bias(
-                    jax.lax.stop_gradient(p).reshape(B * S, E), b),
-                    "router_bias")
-        chosen = jnp.argmax(p + b, axis=-1)
+        chosen, weight, r = self.router(self, h, r)
         self.sow("intermediates", "moe_chosen", chosen)
-        weight = jnp.take_along_axis(p, chosen[..., None], -1)[..., 0]
         w_in = self.param("experts_in", lecun, (count, d, 2 * F), f32)
         w_out = self.param("experts_out", lecun, (count, F, d), f32)
+        pairs = chosen.shape[2:]               # () for top-1, (k,) for top-k
         y, tokens = dropless_experts(
             h.astype(self.dtype).reshape(B * S, d),
-            chosen.reshape(B * S).astype(jnp.int32), weight.reshape(B * S),
-            w_in, w_out, experts=(first, count), total=E)
+            chosen.reshape((B * S,) + pairs).astype(jnp.int32),
+            weight.reshape((B * S,) + pairs),
+            w_in, w_out, experts=(first, count), total=E,
+            rows=held_rows(B * S, z) if pairs else None)
         seen = self.variable("counters", "moe_tokens",
                              lambda: jnp.zeros((E,), jnp.int32))
-        if counting:
+        if self.counting():
             seen.value = seen.value + tokens
-            bias.value = b
-        return _rescaled(self, x, y.reshape(B, S, d)), r
+        return self.join(self, x, y.reshape(B, S, d)), r
 
 
-class ZayaBlock(nn.Module):
+class _RoutedBlock(nn.Module):
+    """A layer whose second sublayer is :class:`RoutedExperts`: ``__call__``
+    takes and returns ``(x, r)``, the residual stream and the router's state
+    (None for a router without one). A block's ``setup`` makes its first
+    sublayer, which ``attend(x, mask) -> x`` runs, and ``self.moe``. Training only: the
+    dropless experts are not on the paged path, so the serving entry points
+    raise, with ``no_serving``, the block's own reason."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    z: object
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "reference"
+
+    no_serving = "the dropless experts are not on the paged path"
+
+    def __call__(self, x, r, mask=None, training: bool = False):
+        return self.moe(self.attend(x, mask), r)
+
+    def attend(self, x, mask):
+        raise NotImplementedError
+
+    def _no_serving(self, *_, **__):
+        raise NotImplementedError(
+            f"{type(self).__name__} has no serving path: {self.no_serving}")
+
+    prefill = step = extend = paged_extend = _no_serving
+
+
+class ZayaBlock(_RoutedBlock):
     """ZAYA1's layer: :class:`CCAttention`, then :class:`RoutedExperts`,
     whose router state ``r`` travels beside the residual stream ``x``:
     ``__call__`` takes and returns ``(x, r)``.
@@ -609,28 +711,176 @@ class ZayaBlock(nn.Module):
     serving entry points raise.
     """
 
-    dim: int
-    heads: int
-    kv_heads: int
-    z: ZayaDims
-    dtype: jnp.dtype = jnp.bfloat16
-    attn_impl: str = "reference"
+    no_serving = ("CCA's cache would hold a convolution tail and a shifted "
+                  "value beside compressed K/V, and the dropless experts are "
+                  "not on the paged path")
 
     def setup(self):
         self.cca = CCAttention(self.dim, self.heads, self.kv_heads, self.z,
                                self.dtype, self.attn_impl)
         self.moe = RoutedExperts(self.dim, self.z, self.dtype)
 
-    def __call__(self, x, r, mask=None, training: bool = False):
-        return self.moe(self.cca(x, mask), r)
+    def attend(self, x, mask):
+        return self.cca(x, mask)
 
-    def _no_serving(self, *_, **__):
-        raise NotImplementedError(
-            "ZayaBlock has no serving path: CCA's cache would hold a "
-            "convolution tail and a shifted value beside compressed K/V, "
-            "and the dropless experts are not on the paged path")
 
-    prefill = step = extend = paged_extend = _no_serving
+@dataclasses.dataclass(frozen=True)
+class SdarDims:
+    """The sizes of a block-diffusion expert block (:class:`SdarBlock`) that
+    ``dim``, ``heads`` and ``kv_heads`` do not give, and the constants of its
+    training objective. Defaults are SDAR-30B-A3B-Chat's published ones; the
+    block length and the noise's law are the ones assumed for it."""
+
+    head_dim: int = 128
+    rope_base: float = 1e6
+    experts: int = 128                # the router's width
+    experts_per_token: int = 8
+    #: ``(first, count)``: the experts this model holds of all ``experts``
+    #: (an ``ep`` rank's share); ``None`` holds them all
+    experts_held: tuple | None = None
+    expert_dim: int = 768
+    norm_eps: float = 1e-6
+    #: tokens a block: a block is noised at one level and denoised together
+    block_length: int = 4
+    #: the least noise level: ``t = noise_floor + (1 - noise_floor) * u``
+    noise_floor: float = 1e-3
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.experts)
+
+
+def block_diffusion_noise(key, step, tokens, block: int, floor: float,
+                          mask_id: int):
+    """The noise of one block-diffusion training step on ``tokens [R, L]``:
+    ``(noised [R, L], t [R, L], masked [R, L] bool)``. From ``key`` (raw
+    ``uint32[2]``) and the ``step`` count alone: a level ``t = floor + (1 -
+    floor) * u``, ``u ~ U[0, 1)``, a row and block of ``block`` tokens; each
+    token is masked (replaced by ``mask_id``) with probability ``t``,
+    independently."""
+    R, L = tokens.shape
+    level, each = jax.random.split(jax.random.fold_in(key, step))
+    u = jax.random.uniform(level, (R, L // block), jnp.float32)
+    t = jnp.repeat(floor + (1.0 - floor) * u, block, axis=1)
+    masked = jax.random.uniform(each, (R, L), jnp.float32) < t
+    return jnp.where(masked, mask_id, tokens), t, masked
+
+
+class QKNormAttention(nn.Module):
+    """The attention sublayer of :class:`SdarBlock` over a block-diffusion
+    stream ``x [B, 2 L, dim]`` (a noised copy of each row, then its clean
+    copy): RMSNorm, bias-free q / k / v projections (``heads`` query and
+    ``kv_heads`` key-value heads of ``z.head_dim``), an RMSNorm over each
+    head of q and of k (one learned ``[head_dim]`` weight each), rotary over
+    the whole head at the token's position IN ITS ROW (``0 .. L-1`` twice),
+    attention under the block-diffusion mask
+    (``ops.flash_attention.band_predicate``), the output projection and the
+    residual. A training step (the ``counters`` collection mutable) leaves in
+    ``counters/first_block`` (float32 ``[block_length, dim]``, no counter:
+    overwritten, not added to) what came out at the first row's first noised
+    block, whose queries see that block's keys and no other: the one place
+    where what the mask does INSIDE a block is the whole result, read from the
+    step that ran and not from a forward made alike."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    z: SdarDims
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "reference"
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from distkeras_tpu.ops.flash_attention import BLOCK_Q, attention
+
+        z, f32 = self.z, jnp.float32
+        B, S, _ = x.shape
+        H, K, dh = self.heads, self.kv_heads, z.head_dim
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
+        h = h.astype(self.dtype)
+
+        def head_norm(a, heads, name):
+            w = self.param(name, nn.initializers.ones, (dh,), f32)
+            a = a.astype(f32).reshape(B, S, heads, dh)
+            return a * jax.lax.rsqrt(
+                jnp.mean(a * a, -1, keepdims=True) + z.norm_eps) * w
+
+        q = head_norm(dense(H * dh, name="q")(h), H, "q_norm")
+        k = head_norm(dense(K * dh, name="k")(h), K, "k_norm")
+        v = dense(K * dh, name="v")(h).reshape(B, S, K, dh)
+        # positions come from a vector, not from the length: both copies of a
+        # row stand at 0 .. L-1
+        row = np.arange(S // 2)
+        angles = jnp.asarray(rope_angles_at(np.concatenate([row, row]), dh,
+                                            z.rope_base))
+        q, k = apply_rope(q, angles), apply_rope(k, angles)
+        impl = self.attn_impl
+        if impl == "flash" and (S // 2) % BLOCK_Q:
+            impl = "reference"
+        o = attention(q.astype(self.dtype), k.astype(self.dtype), v,
+                      key_mask=mask, impl=impl,
+                      block_diffusion=z.block_length)
+        o = dense(self.dim, name="out")(
+            o.reshape(B, S, H * dh).astype(self.dtype))
+        x = x + o.astype(f32)
+        first = self.variable(
+            "counters", "first_block",
+            lambda: jnp.zeros((z.block_length, self.dim), f32))
+        if self.is_mutable_collection("counters") \
+                and not self.is_initializing():
+            first.value = jax.lax.stop_gradient(x[0, :z.block_length])
+        return x
+
+
+class SdarBlock(_RoutedBlock):
+    """SDAR's layer over a block-diffusion stream: :class:`QKNormAttention`,
+    then :class:`RoutedExperts` with a linear softmax router over all
+    ``z.experts``, the ``z.experts_per_token`` largest renormalised, plain
+    residuals. ``__call__`` takes and returns ``(x, r)`` like every routed
+    block; its router has no state and ``r`` is None.
+
+    Training only: generating by diffusion over blocks (a step that fills a
+    block over several denoising passes against a block-causal cache) is not
+    written, and the experts are not on the paged path."""
+
+    no_serving = ("generation by diffusion over blocks (several denoising "
+                  "passes a block, against a block-causal cache) is not "
+                  "written, and the dropless experts are not on the paged "
+                  "path")
+
+    def setup(self):
+        self.attn = QKNormAttention(self.dim, self.heads, self.kv_heads,
+                                    self.z, self.dtype, self.attn_impl)
+        self.moe = RoutedExperts(self.dim, self.z, self.dtype,
+                                 router=_topk_router, join=_added)
+
+    def attend(self, x, mask):
+        return self.attn(x, mask)
+
+
+def _check_sdar_options(sdar: SdarDims, *, quant, attn_window, pos_embedding,
+                        maxlen, zaya=None):
+    """Raise for an option the block-diffusion block cannot honour."""
+    refused = {
+        "quant=True (quantize_lm knows the dense block's matrices only)":
+            quant,
+        "attn_window (the block-diffusion mask is no band)":
+            attn_window is not None,
+        "pos_embedding other than 'rope' (q and k are rotated at the "
+        "token's position in its row)": pos_embedding != "rope",
+        "zaya= as well (a model has one kind of block)": zaya is not None,
+        "a block_length that is no power of two up to 128, or does not "
+        "divide maxlen": sdar.block_length < 1
+            or sdar.block_length & (sdar.block_length - 1)
+            or sdar.block_length > 128 or maxlen % sdar.block_length,
+        "an odd head_dim (rotary pairs)": sdar.head_dim % 2,
+        "more experts a token than the router has":
+            not 1 <= sdar.experts_per_token <= sdar.experts,
+    }
+    for what, hit in refused.items():
+        if hit:
+            raise ValueError(f"the block-diffusion block cannot honour {what}")
 
 
 def _check_zaya_options(zaya: ZayaDims, *, quant, attn_window, pos_embedding,
@@ -697,6 +947,11 @@ class TransformerLM(nn.Module):
     #: a :class:`ZayaDims` makes every layer a :class:`ZayaBlock` (RMSNorm,
     #: CCA, routed experts) and the head's norm an RMSNorm
     zaya: ZayaDims | None = None
+    #: a :class:`SdarDims` makes every layer a :class:`SdarBlock` (RMSNorm,
+    #: per-head q/k norms, top-k experts) run over a block-diffusion stream:
+    #: a forward doubles each row (``hidden`` returns the clean copy's
+    #: states), ``noised_hidden`` is the training objective's
+    sdar: SdarDims | None = None
 
     def setup(self):
         if self.kv_heads is not None and self.heads % self.kv_heads:
@@ -716,7 +971,28 @@ class TransformerLM(nn.Module):
             )
         self.embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
         if self.zaya is not None:
-            self._setup_zaya()
+            _check_zaya_options(self.zaya, quant=self.quant,
+                                attn_window=self.attn_window,
+                                pos_embedding=self.pos_embedding,
+                                kv_heads=self.kv_heads)
+            self._setup_routed(ZayaBlock, self.zaya)
+            return
+        if self.sdar is not None:
+            _check_sdar_options(self.sdar, quant=self.quant,
+                                attn_window=self.attn_window,
+                                pos_embedding=self.pos_embedding,
+                                maxlen=self.maxlen, zaya=self.zaya)
+            self._setup_routed(SdarBlock, self.sdar)
+            # what the noise of a training step is drawn from, and what the
+            # steps have masked: state beside the experts' counters
+            self.bd_key = self.variable(
+                "counters", "bd_key",
+                lambda: jax.random.key_data(self.make_rng("params")))
+            self.bd_step = self.variable(
+                "counters", "bd_step", lambda: jnp.zeros((), jnp.int32))
+            self.bd_masked = self.variable(
+                "counters", "bd_masked_tokens",
+                lambda: jnp.zeros((), jnp.int32))
             return
         # nn.remat preserves the params tree (blocks_i names unchanged) and
         # transforms __call__ only — prefill/step run through the same
@@ -738,22 +1014,20 @@ class TransformerLM(nn.Module):
             head = QDense if self.quant else nn.Dense
             self.lm_head = head(self.vocab, dtype=self.dtype)
 
-    def _setup_zaya(self):
-        _check_zaya_options(self.zaya, quant=self.quant,
-                            attn_window=self.attn_window,
-                            pos_embedding=self.pos_embedding,
-                            kv_heads=self.kv_heads)
+    def _setup_routed(self, block, dims):
+        """Layers of a routed block (:class:`_RoutedBlock`), the head's
+        RMSNorm and, untied, its bias-free head."""
         block_cls = (nn.remat(
-            ZayaBlock, static_argnums=(4,),
+            block, static_argnums=(4,),
             policy=jax.checkpoint_policies.save_only_these_names(
-                "router_bias")) if self.remat else ZayaBlock)
+                "router_bias")) if self.remat else block)
         self.blocks = [
-            block_cls(dim=self.dim, heads=self.heads, kv_heads=self.kv_heads,
-                      z=self.zaya, dtype=self.dtype, attn_impl=self.attn_impl)
+            block_cls(dim=self.dim, heads=self.heads,
+                      kv_heads=self.kv_heads or self.heads, z=dims,
+                      dtype=self.dtype, attn_impl=self.attn_impl)
             for _ in range(self.depth)
         ]
-        self.ln_head = nn.RMSNorm(epsilon=self.zaya.norm_eps,
-                                  dtype=jnp.float32)
+        self.ln_head = nn.RMSNorm(epsilon=dims.norm_eps, dtype=jnp.float32)
         if not self.tie_embeddings:
             self.lm_head = nn.Dense(self.vocab, use_bias=False,
                                     dtype=self.dtype)
@@ -792,16 +1066,57 @@ class TransformerLM(nn.Module):
         LayerNorm, f32) — the ``fused_ce`` loss path consumes these and
         applies ``lm_head`` chunk-by-chunk, so the ``[B, L, vocab]`` logits
         tensor never materializes (``ops/fused_ce.py``)."""
-        x = self._embed_at(tokens)
+        if self.sdar is not None:
+            # a clean row's states under the block-causal mask are those of
+            # the clean copy of a stream whose noised copy is not read
+            both = None if mask is None else jnp.concatenate([mask, mask], 1)
+            return self._routed_hidden(
+                jnp.concatenate([tokens, tokens], axis=1), both,
+                training)[:, tokens.shape[1]:]
         if self.zaya is not None:
-            # the router state passes from layer to layer beside x
-            r = jnp.zeros(x.shape[:2] + (self.zaya.router_dim,), jnp.float32)
-            for blk in self.blocks:
-                x, r = blk(x, r, mask, training)
-            return self.ln_head(x)
+            return self._routed_hidden(tokens, mask, training)
+        x = self._embed_at(tokens)
         for blk in self.blocks:
             x = blk(x, mask, training)
         return self.ln_head(x)
+
+    def _routed_hidden(self, tokens, mask, training):
+        x = self._embed_at(tokens)
+        # a router's state passes from layer to layer beside x
+        r = None if self.zaya is None else jnp.zeros(
+            x.shape[:2] + (self.zaya.router_dim,), jnp.float32)
+        for blk in self.blocks:
+            x, r = blk(x, r, mask, training)
+        return self.ln_head(x)
+
+    def noised_hidden(self, tokens, training: bool = False):
+        """The block-diffusion objective's states for clean rows ``tokens
+        [R, L]``: ``(hidden [R, L, dim], weight [R, L])``. The step's noise
+        (:func:`block_diffusion_noise` from the state's ``bd_key`` and
+        ``bd_step``) masks some tokens (id ``vocab - 1``: the data never
+        holds it); the stream ``[noised ; clean]`` goes through the layers,
+        and ``hidden`` is the NOISED copy's states after the head's norm:
+        position ``i`` predicts token ``i`` itself. ``weight`` is ``1 / t``
+        at a masked position and 0 elsewhere. With the ``counters``
+        collection mutable (a training step) ``bd_step`` goes up by one and
+        ``bd_masked_tokens`` by the positions masked."""
+        if self.sdar is None:
+            raise ValueError("noised_hidden is the block-diffusion block's "
+                             "(transformer_lm(sdar=...))")
+        L = tokens.shape[1]
+        with jax.named_scope("bd_noise"):
+            noised, t, masked = block_diffusion_noise(
+                self.bd_key.value, self.bd_step.value, tokens,
+                self.sdar.block_length, self.sdar.noise_floor, self.vocab - 1)
+            stream = jnp.concatenate([noised, tokens], axis=1)
+            weight = jnp.where(masked, 1.0 / t, 0.0)
+        h = self._routed_hidden(stream, None, training)[:, :L]
+        if self.is_mutable_collection("counters") \
+                and not self.is_initializing():
+            self.bd_step.value = self.bd_step.value + 1
+            self.bd_masked.value = self.bd_masked.value \
+                + jnp.sum(masked, dtype=jnp.int32)
+        return h, weight
 
     def prefill(self, tokens):
         """Full forward over the prompt; returns ``(logits, caches)`` with
@@ -1647,7 +1962,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
                    attn_window=None, kv_heads=None,
                    pos_embedding="sincos", fused_ce=False,
                    ce_chunk=256, remat=False,
-                   tie_embeddings=False, zaya=None) -> ModelSpec:
+                   tie_embeddings=False, zaya=None, sdar=None) -> ModelSpec:
     """Causal-LM ModelSpec. Train with ``loss="sparse_softmax_cross_entropy"``
     on ``features=tokens [B, L]`` / ``label=tokens shifted left [B, L]``
     (see :func:`next_token_dataset`); decode with :func:`generate`.
@@ -1679,16 +1994,39 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
     step balances on its own tokens (:func:`moe_tokens` reads the counters by
     layer). An option the
     block cannot honour (``attn_window``, another ``pos_embedding``) and the
-    serving entry points raise."""
+    serving entry points raise.
+    ``sdar=SdarDims(...)`` makes every layer a :class:`SdarBlock` (SDAR:
+    RMSNorm, bias-free grouped-query attention with an RMSNorm a head on q
+    and k, dropless top-k experts behind a linear router;
+    ``pos_embedding="rope"``) trained by DIFFUSION OVER BLOCKS, which is the
+    fused loss's business (``fused_ce=True`` is required): ``x`` is the clean
+    rows ``[R, L]`` and ``y`` the same tokens; each step noises the rows on
+    the device from the state's ``counters/bd_key`` and ``bd_step``, runs a
+    noised and a clean copy of every row under one block-diffusion mask
+    (``flash_attention(block_diffusion=...)``) and takes the cross-entropy of
+    the noised copy's masked positions, unshifted, weighted ``1 / t`` over
+    ``R L``. The state also counts ``bd_masked_tokens`` and, a layer,
+    ``moe_tokens`` ((token, expert) pairs by expert). A plain forward
+    (``spec.apply``) gives a clean row's logits under the block-causal
+    mask."""
     if zaya is not None:
         # here, by name, and not at the module's first trace
         _check_zaya_options(zaya, quant=False, attn_window=attn_window,
                             pos_embedding=pos_embedding, kv_heads=kv_heads)
+    if sdar is not None:
+        _check_sdar_options(sdar, quant=False, attn_window=attn_window,
+                            pos_embedding=pos_embedding, maxlen=maxlen,
+                            zaya=zaya)
+        if not fused_ce:
+            raise ValueError(
+                "the block-diffusion block cannot honour fused_ce=False: its "
+                "training objective (the noise, the two copies, the 1/t "
+                "weights) is the fused loss's")
     module = TransformerLM(
         vocab=vocab, maxlen=maxlen, dim=dim, heads=heads, depth=depth,
         dtype=dtype, attn_impl=attn_impl, attn_window=attn_window,
         kv_heads=kv_heads, pos_embedding=pos_embedding, remat=remat,
-        tie_embeddings=tie_embeddings, zaya=zaya,
+        tie_embeddings=tie_embeddings, zaya=zaya, sdar=sdar,
     )
     example = jnp.zeros((1, maxlen), jnp.int32)
     spec = from_flax(module, example, name="transformer_lm",
@@ -1702,12 +2040,16 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
             counting = training and "counters" in state
             h = module.apply(
                 {"params": params, **state}, x, training=training,
-                method=TransformerLM.hidden,
+                method=(TransformerLM.hidden if sdar is None
+                        else TransformerLM.noised_hidden),
                 mutable=["counters"] if counting else False,
             )
             if counting:
                 h, counted = h
                 state = {**state, **counted}
+            weight = None
+            if sdar is not None:
+                h, weight = h
             b_, l_, d_ = h.shape
             token_mask = None
             if mask is not None:
@@ -1726,6 +2068,13 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
             else:
                 kernel = params["lm_head"]["kernel"].astype(module.dtype)
                 bias = params["lm_head"].get("bias")
+            if weight is not None:
+                # the objective's own weights and normaliser: 1 / t at the
+                # masked positions over ALL R L positions (the mean of the
+                # weights is 1), times the rows' validity where one is given
+                weight = weight.reshape(b_ * l_)
+                token_mask = weight if token_mask is None \
+                    else weight * token_mask
             loss = chunked_softmax_cross_entropy(
                 h.astype(module.dtype).reshape(b_ * l_, d_),
                 jnp.reshape(y, (b_ * l_,)),
@@ -1733,6 +2082,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
                 bias,
                 mask=token_mask,
                 chunk=chunk,
+                denominator=None if weight is None else float(b_ * l_),
             )
             return loss, state
 

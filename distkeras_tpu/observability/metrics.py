@@ -378,10 +378,14 @@ def serving_metrics(stats: dict, labels: dict | None = None,
 
 def training_metrics(moe_tokens, labels: dict | None = None,
                      registry: MetricsRegistry | None = None,
-                     ) -> MetricsRegistry:
+                     masked: int | None = None) -> MetricsRegistry:
     """``models.lm.moe_tokens(trainer.counters_)`` (``[layers, experts]``:
-    the tokens a run's steps routed to each expert, counted by the model on
-    the device) as ``dk_train_moe_tokens_total{layer,expert}``."""
+    the tokens — under top-k the (token, expert) pairs — a run's steps routed
+    to each expert, counted by the model on the device) as
+    ``dk_train_moe_tokens_total{layer,expert}``; and ``masked``
+    (``trainer.counters_["bd_masked_tokens"]``: the positions a
+    block-diffusion model's steps masked) as
+    ``dk_train_bd_masked_tokens_total``."""
     reg = registry if registry is not None else MetricsRegistry()
     for layer, row in enumerate(moe_tokens):
         for expert, n in enumerate(row):
@@ -389,6 +393,9 @@ def training_metrics(moe_tokens, labels: dict | None = None,
                         {**(labels or {}), "layer": str(layer),
                          "expert": str(expert)},
                         "tokens routed to an expert of a layer")
+    if masked is not None:
+        reg.counter("dk_train_bd_masked_tokens_total", int(masked), labels,
+                    "positions masked by block-diffusion training steps")
     return reg
 
 

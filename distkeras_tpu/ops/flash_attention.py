@@ -37,6 +37,15 @@ a tile wholly inside, and for a tile the band's edge crosses the pieces
 ``_band_plan`` laid out while tracing. ``band_census`` counts, from the same
 index arithmetic, what that comes to at a length.
 
+One mask is no band: block-diffusion training (``block_diffusion=G``) runs a
+noised copy of each row and then its clean copy as one stream, under a block
+diagonal (noised over noised), a strict block-causal quarter (noised over
+clean), an empty quarter and a block-causal one (``band_predicate``). A tile
+lies in one quarter (tiles are cut from the row's length), so the same three
+fates hold, decided from the quarter and the tile's offset within the row
+(``_chunk_diffusion``); a q tile's steps are its own noised tile, then the
+clean tiles up to its diagonal.
+
 On TPU the kernel compiles natively; elsewhere (the 8-device CPU mesh in CI)
 it runs in Pallas interpret mode, so the SAME code path is oracle-tested
 everywhere (tests/test_flash_attention.py pins it against
@@ -101,13 +110,49 @@ def _last_q_tile(jk, nq, *, block_q, block_k, window):
     )
 
 
-def band_predicate(q_pos, k_pos, causal, window):
-    """THE causal/sliding-window validity predicate, shared by the kernels
-    (both orientations), the XLA backward oracle, and
-    ``attention_reference``: query ``i`` sees key ``j`` iff ``j <= i`` when
-    causal, ``i - j < window`` (and ``j - i < window`` when bidirectional)
-    under a window. ``q_pos``/``k_pos`` broadcast; returns None when
-    everything is valid."""
+def _copies_see(qb, kb, q_clean: bool, k_clean: bool):
+    """Block diffusion's table, a quarter at a time: may a query of block
+    ``qb`` see a key of block ``kb``, for a noised or a clean query and a
+    noised or a clean key? Noised sees noised in its own block; noised sees
+    clean in earlier blocks; clean sees clean in its own and earlier blocks;
+    clean never sees noised."""
+    if k_clean:
+        return kb <= qb if q_clean else kb < qb
+    return False if q_clean else kb == qb
+
+
+def band_predicate(q_pos, k_pos, causal, window, diffusion=None, copies=None):
+    """THE validity predicate, shared by the kernels (both orientations), the
+    XLA backward oracle, and ``attention_reference``: query ``i`` sees key
+    ``j`` iff ``j <= i`` when causal, ``i - j < window`` (and ``j - i <
+    window`` when bidirectional) under a window. ``q_pos``/``k_pos``
+    broadcast; returns None when everything is valid.
+
+    ``diffusion=(block, length)`` is the block-diffusion training mask and no
+    band: a stream of ``2 * length`` positions holds a NOISED copy of a row
+    (``< length``) and then its CLEAN copy; with ``b(i) = (i % length) //
+    block``, a noised query sees the noised keys of its own block (both
+    directions) and the clean keys of earlier blocks, a clean query the clean
+    keys of its own and earlier blocks and no noised key
+    (:func:`_copies_see`). ``block`` is a power of two (a shift, not a
+    division: the kernels run this on vectors). ``copies=(q_clean, k_clean)``,
+    Python bools: the caller knows which copy its queries and its keys lie in
+    (a kernel's piece lies in one quarter of the mask) and passes positions
+    WITHIN THE ROW; the mask is then one comparison of blocks, where telling
+    the copies apart element by element costs a dozen vector operations a
+    pair (v5e, PR 31: the forward 164.8 ms a call so, 13.8 with one)."""
+    if diffusion is not None:
+        block, length = diffusion
+        shift = block.bit_length() - 1
+        if copies is not None:
+            return _copies_see(q_pos >> shift, k_pos >> shift, *copies)
+        q_noised, k_noised = q_pos < length, k_pos < length
+        q_clean, k_clean = q_pos >= length, k_pos >= length
+        qb = (q_pos - length * q_clean) >> shift
+        kb = (k_pos - length * k_clean) >> shift
+        return (q_noised & k_noised & _copies_see(qb, kb, False, False)) | (
+            k_clean & ((q_noised & _copies_see(qb, kb, False, True))
+                       | (q_clean & _copies_see(qb, kb, True, True))))
     if not causal and window is None:
         return None
     valid = None
@@ -121,17 +166,37 @@ def band_predicate(q_pos, k_pos, causal, window):
     return valid
 
 
-def _band_valid(q0, k0, rows, cols, causal, window):
+def _band_valid(q0, k0, rows, cols, causal, window, diffusion=None,
+                copies=None):
     """[rows, cols] tile of :func:`band_predicate` for the queries from ``q0``
-    and the keys from ``k0`` (None when everything is valid)."""
+    and the keys from ``k0`` (None when everything is valid). Under
+    ``diffusion`` the piece lies in the quarter ``copies = (q_clean,
+    k_clean)`` of the mask (static: a plan's piece knows it); the blocks are
+    worked out on a column of queries and a row of keys, in-row positions,
+    and only the one comparison is ``[rows, cols]``."""
+    if diffusion is not None:
+        length = diffusion[1]
+        q_pos = q0 - length * copies[0] \
+            + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
+        k_pos = k0 - length * copies[1] \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, cols), 1)
+        return band_predicate(q_pos, k_pos, False, None, diffusion, copies)
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (rows, cols), 1)
     return band_predicate(q_pos, k_pos, causal, window)
 
 
-def _band_valid_t(q0, k0, rows, cols, causal, window):
+def _band_valid_t(q0, k0, rows, cols, causal, window, diffusion=None,
+                  copies=None):
     """Transposed [cols, rows] tile (keys down, queries across) of
     :func:`band_predicate`: what the dk/dv kernel masks with."""
+    if diffusion is not None:
+        length = diffusion[1]
+        k_pos = k0 - length * copies[1] \
+            + jax.lax.broadcasted_iota(jnp.int32, (cols, 1), 0)
+        q_pos = q0 - length * copies[0] \
+            + jax.lax.broadcasted_iota(jnp.int32, (1, rows), 1)
+        return band_predicate(q_pos, k_pos, False, None, diffusion, copies)
     k_pos = k0 + jax.lax.broadcasted_iota(jnp.int32, (cols, rows), 0)
     q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (cols, rows), 1)
     return band_predicate(q_pos, k_pos, causal, window)
@@ -187,11 +252,46 @@ def _chunk_band(d, rows, cols, causal, window):
     return in_lo | in_hi | ((lo < 0) & (hi > 0)), in_lo & in_hi
 
 
-def _band_pieces(d, rows, cols, causal, window, r=0, c=0):
-    """The bodies a ``[rows, cols]`` tile at offset ``d`` takes, as a list of
-    ``(r, c, rows, cols, edge)``: the piece at ``(r, c)`` of the tile, under
-    the band's mask if ``edge``. Static: ``d`` is a Python int."""
-    some, every = _chunk_band(d + r - c, rows, cols, causal, window)
+def _chunk_diffusion(q0, k0, rows, cols, diffusion):
+    """``(key, some, every)`` of the ``[rows, cols]`` chunk whose first query
+    and key are at ``q0`` and ``k0`` of a block-diffusion stream: whether
+    some / every pair of it is visible, and ``key``, which names what of the
+    chunk is: its quarter of the mask (noised or clean queries over noised or
+    clean keys) and how far its first query is past its first key within the
+    row, on which alone ``some``, ``every`` and the mask inside the chunk
+    depend. The chunk lies in one quarter (a tile never crosses the middle of
+    the stream) and begins and ends on block boundaries (blocks divide
+    ``BLOCK_Q``), so the first and last block of its queries and of its keys
+    decide. Python ints while tracing, scalars in a kernel."""
+    block, length = diffusion
+    shift = block.bit_length() - 1
+    q_clean, k_clean = q0 >= length, k0 >= length
+    q_noised, k_noised = q0 < length, k0 < length
+    qp, kp = q0 - length * q_clean, k0 - length * k_clean
+    q_lo, q_hi = qp >> shift, (qp + rows - 1) >> shift
+    k_lo, k_hi = kp >> shift, (kp + cols - 1) >> shift
+    some = (q_noised & k_noised & (k_lo <= q_hi) & (q_lo <= k_hi)) | (
+        k_clean & ((k_lo < q_hi) | (q_clean & (k_lo <= q_hi))))
+    every = (q_noised & k_noised & (k_lo == q_hi) & (k_hi == q_lo)) | (
+        k_clean & ((k_hi < q_lo) | (q_clean & (k_hi <= q_lo))))
+    return qp - kp + 2 * length * (k_clean + 2 * q_clean), some, every
+
+
+def _chunk(q0, k0, rows, cols, causal, window, diffusion):
+    """``(key, some, every)`` of a chunk under either kind of mask: a band's
+    key is ``q0 - k0`` (:func:`_chunk_band`)."""
+    if diffusion is not None:
+        return _chunk_diffusion(q0, k0, rows, cols, diffusion)
+    return (q0 - k0,) + _chunk_band(q0 - k0, rows, cols, causal, window)
+
+
+def _band_pieces(at, rows, cols, mask, r=0, c=0):
+    """The bodies a ``[rows, cols]`` tile at ``at = (q0, k0)`` takes under
+    ``mask = (causal, window, diffusion)``, as a list of ``(r, c, rows, cols,
+    edge)``: the piece at ``(r, c)`` of the tile, under the mask if ``edge``
+    (True for a band; under block diffusion the quarter of the mask the piece
+    lies in, :func:`_edge`). Static: ``at`` holds Python ints."""
+    _, some, every = _chunk(at[0] + r, at[1] + c, rows, cols, *mask)
     if not some:
         return []
     if every:
@@ -200,62 +300,80 @@ def _band_pieces(d, rows, cols, causal, window, r=0, c=0):
     hr = halved(rows) if rows >= cols else rows
     hc = halved(cols) if cols >= rows else cols
     if (hr, hc) == (rows, cols):
-        return [(r, c, rows, cols, True)]
+        return [(r, c, rows, cols, _edge(at, mask[2]))]
     return [piece for rr in range(r, r + rows, hr)
             for cc in range(c, c + cols, hc)
-            for piece in _band_pieces(d, hr, hc, causal, window, rr, cc)]
+            for piece in _band_pieces(at, hr, hc, mask, rr, cc)]
 
 
-def _band_plan(L, tiles, causal, window, one_body=False):
-    """``((d, pieces), ...)``: for every offset ``d = q0 - k0`` of the
-    length-``L`` grid whose ``[bq, bk]`` tile (``tiles``) touches the band,
-    the pieces that tile runs, cut down to ``_FINE``. ``one_body`` (the
+def _edge(at, diffusion):
+    """What a masked piece of the tile at ``at = (q0, k0)`` carries as its
+    ``edge``: True, or under block diffusion ``(q_clean, k_clean)``, the
+    copies its queries and keys lie in (a tile lies in one quarter)."""
+    if diffusion is None:
+        return True
+    return at[0] >= diffusion[1], at[1] >= diffusion[1]
+
+
+def _band_plan(L, tiles, causal, window, one_body=False, diffusion=None):
+    """``((key, pieces, whole), ...)``: for every kind of ``[bq, bk]`` tile
+    (``tiles``) of the length-``L`` grid that touches the mask (a band's
+    kinds are the offsets ``d = q0 - k0``, block diffusion's
+    :func:`_chunk_diffusion`'s keys), the pieces that tile runs, cut down to
+    ``_FINE``, and whether the whole tile is visible. ``one_body`` (the
     forward): the one body over the bounding box of what :func:`_band_pieces`
     keeps of the tile, masked unless that is the whole tile. Else (dq, dk/dv,
     which pay by the pair): those pieces themselves, of each ``_WIDEST`` keys
     of the tile apart, so that a wide tile's float32 ``[rows, keys]``
     temporaries stay what they were at 1024 keys a step."""
     bq, bk = tiles
+    mask = (causal, window, diffusion)
+    if diffusion is None:     # a band's tiles differ by their offset alone
+        kinds = {d: (d, 0) for d in {i * bq - j * bk for i in range(L // bq)
+                                     for j in range(L // bk)}}
+    else:
+        kinds = {_chunk_diffusion(i * bq, j * bk, bq, bk, diffusion)[0]:
+                 (i * bq, j * bk)
+                 for i in range(L // bq) for j in range(L // bk)}
     plan = []
-    for d in sorted({i * bq - j * bk
-                     for i in range(L // bq) for j in range(L // bk)}):
+    for key in sorted(kinds):
+        at = kinds[key]
         if one_body:
-            pieces = _band_pieces(d, bq, bk, causal, window)
+            pieces = _band_pieces(at, bq, bk, mask)
             if len(pieces) > 1:
                 r0 = min(r for r, *_ in pieces)
                 c0 = min(c for _, c, *_ in pieces)
                 r1 = max(r + rows for r, _, rows, _, _ in pieces)
                 c1 = max(c + cols for _, c, _, cols, _ in pieces)
-                pieces = [(r0, c0, r1 - r0, c1 - c0, True)]
+                pieces = [(r0, c0, r1 - r0, c1 - c0, _edge(at, diffusion))]
         else:
             wide = min(bk, _WIDEST)
             pieces = [piece for c in range(0, bk, wide)
-                      for piece in _band_pieces(d, bq, wide, causal, window,
-                                                0, c)]
+                      for piece in _band_pieces(at, bq, wide, mask, 0, c)]
         if pieces:
-            plan.append((d, tuple(pieces)))
+            plan.append((key, tuple(pieces),
+                         bool(_chunk(*at, bq, bk, *mask)[2])))
     return tuple(plan)
 
 
-def _run_band(fold, d, live, rows, cols, plan, causal, window):
+def _run_band(fold, tile, live, plan):
     """One grid step's bodies, ``fold(r, c, rows, cols, edge)`` each: the
-    pieces the plan has for the step's offset ``d``. The tiles wholly inside
-    the band all run the same unmasked pieces, under one test of the tile's
-    corners; each offset at which the band's edge crosses has its own pieces
-    under a test of ``d``; a tile outside the band runs nothing."""
-    inside = {pieces for at, pieces in plan
-              if _chunk_band(at, rows, cols, causal, window)[1]}
+    pieces the plan has for the step's tile, ``tile = (key, some, every)``
+    from :func:`_chunk` on the step's scalars. The wholly visible tiles all
+    run the same unmasked pieces, under one test of ``every``; each kind of
+    tile that the mask's edge crosses has its own pieces under a test of
+    ``key``; a tile outside the mask runs nothing."""
+    key, _, every = tile
+    inside = {pieces for _, pieces, whole in plan if whole}
     assert len(inside) <= 1, inside
     for pieces in inside:
-        _, every = _chunk_band(d, rows, cols, causal, window)
-
         @pl.when(live & every)
         def _():
             for piece in pieces:
                 fold(*piece)
-    for at, pieces in plan:
-        if pieces not in inside:
-            @pl.when(live & (d == at))
+    for at, pieces, whole in plan:
+        if not whole:
+            @pl.when(live & (key == at))
             def _():
                 for piece in pieces:
                     fold(*piece)
@@ -267,29 +385,40 @@ def _num_band_tiles(n_tiles, span, block):
     return min(n_tiles, (span - 2) // block + 2)
 
 
-def _restricted_k_axis(nk, bq, bk, causal, window):
+def _pick(cond, a, b):
+    """``a if cond else b`` for Python values, ``jnp.where`` for scalars."""
+    if isinstance(cond, (bool, int)):
+        return a if cond else b
+    return jnp.where(cond, a, b)
+
+
+def _restricted_k_axis(nk, bq, bk, causal, window, diffusion=None):
     """(nkt, k_tile(iq, j)) for the forward/dq grids: the static size of the
     k axis and the index map from (q tile, band step) → real k tile. With a
     window only the tiles the band can touch are visited, so compute and
-    bandwidth are O(L·window). The map is clamped to the q tile's last
-    contributing k tile: a step past it (guarded off in-kernel by
-    ``kt <= last_k``: above the causal diagonal, or past the sequence end)
-    names the block already held and copies nothing."""
-    if window is None:
+    bandwidth are O(L·window); under block diffusion a q tile's steps are its
+    own noised tile (noised queries only) and then the clean tiles up to its
+    diagonal. The map is clamped to the q tile's last contributing k tile: a
+    step past it (guarded off in-kernel by ``kt <= last_k``: above the causal
+    diagonal, or past the sequence end) names the block already held and
+    copies nothing."""
+    if diffusion is not None:
+        nkt = nk // 2 + 1
+    elif window is None:
         nkt = nk
     else:
         span = bq + window - 1 if causal else bq + 2 * window - 2
         nkt = _num_band_tiles(nk, span, bk)
 
     def k_tile(i, j):
-        fk = _first_k_tile(i, block_q=bq, block_k=bk, window=window)
-        return jnp.minimum(fk + j, _last_k_tile(
-            i, nk, block_q=bq, block_k=bk, causal=causal, window=window))
+        return jnp.minimum(*_k_step(i, j, nk, block_q=bq, block_k=bk,
+                                    causal=causal, window=window,
+                                    diffusion=diffusion))
 
     return nkt, k_tile
 
 
-def _restricted_q_axis(nq, bq, bk, causal, window):
+def _restricted_q_axis(nq, bq, bk, causal, window, diffusion=None):
     """(nqt, q_tile(jk, i)) for the dkv grid — the transposed mirror of
     :func:`_restricted_k_axis`: band steps count from the first q tile that
     sees the k tile, and the steps past the last one are clamped to it."""
@@ -300,59 +429,115 @@ def _restricted_q_axis(nq, bq, bk, causal, window):
         nqt = _num_band_tiles(nq, span, bq)
 
     def q_tile(j, i):
-        fq = _first_q_tile(j, block_q=bq, block_k=bk, causal=causal,
-                           window=window)
-        return jnp.minimum(fq + i, _last_q_tile(
-            j, nq, block_q=bq, block_k=bk, window=window))
+        return jnp.minimum(*_q_step(j, i, nq, block_q=bq, block_k=bk,
+                                    causal=causal, window=window,
+                                    diffusion=diffusion))
 
     return nqt, q_tile
 
 
-def _k_step(iq, jk, nk, *, block_q, block_k, causal, window):
+def _k_step(iq, jk, nk, *, block_q, block_k, causal, window, diffusion=None):
     """``(kt, last_k)`` of step ``(iq, jk)`` of the forward's and dq's grids:
     the k tile the step stands on (``jk`` counts from the first tile of q
     tile ``iq``'s band) and the last tile that contributes to ``iq``. The step
     is live iff ``kt <= last_k``. Python ints give ints (the census), program
-    ids scalars (the kernels)."""
+    ids scalars (the kernels).
+
+    Under block diffusion (tiles of the row's length, so none crosses the
+    middle of the stream) a noised q tile stands first on the noised tile
+    that holds its own blocks and then on the clean tiles ``0 ..`` that hold
+    an EARLIER block than its last one; a clean q tile on the clean tiles up
+    to the one that holds its last block."""
+    if diffusion is not None:
+        block, length = diffusion
+        half = nk // 2
+        q0 = iq * block_q
+        is_clean = q0 >= length
+        clean = _pick(is_clean, 1, 0)
+        qp0 = q0 - length * clean
+        # the last clean key a query of the tile sees, as a tile of its half
+        seen = (qp0 + block_q - 1 - block * (1 - clean)) // block_k
+        own = qp0 // block_k
+        kt = _pick(is_clean, half + jk,
+                   _pick(jk == 0, own, half + jk - 1))
+        return kt, _pick(seen < 0, own, half + seen)
     kt = _first_k_tile(iq, block_q=block_q, block_k=block_k,
                        window=window) + jk
     return kt, _last_k_tile(iq, nk, block_q=block_q, block_k=block_k,
                             causal=causal, window=window)
 
 
-def _q_step(jk, iq, nq, *, block_q, block_k, causal, window):
+def _q_step(jk, iq, nq, *, block_q, block_k, causal, window, diffusion=None):
     """``(qt, last_q)`` of step ``(jk, iq)`` of dk/dv's grid: the transposed
-    mirror of :func:`_k_step`, live iff ``qt <= last_q``."""
+    mirror of :func:`_k_step`, live iff ``qt <= last_q``. Under block
+    diffusion a noised k tile is seen by the noised q tiles over its own rows
+    and no other; a clean k tile by the noised q tiles from the first that
+    holds a LATER block than its first one, then by the clean q tiles from
+    the one over its first row."""
+    if diffusion is not None:
+        block, length = diffusion
+        half = nq // 2
+        k0 = jk * block_k
+        is_clean = k0 >= length
+        kp0 = k0 - length * _pick(is_clean, 1, 0)
+        over = kp0 // block_q
+        later = (kp0 + block) // block_q       # may be `half`: no noised tile
+        qt = _pick(is_clean,
+                   _pick(iq < half - later, later + iq,
+                         half + over + iq - (half - later)),
+                   over + iq)
+        return qt, _pick(is_clean, nq - 1, over + block_k // block_q - 1)
     qt = _first_q_tile(jk, block_q=block_q, block_k=block_k, causal=causal,
                        window=window) + iq
     return qt, _last_q_tile(jk, nq, block_q=block_q, block_k=block_k,
                             window=window)
 
 
-def _grid_steps(L, tiles, causal, window, transposed=False):
+def _grid_steps(L, tiles, causal, window, transposed=False, diffusion=None):
     """``(qt, kt, live)`` of every step one head's grid takes at length ``L``,
     in the grid's order: the forward's and dq's (k innermost), or dk/dv's
     (``transposed``: q innermost), from the axes and the step rule the
     kernels themselves run."""
     bq, bk = tiles
     nq, nk = L // bq, L // bk
-    kw = dict(block_q=bq, block_k=bk, causal=causal, window=window)
+    kw = dict(block_q=bq, block_k=bk, causal=causal, window=window,
+              diffusion=diffusion)
     if transposed:
-        nqt, _ = _restricted_q_axis(nq, bq, bk, causal, window)
+        nqt, _ = _restricted_q_axis(nq, bq, bk, causal, window, diffusion)
         for jk in range(nk):
             for i in range(nqt):
                 qt, last = (int(x) for x in _q_step(jk, i, nq, **kw))
                 yield qt, jk, qt <= last
     else:
-        nkt, _ = _restricted_k_axis(nk, bq, bk, causal, window)
+        nkt, _ = _restricted_k_axis(nk, bq, bk, causal, window, diffusion)
         for iq in range(nq):
             for j in range(nkt):
                 kt, last = (int(x) for x in _k_step(iq, j, nk, **kw))
                 yield iq, kt, kt <= last
 
 
+# A per-row statistic (the log-sum-exp, the backward's ``delta``) is a column
+# ``[rows, 1]`` in a kernel and, as a ``[B·H, L, 1]`` array, 128 times its
+# size in HBM (the tiling pads the last dimension to a lane tile): 128 MB an
+# array at 8 x 8 heads x 4096, which the band's callers have room for, and
+# 512 MB at the block-diffusion cell's 4 x 32 heads x 8192, which that step
+# has not. So under block diffusion the forward and dq take and give them as
+# ROWS, ``[B·H, 1, L]`` (as dk/dv always has), and turn a q tile's row into
+# its column once, on the tile's first step.
+
+
+def _as_col(row):
+    """``[1, n]`` → ``[n, 1]`` in a kernel (``n`` a multiple of 128)."""
+    return jnp.transpose(jnp.broadcast_to(row, (128, row.shape[1])))[:, :1]
+
+
+def _as_row(col):
+    """``[n, 1]`` → ``[1, n]`` in a kernel (``n`` a multiple of 128)."""
+    return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], 128)))[:1, :]
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
-               plan, window, nk):
+               plan, window, nk, diffusion=None):
     """One (bh, iq, jk) step: fold the step's [bq, bk] score tile, piece by
     piece (:func:`_run_band`), into the online softmax state; finalize on
     this q block's last contributing k step.
@@ -377,7 +562,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
     # nothing — the restricted grid never visits tiles below the band, and
     # the guard skips the steps past its end (≈2× at long causal context)
     kt, last_k = _k_step(iq, jk, nk, block_q=block_q, block_k=block_k,
-                         causal=causal, window=window)
+                         causal=causal, window=window, diffusion=diffusion)
     q0, k0 = iq * block_q, kt * block_k
 
     def fold(r, c, rows, cols, edge):
@@ -390,7 +575,8 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
             preferred_element_type=jnp.float32,
         )                                                # [rows, cols]
         valid = _piece_valid(
-            _band_valid(q0 + r, k0 + c, rows, cols, causal, window)
+            _band_valid(q0 + r, k0 + c, rows, cols, causal, window, diffusion,
+                        edge)
             if edge else None,
             None if km_ref is None else km_ref[0, :, cs],     # [1, cols]
             s.shape)
@@ -410,14 +596,15 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         )
         m_s[rs, :] = m_new
 
-    _run_band(fold, q0 - k0, kt <= last_k, block_q, block_k, plan, causal,
-              window)
+    _run_band(fold, _chunk(q0, k0, block_q, block_k, causal, window,
+                           diffusion), kt <= last_k, plan)
 
     @pl.when(kt == last_k)
     def _():
         l = jnp.maximum(l_s[:], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
-        lse_ref[0] = m_s[:] + jnp.log(l)
+        lse = m_s[:] + jnp.log(l)
+        lse_ref[0] = lse if diffusion is None else _as_row(lse)
 
 
 def _pick_block_q(L):
@@ -451,7 +638,8 @@ def _pick_block_k(L):
     return next(c for c in (2048, 1024, BLOCK_K, 384, 256, 128) if L % c == 0)
 
 
-def band_census(L, causal=False, window=None, masked=False):
+def band_census(L, causal=False, window=None, masked=False,
+                block_diffusion=None):
     """What the three kernels do at length ``L``, for one head, counted from
     the index arithmetic they run (:func:`_grid_steps`: their grids' axes and
     step rule; their :func:`_band_plan`) and from nothing measured:
@@ -469,25 +657,37 @@ def band_census(L, causal=False, window=None, masked=False):
     Causal, at the 512 x 2048 tiles of L = 2048 and 4096: dq and dk/dv
     compute 1.125 and 1.062 times the band, 78 % and 88 % of it with no
     mask; the forward 1.25 and 1.125 times, none and 44 % of it with no mask
-    (whole 512 x 1024 tiles under the mask before PR 28: 1.50 and 1.25)."""
+    (whole 512 x 1024 tiles under the mask before PR 28: 1.50 and 1.25).
+
+    ``block_diffusion=G``: the block-diffusion mask over a stream of ``L``
+    positions (``L`` is the call's length, twice the rows': a noised and a
+    clean copy). At ``L = 8192`` (rows of 4096), ``G = 4``, tiles 512 x 2048:
+    the forward computes 1.25 times the visible pairs, dq and dk/dv 1.125
+    times; 0.125 and 0.0625 of that is the noised copy's own diagonal, where
+    a 512 x 512 body (256 x 256 in dq and dk/dv) holds 4 x 4 blocks."""
     import numpy as np
 
+    diffusion = _canonical_diffusion(block_diffusion, L, causal, window)
     window = _canonical_window(window, L)
-    tiles = bq, bk = _tiles(L)
+    tiles = bq, bk = _tiles(L if diffusion is None else L // 2)
+    mask = (causal, window, diffusion)
     pairs_band = L * L
-    if causal or window is not None:      # a q tile's rows at a time
+    if causal or window is not None or diffusion:   # a q tile's rows at a time
         pairs_band = sum(int(band_predicate(
-            np.arange(q0, q0 + bq)[:, None], np.arange(L)[None, :], causal,
-            window).sum()) for q0 in range(0, L, bq))
+            np.arange(q0, q0 + bq)[:, None], np.arange(L)[None, :],
+            *mask).sum()) for q0 in range(0, L, bq))
 
     def count(transposed, one_body):
-        plan = dict(_band_plan(L, tiles, causal, window, one_body))
+        plan = {key: pieces for key, pieces, _ in
+                _band_plan(L, tiles, causal, window, one_body, diffusion)}
         out = dict(steps=0, steps_idle=0, bodies_unmasked=0, bodies_masked=0,
                    pairs_unmasked=0, pairs_masked=0)
-        for qt, kt, live in _grid_steps(L, tiles, causal, window, transposed):
+        for qt, kt, live in _grid_steps(L, tiles, causal, window, transposed,
+                                        diffusion):
             out["steps"] += 1
             out["steps_idle"] += not live
-            pieces = plan.get(qt * bq - kt * bk, ()) if live else ()
+            key = _chunk(qt * bq, kt * bk, bq, bk, *mask)[0]
+            pieces = plan.get(key, ()) if live else ()
             for _, _, rows, cols, edge in pieces:
                 kind = "masked" if edge or masked else "unmasked"
                 out["bodies_" + kind] += 1
@@ -527,7 +727,7 @@ def _tiles(L):
 
 
 def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
-                window=None):
+                window=None, diffusion=None):
     """q [B, L, H, D], k/v [B, L, Hkv, D] with Hkv | H (grouped-query
     attention reads shared K/V heads straight from the index maps — no
     repeated-KV materialization), + key_mask [B, L] →
@@ -538,8 +738,10 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
         raise ValueError(
             f"sequence length {L} must be a multiple of {BLOCK_Q}"
         )
-    return _fwd_call(q, k, v, key_mask, tiles=_tiles(L), scale=scale,
-                     causal=causal, interpret=interpret, window=window)
+    tiles = _tiles(L if diffusion is None else L // 2)
+    return _fwd_call(q, k, v, key_mask, tiles=tiles, scale=scale,
+                     causal=causal, interpret=interpret, window=window,
+                     diffusion=diffusion)
 
 
 # The two launchers are jitted on their own: a model calls them once a layer
@@ -551,11 +753,12 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
 # that reads dq, dk and dv takes 0.2 ms a layer more (PERF.md section 6).
 # The tiles are an argument so that the choice is part of the cache's key;
 # the band's grain (``_FINE``, ``_WIDEST``) is read when a launcher traces.
-_STATIC = ("tiles", "scale", "causal", "interpret", "window")
+_STATIC = ("tiles", "scale", "causal", "interpret", "window", "diffusion")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
-def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window):
+def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
+              diffusion=None):
     B, L, H, D = q.shape
     Hkv = k.shape[2]
     bq, bk = tiles
@@ -565,7 +768,7 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window):
         return jnp.moveaxis(x, 2, 1).reshape(B * h, L, D)
 
     nk = L // bk
-    nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window)
+    nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window, diffusion)
     grid = (B * H, L // bq, nkt)
     qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     kvspec = pl.BlockSpec(
@@ -573,11 +776,17 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window):
     )
     ospec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     # lse carries a trailing singleton so its block obeys the (8, 128)
-    # tile rule (last dim equal to the array dim is allowed)
-    lspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    # tile rule (last dim equal to the array dim is allowed); under block
+    # diffusion it is a row (see _as_col)
+    if diffusion is None:
+        lspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+        lshape = (B * H, L, 1)
+    else:
+        lspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+        lshape = (B * H, 1, L)
     out_shape = [
         jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-        jax.ShapeDtypeStruct((B * H, L, 1), jnp.float32),
+        jax.ShapeDtypeStruct(lshape, jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((bq, 1), jnp.float32),   # running max m
@@ -595,8 +804,8 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window):
         args.append(key_mask.astype(jnp.float32)[:, None, :])
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
-        plan=_band_plan(L, tiles, causal, window, one_body=True),
-        window=window, nk=nk,
+        plan=_band_plan(L, tiles, causal, window, True, diffusion),
+        window=window, nk=nk, diffusion=diffusion,
     )
 
     o, lse = pl.pallas_call(
@@ -609,15 +818,19 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window):
         name="flash_fwd",
     )(*args)
     out = jnp.moveaxis(o.reshape(B, H, L, D), 1, 2)
-    return out, lse[..., 0]
+    return out, lse[..., 0] if diffusion is None else lse[:, 0]
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
-                      scale, causal, block_q, block_k, plan, window, nk):
+                      scale, causal, block_q, block_k, plan, window, nk,
+                      diffusion=None):
     """One (bh, iq, jk) step: rebuild the step's [bq, bk] probability tile
     from the saved lse, piece by piece (:func:`_run_band`), and fold
     ``ds @ k`` into the dq accumulator; write on this q block's last
     contributing k step."""
+    lse_s = d_s = None
+    if diffusion is not None:     # the statistics came as rows: see _as_col
+        *rest, lse_s, d_s = rest
     if len(rest) == 3:
         km_ref, dq_ref, acc = rest
     else:
@@ -628,9 +841,15 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
     @pl.when(jk == 0)
     def _():
         acc[:] = jnp.zeros_like(acc)
+        if diffusion is not None:
+            lse_s[:] = _as_col(lse_ref[0])
+            d_s[:] = _as_col(d_ref[0])
+
+    def stat(ref, col, rs):       # a statistic's [rows, 1] piece
+        return ref[0, rs, :] if diffusion is None else col[rs, :]
 
     kt, last_k = _k_step(iq, jk, nk, block_q=block_q, block_k=block_k,
-                         causal=causal, window=window)
+                         causal=causal, window=window, diffusion=diffusion)
     q0, k0 = iq * block_q, kt * block_k
 
     def fold(r, c, rows, cols, edge):
@@ -644,27 +863,29 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
             preferred_element_type=jnp.float32,
         )                                                 # [rows, cols]
         valid = _piece_valid(
-            _band_valid(q0 + r, k0 + c, rows, cols, causal, window)
+            _band_valid(q0 + r, k0 + c, rows, cols, causal, window, diffusion,
+                        edge)
             if edge else None,
             None if km_ref is None else km_ref[0, :, cs],     # [1, cols]
             s.shape)
         if valid is not None:
             s = jnp.where(valid, s, _NEG)
-        p = jnp.exp(s - lse_ref[0, rs, :])                # lse [rows, 1]
+        lse = stat(lse_ref, lse_s, rs)                    # [rows, 1]
+        p = jnp.exp(s - lse)
         if valid is not None:
             p = jnp.where(valid, p, 0.0)
         dp = jax.lax.dot_general(
             gg, vv, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )                                                 # [rows, cols]
-        ds = p * (dp - d_ref[0, rs, :])                   # delta [rows, 1]
+        ds = p * (dp - stat(d_ref, d_s, rs))              # delta [rows, 1]
         acc[rs, :] += jax.lax.dot_general(
             ds, kk, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         ) * scale
 
-    _run_band(fold, q0 - k0, kt <= last_k, block_q, block_k, plan, causal,
-              window)
+    _run_band(fold, _chunk(q0, k0, block_q, block_k, causal, window,
+                           diffusion), kt <= last_k, plan)
 
     @pl.when(kt == last_k)
     def _():
@@ -673,7 +894,7 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
 
 def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
                        scale, causal, block_q, block_k, plan, window, nq,
-                       gqa_groups=None):
+                       gqa_groups=None, diffusion=None):
     """One (bh, jk, iq) step — or (b·hkv, jk, gg, iq) under grouped-query
     attention, where the extra ``gg`` axis walks the q heads sharing this
     k/v head and the dk/dv accumulators run across the whole group:
@@ -703,7 +924,7 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
         dv_acc[:] = jnp.zeros_like(dv_acc)
 
     qt, last_q = _q_step(jk, iq, nq, block_q=block_q, block_k=block_k,
-                         causal=causal, window=window)
+                         causal=causal, window=window, diffusion=diffusion)
     q0, k0 = qt * block_q, jk * block_k
 
     def fold(r, c, rows, cols, edge):
@@ -717,7 +938,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
             preferred_element_type=jnp.float32,
         )                                                 # [cols, rows]
         valid = _piece_valid(
-            _band_valid_t(q0 + r, k0 + c, rows, cols, causal, window)
+            _band_valid_t(q0 + r, k0 + c, rows, cols, causal, window,
+                          diffusion, edge)
             if edge else None,
             None if km_ref is None else km_ref[0, cs, :],     # [cols, 1]
             st.shape)
@@ -740,8 +962,8 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
             preferred_element_type=jnp.float32,
         )
 
-    _run_band(fold, q0 - k0, qt <= last_q, block_q, block_k, plan, causal,
-              window)
+    _run_band(fold, _chunk(q0, k0, block_q, block_k, causal, window,
+                           diffusion), qt <= last_q, plan)
 
     write = qt == last_q if last_g is None else ((qt == last_q) & last_g)
 
@@ -752,26 +974,28 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
 
 
 def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
-                 interpret, window=None):
+                 interpret, window=None, diffusion=None):
     """Blockwise flash-attention backward: (dq, dk, dv) via two Pallas
     kernels, ``O(block_q · block_k)`` on-chip — no [B, H, L, L] tensors.
     Under grouped-query attention (k/v hold Hkv < H heads) dq reads the
     shared heads through the index maps and the dkv grid gains a group
     axis whose accumulators sum the whole group — dk/dv come out
     Hkv-wide, no repeated-KV tensors anywhere."""
-    return _bwd_call(q, k, v, key_mask, out, lse, g, tiles=_tiles(q.shape[1]),
+    L = q.shape[1]
+    tiles = _tiles(L if diffusion is None else L // 2)
+    return _bwd_call(q, k, v, key_mask, out, lse, g, tiles=tiles,
                      scale=scale, causal=causal, interpret=interpret,
-                     window=window)
+                     window=window, diffusion=diffusion)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
-              interpret, window):
+              interpret, window, diffusion=None):
     B, L, H, D = q.shape
     Hkv = k.shape[2]
     groups = _gqa_groups(q, k)
     bq, bk = tiles  # the forward's: one ladder
-    plan = _band_plan(L, tiles, causal, window)
+    plan = _band_plan(L, tiles, causal, window, False, diffusion)
 
     def bh(x):  # [B, L, h, D] → [B·h, L, D]
         h = x.shape[2]
@@ -781,22 +1005,27 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
     # delta = rowsum(dO · O): one elementwise pass, [B·H, L]
     delta = jnp.sum(gb.astype(jnp.float32) * bh(out).astype(jnp.float32),
                     axis=-1)
-    lse_col, d_col = lse[..., None], delta[..., None]      # [B·H, L, 1]
     lse_row, d_row = lse[:, None, :], delta[:, None, :]    # [B·H, 1, L]
     nk, nq = L // bk, L // bq
     # same restricted band axes as the forward (one shared builder, so the
     # forward and backward grids cannot drift apart)
-    nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window)
-    nqt, q_tile = _restricted_q_axis(nq, bq, bk, causal, window)
+    nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window, diffusion)
+    nqt, q_tile = _restricted_q_axis(nq, bq, bk, causal, window, diffusion)
 
     qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
     kvspec_q = pl.BlockSpec(
         (1, bk, D), lambda b, i, j: (_kv_row(b, H, Hkv), k_tile(i, j), 0)
     )
-    colspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+    if diffusion is None:         # columns [B·H, L, 1]
+        statspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
+        stats, stat_scratch = [lse[..., None], delta[..., None]], []
+    else:                         # rows, turned in the kernel: see _as_col
+        statspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
+        stats = [lse_row, d_row]
+        stat_scratch = [pltpu.VMEM((bq, 1), jnp.float32)] * 2
 
-    dq_specs = [qspec, kvspec_q, kvspec_q, qspec, colspec, colspec]
-    dq_args = [qb, kb, vb, gb, lse_col, d_col]
+    dq_specs = [qspec, kvspec_q, kvspec_q, qspec, statspec, statspec]
+    dq_args = [qb, kb, vb, gb] + stats
     if key_mask is not None:
         dq_specs.append(
             pl.BlockSpec((1, 1, bk), lambda b, i, j: (b // H, 0,
@@ -806,12 +1035,12 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, plan=plan, window=window,
-                          nk=nk),
+                          nk=nk, diffusion=diffusion),
         grid=(B * H, nq, nkt),
         in_specs=dq_specs,
         out_specs=qspec,
         out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)] + stat_scratch,
         interpret=interpret,
         name="flash_dq",
     )(*dq_args)
@@ -855,7 +1084,8 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_fa_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, plan=plan, window=window,
-                          nq=nq, gqa_groups=None if groups == 1 else groups),
+                          nq=nq, gqa_groups=None if groups == 1 else groups,
+                          diffusion=diffusion),
         grid=grid,
         in_specs=dkv_specs,
         out_specs=[kvspec, kvspec],
@@ -875,7 +1105,7 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
 
 
 def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
-                        window=None):
+                        window=None, diffusion=None):
     """Recompute-based backward (plain XLA): p from saved lse, then the
     standard flash-attention gradient identities. GQA: k/v may hold
     Hkv < H heads — expanded here, with dk/dv group-summed back."""
@@ -888,7 +1118,7 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
     qf = q.astype(jnp.float32) * scale
     s = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
     band = band_predicate(jnp.arange(L)[:, None], jnp.arange(L)[None, :],
-                          causal, window)
+                          causal, window, diffusion)
     valid = (None if band is None
              else jnp.broadcast_to(band[None, None], s.shape))
     if key_mask is not None:
@@ -922,39 +1152,44 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
 # dim 0 — q/k/v/out/g ``[B, …]``, the mask ``[B, L]``, lse ``[B·H, L]``.
 
 
-def _forward(q, k, v, key_mask, scale, causal, interpret, window):
+def _forward(q, k, v, key_mask, scale, causal, interpret, window, diffusion):
     return ops.on_each_device(
         functools.partial(_fa_forward, scale=scale, causal=causal,
-                          interpret=interpret, window=window),
+                          interpret=interpret, window=window,
+                          diffusion=diffusion),
         q, k, v, key_mask,
     )
 
 
 def _backward(q, k, v, key_mask, out, lse, g, scale, causal, interpret,
-              window):
+              window, diffusion):
     return ops.on_each_device(
         functools.partial(_fa_backward, scale=scale, causal=causal,
-                          interpret=interpret, window=window),
+                          interpret=interpret, window=window,
+                          diffusion=diffusion),
         q, k, v, key_mask, out, lse, g,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
-def _flash_core(q, k, v, key_mask, causal, scale, interpret, window):
-    out, _ = _forward(q, k, v, key_mask, scale, causal, interpret, window)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+def _flash_core(q, k, v, key_mask, causal, scale, interpret, window,
+                diffusion):
+    out, _ = _forward(q, k, v, key_mask, scale, causal, interpret, window,
+                      diffusion)
     return out
 
 
-def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window):
-    out, lse = _forward(q, k, v, key_mask, scale, causal, interpret, window)
+def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window, diffusion):
+    out, lse = _forward(q, k, v, key_mask, scale, causal, interpret, window,
+                        diffusion)
     # saving `out` adds no memory under jit: it aliases the primal output
     return out, (q, k, v, key_mask, out, lse)
 
 
-def _fa_bwd(causal, scale, interpret, window, res, g):
+def _fa_bwd(causal, scale, interpret, window, diffusion, res, g):
     q, k, v, key_mask, out, lse = res
     dq, dk, dv = _backward(q, k, v, key_mask, out, lse, g, scale, causal,
-                           interpret, window)
+                           interpret, window, diffusion)
     dmask = None if key_mask is None else jnp.zeros_like(key_mask)
     return dq, dk, dv, dmask
 
@@ -972,8 +1207,35 @@ def _canonical_window(window, L):
     return None if window >= L else window
 
 
+def _canonical_diffusion(block, L, causal, window):
+    """``(block, rows' length)`` for ``block_diffusion=block`` over a stream
+    of ``L`` positions, or None without one; a named error for what the
+    block-diffusion mask cannot be combined with or cut into tiles."""
+    if block is None:
+        return None
+    block = int(block)
+    if causal or window is not None:
+        raise ValueError(
+            "block_diffusion is a mask of its own: it cannot be combined "
+            "with causal=True or window"
+        )
+    if block < 1 or block & (block - 1) or block > BLOCK_Q:
+        raise ValueError(
+            f"block_diffusion must be a power of two from 1 to {BLOCK_Q}, "
+            f"got {block}"
+        )
+    if L % (2 * block):
+        raise ValueError(
+            f"block_diffusion={block} over {L} positions: the stream is a "
+            f"noised and a clean copy of one row of whole blocks, so its "
+            f"length must be a multiple of {2 * block}"
+        )
+    return block, L // 2
+
+
 def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
-                    interpret: bool | None = None, window: int | None = None):
+                    interpret: bool | None = None, window: int | None = None,
+                    block_diffusion: int | None = None):
     """Pallas flash attention; same contract as ``attention_reference``.
 
     ``q/k/v`` [B, L, H, D] → [B, L, H, D]; optional ``key_mask`` [B, L]
@@ -981,13 +1243,27 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
     with the hard mask in the reference). ``window`` enables sliding-window
     (local) attention: query ``i`` sees keys ``(i-window, i]`` when causal,
     ``|i-j| < window`` otherwise; the kernel grid only visits in-band tiles,
-    so compute AND k/v DMA scale as O(L·window).
+    so compute AND k/v DMA scale as O(L·window). ``block_diffusion=G`` is
+    the block-diffusion training mask (:func:`band_predicate`): the ``L``
+    positions are a noised copy of a row of ``L // 2`` tokens followed by its
+    clean copy, in blocks of ``G``; not with ``causal`` or ``window``, and
+    ``L // 2`` must be a multiple of 128. No mask array is built: a tile the
+    mask empties is neither computed nor fetched, a wholly visible one runs
+    with no mask, and only tiles a diagonal crosses are masked.
     """
+    L = q.shape[1]
+    diffusion = _canonical_diffusion(block_diffusion, L, causal, window)
+    if diffusion is not None and (L // 2) % BLOCK_Q:
+        raise ValueError(
+            f"block_diffusion: the rows' length {L // 2} (half the stream) "
+            f"must be a multiple of {BLOCK_Q}"
+        )
     return _flash_core(
         q, k, v, key_mask, bool(causal),
         float(scale if scale is not None else q.shape[-1] ** -0.5),
         ops.interpreted(interpret),
-        _canonical_window(window, q.shape[1]),
+        _canonical_window(window, L),
+        diffusion,
     )
 
 
@@ -1008,18 +1284,25 @@ def attention_impl(impl: str = "auto", *, L: int) -> str:
 
 
 def attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
-              impl: str = "auto", window: int | None = None):
+              impl: str = "auto", window: int | None = None,
+              block_diffusion: int | None = None):
     """Dispatch between the Pallas kernel and the XLA reference.
 
     ``impl``: ``"flash"`` forces the kernel (requires ``L % 128 == 0``),
     ``"reference"`` the XLA path, ``"auto"`` is decided by
     :func:`attention_impl`. ``key_mask`` is treated as a static-presence
     argument (its values are traced, its presence is not). ``window``:
-    sliding-window (local) attention span — see :func:`flash_attention`.
+    sliding-window (local) attention span, ``block_diffusion``: the
+    block-diffusion training mask over a noised and a clean copy of each row
+    — see :func:`flash_attention`.
     """
     from distkeras_tpu.parallel.sequence import attention_reference
 
-    if attention_impl(impl, L=q.shape[1]) == "reference":
+    # under block diffusion the tiles are cut from the rows' length
+    tiled = q.shape[1] if block_diffusion is None else q.shape[1] // 2
+    if attention_impl(impl, L=tiled) == "reference":
         return attention_reference(q, k, v, causal=causal, scale=scale,
-                                   key_mask=key_mask, window=window)
-    return flash_attention(q, k, v, causal, scale, key_mask, window=window)
+                                   key_mask=key_mask, window=window,
+                                   block_diffusion=block_diffusion)
+    return flash_attention(q, k, v, causal, scale, key_mask, window=window,
+                           block_diffusion=block_diffusion)

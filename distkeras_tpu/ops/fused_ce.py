@@ -260,7 +260,8 @@ _fused_ce.defvjp(_fused_ce_fwd, _fused_ce_bwd)
 
 
 def chunked_softmax_cross_entropy(hidden, labels, kernel, bias=None, *,
-                                  mask=None, chunk: int = 256):
+                                  mask=None, chunk: int = 256,
+                                  denominator=None):
     """Mean sparse softmax cross-entropy of ``hidden @ kernel (+ bias)``
     against integer ``labels``, computed ``chunk`` rows at a time.
 
@@ -280,6 +281,12 @@ def chunked_softmax_cross_entropy(hidden, labels, kernel, bias=None, *,
         (the backward's ``[N, Vb]`` vocabulary tile is cut from the same
         budget where that is wider than what hides the carry's traffic:
         module docstring).
+      denominator: what the weighted sum is divided by in place of
+        ``max(sum(mask), 1)``: a loss whose weights are no 0/1 validity and
+        whose normaliser is its own (block diffusion's ``1 / t`` at masked
+        positions, over every position). A scalar; the loss is
+        ``sum(nll · mask) / denominator``, rescaled from the masked mean by
+        two scalars, with no second pass over the logits.
     """
     hidden = jnp.asarray(hidden)
     if hidden.ndim != 2:
@@ -291,4 +298,7 @@ def chunked_softmax_cross_entropy(hidden, labels, kernel, bias=None, *,
         mask = jnp.asarray(mask, jnp.float32).reshape(hidden.shape[0])
     if int(chunk) < 1:
         raise ValueError(f"chunk must be >= 1, got {chunk}")
-    return _fused_ce(hidden, kernel, bias, labels, mask, int(chunk))
+    loss = _fused_ce(hidden, kernel, bias, labels, mask, int(chunk))
+    if denominator is None:
+        return loss
+    return loss * (jnp.maximum(jnp.sum(mask), 1.0) / denominator)

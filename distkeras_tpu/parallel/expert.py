@@ -19,9 +19,11 @@ path. Equality with the single-device oracle is pinned by
 tests/test_expert_parallel.py on an 8-device mesh.
 
 :func:`dropless_experts` is the decoder's expert layer (``models/lm.py``
-``ZayaBlock``): no capacity and no ``[tokens, experts, capacity]`` array. It
-is told which experts it holds, sorts the tokens by chosen expert and runs
-one grouped product a projection over the held experts' stacked weights.
+``RoutedExperts``): no capacity and no ``[tokens, experts, capacity]`` array.
+It is told which experts it holds, sorts the tokens (top-1) or the (token,
+expert) pairs (top-k) by chosen expert and runs one grouped product a
+projection over the held experts' stacked weights; under top-k only the held
+pairs' rows are gathered, a chunk at a time, in a loop as long as the load.
 """
 
 from __future__ import annotations
@@ -214,26 +216,145 @@ def _permute_rows_bwd(res, g):
 _permute_rows.defvjp(_permute_rows_fwd, _permute_rows_bwd)
 
 
-def dropless_experts(x, expert, weight, w_in, w_out, *, experts, total):
-    """Top-1 SwiGLU experts without capacity: every token routed to a held
-    expert is computed, none is dropped.
+def _held_order(expert, experts, total):
+    """The sort behind :func:`dropless_experts`, of ``expert`` flattened:
+    ``(tokens [total], held, order, sizes [count])`` — every expert's count,
+    which entries go to a held expert, the stable order by held expert with
+    the absent experts' entries last, and the held experts' counts."""
+    first, count = experts
+    flat = expert.reshape(-1)
+    tokens = jnp.bincount(flat, length=total).astype(jnp.int32)
+    local = flat - first
+    held = (local >= 0) & (local < count)
+    key = jnp.where(held, local, count)          # absent experts sort last
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
+    return tokens, held, order, tokens[first:first + count]
 
-    ``x`` [T, d] tokens; ``expert`` int32 [T], each token's chosen expert out
-    of ``total``; ``weight`` float32 [T], what the chosen expert's result is
-    multiplied by (the router's probability). ``experts=(first, count)``:
-    this layer holds experts ``first .. first + count - 1`` — ``w_in``
-    ``[count, d, 2 f]`` (gate and up side by side) and ``w_out``
-    ``[count, f, d]``. A token whose expert is not held gets 0: on an ``ep``
-    axis that is another chip's part of the sum.
 
-    Tokens are sorted by held expert (stable; absent experts' tokens last),
+def _swiglu_groups(xs, w_in, w_out, sizes):
+    """The two grouped products over rows sorted by expert: gate and up side
+    by side, SiLU, down."""
+    with jax.named_scope("moe_experts"):
+        gate, up = jnp.split(jax.lax.ragged_dot(xs, w_in, sizes), 2, axis=-1)
+        return jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_out, sizes)
+
+
+def _chunk_rows(y, x, w_in, w_out, scale, token, sizes, lo):
+    """``y`` (float32 ``[T, d]``, the tokens' sums so far) with what the
+    sorted pairs ``lo .. lo + R - 1`` add to them: ``token`` ``[R]`` is each
+    pair's token, ``scale`` ``[R]`` its weight, ``sizes`` the held experts'
+    counts over ALL sorted pairs (this chunk's share of each group is cut out
+    of them). The chunk's rows past the last held pair are zeros given to the
+    last group, so the products run all ``R`` rows whatever the load: a
+    chunk's time is its size's, not the router's."""
+    R = token.shape[0]
+    with jax.named_scope("moe_route"):
+        ends = jnp.cumsum(sizes)
+        cut = lambda at: jnp.clip(at, lo, lo + R)
+        mine = (cut(ends) - cut(ends - sizes)).astype(jnp.int32)
+        mine = mine.at[-1].add(R - jnp.sum(mine))
+        live = (lo + jnp.arange(R) < ends[-1])[:, None]
+        xs = jnp.where(live, x[token], 0)
+    ys = _swiglu_groups(xs, w_in, w_out, mine)
+    with jax.named_scope("moe_route"):
+        ys = jnp.where(live, ys, 0).astype(jnp.float32) * scale[:, None]
+        return y.at[token].add(ys)
+
+
+def _later_chunks(first, token, sizes):
+    """``(rows a later chunk, later chunks that hold a held pair)``: the
+    first chunk (``first`` rows) always runs, the padded ``token [C, R]``'s
+    only as far as the held pairs reach past it."""
+    R = token.shape[1]
+    return R, jnp.maximum(0, (jnp.sum(sizes) - first + R - 1) // R)
+
+
+def _paired_rows(x, w_in, w_out, scale0, token0, scale, token, sizes):
+    """:func:`_chunk_rows` chunk after chunk: the sorted pairs' first
+    ``R0`` (``token0``, ``scale0``) and then chunks of ``R`` (``token``,
+    ``scale``: ``[C, R]``). A loop whose length follows the load: the first
+    chunk always runs and, sized over the expected load, mostly alone; a
+    later one only if a held pair lies in it, in the forward and, chunk by
+    chunk from the inputs again, in the backward, so that no more than one
+    chunk's rows are ever held and a load over the first chunk costs its
+    excess in small steps and not a second chunk of the first's size. A
+    chunk that runs costs its rows whatever share of them is held, so under
+    the first chunk's size a step's time does not follow the router."""
+    first = token0.shape[0]
+    R, n = _later_chunks(first, token, sizes)
+    return jax.lax.fori_loop(
+        0, n, lambda c, y: _chunk_rows(y, x, w_in, w_out, scale[c], token[c],
+                                       sizes, first + c * R),
+        _chunk_rows(jnp.zeros(x.shape, jnp.float32), x, w_in, w_out, scale0,
+                    token0, sizes, 0))
+
+
+_paired_experts = jax.custom_vjp(_paired_rows)
+
+
+def _paired_experts_fwd(*args):
+    return _paired_rows(*args), args
+
+
+def _paired_experts_bwd(res, g):
+    x, w_in, w_out, scale0, token0, scale, token, sizes = res
+    first = token0.shape[0]
+    R, n = _later_chunks(first, token, sizes)
+
+    def pull(s, t, lo):
+        _, back = jax.vjp(
+            lambda x, w_in, w_out, s: _chunk_rows(
+                jnp.zeros(x.shape, jnp.float32), x, w_in, w_out, s, t, sizes,
+                lo), x, w_in, w_out, s)
+        return back(g)
+
+    def more(c, acc):
+        dx, dw_in, dw_out, dscale = acc
+        a, b, d, e = pull(scale[c], token[c], first + c * R)
+        return dx + a, dw_in + b, dw_out + d, dscale.at[c].set(e)
+
+    dx, dw_in, dw_out, dscale0 = pull(scale0, token0, 0)
+    dx, dw_in, dw_out, dscale = jax.lax.fori_loop(
+        0, n, more, (dx, dw_in, dw_out, jnp.zeros_like(scale)))
+    return dx, dw_in, dw_out, dscale0, None, dscale, None, None
+
+
+_paired_experts.defvjp(_paired_experts_fwd, _paired_experts_bwd)
+
+
+def dropless_experts(x, expert, weight, w_in, w_out, *, experts, total,
+                     rows=None):
+    """SwiGLU experts without capacity: every token routed to a held expert
+    is computed, none is dropped.
+
+    ``x`` [T, d] tokens; ``expert`` int32 [T] or [T, k], each token's chosen
+    expert(s) out of ``total``; ``weight`` float32 of the same shape, what a
+    chosen expert's result is multiplied by (the router's probability; under
+    top-k the renormalised one). ``experts=(first, count)``: this layer holds
+    experts ``first .. first + count - 1`` — ``w_in`` ``[count, d, 2 f]``
+    (gate and up side by side) and ``w_out`` ``[count, f, d]``. A (token,
+    expert) pair whose expert is not held adds 0: on an ``ep`` axis that is
+    another chip's part of the sum.
+
+    Pairs are sorted by held expert (stable; absent experts' pairs last),
     each projection is ONE ``jax.lax.ragged_dot`` over the stacked weights
     (a grouped matmul kernel on TPU), and the result is put back in token
     order. Rows past the last group are masked on the way in and on the way
     out, so nothing depends on what the grouped product leaves there.
 
+    ``[T]`` (top-1) sorts the tokens themselves: ``[T, d]`` rows through the
+    products, held or not, and a gather each way. ``[T, k]`` computes only the
+    pairs that are held: the sorted pairs are cut into chunks, ``rows =
+    (first, later)`` (required) rows in the first and in each later one, and
+    a loop runs the chunks that hold a held pair, each gathering its tokens'
+    rows and adding its results into the tokens' sums. With ``first`` over
+    the expected load one chunk runs, at the cost of ITS rows (all of them go
+    through the products, the rows past the held pairs as zeros: the time is
+    the chunk's, whatever the router sent) and not of ``T k``; a heavier load
+    runs ``later`` rows more at a time and still drops nothing.
+
     Returns ``(y [T, d], tokens int32 [total])``: ``tokens[e]`` counts the
-    tokens routed to expert ``e`` of all ``total``, held or not.
+    pairs routed to expert ``e`` of all ``total``, held or not.
     """
     first, count = experts
     if not (0 <= first and count >= 1 and first + count <= total):
@@ -242,25 +363,48 @@ def dropless_experts(x, expert, weight, w_in, w_out, *, experts, total):
         raise ValueError(
             f"experts={experts!r} but the weights hold {w_in.shape[0]} and "
             f"{w_out.shape[0]} experts")
+    if expert.shape != weight.shape or expert.ndim not in (1, 2):
+        raise ValueError(
+            f"expert {expert.shape} and weight {weight.shape} must both be "
+            f"[tokens] or [tokens, k]")
     T = x.shape[0]
+    if expert.ndim == 2:
+        return _dropless_pairs(x, expert, weight, w_in, w_out, experts, total,
+                               rows)
+    if rows is not None:
+        raise ValueError("rows= cuts the pairs of a [tokens, k] routing; a "
+                         "[tokens] routing sorts the tokens themselves")
     with jax.named_scope("moe_route"):
-        tokens = jnp.bincount(expert, length=total).astype(jnp.int32)
-        local = expert - first
-        held = (local >= 0) & (local < count)
-        key = jnp.where(held, local, count)          # absent experts sort last
-        order = jnp.argsort(key, stable=True).astype(jnp.int32)
+        tokens, held, order, sizes = _held_order(expert, experts, total)
         inverse = jnp.zeros((T,), jnp.int32).at[order].set(
             jnp.arange(T, dtype=jnp.int32), unique_indices=True)
-        sizes = tokens[first:first + count]
         live = (jnp.arange(T) < jnp.sum(sizes))[:, None]
         xs = jnp.where(live, _permute_rows(x, order, inverse), 0)
-    with jax.named_scope("moe_experts"):
-        gate, up = jnp.split(jax.lax.ragged_dot(xs, w_in.astype(x.dtype), sizes),
-                             2, axis=-1)
-        ys = jax.lax.ragged_dot(jax.nn.silu(gate) * up, w_out.astype(x.dtype),
-                                sizes)
+    ys = _swiglu_groups(xs, w_in.astype(x.dtype), w_out.astype(x.dtype), sizes)
     with jax.named_scope("moe_route"):
         ys = jnp.where(live, ys, 0)
         y = _permute_rows(ys, inverse, order)
         y = y * jnp.where(held, weight, 0.0).astype(jnp.float32)[:, None]
+    return y, tokens
+
+
+def _dropless_pairs(x, expert, weight, w_in, w_out, experts, total, rows):
+    """:func:`dropless_experts` for ``expert`` and ``weight`` ``[T, k]``."""
+    T, k = expert.shape
+    pairs = T * k
+    if not isinstance(rows, tuple) or len(rows) != 2 or min(rows) < 1:
+        raise ValueError(
+            f"a [tokens, k] routing needs rows=(first, later), the sorted "
+            f"pairs a chunk holds, each >= 1; got {rows!r}")
+    first, later = min(int(rows[0]), pairs), int(rows[1])
+    chunks = max(1, -(-(pairs - first) // later))
+    with jax.named_scope("moe_route"):
+        tokens, _, order, sizes = _held_order(expert, experts, total)
+        order = jnp.pad(order, (0, first + chunks * later - pairs))
+        token = order // k
+        scale = weight.astype(jnp.float32).reshape(pairs)[order]
+    y = _paired_experts(
+        x, w_in.astype(x.dtype), w_out.astype(x.dtype), scale[:first],
+        token[:first], scale[first:].reshape(chunks, later),
+        token[first:].reshape(chunks, later), sizes)
     return y, tokens

@@ -29,16 +29,20 @@ _NEG = -1e9  # finite "masked" score: keeps the online softmax NaN-free
 
 
 def attention_reference(q, k, v, causal: bool = False, scale=None,
-                        key_mask=None, window: int | None = None):
+                        key_mask=None, window: int | None = None,
+                        block_diffusion: int | None = None):
     """Plain single-device softmax attention — the correctness oracle.
 
     Shapes: q/k/v ``[B, L, H, D]`` → ``[B, L, H, D]``. ``key_mask`` is an
     optional ``[B, Lk]`` validity mask (1 = attend, 0 = ignore, e.g.
     padding). ``window`` restricts attention to a sliding local band:
     query ``i`` sees keys ``(i-window, i]`` when causal, ``|i-j| < window``
-    otherwise (same contract as ``ops.flash_attention``).
+    otherwise; ``block_diffusion=G`` is the block-diffusion training mask
+    over a noised and a clean copy of each row (same contract as
+    ``ops.flash_attention``: one predicate serves both).
     """
-    from distkeras_tpu.ops.flash_attention import _gqa_groups, band_predicate
+    from distkeras_tpu.ops.flash_attention import (
+        _canonical_diffusion, _gqa_groups, band_predicate)
 
     if window is not None and int(window) < 1:
         raise ValueError(f"window must be >= 1, got {window}")
@@ -55,7 +59,9 @@ def attention_reference(q, k, v, causal: bool = False, scale=None,
     # one shared band predicate with the flash kernels — the oracle and the
     # kernel cannot drift apart on window semantics
     band = band_predicate(jnp.arange(Lq)[:, None], jnp.arange(Lk)[None, :],
-                          causal, window)
+                          causal, window,
+                          _canonical_diffusion(block_diffusion, Lq, causal,
+                                               window))
     if band is not None:
         s = jnp.where(band, s, _NEG)
     if key_mask is not None:

@@ -19,11 +19,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import checks, harness, loader, weights, weights_zaya
-from benchmark.drivers import serve, train, train_moe
+from benchmark import checks, harness, loader, weights, weights_sdar, weights_zaya
+from benchmark.drivers import serve, train, train_bd, train_moe
 
 DATA = os.path.join(loader.ROOT, "benchmark", "tests", "data")
-DRIVERS = {"train": train, "train_moe": train_moe, "serve": serve}
+DRIVERS = {"train": train, "train_moe": train_moe, "train_bd": train_bd, "serve": serve}
 
 
 def _json(*parts):
@@ -40,20 +40,22 @@ def _leaves(tree) -> dict:
 
 
 @pytest.mark.parametrize("config, traffic", [
-    ("xglm-564m", "train"), ("starcoder2-3b", "serve.closed"), ("zaya1-8b", "train.moe4k")])
+    ("xglm-564m", "train"), ("starcoder2-3b", "serve.closed"), ("zaya1-8b", "train.moe4k"),
+    ("sdar-30b-a3b", "train.bd4k")])
 def test_the_model_initialises_to_the_tree_the_benchmark_makes_by_name(config, traffic):
     """The committed configuration at full size under the options of the job
     file that runs it; ``jax.eval_shape`` on both sides, so nothing is made."""
     m, job = _json("configs", config + ".json")["model"], _json("traffic", traffic + ".json")
     options = {k: job[k] for k in ("attn_impl", "fused_ce", "ce_chunk", "remat") if k in job}
-    zaya = m.get("block") == "zaya"
-    spec = (train_moe.program_lm if zaya else harness.program_lm)(m, **options)
+    routed = {"zaya": (train_moe, weights_zaya), "sdar": (train_bd, weights_sdar)}.get(
+        m.get("block"))
+    spec = (routed[0].program_lm if routed else harness.program_lm)(m, **options)
     got = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
     key = jax.eval_shape(lambda: weights.seed_key(2 ** 31 + 29))
-    if zaya:
-        made, by = jax.eval_shape(lambda k: (weights_zaya.program_tree(m, k),
-                                             weights_zaya.counters_tree(m, k)),
-                                  key), "benchmark/weights_zaya.py"
+    if routed:
+        made, by = jax.eval_shape(lambda k: (routed[1].program_tree(m, k),
+                                             routed[1].counters_tree(m, k)),
+                                  key), f"benchmark/{routed[1].__name__.split('.')[-1]}.py"
     else:
         made, by = jax.eval_shape(lambda k: (weights.program_tree(m, k, "float32"), {}),
                                   key), "benchmark/weights.py"
@@ -124,6 +126,7 @@ def runs(tmp_path_factory):
     JAX lowers meanwhile goes to the run's directory as text, locations and all."""
     cells = {"tiny-train": ("tiny-sincos.tiny-train", "BENCHMARK.json", 1.0),
              "tiny-train-moe": ("tiny-zaya.tiny-train-moe", "BENCHMARK.zaya.json", 1.0),
+             "tiny-train-bd": ("tiny-sdar.tiny-train-bd", "BENCHMARK.sdar.json", 1.0),
              "tiny-serve": ("tiny-rope-gqa.tiny-serve", "BENCHMARK.json", 2.0)}
     made = {}
 
@@ -144,7 +147,7 @@ def runs(tmp_path_factory):
     return run
 
 
-@pytest.mark.parametrize("traffic", ["tiny-train", "tiny-train-moe"])
+@pytest.mark.parametrize("traffic", ["tiny-train", "tiny-train-moe", "tiny-train-bd"])
 def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
     loaded, facts, _ = runs(traffic)
     job = loaded["traffic"]
@@ -155,12 +158,20 @@ def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
         facts["window"]["steps"] * job["batch_size"] * job["seq_len"]), where
     assert facts["attempted"] == job["warmup_steps"] + facts["window"]["steps"], where
     assert facts["end_to_end"]["train_tokens_per_s"] > 0 < facts["end_to_end"]["setup_s"], where
-    if job["driver"] == "train_moe":
+    if job["driver"] in ("train_moe", "train_bd"):
         m = loaded["config"]["model"]
-        assert "route_gap" in facts["checks"], where
-        # every routed (token, layer) pair of the window is in the fetched counters
-        assert np.sum(facts["moe"]["window_tokens"]) == facts["window"]["tokens"] * m["depth"], (
+        routes = "route_count_gap" if job["driver"] == "train_bd" else "route_gap"
+        assert routes in facts["checks"], where
+        # every routed (token, layer) pair of the window is in the fetched
+        # counters; under block diffusion a clean token is two positions of
+        # its stream and a position sends experts_per_token pairs
+        pairs = 2 * m["experts_per_token"] if job["driver"] == "train_bd" else 1
+        assert np.sum(facts["moe"]["window_tokens"]) == (
+            facts["window"]["tokens"] * m["depth"] * pairs), (
             f"{where} reads MeshTrainer's history 'counters' through models.lm.moe_tokens")
+    if job["driver"] == "train_bd":
+        assert 0 < facts["bd"]["window_masked"] < facts["window"]["tokens"], (
+            f"{where} reads the history's 'bd_masked_tokens' counter")
 
 
 def test_the_serve_driver_runs_its_window_and_reports_correct(runs):
@@ -182,14 +193,17 @@ NAMES = [("jit_serve_decode_greedy", "tiny-serve", "serve_decode_greedy"),
          ("moe_experts", "tiny-train-moe", "train_step"),
          ("moe_route", "tiny-train-moe", "train_step"),
          ("moe_balance", "tiny-train-moe", "train_step"),
-         ("cca_conv", "tiny-train-moe", "train_step")]
+         ("cca_conv", "tiny-train-moe", "train_step"),
+         ("bd_noise", "tiny-train-bd", "train_step"),
+         ("moe_route", "tiny-train-bd", "train_step")]
 READ_BY = {"jit_serve_decode_greedy": "benchmark/metrics/decode_roofline.py finds the decode "
                                       "program by name"}
 BY_STEM = ("benchmark/spans.py sums a trace's kernels by name stem, and PERF.md section 5 maps "
            "them to this scope through the step's text")
 
 
-@pytest.mark.parametrize("name, traffic, program", NAMES, ids=[n[0] for n in NAMES])
+@pytest.mark.parametrize("name, traffic, program", NAMES,
+                         ids=[f"{n[0]}-{n[1]}" for n in NAMES])
 def test_a_name_the_readers_search_for_is_in_the_lowered_program(runs, name, traffic, program):
     _, _, directory = runs(traffic)
     text = _program(directory, "jit_" + program)

@@ -23,13 +23,20 @@ def test_kernels_phase_interpret_mode():
     line = chip_smoke.kernels(
         attn=(1, 128, 2, 64), qmm=((8, 128, 256), (40, 256, 128)),
         adam=(40, 33), lstm=(8, 5, 128), interpret=True,
-        timed=((1, 256, 2, 64, 1),),
+        timed=((1, 256, 2, 64, 1), (1, 512, 2, 64, 1, 4)),
     )
     assert line["phase"] == "kernels" and line["interpret"] is True
     assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
                                      "fused_adam.update2"}
+    # a stream of 128 is rows of 64: too short for the kernel's tiles, so
+    # the block-diffusion comparison waits for a longer call (below)
+    assert "flash_bd.out" not in line["norm_err"]
     # the timed leg: no device time off the chip, the static census beside it
-    causal, full = line["flash"]
+    causal, full, blocks = line["flash"]
+    assert blocks["block_diffusion"] == 4 and "causal" not in blocks
+    assert blocks["flash_dkv"]["ms"] is None
+    assert blocks["flash_fwd"]["steps"] == 4 * 2     # 4 q tiles x (own + 1 clean)
+    assert blocks["flash_dq"]["computed_over_band"] > 1.0
     assert causal["causal"] and causal["flash_dkv"]["ms"] is None
     assert causal["flash_dq"]["computed_over_band"] > 1.0
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
@@ -40,6 +47,13 @@ def test_kernels_phase_interpret_mode():
     # the phase reports it, and only a native run insists on the kernels
     assert line["auto"] == {"attention": "reference", "lstm_scan": "xla",
                             "q_matmul": "pallas"}
+
+
+def test_kernels_phase_compares_the_block_diffusion_mask():
+    line = chip_smoke.kernels(attn=(1, 256, 2, 64), qmm=((8, 128, 128),), adam=(8, 16),
+                              lstm=(8, 2, 128), interpret=True, timed=())
+    assert {"flash_bd.out", "flash_bd.dq", "flash_bd.dk",
+            "flash_bd.dv"} <= set(line["norm_err"])
 
 
 def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
@@ -94,10 +108,14 @@ def test_moe_phase_tiny():
     line = chip_smoke.moe(vocab=256, maxlen=128, dim=64, heads=4, kv_heads=2,
                           depth=2, head_dim=16, router_dim=16, experts=4,
                           experts_held=(0, 2), expert_dim=64, ce_chunk=64,
-                          batch=2, steps=2, epochs=2, kernel_calls=0)
+                          batch=2, steps=2, epochs=2, kernel_calls=0,
+                          top_k=(64, 32, 16, 16, 4, 8))
     assert line["steps"] == 4
     assert abs(line["losses"][0] - line["plain_f32_first_loss"]) < 0.06
     assert 0.0 < line["held_share"] < 1.0
+    top = line["top_k"]
+    assert top["k"] == 8 and top["pairs"] == 64 * 8
+    assert 0 < top["pairs_held"] < top["pairs"] and top["norm_err"] < 2e-2
 
 
 def test_serve_phase_tiny():

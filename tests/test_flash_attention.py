@@ -662,10 +662,13 @@ def _band_case(rng, case):
     mk = lambda h: rng.normal(0, 1, size=(1, Lb, h, D)).astype(np.float32)
     q, k, v = mk(heads), mk(kv_heads), mk(kv_heads)
     kind = case.split("-", 1)[1]
-    kw = {"causal": kind != "bidirectional-window"}
+    kw = {"causal": kind not in ("bidirectional-window", "blocks",
+                                 "blocks-keymask")}
     if "window" in kind:
         kw["window"] = 300
-    if kind == "keymask":
+    if "blocks" in kind:         # a noised and a clean copy of rows of 512
+        kw["block_diffusion"] = 4
+    if "keymask" in kind:
         mask = np.ones((1, Lb), np.float32)
         mask[:, :40] = 0.0       # queries 0..39 see no key at all
         mask[:, 700:760] = 0.0
@@ -675,7 +678,7 @@ def _band_case(rng, case):
 
 BAND_CASES = [f"{h}-{k}" for h in ("mha64", "gqa128")
               for k in ("causal", "causal-window", "bidirectional-window",
-                        "keymask")]
+                        "keymask", "blocks", "blocks-keymask")]
 
 
 @pytest.fixture
@@ -690,7 +693,8 @@ def small_band_tiles(monkeypatch):
     monkeypatch.setattr(fa, "_pick_block_k", lambda L: 512)
     monkeypatch.setattr(fa, "_FINE", 128)
     monkeypatch.setattr(fa, "_WIDEST", 256)
-    plan = dict(fa._band_plan(1024, fa._tiles(1024), True, None))
+    plan = {d: pieces for d, pieces, _ in
+            fa._band_plan(1024, fa._tiles(1024), True, None)}
     first, last = plan[0], plan[256]     # steps (0, 0) and (3, 1)
     for pieces in (first, last):
         edges = [edge for *_, edge in pieces]
@@ -734,7 +738,8 @@ def test_band_census_is_what_the_kernels_run(rng, case, small_band_tiles,
     jax.effects_barrier()
     census = fa.band_census(q.shape[1], causal=kw["causal"],
                             window=kw.get("window"),
-                            masked="key_mask" in kw)
+                            masked="key_mask" in kw,
+                            block_diffusion=kw.get("block_diffusion"))
     heads = q.shape[0] * q.shape[2]
     assert len(ran) == 3
     for name, bodies in zip(("flash_fwd", "flash_dq", "flash_dkv"), ran):
@@ -754,9 +759,12 @@ def test_band_pieces_forward_and_lse_match_reference(rng, case,
     q, k, v, kw = _band_case(rng, case)
     scale = q.shape[-1] ** -0.5
     with jax.default_matmul_precision("highest"):
+        diffusion = fa._canonical_diffusion(
+            kw.get("block_diffusion"), q.shape[1], kw["causal"],
+            kw.get("window"))
         out, lse = fa._fa_forward(
             q, k, v, kw.get("key_mask"), scale=scale, causal=kw["causal"],
-            interpret=True, window=kw.get("window"))
+            interpret=True, window=kw.get("window"), diffusion=diffusion)
         ref = attention_reference(q, k, v, **kw)
         groups = q.shape[2] // k.shape[2]
         s = jnp.einsum("bqhd,bkhd->bhqk", q * scale,
@@ -765,7 +773,7 @@ def test_band_pieces_forward_and_lse_match_reference(rng, case,
                                rtol=2e-4, atol=2e-5)
     Lb = q.shape[1]
     valid = fa.band_predicate(np.arange(Lb)[:, None], np.arange(Lb)[None, :],
-                              kw["causal"], kw.get("window"))
+                              kw["causal"], kw.get("window"), diffusion)
     valid = np.broadcast_to(valid, s.shape)
     if "key_mask" in kw:
         valid = valid & (kw["key_mask"][:, None, None, :] > 0.5)
@@ -776,7 +784,9 @@ def test_band_pieces_forward_and_lse_match_reference(rng, case,
                                rtol=2e-5, atol=2e-5)
     if "key_mask" in kw:
         assert not seen.all()
-        dead = np.asarray(out)[:, :40]
+        # causal: queries 0..39 see no key; under blocks only the first four
+        # (later noised blocks see earlier clean ones, which are not masked)
+        dead = np.asarray(out)[:, :40 if kw["causal"] else 4]
         np.testing.assert_allclose(dead, np.zeros_like(dead), atol=1e-6)
 
 

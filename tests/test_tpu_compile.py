@@ -47,7 +47,7 @@ def one_chip(topo):
 
 
 def _flash(shape, *, kv_heads=None, dtype=BF16, causal=True, window=None,
-           masked=False, backward=True):
+           masked=False, backward=True, block_diffusion=None):
     """(fn, argument shapes) for flash attention at q ``shape``."""
     from distkeras_tpu.ops.flash_attention import flash_attention
 
@@ -59,7 +59,8 @@ def _flash(shape, *, kv_heads=None, dtype=BF16, causal=True, window=None,
 
     def fwd(q, k, v, mask=None):
         return flash_attention(q, k, v, causal=causal, key_mask=mask,
-                               window=window, interpret=False)
+                               window=window, interpret=False,
+                               block_diffusion=block_diffusion)
 
     if not backward:
         return fwd, args
@@ -122,6 +123,15 @@ KERNELS = {
         lambda: _flash((8, 4096, 8, 128), kv_heads=2, backward=False),
     "flash-fwdbwd-causal-gqa-kv2-8x4096x8x128":
         lambda: _flash((8, 4096, 8, 128), kv_heads=2),
+    # sdar-30b-a3b.train's call: a noised and a clean copy of 4 rows of 4096
+    # under the block-diffusion mask, 32 query heads over 4 key-value heads
+    "flash-fwdbwd-blockdiffusion4-gqa-kv4-4x8192x32x128":
+        lambda: _flash((4, 8192, 32, 128), kv_heads=4, causal=False,
+                       block_diffusion=4),
+    # and with a key mask, at tiles of 128 x 384 (rows of 384)
+    "flash-fwdbwd-blockdiffusion8-keymask-2x768x4x128":
+        lambda: _flash((2, 768, 4, 128), causal=False, masked=True,
+                       block_diffusion=8),
     # the heaviest body of the 2048-key ladder: the forward's 512 x 2048
     # float32 tile under the band AND a key mask, at head 128
     "flash-fwdbwd-causal-keymask-8x2048x8x128":
@@ -171,6 +181,8 @@ NAMED = {
                                               "flash_dkv"),
     "flash-fwdbwd-causal-gqa-kv2-8x4096x8x128": ("flash_fwd", "flash_dq",
                                                  "flash_dkv"),
+    "flash-fwdbwd-blockdiffusion4-gqa-kv4-4x8192x32x128": (
+        "flash_fwd", "flash_dq", "flash_dkv"),
     "lstm-fwdbwd-T200-H128-B32": ("lstm_scan_fwd", "lstm_scan_bwd"),
     "fused-adam-16384x1024": ("fused_adam",),
     "q_matmul-8x2048x2048": ("q_matmul",),
@@ -306,6 +318,59 @@ def test_zaya_train_step_compiles_with_its_kernels(topo, monkeypatch):
     T, E = B * L, size["experts"]
     assert f"[{T},{E},{T}]" not in text and f"[{T},{E}," not in text.replace(
         f"[{T},{E}]", "")
+
+
+def test_block_diffusion_train_step_compiles_with_its_kernels(topo, monkeypatch):
+    """A block-diffusion expert model's step (2 layers; 4 query / 2 key-value
+    heads of 128; 16 experts of which 4 are held, 4 a token; rows of 1024 as
+    streams of 2048; remat, fused CE) for one described chip: in each layer
+    the three flash kernels (the forward twice under remat) and the grouped
+    products as ``ragged-dot`` kernels; the noise is drawn inside the step
+    (``bd_noise``), and neither a ``[2 L, 2 L]`` mask nor a ``[pairs, dim]``
+    array of every (token, expert) pair is anywhere in the program."""
+    from distkeras_tpu import ops
+    from distkeras_tpu.models import SdarDims, transformer_lm
+    from distkeras_tpu.models.lm import held_rows
+    from distkeras_tpu.trainers import MeshTrainer
+
+    monkeypatch.setattr(ops, "native_kernels", lambda: True)
+    B, L, dim, depth = 4, 1024, 512, 2
+    dims = SdarDims(head_dim=128, experts=16, experts_per_token=4,
+                    experts_held=(0, 4), expert_dim=256, block_length=4)
+    spec = transformer_lm(vocab=8192, maxlen=L, dim=dim, heads=4, kv_heads=2,
+                          depth=depth, pos_embedding="rope", dtype=BF16,
+                          attn_impl="flash", fused_ce=True, ce_chunk=256,
+                          remat=True, sdar=dims)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    trainer = MeshTrainer(spec, loss="sparse_softmax_cross_entropy",
+                          worker_optimizer="adam", learning_rate=1e-5,
+                          mesh=mesh, batch_size=B)
+    engine, _, _ = trainer._build_engine()
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+            tree)
+
+    params, nt = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
+    engine._resolve_specs(params)
+    engine._build_step()
+    tokens = jax.ShapeDtypeStruct((B, L), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp")))
+    lowered = engine._step.lower(
+        placed(params), placed(nt),
+        placed(jax.eval_shape(engine.optimizer.init, params)),
+        (tokens, tokens))
+    assert "bd_noise" in lowered.as_text(debug_info=True)
+    text = lowered.compile().as_text()
+    for name, n in (("flash_fwd", 2), ("flash_dq", 1), ("flash_dkv", 1)):
+        assert text.count(f"%{name}") >= n * depth, name
+    assert "ragged-dot" in text
+    pairs = B * 2 * L * dims.experts_per_token
+    assert held_rows(B * 2 * L, dims) == (11264, 4096) and 11264 < pairs
+    for shape in (f"[{2 * L},{2 * L}]", f",{2 * L},{2 * L}]", f"[{pairs},{dim}]"):
+        assert shape not in text, shape
 
 
 # -- the serving steps -----------------------------------------------------------
