@@ -168,11 +168,73 @@ def _flash_times(timed, interpret, reps=3) -> list:
     return out
 
 
+def _qk_prep_times(shapes, interpret, reps=3, eps=1e-6) -> list:
+    """``ops.qk_prep`` forward and backward at each ``(B, S, heads, D)`` of
+    ``shapes`` (bf16; the block-diffusion cell's q and k projections by
+    default; rows at ``0 .. S/2 - 1`` twice) beside the ``jnp`` chain it
+    replaces (``head_norm_rope``, the cast, the move to head-major): ms a call
+    of each kernel on the chip and the GB/s its floor's bytes come to (the
+    forward reads the projection's result and writes the operand; the backward
+    reads both and writes the gradient; the chip moves 819), the chain's ms
+    for the same two passes (None in interpret mode: a CPU gives no device
+    time), and the kernel's error against it."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models.lm import head_norm_rope, rope_angles_at
+    from distkeras_tpu.ops.qk_prep import qk_prep
+
+    names = ("qk_prep_fwd", "qk_prep_bwd")
+    out = []
+    for B, S, heads, D in shapes:
+        ks = jax.random.split(jax.random.PRNGKey(SEED + heads), 3)
+        x = jax.random.normal(ks[0], (B, S, heads * D), jnp.bfloat16)
+        g = jax.random.normal(ks[1], (B * heads, S, D), jnp.bfloat16)
+        w = 1.0 + 0.1 * jax.random.normal(ks[2], (D,), jnp.float32)
+        row = np.arange(S // 2)
+        angles = jnp.asarray(rope_angles_at(np.concatenate([row, row]), D,
+                                            1e6))
+
+        def chain(x, w):
+            y = head_norm_rope(x, w, angles, heads, eps).astype(x.dtype)
+            return jnp.moveaxis(y, 2, 1).reshape(B * heads, S, D)
+
+        def fwd_bwd(fn):
+            def run(x, w, g):
+                o, vjp = jax.vjp(fn, x, w)
+                return (o,) + vjp(g)
+            return jax.jit(run)
+
+        kernel = fwd_bwd(lambda x, w: qk_prep(
+            x, w, angles, heads=heads, eps=eps, interpret=interpret))
+        plain = fwd_bwd(chain)
+        got, want = kernel(x, w, g), plain(x, w, g)        # compiles
+        entry = {"shape": [B, S, heads, D], "norm_err": {
+            part: _norm_err(a, b)
+            for part, a, b in zip(("out", "dx", "dw"), got, want)}}
+        floor = {"qk_prep_fwd": 2 * x.nbytes, "qk_prep_bwd": 3 * x.nbytes}
+        ms, chain_ms = dict.fromkeys(names), None
+        if not interpret:
+            ms = _device_ms_by_kernel(
+                lambda: jax.block_until_ready(
+                    [kernel(x, w, g) for _ in range(reps)]), names, reps)
+            chain_ms = round(sum(_device_ms_by_op(
+                lambda: jax.block_until_ready(
+                    [plain(x, w, g) for _ in range(reps)]), reps).values()), 4)
+        for n in names:
+            entry[n] = {"ms": ms[n], "floor_bytes": floor[n],
+                        "gb_per_s": ms[n] and round(floor[n] / ms[n] / 1e6, 1)}
+        entry["chain_ms"] = chain_ms
+        out.append(entry)
+    return out
+
+
 def kernels(*, attn=(8, 2048, 8, 128),
             qmm=((8, 2048, 8192), (1024, 8192, 2048)),
             adam=(16384, 1024), lstm=(64, 200, 512), interpret=False,
             timed=((8, 2048, 16, 64, 16), (8, 4096, 8, 128, 2),
-                   (4, 8192, 32, 128, 4, 4)), block=4):
+                   (4, 8192, 32, 128, 4, 4)), block=4,
+            prep=((4, 8192, 32, 128), (4, 8192, 4, 128))):
     """flash attention fwd+bwd (causal, and under the block-diffusion mask
     over blocks of ``block``; bf16), ``q_matmul`` (bf16 × int8), fused Adam
     (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real call shape
@@ -181,7 +243,10 @@ def kernels(*, attn=(8, 2048, 8, 128),
     three flash kernels timed on the chip at ``timed``, the benchmark cells'
     ``(B, L, H, D, Hkv)`` (the third with its block length: the
     block-diffusion call): what a computed pair costs with and without the
-    mask, beside what ``band_census`` says they compute."""
+    mask, beside what ``band_census`` says they compute. Then ``qk_prep``
+    each way at ``prep``, the block-diffusion cell's q and k projections
+    ``(B, S, heads, D)``, beside the ``jnp`` chain it replaces
+    (:func:`_qk_prep_times`)."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -277,18 +342,24 @@ def kernels(*, attn=(8, 2048, 8, 128),
         "attention": kernel_impl("attention", L=L),
         "lstm_scan": kernel_impl("lstm_scan", B=Bl, H=Hl),
         "q_matmul": kernel_impl("q_matmul", k=qmm[0][1], n=qmm[0][2]),
+        **({"qk_prep": kernel_impl("qk_prep", S=prep[0][1], D=prep[0][3])}
+           if prep else {}),
     }
+    preps = _qk_prep_times(prep, interpret)
+    for entry in preps:
+        for part, e in entry["norm_err"].items():
+            # the weight's gradient is f32 but sums products of bf16 rows
+            errs[f"qk_prep{entry['shape'][2]}.{part}"] = (e, "bfloat16")
     line = _report("kernels", t0, interpret=bool(interpret), auto=auto,
                    norm_err={k: e for k, (e, _) in errs.items()},
-                   flash=_flash_times(timed, interpret))
+                   flash=_flash_times(timed, interpret), qk_prep=preps)
     for name, (e, dtype) in errs.items():
         # wh's gradient is f32 but flows through the bf16 recurrence
         tol = TOL["bfloat16"] if name.startswith("lstm") else TOL[dtype]
         _check(e <= tol, f"kernels: {name} off its reference by {e:.3g} "
                          f"(normalized), tolerance {tol:g}")
     if not interpret:
-        _check(auto == {"attention": "flash", "lstm_scan": "pallas",
-                        "q_matmul": "pallas"},
+        _check(set(auto.values()) <= {"flash", "pallas"},
                f"kernels: 'auto' does not pick the kernels here: {auto}")
     return line
 

@@ -68,6 +68,19 @@ def apply_rope(x, angles):
     return out.astype(x.dtype)
 
 
+def head_norm_rope(a, w, angles, heads: int, eps: float):
+    """A q or k projection's result ``a [B, S, heads·Dh]`` as float32
+    ``[B, S, heads, Dh]``: an RMSNorm over each head with the weight
+    ``w [Dh]``, then :func:`apply_rope` by ``angles [S, Dh // 2]``. The plain
+    chain that ``ops.qk_prep`` does in one kernel (and hands over head-major,
+    in ``a.dtype``): what runs at shapes the kernel refuses, and what its
+    tests compare with."""
+    B, S, _ = a.shape
+    a = a.astype(jnp.float32).reshape(B, S, heads, -1)
+    a = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + eps) * w
+    return apply_rope(a, angles)
+
+
 class QDense(nn.Module):
     """Dense over an int8 weight-only-quantized kernel (``ops.quant``).
 
@@ -775,7 +788,12 @@ class QKNormAttention(nn.Module):
     the whole head at the token's position IN ITS ROW (``0 .. L-1`` twice),
     attention under the block-diffusion mask
     (``ops.flash_attention.band_predicate``), the output projection and the
-    residual. A training step (the ``counters`` collection mutable) leaves in
+    residual. Under ``attn_impl="flash"`` at heads of a multiple of 128 the
+    norm, the rotation and the move into the kernels' head-major layout are
+    ONE kernel each way between a projection and ``flash_fwd``
+    (``ops.qk_prep``; ``ops.kernel_impl("qk_prep", …)`` says whether), at any
+    other shape the ``jnp`` chain :func:`head_norm_rope`. A training step
+    (the ``counters`` collection mutable) leaves in
     ``counters/first_block`` (float32 ``[block_length, dim]``, no counter:
     overwritten, not added to) what came out at the first row's first noised
     block, whose queries see that block's keys and no other: the one place
@@ -791,7 +809,10 @@ class QKNormAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None):
-        from distkeras_tpu.ops.flash_attention import BLOCK_Q, attention
+        from distkeras_tpu.ops import kernel_impl
+        from distkeras_tpu.ops.flash_attention import (BLOCK_Q, attention,
+                                                       flash_attention)
+        from distkeras_tpu.ops.qk_prep import qk_prep
 
         z, f32 = self.z, jnp.float32
         B, S, _ = x.shape
@@ -799,28 +820,30 @@ class QKNormAttention(nn.Module):
         dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
         h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
         h = h.astype(self.dtype)
-
-        def head_norm(a, heads, name):
-            w = self.param(name, nn.initializers.ones, (dh,), f32)
-            a = a.astype(f32).reshape(B, S, heads, dh)
-            return a * jax.lax.rsqrt(
-                jnp.mean(a * a, -1, keepdims=True) + z.norm_eps) * w
-
-        q = head_norm(dense(H * dh, name="q")(h), H, "q_norm")
-        k = head_norm(dense(K * dh, name="k")(h), K, "k_norm")
+        q, k = dense(H * dh, name="q")(h), dense(K * dh, name="k")(h)
         v = dense(K * dh, name="v")(h).reshape(B, S, K, dh)
+        wq, wk = (self.param(name, nn.initializers.ones, (dh,), f32)
+                  for name in ("q_norm", "k_norm"))
         # positions come from a vector, not from the length: both copies of a
         # row stand at 0 .. L-1
         row = np.arange(S // 2)
         angles = jnp.asarray(rope_angles_at(np.concatenate([row, row]), dh,
                                             z.rope_base))
-        q, k = apply_rope(q, angles), apply_rope(k, angles)
         impl = self.attn_impl
         if impl == "flash" and (S // 2) % BLOCK_Q:
             impl = "reference"
-        o = attention(q.astype(self.dtype), k.astype(self.dtype), v,
-                      key_mask=mask, impl=impl,
-                      block_diffusion=z.block_length)
+        prep = "pallas" if impl == "flash" else "xla"
+        if kernel_impl("qk_prep", prep, S=S, D=dh) == "pallas":
+            q, k = (qk_prep(a, w, angles, heads=n, eps=z.norm_eps)
+                    for a, w, n in ((q, wq, H), (k, wk, K)))
+            o = flash_attention(q, k, v, key_mask=mask, qk_major=True,
+                                block_diffusion=z.block_length)
+        else:
+            q, k = (head_norm_rope(a, w, angles, n, z.norm_eps)
+                    .astype(self.dtype)
+                    for a, w, n in ((q, wq, H), (k, wk, K)))
+            o = attention(q, k, v, key_mask=mask, impl=impl,
+                          block_diffusion=z.block_length)
         o = dense(self.dim, name="out")(
             o.reshape(B, S, H * dh).astype(self.dtype))
         x = x + o.astype(f32)

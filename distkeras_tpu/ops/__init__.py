@@ -57,28 +57,31 @@ def kernel_mesh(mesh, batch_axis: str):
         _KERNEL_MESH.reset(token)
 
 
-def on_each_device(kernel, *arrays):
-    """``kernel(*arrays)`` for a kernel that is independent across dim 0 of
-    every array (``None`` entries pass through): under :func:`kernel_mesh`,
-    a ``shard_map`` in which each device runs it on its own rows — split on
-    the batch axis, whole along every other mesh axis; with no mesh
-    declared, on a one-device mesh, or inside a region that is already
-    manual (a strategy's own ``shard_map``), the plain call."""
+def on_each_device(kernel, *arrays, whole=()):
+    """``kernel(*arrays, *whole)`` for a kernel that is independent across
+    dim 0 of every one of ``arrays`` (``None`` entries pass through) and of
+    its results: under :func:`kernel_mesh`, a ``shard_map`` in which each
+    device runs it on its own rows — split on the batch axis, whole along
+    every other mesh axis — and on all of every array in ``whole`` (a
+    weight, a table); with no mesh declared, on a one-device mesh, or inside
+    a region that is already manual (a strategy's own ``shard_map``), the
+    plain call."""
     import jax
     from jax.sharding import PartitionSpec as P
 
     declared = _KERNEL_MESH.get()
     if (declared is None or declared[0].size == 1
             or jax.sharding.get_abstract_mesh().manual_axes):
-        return kernel(*arrays)
+        return kernel(*arrays, *whole)
     mesh, batch_axis = declared
     # a mesh without that axis (tp only): every device runs the whole batch
     rows = P(batch_axis if batch_axis in mesh.axis_names else None)
     return jax.shard_map(
         kernel, mesh=mesh,
-        in_specs=tuple(None if a is None else rows for a in arrays),
+        in_specs=tuple(None if a is None else rows for a in arrays)
+        + (P(),) * len(whole),
         out_specs=rows, check_vma=False,
-    )(*arrays)
+    )(*arrays, *whole)
 
 
 def kernel_impl(op: str, impl: str = "auto", **dims) -> str:
@@ -87,15 +90,19 @@ def kernel_impl(op: str, impl: str = "auto", **dims) -> str:
     ``kernel_impl("attention", L=2048)`` → ``"flash"`` | ``"reference"``;
     ``kernel_impl("lstm_scan", B=64, H=512)`` and
     ``kernel_impl("q_matmul", k=2048, n=8192)`` → ``"pallas"`` |
-    ``"xla"``. The dispatchers themselves (``flash_attention.attention``,
-    ``recurrent.lstm_scan``, ``quant.q_matmul``) decide through the same
-    functions, so what this returns is what runs — the answer a smoke run or
-    a test asserts on instead of trusting that ``"auto"`` found the chip.
+    ``"xla"``, as is ``kernel_impl("qk_prep", "pallas", S=8192, D=128)``
+    (what stands between a q / k projection and the flash kernels). The
+    dispatchers themselves (``flash_attention.attention``,
+    ``recurrent.lstm_scan``, ``quant.q_matmul``, ``QKNormAttention``) decide
+    through the same functions, so what this returns is what runs — the
+    answer a smoke run or a test asserts on instead of trusting that
+    ``"auto"`` found the chip.
     """
     resolvers = {
         "attention": ("flash_attention", "attention_impl"),
         "lstm_scan": ("recurrent", "lstm_impl"),
         "q_matmul": ("quant", "q_matmul_impl"),
+        "qk_prep": ("qk_prep", "qk_prep_impl"),
     }
     if op not in resolvers:
         raise ValueError(f"unknown op {op!r}; one of {sorted(resolvers)}")

@@ -702,9 +702,11 @@ def band_census(L, causal=False, window=None, masked=False,
             "flash_dkv": count(True, False)}
 
 
-def _gqa_groups(q, k):
-    """Validated GQA group size: q heads per shared k/v head (1 = MHA)."""
-    H, Hkv = q.shape[2], k.shape[2]
+def _gqa_groups(q, k, qk_major=False):
+    """Validated GQA group size: q heads per shared k/v head (1 = MHA);
+    ``qk_major``: q and k are head-major, ``[B·heads, L, D]``."""
+    axis = 0 if qk_major else 2
+    H, Hkv = q.shape[axis], k.shape[axis]
     if H % Hkv:
         raise ValueError(
             f"q heads {H} must be a multiple of kv heads {Hkv}"
@@ -727,13 +729,14 @@ def _tiles(L):
 
 
 def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
-                window=None, diffusion=None):
+                window=None, diffusion=None, qk_major=False):
     """q [B, L, H, D], k/v [B, L, Hkv, D] with Hkv | H (grouped-query
     attention reads shared K/V heads straight from the index maps — no
     repeated-KV materialization), + key_mask [B, L] →
-    (out [B, L, H, D], lse)."""
+    (out [B, L, H, D], lse). ``qk_major``: q and k come as the kernels walk
+    them, ``[B·H, L, D]`` and ``[B·Hkv, L, D]`` (see :func:`flash_attention`)."""
     L = q.shape[1]
-    _gqa_groups(q, k)
+    _gqa_groups(q, k, qk_major)
     if L % BLOCK_Q:
         raise ValueError(
             f"sequence length {L} must be a multiple of {BLOCK_Q}"
@@ -741,7 +744,7 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
     tiles = _tiles(L if diffusion is None else L // 2)
     return _fwd_call(q, k, v, key_mask, tiles=tiles, scale=scale,
                      causal=causal, interpret=interpret, window=window,
-                     diffusion=diffusion)
+                     diffusion=diffusion, qk_major=qk_major)
 
 
 # The two launchers are jitted on their own: a model calls them once a layer
@@ -753,19 +756,22 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
 # that reads dq, dk and dv takes 0.2 ms a layer more (PERF.md section 6).
 # The tiles are an argument so that the choice is part of the cache's key;
 # the band's grain (``_FINE``, ``_WIDEST``) is read when a launcher traces.
-_STATIC = ("tiles", "scale", "causal", "interpret", "window", "diffusion")
+_STATIC = ("tiles", "scale", "causal", "interpret", "window", "diffusion",
+           "qk_major")
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
-              diffusion=None):
-    B, L, H, D = q.shape
-    Hkv = k.shape[2]
+              diffusion=None, qk_major=False):
+    B, L, Hkv, D = v.shape
+    H = Hkv * _gqa_groups(q, k, qk_major)
     bq, bk = tiles
 
     def bh(x):  # [B, L, h, D] → [B·h, L, D]
         h = x.shape[2]
         return jnp.moveaxis(x, 2, 1).reshape(B * h, L, D)
+
+    qb, kb = (q, k) if qk_major else (bh(q), bh(k))
 
     nk = L // bk
     nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window, diffusion)
@@ -794,7 +800,7 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
         pltpu.VMEM((bq, D), jnp.float32),   # running numerator acc
     ]
     in_specs = [qspec, kvspec, kvspec]
-    args = [bh(q), bh(k), bh(v)]
+    args = [qb, kb, bh(v)]
     if key_mask is not None:
         # mask ships as [B, 1, L] so its block obeys the (8, 128) tile rule
         in_specs.append(
@@ -974,26 +980,27 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
 
 
 def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
-                 interpret, window=None, diffusion=None):
+                 interpret, window=None, diffusion=None, qk_major=False):
     """Blockwise flash-attention backward: (dq, dk, dv) via two Pallas
     kernels, ``O(block_q · block_k)`` on-chip — no [B, H, L, L] tensors.
     Under grouped-query attention (k/v hold Hkv < H heads) dq reads the
     shared heads through the index maps and the dkv grid gains a group
     axis whose accumulators sum the whole group — dk/dv come out
-    Hkv-wide, no repeated-KV tensors anywhere."""
+    Hkv-wide, no repeated-KV tensors anywhere. ``qk_major``: q and k come,
+    and dq and dk go, head-major."""
     L = q.shape[1]
     tiles = _tiles(L if diffusion is None else L // 2)
     return _bwd_call(q, k, v, key_mask, out, lse, g, tiles=tiles,
                      scale=scale, causal=causal, interpret=interpret,
-                     window=window, diffusion=diffusion)
+                     window=window, diffusion=diffusion, qk_major=qk_major)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
-              interpret, window, diffusion=None):
-    B, L, H, D = q.shape
-    Hkv = k.shape[2]
-    groups = _gqa_groups(q, k)
+              interpret, window, diffusion=None, qk_major=False):
+    B, L, Hkv, D = v.shape
+    groups = _gqa_groups(q, k, qk_major)
+    H = Hkv * groups
     bq, bk = tiles  # the forward's: one ladder
     plan = _band_plan(L, tiles, causal, window, False, diffusion)
 
@@ -1001,7 +1008,8 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
         h = x.shape[2]
         return jnp.moveaxis(x, 2, 1).reshape(B * h, L, D)
 
-    qb, kb, vb, gb = bh(q), bh(k), bh(v), bh(g)
+    qb, kb = (q, k) if qk_major else (bh(q), bh(k))
+    vb, gb = bh(v), bh(g)
     # delta = rowsum(dO · O): one elementwise pass, [B·H, L]
     delta = jnp.sum(gb.astype(jnp.float32) * bh(out).astype(jnp.float32),
                     axis=-1)
@@ -1101,6 +1109,8 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
         h = x.shape[0] // B
         return jnp.moveaxis(x.reshape(B, h, L, D), 1, 2)
 
+    if qk_major:
+        return dq, dk, unbh(dv)
     return unbh(dq), unbh(dk), unbh(dv)
 
 
@@ -1152,44 +1162,46 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
 # dim 0 — q/k/v/out/g ``[B, …]``, the mask ``[B, L]``, lse ``[B·H, L]``.
 
 
-def _forward(q, k, v, key_mask, scale, causal, interpret, window, diffusion):
+def _forward(q, k, v, key_mask, scale, causal, interpret, window, diffusion,
+             qk_major):
     return ops.on_each_device(
         functools.partial(_fa_forward, scale=scale, causal=causal,
                           interpret=interpret, window=window,
-                          diffusion=diffusion),
+                          diffusion=diffusion, qk_major=qk_major),
         q, k, v, key_mask,
     )
 
 
 def _backward(q, k, v, key_mask, out, lse, g, scale, causal, interpret,
-              window, diffusion):
+              window, diffusion, qk_major):
     return ops.on_each_device(
         functools.partial(_fa_backward, scale=scale, causal=causal,
                           interpret=interpret, window=window,
-                          diffusion=diffusion),
+                          diffusion=diffusion, qk_major=qk_major),
         q, k, v, key_mask, out, lse, g,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
 def _flash_core(q, k, v, key_mask, causal, scale, interpret, window,
-                diffusion):
+                diffusion, qk_major):
     out, _ = _forward(q, k, v, key_mask, scale, causal, interpret, window,
-                      diffusion)
+                      diffusion, qk_major)
     return out
 
 
-def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window, diffusion):
+def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window, diffusion,
+            qk_major):
     out, lse = _forward(q, k, v, key_mask, scale, causal, interpret, window,
-                        diffusion)
+                        diffusion, qk_major)
     # saving `out` adds no memory under jit: it aliases the primal output
     return out, (q, k, v, key_mask, out, lse)
 
 
-def _fa_bwd(causal, scale, interpret, window, diffusion, res, g):
+def _fa_bwd(causal, scale, interpret, window, diffusion, qk_major, res, g):
     q, k, v, key_mask, out, lse = res
     dq, dk, dv = _backward(q, k, v, key_mask, out, lse, g, scale, causal,
-                           interpret, window, diffusion)
+                           interpret, window, diffusion, qk_major)
     dmask = None if key_mask is None else jnp.zeros_like(key_mask)
     return dq, dk, dv, dmask
 
@@ -1235,7 +1247,8 @@ def _canonical_diffusion(block, L, causal, window):
 
 def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
                     interpret: bool | None = None, window: int | None = None,
-                    block_diffusion: int | None = None):
+                    block_diffusion: int | None = None,
+                    qk_major: bool = False):
     """Pallas flash attention; same contract as ``attention_reference``.
 
     ``q/k/v`` [B, L, H, D] → [B, L, H, D]; optional ``key_mask`` [B, L]
@@ -1250,6 +1263,10 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
     ``L // 2`` must be a multiple of 128. No mask array is built: a tile the
     mask empties is neither computed nor fetched, a wholly visible one runs
     with no mask, and only tiles a diagonal crosses are masked.
+    ``qk_major``: ``q`` and ``k`` come head-major, ``[B·H, L, D]`` and
+    ``[B·Hkv, L, D]`` (what ``ops.qk_prep`` writes: the layout the kernels'
+    grids walk, which any other caller's q and k are copied into), and their
+    gradients go back so; ``v`` and the result keep ``[B, L, heads, D]``.
     """
     L = q.shape[1]
     diffusion = _canonical_diffusion(block_diffusion, L, causal, window)
@@ -1264,6 +1281,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
         ops.interpreted(interpret),
         _canonical_window(window, L),
         diffusion,
+        bool(qk_major),
     )
 
 

@@ -24,6 +24,7 @@ def test_kernels_phase_interpret_mode():
         attn=(1, 128, 2, 64), qmm=((8, 128, 256), (40, 256, 128)),
         adam=(40, 33), lstm=(8, 5, 128), interpret=True,
         timed=((1, 256, 2, 64, 1), (1, 512, 2, 64, 1, 4)),
+        prep=((2, 256, 2, 128), (1, 128, 1, 128)),
     )
     assert line["phase"] == "kernels" and line["interpret"] is True
     assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
@@ -46,12 +47,21 @@ def test_kernels_phase_interpret_mode():
     # off the chip "auto" keeps the references for attention and the LSTM —
     # the phase reports it, and only a native run insists on the kernels
     assert line["auto"] == {"attention": "reference", "lstm_scan": "xla",
-                            "q_matmul": "pallas"}
+                            "q_matmul": "pallas", "qk_prep": "xla"}
+    # qk_prep at a q and a k projection's shape: its error against the jnp
+    # chain beside (off the chip) no device time
+    q, k = line["qk_prep"]
+    assert q["shape"] == [2, 256, 2, 128] and k["shape"] == [1, 128, 1, 128]
+    assert q["qk_prep_fwd"] == {"ms": None, "floor_bytes": 2 * 2 * 256 * 256 * 2,
+                                "gb_per_s": None}
+    assert q["qk_prep_bwd"]["floor_bytes"] == 3 * 2 * 256 * 256 * 2
+    assert q["chain_ms"] is None and max(q["norm_err"].values()) < 2e-2
+    assert {"qk_prep2.out", "qk_prep2.dx", "qk_prep1.dw"} <= set(line["norm_err"])
 
 
 def test_kernels_phase_compares_the_block_diffusion_mask():
     line = chip_smoke.kernels(attn=(1, 256, 2, 64), qmm=((8, 128, 128),), adam=(8, 16),
-                              lstm=(8, 2, 128), interpret=True, timed=())
+                              lstm=(8, 2, 128), interpret=True, timed=(), prep=())
     assert {"flash_bd.out", "flash_bd.dq", "flash_bd.dk",
             "flash_bd.dv"} <= set(line["norm_err"])
 
@@ -69,7 +79,7 @@ def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure, match="off its reference"):
         chip_smoke.kernels(attn=(1, 128, 1, 64), qmm=((8, 128, 128),),
                            adam=(8, 16), lstm=(8, 2, 128), interpret=True,
-                           timed=())
+                           timed=(), prep=())
 
 
 def test_loss_phase_tiny():

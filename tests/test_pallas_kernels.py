@@ -120,6 +120,12 @@ def test_fused_adam_vs_adam_trainer_equivalence():
     ("q_matmul", "auto", dict(k=2048, n=8192), False, "pallas"),
     ("q_matmul", "auto", dict(k=100, n=8192), True, "xla"),
     ("q_matmul", "auto", dict(k=16384, n=128), True, "xla"),
+    # qk_prep by name is the kernel where its tiles fit and falls back where
+    # they do not; "auto" also wants the chip
+    ("qk_prep", "pallas", dict(S=8192, D=128), False, "pallas"),
+    ("qk_prep", "pallas", dict(S=8192, D=64), True, "xla"),
+    ("qk_prep", "auto", dict(S=8192, D=128), True, "pallas"),
+    ("qk_prep", "auto", dict(S=8192, D=128), False, "xla"),
 ])
 def test_kernel_impl_reports_what_runs(monkeypatch, op, impl, dims, native,
                                        want):

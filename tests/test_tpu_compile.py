@@ -72,6 +72,24 @@ def _flash(shape, *, kv_heads=None, dtype=BF16, causal=True, window=None,
     return fwd_bwd, args
 
 
+def _qk_prep(B, S, heads, D=128):
+    """(fn, argument shapes): ``qk_prep`` and its gradients at a projection's
+    result ``[B, S, heads·D]``, rows at ``0 .. S/2 - 1`` twice."""
+    from distkeras_tpu.models.lm import rope_angles_at
+    from distkeras_tpu.ops.qk_prep import qk_prep
+
+    row = np.arange(S // 2)
+    angles = rope_angles_at(np.concatenate([row, row]), D, 1e6)
+
+    def fwd_bwd(x, w, g):
+        out, vjp = jax.vjp(lambda x, w: qk_prep(
+            x, w, angles, heads=heads, eps=1e-6, interpret=False), x, w)
+        return (out,) + vjp(g)
+
+    return fwd_bwd, [((B, S, heads * D), BF16), ((D,), F32),
+                     ((B * heads, S, D), BF16)]
+
+
 def _lstm(B, T, H, workers=None):
     from distkeras_tpu.ops.recurrent import lstm_scan
 
@@ -144,6 +162,10 @@ KERNELS = {
         lambda: _flash((8, 128, 16, 128), kv_heads=1, backward=False),
     "flash-fwdbwd-f32-L16384":
         lambda: _flash((1, 16384, 8, 64), dtype=F32),
+    # sdar-30b-a3b.train's q and k on their way to that call: [4, 8192, 4096]
+    # to [128, 8192, 128] and [4, 8192, 512] to [16, 8192, 128], and back
+    "qk_prep-fwdbwd-q-4x8192x32x128": lambda: _qk_prep(4, 8192, 32),
+    "qk_prep-fwdbwd-k-4x8192x4x128": lambda: _qk_prep(4, 8192, 4),
     # fused LSTM scan: the IMDB config's batches, the stacked-worker vmap,
     # and chip_smoke's shape
     **{f"lstm-fwdbwd-T200-H128-B{b}": (lambda b=b: _lstm(b, 200, 128))
@@ -183,6 +205,8 @@ NAMED = {
                                                  "flash_dkv"),
     "flash-fwdbwd-blockdiffusion4-gqa-kv4-4x8192x32x128": (
         "flash_fwd", "flash_dq", "flash_dkv"),
+    "qk_prep-fwdbwd-q-4x8192x32x128": ("qk_prep_fwd", "qk_prep_bwd"),
+    "qk_prep-fwdbwd-k-4x8192x4x128": ("qk_prep_fwd", "qk_prep_bwd"),
     "lstm-fwdbwd-T200-H128-B32": ("lstm_scan_fwd", "lstm_scan_bwd"),
     "fused-adam-16384x1024": ("fused_adam",),
     "q_matmul-8x2048x2048": ("q_matmul",),
@@ -256,6 +280,7 @@ def test_lm_train_step_compiles_with_its_kernels(topo, monkeypatch, dp,
     assert "HloModule jit_train_step" in text
     for name in ("flash_fwd", "flash_dq", "flash_dkv"):
         assert text.count(name) >= 8, name
+    assert "qk_prep" not in text      # the block-diffusion block's alone
     assert (" all-gather(" in text) == (dp > 1)
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8 * 2 ** 30, mem
@@ -315,9 +340,38 @@ def test_zaya_train_step_compiles_with_its_kernels(topo, monkeypatch):
     for name, n in (("flash_fwd", 2), ("flash_dq", 1), ("flash_dkv", 1)):
         assert text.count(f"%{name}") >= n * depth, name
     assert text.count(chip_smoke.KERNEL_CALL) == size["kernel_calls"]
+    assert "qk_prep" not in text      # CCA keeps apply_rope and its program
     T, E = B * L, size["experts"]
     assert f"[{T},{E},{T}]" not in text and f"[{T},{E}," not in text.replace(
         f"[{T},{E}]", "")
+
+
+def _instructions(text):
+    """``{name: (opcode, operand names, the line)}`` of a compiled program."""
+    import re
+
+    out = {}
+    for line in text.splitlines():
+        m = re.match(r"\s+(?:ROOT )?%([\w.\-]+) = .*? ([\w\-]+)\((.*)", line)
+        if m:
+            operands = re.findall(r"%([\w.\-]+)", m.group(3).split(")")[0])
+            out[m.group(1)] = (m.group(2), operands, line)
+    return out
+
+
+#: what may stand between two kernels without being a pass over the array: a
+#: view, a tuple's element, and the compiler's own moves between its memory
+#: spaces (``S(1)`` in a layout), which run beside other work
+_NO_PASS = ("bitcast", "get-tuple-element", "copy-start", "copy-done")
+
+
+def _source(instructions, name):
+    """The instruction ``name`` is a view of, and the opcodes on the way."""
+    seen = []
+    while instructions[name][0] in _NO_PASS:
+        seen.append(instructions[name][0])
+        name = instructions[name][1][0]
+    return name, seen
 
 
 def test_block_diffusion_train_step_compiles_with_its_kernels(topo, monkeypatch):
@@ -327,7 +381,11 @@ def test_block_diffusion_train_step_compiles_with_its_kernels(topo, monkeypatch)
     the three flash kernels (the forward twice under remat) and the grouped
     products as ``ragged-dot`` kernels; the noise is drawn inside the step
     (``bd_noise``), and neither a ``[2 L, 2 L]`` mask nor a ``[pairs, dim]``
-    array of every (token, expert) pair is anywhere in the program."""
+    array of every (token, expert) pair is anywhere in the program. q and k
+    go from their projections to ``flash_fwd`` through ``qk_prep_fwd`` (4
+    calls a layer) and their gradients back through ``qk_prep_bwd`` (2) with
+    no copy, transpose or fusion between the kernels, and nothing under
+    ``/attn/`` is a gather or a scatter."""
     from distkeras_tpu import ops
     from distkeras_tpu.models import SdarDims, transformer_lm
     from distkeras_tpu.models.lm import held_rows
@@ -367,6 +425,25 @@ def test_block_diffusion_train_step_compiles_with_its_kernels(topo, monkeypatch)
     for name, n in (("flash_fwd", 2), ("flash_dq", 1), ("flash_dkv", 1)):
         assert text.count(f"%{name}") >= n * depth, name
     assert "ragged-dot" in text
+    for name, n in (("qk_prep_fwd", 4), ("qk_prep_bwd", 2)):
+        assert text.count(f"%{name}") >= n * depth, name
+    ins = _instructions(text)
+    kernel = lambda stem: [n for n in ins if n.split(".")[0] == stem
+                           and ins[n][0] == "custom-call"]
+    assert len(kernel("flash_fwd")) == 2 * depth
+    for name in kernel("flash_fwd"):            # q and k: operands 0 and 1
+        for operand in ins[name][1][:2]:
+            source, _ = _source(ins, operand)
+            assert source.split(".")[0] == "qk_prep_fwd", ins[source][2][:300]
+    assert len(kernel("qk_prep_bwd")) == 2 * depth
+    for name in kernel("qk_prep_bwd"):          # the cotangent: operand 1
+        source, _ = _source(ins, ins[name][1][1])
+        assert source.split(".")[0] in ("flash_dq", "flash_dkv"), \
+            ins[source][2][:300]
+    for name, (opcode, _, line) in ins.items():
+        if "/attn/" in line and "op_name=" in line:
+            assert "gather" not in opcode and "scatter" not in opcode \
+                and not name.startswith(("gather", "scatter")), line[:300]
     pairs = B * 2 * L * dims.experts_per_token
     assert held_rows(B * 2 * L, dims) == (11264, 4096) and 11264 < pairs
     for shape in (f"[{2 * L},{2 * L}]", f",{2 * L},{2 * L}]", f"[{pairs},{dim}]"):
