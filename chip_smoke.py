@@ -124,7 +124,9 @@ def _flash_times(timed, interpret, reps=3) -> list:
     """The three flash kernels at each ``(B, L, H, D, Hkv)`` of ``timed``,
     causal and not — or, for an entry ``(B, L, H, D, Hkv, G)``, under the
     block-diffusion mask over blocks of ``G`` (``L`` the stream's length: a
-    noised and a clean copy of rows of ``L // 2``): ms a call on the chip
+    noised and a clean copy of rows of ``L // 2``); ``D`` a pair ``(Dk, Dv)``
+    is latent attention's call, q and k ``Dk`` wide and v ``Dv``, causal: ms a
+    call on the chip
     (None in interpret mode: a CPU gives no device time) beside what
     ``band_census`` counts for that length: grid steps a head and how many
     are idle, computed over band pairs, and the share of the computed pairs
@@ -137,13 +139,15 @@ def _flash_times(timed, interpret, reps=3) -> list:
     names = ("flash_fwd", "flash_dq", "flash_dkv")
     out = []
     for B, L, H, D, Hkv, *block in timed:
+        Dk, Dv = D if isinstance(D, (tuple, list)) else (D, D)
         keys = jax.random.split(jax.random.PRNGKey(SEED + L), 4)
-        q, g = (jax.random.normal(k, (B, L, H, D), jnp.bfloat16)
-                for k in keys[:2])
-        k, v = (jax.random.normal(k, (B, L, Hkv, D), jnp.bfloat16)
-                for k in keys[2:])
-        # one mask an entry: the block-diffusion one, or causal and none
+        q, g, k, v = (jax.random.normal(key, (B, L, heads, d), jnp.bfloat16)
+                      for key, heads, d in zip(
+                          keys, (H, H, Hkv, Hkv), (Dk, Dv, Dk, Dv)))
+        # one mask an entry: the block-diffusion one, or causal (and, at one
+        # width, none)
         for mask in ([dict(block_diffusion=block[0])] if block
+                     else [dict(causal=True)] if Dk != Dv
                      else [dict(causal=True), dict(causal=False)]):
             def fwd_bwd(q, k, v, g):
                 o, vjp = jax.vjp(lambda q, k, v: flash_attention(
@@ -233,8 +237,9 @@ def kernels(*, attn=(8, 2048, 8, 128),
             qmm=((8, 2048, 8192), (1024, 8192, 2048)),
             adam=(16384, 1024), lstm=(64, 200, 512), interpret=False,
             timed=((8, 2048, 16, 64, 16), (8, 4096, 8, 128, 2),
-                   (4, 8192, 32, 128, 4, 4)), block=4,
-            prep=((4, 8192, 32, 128), (4, 8192, 4, 128))):
+                   (4, 8192, 32, 128, 4, 4), (4, 8192, 32, (192, 128), 32)),
+            block=4, prep=((4, 8192, 32, 128), (4, 8192, 4, 128)),
+            wide=(1, 8192, 2, 192, 128)):
     """flash attention fwd+bwd (causal, and under the block-diffusion mask
     over blocks of ``block``; bf16), ``q_matmul`` (bf16 × int8), fused Adam
     (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real call shape
@@ -243,7 +248,10 @@ def kernels(*, attn=(8, 2048, 8, 128),
     three flash kernels timed on the chip at ``timed``, the benchmark cells'
     ``(B, L, H, D, Hkv)`` (the third with its block length: the
     block-diffusion call): what a computed pair costs with and without the
-    mask, beside what ``band_census`` says they compute. Then ``qk_prep``
+    mask, beside what ``band_census`` says they compute; the fourth is latent
+    attention's call (q and k 192 wide, v 128), whose error against the
+    ``jnp`` path is taken at ``wide``, ``(B, L, H, Dk, Dv)``: the same length
+    at few enough heads for the path's ``[L, L]`` scores. Then ``qk_prep``
     each way at ``prep``, the block-diffusion cell's q and k projections
     ``(B, S, heads, D)``, beside the ``jnp`` chain it replaces
     (:func:`_qk_prep_times`)."""
@@ -294,6 +302,19 @@ def kernels(*, attn=(8, 2048, 8, 128),
                 (q, k, v), g),
             fwd_bwd(lambda q, k, v: attention_reference(
                 q, k, v, block_diffusion=block), (q, k, v), g),
+            ("out", "dq", "dk", "dv"),
+        )
+
+    if wide:
+        Bw, Lw, Hw, Dk, Dv = wide
+        qw, kw, vw, gw = (jax.random.normal(next(keys), (Bw, Lw, Hw, d), bf16)
+                          for d in (Dk, Dk, Dv, Dv))
+        compare(
+            f"flash_{Dk}_{Dv}",
+            fwd_bwd(lambda q, k, v: flash_attention(
+                q, k, v, causal=True, interpret=interpret), (qw, kw, vw), gw),
+            fwd_bwd(lambda q, k, v: attention_reference(
+                q, k, v, causal=True), (qw, kw, vw), gw),
             ("out", "dq", "dk", "dv"),
         )
 

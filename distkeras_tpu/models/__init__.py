@@ -32,6 +32,7 @@ from distkeras_tpu.models.moe import (
     moe_transformer_classifier,
 )
 from distkeras_tpu.models.lm import (
+    MlaDims,
     SdarDims,
     TransformerLM,
     ZayaDims,
@@ -62,7 +63,7 @@ __all__ = [
     "pipelined_transformer_forward",
     "sequence_parallel_transformer_forward",
     "MoETransformerClassifier", "moe_transformer_classifier",
-    "TransformerLM", "SdarDims", "ZayaDims", "transformer_lm", "generate", "beam_search",
+    "TransformerLM", "MlaDims", "SdarDims", "ZayaDims", "transformer_lm", "generate", "beam_search",
     "speculative_generate",
     "next_token_dataset", "quantize_lm",
 ]

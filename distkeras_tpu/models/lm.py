@@ -598,6 +598,38 @@ def _topk_router(mod, h, r):
     return chosen, top / jnp.sum(top, axis=-1, keepdims=True), r
 
 
+def _sigmoid_router(mod, h, r):
+    """A sigmoid router with a selection bias, a part of
+    :class:`RoutedExperts` (DeepSeek-V3's ``noaux_tc`` with one group):
+    float32 ``s = sigmoid(h Wr)``; chosen are the ``z.experts_per_token``
+    largest of ``s + b``; a chosen expert's weight is ``z.route_scale * s_e /
+    (sum of the chosen s + 1e-20)``: of ``s``, not of ``s + b``, so the
+    weights sum to ``z.route_scale`` and not to 1. ``b`` (``router_bias``,
+    float32 ``[experts]``) is state in the ``counters`` collection: no
+    gradient reaches it, and a training step (the collection mutable) leaves
+    ``b + z.bias_rate * sign(mean(n) - n)`` for the next, ``n`` the step's own
+    (token, expert) pairs by expert over ALL experts, held or not (the
+    auxiliary-loss-free balancing rule). No state beside the residual stream
+    (``r`` passes through). Returns ``(chosen [B, S, k], weight [B, S, k],
+    r)``."""
+    z, f32 = mod.z, jnp.float32
+    logits = nn.Dense(z.experts, use_bias=False, dtype=f32,
+                      precision=jax.lax.Precision.HIGHEST, name="router")(h)
+    s = jax.nn.sigmoid(logits)
+    bias = mod.variable("counters", "router_bias",
+                        lambda: jnp.zeros((z.experts,), f32))
+    b = bias.value
+    _, chosen = jax.lax.top_k(s + b, z.experts_per_token)
+    top = jnp.take_along_axis(s, chosen, axis=-1)
+    weight = z.route_scale * top / (jnp.sum(top, -1, keepdims=True) + 1e-20)
+    if mod.counting():
+        with jax.named_scope("moe_bias"):
+            n = jnp.bincount(chosen.reshape(-1), length=z.experts) \
+                .astype(f32)
+            bias.value = b + z.bias_rate * jnp.sign(jnp.mean(n) - n)
+    return chosen, weight, r
+
+
 def _added(mod, x, y):
     """The plain residual: ``x + y``."""
     return x + y.astype(jnp.float32)
@@ -628,6 +660,16 @@ def held_rows(tokens: int, z) -> tuple:
     return first, min(HELD_ROWS_LATER, pairs)
 
 
+def _swiglu(mod, h, width: int, name: str):
+    """``(silu(h Wg) * (h Wu)) Wd`` at ``width``, bias-free, in ``h``'s dtype:
+    gate and up side by side in one ``[d, 2 width]`` matrix ``<name>_in``
+    (as the routed experts hold theirs), down ``<name>_out``; parameters of
+    ``mod``, a compact module."""
+    dense = functools.partial(nn.Dense, use_bias=False, dtype=h.dtype)
+    gate, up = jnp.split(dense(2 * width, name=name + "_in")(h), 2, axis=-1)
+    return dense(h.shape[-1], name=name + "_out")(nn.silu(gate) * up)
+
+
 class RoutedExperts(nn.Module):
     """An expert sublayer: RMSNorm, a router (``router``, a part: a function
     ``(module, h, r) -> (chosen, weight, r)`` that makes its parameters and
@@ -637,7 +679,10 @@ class RoutedExperts(nn.Module):
     the router's state beside the residual stream, or None. The defaults are
     ZAYA1's: :func:`_zaya_router` (top-1) and the learned scaling
     :func:`_rescaled`; :func:`_topk_router` and :func:`_added` make the
-    top-k layer of a plain pre-norm block.
+    top-k layer of a plain pre-norm block, :func:`_sigmoid_router` one whose
+    weights sum to a scaling factor. ``shared_dim > 0`` adds a shared expert:
+    one SwiGLU of that width on EVERY token beside the routed sum (every chip
+    of an ``ep`` axis holds it whole; it is added once).
 
     The ``counters`` collection holds what no gradient reaches:
     ``moe_tokens`` (int32 ``[experts]``), increased in a training step (the
@@ -651,6 +696,7 @@ class RoutedExperts(nn.Module):
     dtype: jnp.dtype = jnp.bfloat16
     router: object = _zaya_router
     join: object = _rescaled
+    shared_dim: int = 0
 
     def counting(self) -> bool:
         return self.is_mutable_collection("counters") \
@@ -681,13 +727,20 @@ class RoutedExperts(nn.Module):
                              lambda: jnp.zeros((E,), jnp.int32))
         if self.counting():
             seen.value = seen.value + tokens
-        return self.join(self, x, y.reshape(B, S, d)), r
+        y = y.reshape(B, S, d)
+        if self.shared_dim:
+            with jax.named_scope("moe_shared"):
+                y = y + _swiglu(self, h.astype(self.dtype), self.shared_dim,
+                                "shared").astype(jnp.float32)
+        return self.join(self, x, y), r
 
 
 class _RoutedBlock(nn.Module):
     """A layer whose second sublayer is :class:`RoutedExperts`: ``__call__``
     takes and returns ``(x, r)``, the residual stream and the router's state
-    (None for a router without one). A block's ``setup`` makes its first
+    (None for a router without one). A model's layers may be of two kinds
+    (:class:`MlaBlock`: leading layers with a dense SwiGLU in the expert
+    sublayer's place); ``(x, r)`` passes through both alike. A block's ``setup`` makes its first
     sublayer, which ``attend(x, mask) -> x`` runs, and ``self.moe``. Training only: the
     dropless experts are not on the paged path, so the serving entry points
     raise, with ``no_serving``, the block's own reason."""
@@ -882,6 +935,175 @@ class SdarBlock(_RoutedBlock):
         return self.attn(x, mask)
 
 
+@dataclasses.dataclass(frozen=True)
+class MlaDims:
+    """The sizes of a latent-attention expert block (:class:`MlaBlock`) that
+    ``dim`` and ``heads`` do not give. Defaults are
+    kanana-2-30b-a3b-instruct-2601's published ones (``model_type``
+    ``deepseek_v3`` without a query latent); ``bias_rate`` is the DeepSeek-V3
+    report's, which its ``config.json`` has no key for."""
+
+    qk_nope_dim: int = 128            # a head's part of q and k with no position
+    qk_rope_dim: int = 64             # and its rotary part; k's is ONE for all heads
+    v_dim: int = 128
+    kv_rank: int = 512                # the key-value latent's width
+    rope_base: float = 1e6
+    experts: int = 128                # the router's width
+    experts_per_token: int = 6
+    #: ``(first, count)``: the experts this model holds of all ``experts``
+    #: (an ``ep`` rank's share); ``None`` holds them all
+    experts_held: tuple | None = None
+    expert_dim: int = 768
+    #: shared experts: ONE SwiGLU of ``shared_experts * expert_dim`` on every
+    #: token, whole on every chip
+    shared_experts: int = 2
+    #: the first ``dense_layers`` layers have a dense SwiGLU of ``dense_dim``
+    #: in the expert sublayer's place
+    dense_layers: int = 1
+    dense_dim: int = 6144
+    route_scale: float = 2.448        # what a token's routed weights sum to
+    bias_rate: float = 0.001          # the balancing bias's step
+    norm_eps: float = 1e-6
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.experts)
+
+
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (MLA), the attention sublayer of
+    :class:`MlaBlock`: RMSNorm; ``q = h Wq`` as ``heads`` heads of
+    ``qk_nope_dim + qk_rope_dim``; ``(c, k_rope) = split(h Wkva, kv_rank |
+    qk_rope_dim)``, ``k_rope`` one vector a token for ALL heads; ``RMSNorm(c)
+    Wkvb`` as ``heads`` heads of ``qk_nope_dim + v_dim`` (a key part with no
+    position and the value); rotary over the ``qk_rope_dim`` columns of q's
+    and of k's rope part only, pairs ``(2i, 2i+1)``; ``k_h = [k_nope_h ;
+    k_rope]``; causal softmax attention at scale ``(qk_nope_dim +
+    qk_rope_dim) ** -0.5`` with values ``v_dim`` wide (the flash kernels take
+    the two widths as they are); the output projection and the residual.
+    Float32 norms, products in ``dtype``. Everything between the projections
+    and the attention call lies in the scope ``mla_latent``."""
+
+    dim: int
+    heads: int
+    z: MlaDims
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "reference"
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from distkeras_tpu.ops.flash_attention import BLOCK_Q, attention
+
+        z, f32 = self.z, jnp.float32
+        B, S, _ = x.shape
+        H, dn, dr, dv, R = (self.heads, z.qk_nope_dim, z.qk_rope_dim, z.v_dim,
+                            z.kv_rank)
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
+        h = h.astype(self.dtype)
+        q = dense(H * (dn + dr), name="q")(h)
+        kva = dense(R + dr, name="kv_a")(h)
+        with jax.named_scope("mla_latent"):
+            q = q.reshape(B, S, H, dn + dr)
+            c = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="kv_norm")(
+                kva[..., :R])
+            kv = dense(H * (dn + dv), name="kv_b")(c.astype(self.dtype))
+            kv = kv.reshape(B, S, H, dn + dv)
+            angles = jnp.asarray(rope_angles(S, dr, z.rope_base))
+            q = jnp.concatenate(
+                [q[..., :dn], apply_rope(q[..., dn:], angles)], axis=-1)
+            k_rope = apply_rope(kva[..., None, R:], angles)    # [B, S, 1, dr]
+            k = jnp.concatenate(
+                [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))],
+                axis=-1)
+            v = kv[..., dn:]
+        impl = self.attn_impl
+        if impl == "flash" and S % BLOCK_Q:
+            impl = "reference"
+        o = attention(q, k, v, causal=True, key_mask=mask, impl=impl)
+        o = dense(self.dim, name="out")(
+            o.reshape(B, S, H * dv).astype(self.dtype))
+        return x + o.astype(f32)
+
+
+class DenseSwiGLU(nn.Module):
+    """The second sublayer of an :class:`MlaBlock` that has no experts:
+    RMSNorm, one bias-free SwiGLU of ``width``, the residual."""
+
+    width: int
+    eps: float
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        h = nn.RMSNorm(epsilon=self.eps, dtype=jnp.float32, name="ln")(x)
+        y = _swiglu(self, h.astype(self.dtype), self.width, "mlp")
+        return x + y.astype(jnp.float32)
+
+
+class MlaBlock(_RoutedBlock):
+    """A DeepSeek-V3-style layer: :class:`LatentAttention`, then either
+    :class:`RoutedExperts` behind :func:`_sigmoid_router` with a shared
+    expert (``z.shared_experts * z.expert_dim`` wide) and plain residuals, or,
+    in a model's leading ``z.dense_layers`` layers (``dense=True``), a
+    :class:`DenseSwiGLU` of ``z.dense_dim``: such a layer has no router and no
+    ``moe_tokens``. ``__call__`` takes and returns ``(x, r)`` either way; the
+    router has no state beside the residual stream and ``r`` is None.
+
+    Training only: a cache for latent attention is one ``kv_rank +
+    qk_rope_dim`` wide row a token (the latent and the shared rotary key),
+    where ``serving/paged_cache.py`` pools per-head K and V, and the experts
+    are not on the paged path."""
+
+    dense: bool = False
+
+    no_serving = ("a latent cache is one (kv_rank + qk_rope_dim)-wide row a "
+                  "token (the key-value latent and the shared rotary key) "
+                  "where serving/paged_cache.py pools per-head K and V, and "
+                  "the dropless experts are not on the paged path")
+
+    def setup(self):
+        z = self.z
+        self.attn = LatentAttention(self.dim, self.heads, z, self.dtype,
+                                    self.attn_impl)
+        if self.dense:
+            self.mlp = DenseSwiGLU(z.dense_dim, z.norm_eps, self.dtype)
+        else:
+            self.moe = RoutedExperts(
+                self.dim, z, self.dtype, router=_sigmoid_router, join=_added,
+                shared_dim=z.shared_experts * z.expert_dim)
+
+    def attend(self, x, mask):
+        return self.attn(x, mask)
+
+    def __call__(self, x, r, mask=None, training: bool = False):
+        x = self.attend(x, mask)
+        return (self.mlp(x), r) if self.dense else self.moe(x, r)
+
+
+def _check_mla_options(mla: MlaDims, *, quant, attn_window, pos_embedding,
+                       depth, zaya=None, sdar=None):
+    """Raise for an option the latent-attention block cannot honour."""
+    refused = {
+        "quant=True (quantize_lm knows the dense block's matrices only)":
+            quant,
+        "attn_window (latent attention attends to every earlier position)":
+            attn_window is not None,
+        "pos_embedding other than 'rope' (the rotary part of q and k is "
+        "rotated in the block)": pos_embedding != "rope",
+        "zaya= or sdar= as well (a model has one family of block)":
+            zaya is not None or sdar is not None,
+        "an odd qk_rope_dim (rotary pairs)": mla.qk_rope_dim % 2,
+        "more experts a token than the router has":
+            not 1 <= mla.experts_per_token <= mla.experts,
+        "dense_layers outside 0 .. depth": not 0 <= mla.dense_layers <= depth,
+    }
+    for what, hit in refused.items():
+        if hit:
+            raise ValueError(
+                f"the latent-attention block cannot honour {what}")
+
+
 def _check_sdar_options(sdar: SdarDims, *, quant, attn_window, pos_embedding,
                         maxlen, zaya=None):
     """Raise for an option the block-diffusion block cannot honour."""
@@ -926,10 +1148,14 @@ def _check_zaya_options(zaya: ZayaDims, *, quant, attn_window, pos_embedding,
 
 
 def moe_tokens(counters) -> np.ndarray | None:
-    """The tokens routed to each expert of each ZAYA layer, ``[layers,
-    experts]``, from a model's counters by path (``{"blocks_<i>/moe/
-    moe_tokens": counts}``, as ``MeshTrainer.counters_`` and a history
-    record's ``counters`` hold them); ``None`` where there are none."""
+    """The tokens (under top-k: (token, expert) pairs) routed to each expert
+    of each expert layer, ``[expert layers, experts]``, from a model's
+    counters by path (``{"blocks_<i>/moe/moe_tokens": counts}``, as
+    ``MeshTrainer.counters_`` and a history record's ``counters`` hold them);
+    ``None`` where there are none. Rows follow the layers that HAVE experts,
+    in order: for a model whose leading layers are dense
+    (``MlaDims.dense_layers``) row 0 is layer ``dense_layers``, not layer
+    0."""
     rows = {int(path.split("/")[0].removeprefix("blocks_")): counts
             for path, counts in (counters or {}).items()
             if path.endswith("/moe/moe_tokens")}
@@ -975,6 +1201,11 @@ class TransformerLM(nn.Module):
     #: a forward doubles each row (``hidden`` returns the clean copy's
     #: states), ``noised_hidden`` is the training objective's
     sdar: SdarDims | None = None
+    #: a :class:`MlaDims` makes every layer an :class:`MlaBlock` (latent
+    #: attention; the leading ``dense_layers`` with a dense SwiGLU, the others
+    #: with sigmoid-routed experts and a shared expert): layers of two kinds
+    #: in the one list of blocks
+    mla: MlaDims | None = None
 
     def setup(self):
         if self.kv_heads is not None and self.heads % self.kv_heads:
@@ -993,6 +1224,17 @@ class TransformerLM(nn.Module):
                 f"{self.dim // self.heads}"
             )
         self.embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
+        if self.mla is not None:
+            _check_mla_options(self.mla, quant=self.quant,
+                               attn_window=self.attn_window,
+                               pos_embedding=self.pos_embedding,
+                               depth=self.depth, zaya=self.zaya,
+                               sdar=self.sdar)
+            self._setup_routed(
+                MlaBlock, self.mla,
+                [dict(dense=i < self.mla.dense_layers)
+                 for i in range(self.depth)])
+            return
         if self.zaya is not None:
             _check_zaya_options(self.zaya, quant=self.quant,
                                 attn_window=self.attn_window,
@@ -1037,9 +1279,10 @@ class TransformerLM(nn.Module):
             head = QDense if self.quant else nn.Dense
             self.lm_head = head(self.vocab, dtype=self.dtype)
 
-    def _setup_routed(self, block, dims):
+    def _setup_routed(self, block, dims, kinds=None):
         """Layers of a routed block (:class:`_RoutedBlock`), the head's
-        RMSNorm and, untied, its bias-free head."""
+        RMSNorm and, untied, its bias-free head. ``kinds``: a layer's own
+        fields, where the layers are not all of one kind."""
         block_cls = (nn.remat(
             block, static_argnums=(4,),
             policy=jax.checkpoint_policies.save_only_these_names(
@@ -1047,8 +1290,8 @@ class TransformerLM(nn.Module):
         self.blocks = [
             block_cls(dim=self.dim, heads=self.heads,
                       kv_heads=self.kv_heads or self.heads, z=dims,
-                      dtype=self.dtype, attn_impl=self.attn_impl)
-            for _ in range(self.depth)
+                      dtype=self.dtype, attn_impl=self.attn_impl, **kind)
+            for kind in kinds or [{}] * self.depth
         ]
         self.ln_head = nn.RMSNorm(epsilon=dims.norm_eps, dtype=jnp.float32)
         if not self.tie_embeddings:
@@ -1096,7 +1339,7 @@ class TransformerLM(nn.Module):
             return self._routed_hidden(
                 jnp.concatenate([tokens, tokens], axis=1), both,
                 training)[:, tokens.shape[1]:]
-        if self.zaya is not None:
+        if self.zaya is not None or self.mla is not None:
             return self._routed_hidden(tokens, mask, training)
         x = self._embed_at(tokens)
         for blk in self.blocks:
@@ -1985,7 +2228,8 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
                    attn_window=None, kv_heads=None,
                    pos_embedding="sincos", fused_ce=False,
                    ce_chunk=256, remat=False,
-                   tie_embeddings=False, zaya=None, sdar=None) -> ModelSpec:
+                   tie_embeddings=False, zaya=None, sdar=None,
+                   mla=None) -> ModelSpec:
     """Causal-LM ModelSpec. Train with ``loss="sparse_softmax_cross_entropy"``
     on ``features=tokens [B, L]`` / ``label=tokens shifted left [B, L]``
     (see :func:`next_token_dataset`); decode with :func:`generate`.
@@ -2031,7 +2275,25 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
     ``R L``. The state also counts ``bd_masked_tokens`` and, a layer,
     ``moe_tokens`` ((token, expert) pairs by expert). A plain forward
     (``spec.apply``) gives a clean row's logits under the block-causal
-    mask."""
+    mask.
+    ``mla=MlaDims(...)`` makes every layer an :class:`MlaBlock` (a
+    DeepSeek-V3-style layer: RMSNorm, multi-head latent attention whose
+    queries and keys are ``qk_nope_dim + qk_rope_dim`` wide and whose values
+    ``v_dim``, through the flash kernels as they are;
+    ``pos_embedding="rope"``; ``kv_heads`` is not read). The first
+    ``mla.dense_layers`` layers have a dense SwiGLU, the others dropless
+    top-k experts behind a sigmoid router with a balancing bias plus a shared
+    expert: layers of two kinds in one model. It trains like the dense model
+    (next-token loss, with or without ``fused_ce`` and ``remat``); the state
+    holds, an EXPERT layer, ``counters/blocks_<i>/moe/moe_tokens`` ((token,
+    expert) pairs by expert; :func:`moe_tokens` gives ``[expert layers,
+    experts]``) and ``.../router_bias``, which every training step moves by
+    ``mla.bias_rate`` against the step's own load. The serving entry points
+    raise."""
+    if mla is not None:
+        _check_mla_options(mla, quant=False, attn_window=attn_window,
+                           pos_embedding=pos_embedding, depth=depth,
+                           zaya=zaya, sdar=sdar)
     if zaya is not None:
         # here, by name, and not at the module's first trace
         _check_zaya_options(zaya, quant=False, attn_window=attn_window,
@@ -2049,7 +2311,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
         vocab=vocab, maxlen=maxlen, dim=dim, heads=heads, depth=depth,
         dtype=dtype, attn_impl=attn_impl, attn_window=attn_window,
         kv_heads=kv_heads, pos_embedding=pos_embedding, remat=remat,
-        tie_embeddings=tie_embeddings, zaya=zaya, sdar=sdar,
+        tie_embeddings=tie_embeddings, zaya=zaya, sdar=sdar, mla=mla,
     )
     example = jnp.zeros((1, maxlen), jnp.int32)
     spec = from_flax(module, example, name="transformer_lm",

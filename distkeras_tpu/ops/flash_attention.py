@@ -521,9 +521,10 @@ def _grid_steps(L, tiles, causal, window, transposed=False, diffusion=None):
 # size in HBM (the tiling pads the last dimension to a lane tile): 128 MB an
 # array at 8 x 8 heads x 4096, which the band's callers have room for, and
 # 512 MB at the block-diffusion cell's 4 x 32 heads x 8192, which that step
-# has not. So under block diffusion the forward and dq take and give them as
-# ROWS, ``[B·H, 1, L]`` (as dk/dv always has), and turn a q tile's row into
-# its column once, on the tile's first step.
+# has not. So under block diffusion, and where q / k and v differ in width
+# (``_stat_rows``), the forward and dq take and give them as ROWS,
+# ``[B·H, 1, L]`` (as dk/dv always has), and turn a q tile's row into its
+# column once, on the tile's first step.
 
 
 def _as_col(row):
@@ -536,8 +537,17 @@ def _as_row(col):
     return jnp.transpose(jnp.broadcast_to(col, (col.shape[0], 128)))[:1, :]
 
 
+def _stat_rows(diffusion, Dk, Dv) -> bool:
+    """Whether a call's per-row statistics travel as rows ``[B·H, 1, L]``:
+    under block diffusion and wherever q / k and v differ in width (both came
+    with callers of 128 heads x 8192 positions, where a column array is 512
+    MB). The band's callers of one width keep their columns, and the programs
+    they had."""
+    return diffusion is not None or Dk != Dv
+
+
 def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
-               plan, window, nk, diffusion=None):
+               plan, window, nk, diffusion=None, stat_rows=False):
     """One (bh, iq, jk) step: fold the step's [bq, bk] score tile, piece by
     piece (:func:`_run_band`), into the online softmax state; finalize on
     this q block's last contributing k step.
@@ -567,9 +577,9 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
 
     def fold(r, c, rows, cols, edge):
         rs, cs = pl.ds(r, rows), pl.ds(c, cols)
-        q = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, D]
-        k = k_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
-        v = v_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
+        q = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, Dk]
+        k = k_ref[0, cs, :].astype(jnp.float32)          # [cols, Dk]
+        v = v_ref[0, cs, :].astype(jnp.float32)          # [cols, Dv]
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -604,7 +614,7 @@ def _fa_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
         l = jnp.maximum(l_s[:], 1e-30)
         o_ref[0] = (acc[:] / l).astype(o_ref.dtype)
         lse = m_s[:] + jnp.log(l)
-        lse_ref[0] = lse if diffusion is None else _as_row(lse)
+        lse_ref[0] = _as_row(lse) if stat_rows else lse
 
 
 def _pick_block_q(L):
@@ -730,10 +740,10 @@ def _tiles(L):
 
 def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
                 window=None, diffusion=None, qk_major=False):
-    """q [B, L, H, D], k/v [B, L, Hkv, D] with Hkv | H (grouped-query
-    attention reads shared K/V heads straight from the index maps — no
-    repeated-KV materialization), + key_mask [B, L] →
-    (out [B, L, H, D], lse). ``qk_major``: q and k come as the kernels walk
+    """q [B, L, H, Dk], k [B, L, Hkv, Dk], v [B, L, Hkv, Dv] with Hkv | H
+    (grouped-query attention reads shared K/V heads straight from the index
+    maps — no repeated-KV materialization), + key_mask [B, L] →
+    (out [B, L, H, Dv], lse). ``qk_major``: q and k come as the kernels walk
     them, ``[B·H, L, D]`` and ``[B·Hkv, L, D]`` (see :func:`flash_attention`)."""
     L = q.shape[1]
     _gqa_groups(q, k, qk_major)
@@ -763,43 +773,45 @@ _STATIC = ("tiles", "scale", "causal", "interpret", "window", "diffusion",
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
               diffusion=None, qk_major=False):
-    B, L, Hkv, D = v.shape
+    B, L, Hkv, Dv = v.shape
+    Dk = q.shape[-1]
     H = Hkv * _gqa_groups(q, k, qk_major)
     bq, bk = tiles
+    rows = _stat_rows(diffusion, Dk, Dv)
 
-    def bh(x):  # [B, L, h, D] → [B·h, L, D]
-        h = x.shape[2]
-        return jnp.moveaxis(x, 2, 1).reshape(B * h, L, D)
+    def bh(x):  # [B, L, h, d] → [B·h, L, d]
+        h, d = x.shape[2:]
+        return jnp.moveaxis(x, 2, 1).reshape(B * h, L, d)
 
     qb, kb = (q, k) if qk_major else (bh(q), bh(k))
 
     nk = L // bk
     nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window, diffusion)
     grid = (B * H, L // bq, nkt)
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-    kvspec = pl.BlockSpec(
-        (1, bk, D), lambda b, i, j: (_kv_row(b, H, Hkv), k_tile(i, j), 0)
-    )
-    ospec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
+    qspec = pl.BlockSpec((1, bq, Dk), lambda b, i, j: (b, i, 0))
+    kspec, vspec = (pl.BlockSpec(
+        (1, bk, d), lambda b, i, j: (_kv_row(b, H, Hkv), k_tile(i, j), 0)
+    ) for d in (Dk, Dv))
+    ospec = pl.BlockSpec((1, bq, Dv), lambda b, i, j: (b, i, 0))
     # lse carries a trailing singleton so its block obeys the (8, 128)
-    # tile rule (last dim equal to the array dim is allowed); under block
-    # diffusion it is a row (see _as_col)
-    if diffusion is None:
+    # tile rule (last dim equal to the array dim is allowed); where the
+    # statistics are rows (_stat_rows) it is one (see _as_col)
+    if not rows:
         lspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
         lshape = (B * H, L, 1)
     else:
         lspec = pl.BlockSpec((1, 1, bq), lambda b, i, j: (b, 0, i))
         lshape = (B * H, 1, L)
     out_shape = [
-        jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
+        jax.ShapeDtypeStruct((B * H, L, Dv), q.dtype),
         jax.ShapeDtypeStruct(lshape, jnp.float32),
     ]
     scratch = [
         pltpu.VMEM((bq, 1), jnp.float32),   # running max m
         pltpu.VMEM((bq, 1), jnp.float32),   # running denom l
-        pltpu.VMEM((bq, D), jnp.float32),   # running numerator acc
+        pltpu.VMEM((bq, Dv), jnp.float32),  # running numerator acc
     ]
-    in_specs = [qspec, kvspec, kvspec]
+    in_specs = [qspec, kspec, vspec]
     args = [qb, kb, bh(v)]
     if key_mask is not None:
         # mask ships as [B, 1, L] so its block obeys the (8, 128) tile rule
@@ -811,7 +823,7 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
     kernel = functools.partial(
         _fa_kernel, scale=scale, causal=causal, block_q=bq, block_k=bk,
         plan=_band_plan(L, tiles, causal, window, True, diffusion),
-        window=window, nk=nk, diffusion=diffusion,
+        window=window, nk=nk, diffusion=diffusion, stat_rows=rows,
     )
 
     o, lse = pl.pallas_call(
@@ -823,19 +835,19 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
         interpret=interpret,
         name="flash_fwd",
     )(*args)
-    out = jnp.moveaxis(o.reshape(B, H, L, D), 1, 2)
-    return out, lse[..., 0] if diffusion is None else lse[:, 0]
+    out = jnp.moveaxis(o.reshape(B, H, L, Dv), 1, 2)
+    return out, lse[:, 0] if rows else lse[..., 0]
 
 
 def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
                       scale, causal, block_q, block_k, plan, window, nk,
-                      diffusion=None):
+                      diffusion=None, stat_rows=False):
     """One (bh, iq, jk) step: rebuild the step's [bq, bk] probability tile
     from the saved lse, piece by piece (:func:`_run_band`), and fold
     ``ds @ k`` into the dq accumulator; write on this q block's last
     contributing k step."""
     lse_s = d_s = None
-    if diffusion is not None:     # the statistics came as rows: see _as_col
+    if stat_rows:                 # the statistics came as rows: see _as_col
         *rest, lse_s, d_s = rest
     if len(rest) == 3:
         km_ref, dq_ref, acc = rest
@@ -847,12 +859,12 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
     @pl.when(jk == 0)
     def _():
         acc[:] = jnp.zeros_like(acc)
-        if diffusion is not None:
+        if stat_rows:
             lse_s[:] = _as_col(lse_ref[0])
             d_s[:] = _as_col(d_ref[0])
 
     def stat(ref, col, rs):       # a statistic's [rows, 1] piece
-        return ref[0, rs, :] if diffusion is None else col[rs, :]
+        return col[rs, :] if stat_rows else ref[0, rs, :]
 
     kt, last_k = _k_step(iq, jk, nk, block_q=block_q, block_k=block_k,
                          causal=causal, window=window, diffusion=diffusion)
@@ -860,10 +872,10 @@ def _fa_bwd_dq_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
 
     def fold(r, c, rows, cols, edge):
         rs, cs = pl.ds(r, rows), pl.ds(c, cols)
-        qs = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, D]
-        kk = k_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
-        vv = v_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
-        gg = g_ref[0, rs, :].astype(jnp.float32)          # [rows, D]
+        qs = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, Dk]
+        kk = k_ref[0, cs, :].astype(jnp.float32)          # [cols, Dk]
+        vv = v_ref[0, cs, :].astype(jnp.float32)          # [cols, Dv]
+        gg = g_ref[0, rs, :].astype(jnp.float32)          # [rows, Dv]
         s = jax.lax.dot_general(
             qs, kk, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -935,10 +947,10 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
 
     def fold(r, c, rows, cols, edge):
         rs, cs = pl.ds(r, rows), pl.ds(c, cols)
-        qs = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, D]
-        kk = k_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
-        vv = v_ref[0, cs, :].astype(jnp.float32)          # [cols, D]
-        gg = g_ref[0, rs, :].astype(jnp.float32)          # [rows, D]
+        qs = q_ref[0, rs, :].astype(jnp.float32) * scale  # [rows, Dk]
+        kk = k_ref[0, cs, :].astype(jnp.float32)          # [cols, Dk]
+        vv = v_ref[0, cs, :].astype(jnp.float32)          # [cols, Dv]
+        gg = g_ref[0, rs, :].astype(jnp.float32)          # [rows, Dv]
         st = jax.lax.dot_general(
             kk, qs, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
@@ -998,15 +1010,17 @@ def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
               interpret, window, diffusion=None, qk_major=False):
-    B, L, Hkv, D = v.shape
+    B, L, Hkv, Dv = v.shape
+    Dk = q.shape[-1]
     groups = _gqa_groups(q, k, qk_major)
     H = Hkv * groups
     bq, bk = tiles  # the forward's: one ladder
     plan = _band_plan(L, tiles, causal, window, False, diffusion)
+    rows = _stat_rows(diffusion, Dk, Dv)
 
-    def bh(x):  # [B, L, h, D] → [B·h, L, D]
-        h = x.shape[2]
-        return jnp.moveaxis(x, 2, 1).reshape(B * h, L, D)
+    def bh(x):  # [B, L, h, d] → [B·h, L, d]
+        h, d = x.shape[2:]
+        return jnp.moveaxis(x, 2, 1).reshape(B * h, L, d)
 
     qb, kb = (q, k) if qk_major else (bh(q), bh(k))
     vb, gb = bh(v), bh(g)
@@ -1020,11 +1034,13 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
     nkt, k_tile = _restricted_k_axis(nk, bq, bk, causal, window, diffusion)
     nqt, q_tile = _restricted_q_axis(nq, bq, bk, causal, window, diffusion)
 
-    qspec = pl.BlockSpec((1, bq, D), lambda b, i, j: (b, i, 0))
-    kvspec_q = pl.BlockSpec(
-        (1, bk, D), lambda b, i, j: (_kv_row(b, H, Hkv), k_tile(i, j), 0)
-    )
-    if diffusion is None:         # columns [B·H, L, 1]
+    # q, k, dq and dk are Dk wide; v, dO and dv Dv
+    qspec, gspec = (pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))
+                    for d in (Dk, Dv))
+    kspec_q, vspec_q = (pl.BlockSpec(
+        (1, bk, d), lambda b, i, j: (_kv_row(b, H, Hkv), k_tile(i, j), 0)
+    ) for d in (Dk, Dv))
+    if not rows:                  # columns [B·H, L, 1]
         statspec = pl.BlockSpec((1, bq, 1), lambda b, i, j: (b, i, 0))
         stats, stat_scratch = [lse[..., None], delta[..., None]], []
     else:                         # rows, turned in the kernel: see _as_col
@@ -1032,7 +1048,7 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
         stats = [lse_row, d_row]
         stat_scratch = [pltpu.VMEM((bq, 1), jnp.float32)] * 2
 
-    dq_specs = [qspec, kvspec_q, kvspec_q, qspec, statspec, statspec]
+    dq_specs = [qspec, kspec_q, vspec_q, gspec, statspec, statspec]
     dq_args = [qb, kb, vb, gb] + stats
     if key_mask is not None:
         dq_specs.append(
@@ -1043,12 +1059,12 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
     dq = pl.pallas_call(
         functools.partial(_fa_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=bq, block_k=bk, plan=plan, window=window,
-                          nk=nk, diffusion=diffusion),
+                          nk=nk, diffusion=diffusion, stat_rows=rows),
         grid=(B * H, nq, nkt),
         in_specs=dq_specs,
         out_specs=qspec,
-        out_shape=jax.ShapeDtypeStruct((B * H, L, D), q.dtype),
-        scratch_shapes=[pltpu.VMEM((bq, D), jnp.float32)] + stat_scratch,
+        out_shape=jax.ShapeDtypeStruct((B * H, L, Dk), q.dtype),
+        scratch_shapes=[pltpu.VMEM((bq, Dk), jnp.float32)] + stat_scratch,
         interpret=interpret,
         name="flash_dq",
     )(*dq_args)
@@ -1062,21 +1078,23 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
 
     if groups == 1:
         grid = (B * H, nk, nqt)
-        kvspec = pl.BlockSpec((1, bk, D), lambda b, j, i: (b, j, 0))
-        qspec2 = pl.BlockSpec(
-            (1, bq, D), lambda b, j, i: (b, q_tile(j, i), 0)
-        )
+        kspec, vspec = (pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0))
+                        for d in (Dk, Dv))
+        qspec2, gspec2 = (pl.BlockSpec(
+            (1, bq, d), lambda b, j, i: (b, q_tile(j, i), 0)
+        ) for d in (Dk, Dv))
         rowspec = pl.BlockSpec(
             (1, 1, bq), lambda b, j, i: (b, 0, q_tile(j, i))
         )
         kmspec = pl.BlockSpec((1, bk, 1), lambda b, j, i: (b // H, j, 0))
     else:
         grid = (B * Hkv, nk, groups, nqt)
-        kvspec = pl.BlockSpec((1, bk, D), lambda b, j, gg, i: (b, j, 0))
-        qspec2 = pl.BlockSpec(
-            (1, bq, D),
+        kspec, vspec = (pl.BlockSpec(
+            (1, bk, d), lambda b, j, gg, i: (b, j, 0)) for d in (Dk, Dv))
+        qspec2, gspec2 = (pl.BlockSpec(
+            (1, bq, d),
             lambda b, j, gg, i: (q_row_of(b, gg), q_tile(j, i), 0),
-        )
+        ) for d in (Dk, Dv))
         rowspec = pl.BlockSpec(
             (1, 1, bq),
             lambda b, j, gg, i: (q_row_of(b, gg), 0, q_tile(j, i)),
@@ -1084,7 +1102,7 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
         kmspec = pl.BlockSpec(
             (1, bk, 1), lambda b, j, gg, i: (b // Hkv, j, 0)
         )
-    dkv_specs = [qspec2, kvspec, kvspec, qspec2, rowspec, rowspec]
+    dkv_specs = [qspec2, kspec, vspec, gspec2, rowspec, rowspec]
     dkv_args = [qb, kb, vb, gb, lse_row, d_row]
     if key_mask is not None:
         dkv_specs.append(kmspec)
@@ -1096,18 +1114,18 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
                           diffusion=diffusion),
         grid=grid,
         in_specs=dkv_specs,
-        out_specs=[kvspec, kvspec],
-        out_shape=[jax.ShapeDtypeStruct((B * Hkv, L, D), k.dtype),
-                   jax.ShapeDtypeStruct((B * Hkv, L, D), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((bk, D), jnp.float32),
-                        pltpu.VMEM((bk, D), jnp.float32)],
+        out_specs=[kspec, vspec],
+        out_shape=[jax.ShapeDtypeStruct((B * Hkv, L, Dk), k.dtype),
+                   jax.ShapeDtypeStruct((B * Hkv, L, Dv), v.dtype)],
+        scratch_shapes=[pltpu.VMEM((bk, Dk), jnp.float32),
+                        pltpu.VMEM((bk, Dv), jnp.float32)],
         interpret=interpret,
         name="flash_dkv",
     )(*dkv_args)
 
-    def unbh(x):  # [B·h, L, D] → [B, L, h, D]
-        h = x.shape[0] // B
-        return jnp.moveaxis(x.reshape(B, h, L, D), 1, 2)
+    def unbh(x):  # [B·h, L, d] → [B, L, h, d]
+        h, d = x.shape[0] // B, x.shape[-1]
+        return jnp.moveaxis(x.reshape(B, h, L, d), 1, 2)
 
     if qk_major:
         return dq, dk, unbh(dv)
@@ -1118,9 +1136,10 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
                         window=None, diffusion=None):
     """Recompute-based backward (plain XLA): p from saved lse, then the
     standard flash-attention gradient identities. GQA: k/v may hold
-    Hkv < H heads — expanded here, with dk/dv group-summed back."""
+    Hkv < H heads — expanded here, with dk/dv group-summed back. ``v``
+    and ``g`` may be another width than ``q`` and ``k``."""
     B, L, H, D = q.shape
-    Hkv = k.shape[2]
+    Hkv, Dv = k.shape[2], v.shape[-1]
     groups = _gqa_groups(q, k)
     if groups > 1:
         k = jnp.repeat(k, groups, axis=2)
@@ -1152,7 +1171,7 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
     if groups > 1:
         # sum the group's q-head contributions back onto the shared head
         dk = dk.reshape(B, L, Hkv, groups, D).sum(axis=3)
-        dv = dv.reshape(B, L, Hkv, groups, D).sum(axis=3)
+        dv = dv.reshape(B, L, Hkv, groups, Dv).sum(axis=3)
     return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -1251,8 +1270,11 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
                     qk_major: bool = False):
     """Pallas flash attention; same contract as ``attention_reference``.
 
-    ``q/k/v`` [B, L, H, D] → [B, L, H, D]; optional ``key_mask`` [B, L]
-    (1 = attend). Gradients flow to q/k/v (the mask gets zero cotangent, as
+    ``q/k/v`` [B, L, H, D] → [B, L, H, D]; ``v`` may be another width than
+    ``q`` and ``k`` (latent attention's 192 / 128: ``q``, ``k`` and their
+    gradients are ``Dk`` wide, ``v``, the result, dO and dv ``Dv``, nothing is
+    padded, and the default ``scale`` is ``Dk ** -0.5``). Optional
+    ``key_mask`` [B, L] (1 = attend). Gradients flow to q/k/v (the mask gets zero cotangent, as
     with the hard mask in the reference). ``window`` enables sliding-window
     (local) attention: query ``i`` sees keys ``(i-window, i]`` when causal,
     ``|i-j| < window`` otherwise; the kernel grid only visits in-band tiles,
@@ -1269,6 +1291,17 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
     gradients go back so; ``v`` and the result keep ``[B, L, heads, D]``.
     """
     L = q.shape[1]
+    if q.shape[-1] != k.shape[-1]:
+        raise ValueError(
+            f"q and k must be one width (their product is the score); got "
+            f"{q.shape[-1]} and {k.shape[-1]}"
+        )
+    if v.shape[-1] != q.shape[-1] and (qk_major or block_diffusion is not None):
+        raise ValueError(
+            f"v of another width ({v.shape[-1]}) than q and k "
+            f"({q.shape[-1]}) runs under the causal band, a window or no "
+            f"mask; with qk_major or block_diffusion it is not written"
+        )
     diffusion = _canonical_diffusion(block_diffusion, L, causal, window)
     if diffusion is not None and (L // 2) % BLOCK_Q:
         raise ValueError(

@@ -19,11 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import checks, harness, loader, weights, weights_sdar, weights_zaya
-from benchmark.drivers import serve, train, train_bd, train_moe
+from benchmark import (checks, harness, loader, weights, weights_kanana, weights_sdar,
+                       weights_zaya)
+from benchmark.drivers import serve, train, train_bd, train_kanana, train_moe
 
 DATA = os.path.join(loader.ROOT, "benchmark", "tests", "data")
-DRIVERS = {"train": train, "train_moe": train_moe, "train_bd": train_bd, "serve": serve}
+DRIVERS = {"train": train, "train_moe": train_moe, "train_bd": train_bd,
+           "train_kanana": train_kanana, "serve": serve}
 
 
 def _json(*parts):
@@ -41,14 +43,14 @@ def _leaves(tree) -> dict:
 
 @pytest.mark.parametrize("config, traffic", [
     ("xglm-564m", "train"), ("starcoder2-3b", "serve.closed"), ("zaya1-8b", "train.moe4k"),
-    ("sdar-30b-a3b", "train.bd4k")])
+    ("sdar-30b-a3b", "train.bd4k"), ("kanana-2-30b-a3b", "train.mla8k")])
 def test_the_model_initialises_to_the_tree_the_benchmark_makes_by_name(config, traffic):
     """The committed configuration at full size under the options of the job
     file that runs it; ``jax.eval_shape`` on both sides, so nothing is made."""
     m, job = _json("configs", config + ".json")["model"], _json("traffic", traffic + ".json")
     options = {k: job[k] for k in ("attn_impl", "fused_ce", "ce_chunk", "remat") if k in job}
-    routed = {"zaya": (train_moe, weights_zaya), "sdar": (train_bd, weights_sdar)}.get(
-        m.get("block"))
+    routed = {"zaya": (train_moe, weights_zaya), "sdar": (train_bd, weights_sdar),
+              "mla": (train_kanana, weights_kanana)}.get(m.get("block"))
     spec = (routed[0].program_lm if routed else harness.program_lm)(m, **options)
     got = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
     key = jax.eval_shape(lambda: weights.seed_key(2 ** 31 + 29))
@@ -127,6 +129,7 @@ def runs(tmp_path_factory):
     cells = {"tiny-train": ("tiny-sincos.tiny-train", "BENCHMARK.json", 1.0),
              "tiny-train-moe": ("tiny-zaya.tiny-train-moe", "BENCHMARK.zaya.json", 1.0),
              "tiny-train-bd": ("tiny-sdar.tiny-train-bd", "BENCHMARK.sdar.json", 1.0),
+             "tiny-train-mla": ("tiny-kanana.tiny-train-mla", "BENCHMARK.kanana.json", 1.0),
              "tiny-serve": ("tiny-rope-gqa.tiny-serve", "BENCHMARK.json", 2.0)}
     made = {}
 
@@ -147,7 +150,8 @@ def runs(tmp_path_factory):
     return run
 
 
-@pytest.mark.parametrize("traffic", ["tiny-train", "tiny-train-moe", "tiny-train-bd"])
+@pytest.mark.parametrize("traffic", ["tiny-train", "tiny-train-moe", "tiny-train-bd",
+                                     "tiny-train-mla"])
 def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
     loaded, facts, _ = runs(traffic)
     job = loaded["traffic"]
@@ -158,17 +162,24 @@ def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
         facts["window"]["steps"] * job["batch_size"] * job["seq_len"]), where
     assert facts["attempted"] == job["warmup_steps"] + facts["window"]["steps"], where
     assert facts["end_to_end"]["train_tokens_per_s"] > 0 < facts["end_to_end"]["setup_s"], where
-    if job["driver"] in ("train_moe", "train_bd"):
+    if job["driver"] in ("train_moe", "train_bd", "train_kanana"):
         m = loaded["config"]["model"]
-        routes = "route_count_gap" if job["driver"] == "train_bd" else "route_gap"
+        routes = "route_gap" if job["driver"] == "train_moe" else "route_count_gap"
         assert routes in facts["checks"], where
         # every routed (token, layer) pair of the window is in the fetched
         # counters; under block diffusion a clean token is two positions of
-        # its stream and a position sends experts_per_token pairs
-        pairs = 2 * m["experts_per_token"] if job["driver"] == "train_bd" else 1
+        # its stream and a position sends experts_per_token pairs; a model
+        # with leading dense layers counts in its expert layers only
+        pairs = {"train_moe": 1, "train_bd": 2 * m.get("experts_per_token", 1),
+                 "train_kanana": m.get("experts_per_token", 1)}[job["driver"]]
+        layers = m["depth"] - m.get("dense_layers", 0)
         assert np.sum(facts["moe"]["window_tokens"]) == (
-            facts["window"]["tokens"] * m["depth"] * pairs), (
+            facts["window"]["tokens"] * layers * pairs), (
             f"{where} reads MeshTrainer's history 'counters' through models.lm.moe_tokens")
+    if job["driver"] == "train_kanana":
+        assert {"bias_gap", "expert_grad_gap"} <= set(facts["checks"]), where
+        assert np.asarray(facts["moe"]["window_tokens"]).shape == (layers, m["experts"]), (
+            f"{where} reads the EXPERT layers' counters: layer 0 is dense and has none")
     if job["driver"] == "train_bd":
         assert 0 < facts["bd"]["window_masked"] < facts["window"]["tokens"], (
             f"{where} reads the history's 'bd_masked_tokens' counter")
@@ -195,7 +206,11 @@ NAMES = [("jit_serve_decode_greedy", "tiny-serve", "serve_decode_greedy"),
          ("moe_balance", "tiny-train-moe", "train_step"),
          ("cca_conv", "tiny-train-moe", "train_step"),
          ("bd_noise", "tiny-train-bd", "train_step"),
-         ("moe_route", "tiny-train-bd", "train_step")]
+         ("moe_route", "tiny-train-bd", "train_step"),
+         ("mla_latent", "tiny-train-mla", "train_step"),
+         ("moe_shared", "tiny-train-mla", "train_step"),
+         ("moe_bias", "tiny-train-mla", "train_step"),
+         ("moe_experts", "tiny-train-mla", "train_step")]
 READ_BY = {"jit_serve_decode_greedy": "benchmark/metrics/decode_roofline.py finds the decode "
                                       "program by name"}
 BY_STEM = ("benchmark/spans.py sums a trace's kernels by name stem, and PERF.md section 5 maps "

@@ -23,8 +23,9 @@ def test_kernels_phase_interpret_mode():
     line = chip_smoke.kernels(
         attn=(1, 128, 2, 64), qmm=((8, 128, 256), (40, 256, 128)),
         adam=(40, 33), lstm=(8, 5, 128), interpret=True,
-        timed=((1, 256, 2, 64, 1), (1, 512, 2, 64, 1, 4)),
-        prep=((2, 256, 2, 128), (1, 128, 1, 128)),
+        timed=((1, 256, 2, 64, 1), (1, 512, 2, 64, 1, 4),
+               (1, 256, 2, (48, 32), 2)),
+        prep=((2, 256, 2, 128), (1, 128, 1, 128)), wide=(1, 256, 2, 48, 32),
     )
     assert line["phase"] == "kernels" and line["interpret"] is True
     assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
@@ -33,7 +34,12 @@ def test_kernels_phase_interpret_mode():
     # the block-diffusion comparison waits for a longer call (below)
     assert "flash_bd.out" not in line["norm_err"]
     # the timed leg: no device time off the chip, the static census beside it
-    causal, full, blocks = line["flash"]
+    causal, full, blocks, latent = line["flash"]
+    # latent attention's call: q and k of one width, v of another, causal
+    assert latent["shape"] == [1, 256, 2, (48, 32), 2] and latent["causal"]
+    assert latent["flash_dq"]["computed_over_band"] > 1.0
+    assert {"flash_48_32.out", "flash_48_32.dq", "flash_48_32.dk",
+            "flash_48_32.dv"} <= set(line["norm_err"])
     assert blocks["block_diffusion"] == 4 and "causal" not in blocks
     assert blocks["flash_dkv"]["ms"] is None
     assert blocks["flash_fwd"]["steps"] == 4 * 2     # 4 q tiles x (own + 1 clean)
@@ -61,7 +67,7 @@ def test_kernels_phase_interpret_mode():
 
 def test_kernels_phase_compares_the_block_diffusion_mask():
     line = chip_smoke.kernels(attn=(1, 256, 2, 64), qmm=((8, 128, 128),), adam=(8, 16),
-                              lstm=(8, 2, 128), interpret=True, timed=(), prep=())
+                              lstm=(8, 2, 128), interpret=True, timed=(), prep=(), wide=())
     assert {"flash_bd.out", "flash_bd.dq", "flash_bd.dk",
             "flash_bd.dv"} <= set(line["norm_err"])
 
@@ -79,7 +85,7 @@ def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure, match="off its reference"):
         chip_smoke.kernels(attn=(1, 128, 1, 64), qmm=((8, 128, 128),),
                            adam=(8, 16), lstm=(8, 2, 128), interpret=True,
-                           timed=(), prep=())
+                           timed=(), prep=(), wide=())
 
 
 def test_loss_phase_tiny():

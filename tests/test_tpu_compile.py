@@ -47,13 +47,14 @@ def one_chip(topo):
 
 
 def _flash(shape, *, kv_heads=None, dtype=BF16, causal=True, window=None,
-           masked=False, backward=True, block_diffusion=None):
-    """(fn, argument shapes) for flash attention at q ``shape``."""
+           masked=False, backward=True, block_diffusion=None, v_dim=None):
+    """(fn, argument shapes) for flash attention at q ``shape``; ``v_dim``:
+    the values' width where it is not q's and k's."""
     from distkeras_tpu.ops.flash_attention import flash_attention
 
     B, L, H, D = shape
     kv = (B, L, kv_heads or H, D)
-    args = [(shape, dtype), (kv, dtype), (kv, dtype)]
+    args = [(shape, dtype), (kv, dtype), (kv[:3] + (v_dim or D,), dtype)]
     if masked:
         args.append(((B, L), F32))
 
@@ -146,6 +147,10 @@ KERNELS = {
     "flash-fwdbwd-blockdiffusion4-gqa-kv4-4x8192x32x128":
         lambda: _flash((4, 8192, 32, 128), kv_heads=4, causal=False,
                        block_diffusion=4),
+    # kanana-2-30b-a3b.train's call: latent attention's q and k 192 wide
+    # (128 with no position + 64 rotary), values 128, 32 heads, causal
+    "flash-fwdbwd-causal-qk192-v128-4x8192x32":
+        lambda: _flash((4, 8192, 32, 192), v_dim=128),
     # and with a key mask, at tiles of 128 x 384 (rows of 384)
     "flash-fwdbwd-blockdiffusion8-keymask-2x768x4x128":
         lambda: _flash((2, 768, 4, 128), causal=False, masked=True,
@@ -448,6 +453,66 @@ def test_block_diffusion_train_step_compiles_with_its_kernels(topo, monkeypatch)
     assert held_rows(B * 2 * L, dims) == (11264, 4096) and 11264 < pairs
     for shape in (f"[{2 * L},{2 * L}]", f",{2 * L},{2 * L}]", f"[{pairs},{dim}]"):
         assert shape not in text, shape
+
+
+def test_latent_attention_train_step_compiles_with_its_kernels(topo, monkeypatch):
+    """A latent-attention expert model's step (3 layers, the first dense; 4
+    heads of 128 + 64 / 128 over a latent of 512; 16 experts of which 4 are
+    held, 2 a token, 2 shared; rows of 1024; remat, fused CE) for one
+    described chip: in EVERY layer the three flash kernels at q and k 192
+    wide and values 128 (the forward twice under remat), with no operand
+    padded to 256 or values carried 192 wide; the grouped products as
+    ``ragged-dot`` kernels in the two expert layers only; the scopes
+    ``mla_latent``, ``moe_shared`` and ``moe_bias`` in the lowered step."""
+    from distkeras_tpu import ops
+    from distkeras_tpu.models import MlaDims, transformer_lm
+    from distkeras_tpu.trainers import MeshTrainer
+
+    monkeypatch.setattr(ops, "native_kernels", lambda: True)
+    B, L, dim, heads, depth = 4, 1024, 512, 4, 3
+    dims = MlaDims(experts=16, experts_per_token=2, experts_held=(0, 4),
+                   expert_dim=256, dense_dim=1024)
+    spec = transformer_lm(vocab=8192, maxlen=L, dim=dim, heads=heads,
+                          depth=depth, pos_embedding="rope", dtype=BF16,
+                          attn_impl="flash", fused_ce=True, ce_chunk=256,
+                          remat=True, mla=dims)
+    mesh = Mesh(np.asarray(topo.devices[:1]), ("dp",))
+    trainer = MeshTrainer(spec, loss="sparse_softmax_cross_entropy",
+                          worker_optimizer="adam", learning_rate=1e-4,
+                          mesh=mesh, batch_size=B)
+    engine, _, _ = trainer._build_engine()
+    rep = NamedSharding(mesh, P())
+
+    def placed(tree):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=rep),
+            tree)
+
+    params, nt = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
+    engine._resolve_specs(params)
+    engine._build_step()
+    tokens = jax.ShapeDtypeStruct((B, L), jnp.int32,
+                                  sharding=NamedSharding(mesh, P("dp")))
+    lowered = engine._step.lower(
+        placed(params), placed(nt),
+        placed(jax.eval_shape(engine.optimizer.init, params)),
+        (tokens, tokens))
+    named = lowered.as_text(debug_info=True)
+    for scope in ("mla_latent", "moe_shared", "moe_bias", "moe_experts"):
+        assert scope in named, scope
+    text = lowered.compile().as_text()
+    ins = _instructions(text)
+    kernel = lambda stem: [n for n in ins if n.split(".")[0] == stem
+                           and ins[n][0] == "custom-call"]
+    for name, n in (("flash_fwd", 2), ("flash_dq", 1), ("flash_dkv", 1)):
+        assert len(kernel(name)) == n * depth, name
+    wide, narrow = f"bf16[{B * heads},{L},192]", f"bf16[{B * heads},{L},128]"
+    for name in kernel("flash_fwd"):
+        line = ins[name][2]
+        assert line.count(wide) >= 2 and narrow in line, line[:400]
+        assert f"{L},256]" not in line, line[:400]
+    assert "ragged-dot" in text
+    assert "blocks_0/moe" not in named and "blocks_1/moe" in named
 
 
 # -- the serving steps -----------------------------------------------------------
