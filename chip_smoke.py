@@ -172,6 +172,27 @@ def _flash_times(timed, interpret, reps=3) -> list:
     return out
 
 
+def _kernel_beside_chain(names, floor, fused, plain, args, interpret,
+                         reps) -> dict:
+    """Part of a ``*_prep_times`` entry: for each kernel of ``names`` ms a
+    call of ``fused(*args)`` on the chip, its ``floor`` of bytes and the GB/s
+    they come to, and under ``chain_ms`` the ms of every operation of
+    ``plain(*args)``; no times in interpret mode."""
+    import jax
+
+    ms, chain_ms = dict.fromkeys(names), None
+    if not interpret:
+        ms = _device_ms_by_kernel(
+            lambda: jax.block_until_ready(
+                [fused(*args) for _ in range(reps)]), names, reps)
+        chain_ms = round(sum(_device_ms_by_op(
+            lambda: jax.block_until_ready(
+                [plain(*args) for _ in range(reps)]), reps).values()), 4)
+    return {**{n: {"ms": ms[n], "floor_bytes": floor[n],
+                   "gb_per_s": ms[n] and round(floor[n] / ms[n] / 1e6, 1)}
+               for n in names}, "chain_ms": chain_ms}
+
+
 def _qk_prep_times(shapes, interpret, reps=3, eps=1e-6) -> list:
     """``ops.qk_prep`` forward and backward at each ``(B, S, heads, D)`` of
     ``shapes`` (bf16; the block-diffusion cell's q and k projections by
@@ -217,19 +238,69 @@ def _qk_prep_times(shapes, interpret, reps=3, eps=1e-6) -> list:
             part: _norm_err(a, b)
             for part, a, b in zip(("out", "dx", "dw"), got, want)}}
         floor = {"qk_prep_fwd": 2 * x.nbytes, "qk_prep_bwd": 3 * x.nbytes}
-        ms, chain_ms = dict.fromkeys(names), None
-        if not interpret:
-            ms = _device_ms_by_kernel(
-                lambda: jax.block_until_ready(
-                    [kernel(x, w, g) for _ in range(reps)]), names, reps)
-            chain_ms = round(sum(_device_ms_by_op(
-                lambda: jax.block_until_ready(
-                    [plain(x, w, g) for _ in range(reps)]), reps).values()), 4)
-        for n in names:
-            entry[n] = {"ms": ms[n], "floor_bytes": floor[n],
-                        "gb_per_s": ms[n] and round(floor[n] / ms[n] / 1e6, 1)}
-        entry["chain_ms"] = chain_ms
-        out.append(entry)
+        out.append({**entry, **_kernel_beside_chain(
+            names, floor, kernel, plain, (x, w, g), interpret, reps)})
+    return out
+
+
+def _mla_prep_times(shapes, interpret, reps=3) -> list:
+    """``ops.mla_prep`` forward and backward at each ``(B, S, heads, nope,
+    rope, v)`` of ``shapes`` (bf16; the latent-attention cell's projections by
+    default), q's kernels and k / v's apart, beside the ``jnp`` chain each
+    replaces (``latent_qkv`` and the move to head-major): ms a call of each
+    kernel on the chip and the GB/s its floor's bytes come to (each operand
+    read and each result written once; the chip moves 819), the chain's ms
+    for the same two passes (None in interpret mode: a CPU gives no device
+    time), and the kernel's error against it."""
+    import jax
+    import jax.numpy as jnp
+
+    from distkeras_tpu.models.lm import latent_qkv, rope_angles
+    from distkeras_tpu.ops.mla_prep import mla_prep
+
+    names = ("mla_prep_fwd", "mla_prep_bwd")
+    out = []
+    for B, S, heads, nope, rope, v in shapes:
+        ks = jax.random.split(jax.random.PRNGKey(SEED + heads), 6)
+        bf16 = jnp.bfloat16
+        x = [jax.random.normal(k, (B, S, w), bf16) for k, w in zip(
+            ks, (heads * (nope + rope), heads * (nope + v), rope))]
+        cot = [jax.random.normal(k, (B * heads, S, w), bf16) for k, w in zip(
+            ks[3:], (nope + rope, nope + rope, v))]
+        angles = jnp.asarray(rope_angles(S, rope, 1e6))
+
+        def kernel(*x):
+            return mla_prep(*x, angles, heads=heads, nope=nope,
+                            interpret=interpret)
+
+        def chain(*x):
+            return tuple(jnp.moveaxis(a, 2, 1).reshape(B * heads, S, -1)
+                         for a in latent_qkv(*x, angles, heads, nope))
+
+        # q alone, then k and v: (results, the arguments they depend on)
+        for part, res, args in (("q", (0,), (0,)), ("kv", (1, 2), (1, 2))):
+            def fwd_bwd(fn):
+                def picked(*x):
+                    results = fn(*x)                  # ONE call
+                    return tuple(results[i] for i in res)
+
+                def run(x, g):
+                    o, vjp = jax.vjp(picked, *x)
+                    grads = vjp(tuple(g))
+                    return o + tuple(grads[i] for i in args)
+                return jax.jit(run)
+
+            fused, plain = fwd_bwd(kernel), fwd_bwd(chain)
+            g = [cot[i] for i in res]
+            got, want = fused(x, g), plain(x, g)            # compiles
+            parts = {"q": ("q", "dq"), "kv": ("k", "v", "dkv", "dk_rope")}
+            entry = {"part": part, "shape": [B, S, heads, nope, rope, v],
+                     "norm_err": {n: _norm_err(a, b) for n, a, b in zip(
+                         parts[part], got, want)}}
+            floor = dict.fromkeys(names, sum(x[i].nbytes for i in args)
+                                  + sum(cot[i].nbytes for i in res))
+            out.append({**entry, **_kernel_beside_chain(
+                names, floor, fused, plain, (x, g), interpret, reps)})
     return out
 
 
@@ -239,7 +310,8 @@ def kernels(*, attn=(8, 2048, 8, 128),
             timed=((8, 2048, 16, 64, 16), (8, 4096, 8, 128, 2),
                    (4, 8192, 32, 128, 4, 4), (4, 8192, 32, (192, 128), 32)),
             block=4, prep=((4, 8192, 32, 128), (4, 8192, 4, 128)),
-            wide=(1, 8192, 2, 192, 128)):
+            wide=(1, 8192, 2, 192, 128),
+            latent=((4, 8192, 32, 128, 64, 128),)):
     """flash attention fwd+bwd (causal, and under the block-diffusion mask
     over blocks of ``block``; bf16), ``q_matmul`` (bf16 × int8), fused Adam
     (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real call shape
@@ -254,7 +326,9 @@ def kernels(*, attn=(8, 2048, 8, 128),
     at few enough heads for the path's ``[L, L]`` scores. Then ``qk_prep``
     each way at ``prep``, the block-diffusion cell's q and k projections
     ``(B, S, heads, D)``, beside the ``jnp`` chain it replaces
-    (:func:`_qk_prep_times`)."""
+    (:func:`_qk_prep_times`), and ``mla_prep`` each way at ``latent``, the
+    latent-attention cell's projections ``(B, S, heads, nope, rope, v)``,
+    beside the chain it replaces (:func:`_mla_prep_times`)."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -365,15 +439,23 @@ def kernels(*, attn=(8, 2048, 8, 128),
         "q_matmul": kernel_impl("q_matmul", k=qmm[0][1], n=qmm[0][2]),
         **({"qk_prep": kernel_impl("qk_prep", S=prep[0][1], D=prep[0][3])}
            if prep else {}),
+        **({"mla_prep": kernel_impl("mla_prep", **dict(zip(
+            ("S", "heads", "nope", "rope", "v"), latent[0][1:])))}
+           if latent else {}),
     }
     preps = _qk_prep_times(prep, interpret)
     for entry in preps:
         for part, e in entry["norm_err"].items():
             # the weight's gradient is f32 but sums products of bf16 rows
             errs[f"qk_prep{entry['shape'][2]}.{part}"] = (e, "bfloat16")
+    latents = _mla_prep_times(latent, interpret)
+    for entry in latents:
+        for part, e in entry["norm_err"].items():
+            errs[f"mla_prep{entry['shape'][2]}.{part}"] = (e, "bfloat16")
     line = _report("kernels", t0, interpret=bool(interpret), auto=auto,
                    norm_err={k: e for k, (e, _) in errs.items()},
-                   flash=_flash_times(timed, interpret), qk_prep=preps)
+                   flash=_flash_times(timed, interpret), qk_prep=preps,
+                   mla_prep=latents)
     for name, (e, dtype) in errs.items():
         # wh's gradient is f32 but flows through the bf16 recurrence
         tol = TOL["bfloat16"] if name.startswith("lstm") else TOL[dtype]

@@ -81,6 +81,27 @@ def head_norm_rope(a, w, angles, heads: int, eps: float):
     return apply_rope(a, angles)
 
 
+def latent_qkv(q, kv, k_rope, angles, heads: int, nope: int):
+    """Latent attention's operands from its projections' results: ``q [B, S,
+    heads·(nope + dr)]``, ``kv [B, S, heads·(nope + dv)]`` (a head's key part
+    with no position, then its value) and the ONE rotary key a token
+    ``k_rope [B, S, dr]`` to ``q`` and ``k [B, S, heads, nope + dr]`` and
+    ``v [B, S, heads, dv]``: :func:`apply_rope` by ``angles [S, dr // 2]`` on
+    the last ``dr`` columns of every q head and on ``k_rope``, which is laid
+    beside every head's key part. The plain chain that ``ops.mla_prep`` does
+    in one kernel each way (and hands over head-major): what runs at shapes
+    the kernel refuses, and what its tests compare with."""
+    B, S, _ = q.shape
+    q, kv = q.reshape(B, S, heads, -1), kv.reshape(B, S, heads, -1)
+    q = jnp.concatenate(
+        [q[..., :nope], apply_rope(q[..., nope:], angles)], axis=-1)
+    k_rope = apply_rope(k_rope[..., None, :], angles)      # [B, S, 1, dr]
+    k = jnp.concatenate(
+        [kv[..., :nope],
+         jnp.broadcast_to(k_rope, (B, S, heads, k_rope.shape[-1]))], axis=-1)
+    return q, k, kv[..., nope:]
+
+
 class QDense(nn.Module):
     """Dense over an int8 weight-only-quantized kernel (``ops.quant``).
 
@@ -982,7 +1003,13 @@ class LatentAttention(nn.Module):
     qk_rope_dim) ** -0.5`` with values ``v_dim`` wide (the flash kernels take
     the two widths as they are); the output projection and the residual.
     Float32 norms, products in ``dtype``. Everything between the projections
-    and the attention call lies in the scope ``mla_latent``."""
+    and the attention call lies in the scope ``mla_latent``: the 512 norm,
+    ``Wkvb``'s product, and then either ``ops.mla_prep`` (one kernel each way
+    from the projections' results to the flash kernels' head-major operands,
+    where ``ops.kernel_impl("mla_prep", …)`` says ``"pallas"``:
+    ``attn_impl="flash"``, ``qk_nope_dim`` and ``v_dim`` multiples of 128,
+    ``qk_rope_dim`` 64, an even count of heads, rows of a multiple of 128) or
+    :func:`latent_qkv`'s ``jnp`` lines."""
 
     dim: int
     heads: int
@@ -992,7 +1019,10 @@ class LatentAttention(nn.Module):
 
     @nn.compact
     def __call__(self, x, mask=None):
-        from distkeras_tpu.ops.flash_attention import BLOCK_Q, attention
+        from distkeras_tpu.ops import kernel_impl
+        from distkeras_tpu.ops.flash_attention import (BLOCK_Q, attention,
+                                                       flash_attention)
+        from distkeras_tpu.ops.mla_prep import mla_prep
 
         z, f32 = self.z, jnp.float32
         B, S, _ = x.shape
@@ -1003,24 +1033,25 @@ class LatentAttention(nn.Module):
         h = h.astype(self.dtype)
         q = dense(H * (dn + dr), name="q")(h)
         kva = dense(R + dr, name="kv_a")(h)
-        with jax.named_scope("mla_latent"):
-            q = q.reshape(B, S, H, dn + dr)
-            c = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="kv_norm")(
-                kva[..., :R])
-            kv = dense(H * (dn + dv), name="kv_b")(c.astype(self.dtype))
-            kv = kv.reshape(B, S, H, dn + dv)
-            angles = jnp.asarray(rope_angles(S, dr, z.rope_base))
-            q = jnp.concatenate(
-                [q[..., :dn], apply_rope(q[..., dn:], angles)], axis=-1)
-            k_rope = apply_rope(kva[..., None, R:], angles)    # [B, S, 1, dr]
-            k = jnp.concatenate(
-                [kv[..., :dn], jnp.broadcast_to(k_rope, (B, S, H, dr))],
-                axis=-1)
-            v = kv[..., dn:]
         impl = self.attn_impl
         if impl == "flash" and S % BLOCK_Q:
             impl = "reference"
-        o = attention(q, k, v, causal=True, key_mask=mask, impl=impl)
+        prep = kernel_impl("mla_prep", "pallas" if impl == "flash" else "xla",
+                           S=S, nope=dn, rope=dr, v=dv, heads=H)
+        with jax.named_scope("mla_latent"):
+            c = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="kv_norm")(
+                kva[..., :R])
+            kv = dense(H * (dn + dv), name="kv_b")(c.astype(self.dtype))
+            angles = jnp.asarray(rope_angles(S, dr, z.rope_base))
+            if prep == "pallas":
+                q, k, v = mla_prep(q, kv, kva[..., R:], angles, heads=H,
+                                   nope=dn)
+                attend = functools.partial(flash_attention, qk_major=True,
+                                           heads=H)
+            else:
+                q, k, v = latent_qkv(q, kv, kva[..., R:], angles, H, dn)
+                attend = functools.partial(attention, impl=impl)
+        o = attend(q, k, v, causal=True, key_mask=mask)
         o = dense(self.dim, name="out")(
             o.reshape(B, S, H * dv).astype(self.dtype))
         return x + o.astype(f32)

@@ -91,9 +91,12 @@ def kernel_impl(op: str, impl: str = "auto", **dims) -> str:
     ``kernel_impl("lstm_scan", B=64, H=512)`` and
     ``kernel_impl("q_matmul", k=2048, n=8192)`` → ``"pallas"`` |
     ``"xla"``, as is ``kernel_impl("qk_prep", "pallas", S=8192, D=128)``
-    (what stands between a q / k projection and the flash kernels). The
+    (what stands between a q / k projection and the flash kernels) and
+    ``kernel_impl("mla_prep", "pallas", S=8192, nope=128, rope=64, v=128,
+    heads=32)`` (between latent attention's projections and them). The
     dispatchers themselves (``flash_attention.attention``,
-    ``recurrent.lstm_scan``, ``quant.q_matmul``, ``QKNormAttention``) decide
+    ``recurrent.lstm_scan``, ``quant.q_matmul``, ``QKNormAttention``,
+    ``LatentAttention``) decide
     through the same functions, so what this returns is what runs — the
     answer a smoke run or a test asserts on instead of trusting that
     ``"auto"`` found the chip.
@@ -103,6 +106,7 @@ def kernel_impl(op: str, impl: str = "auto", **dims) -> str:
         "lstm_scan": ("recurrent", "lstm_impl"),
         "q_matmul": ("quant", "q_matmul_impl"),
         "qk_prep": ("qk_prep", "qk_prep_impl"),
+        "mla_prep": ("mla_prep", "mla_prep_impl"),
     }
     if op not in resolvers:
         raise ValueError(f"unknown op {op!r}; one of {sorted(resolvers)}")

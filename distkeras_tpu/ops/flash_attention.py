@@ -739,12 +739,13 @@ def _tiles(L):
 
 
 def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
-                window=None, diffusion=None, qk_major=False):
+                window=None, diffusion=None, qk_major=False, heads=None):
     """q [B, L, H, Dk], k [B, L, Hkv, Dk], v [B, L, Hkv, Dv] with Hkv | H
     (grouped-query attention reads shared K/V heads straight from the index
     maps — no repeated-KV materialization), + key_mask [B, L] →
     (out [B, L, H, Dv], lse). ``qk_major``: q and k come as the kernels walk
-    them, ``[B·H, L, D]`` and ``[B·Hkv, L, D]`` (see :func:`flash_attention`)."""
+    them, ``[B·H, L, D]`` and ``[B·Hkv, L, D]``; with ``heads`` (= H) v too,
+    ``[B·Hkv, L, Dv]`` (see :func:`flash_attention`)."""
     L = q.shape[1]
     _gqa_groups(q, k, qk_major)
     if L % BLOCK_Q:
@@ -754,7 +755,7 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
     tiles = _tiles(L if diffusion is None else L // 2)
     return _fwd_call(q, k, v, key_mask, tiles=tiles, scale=scale,
                      causal=causal, interpret=interpret, window=window,
-                     diffusion=diffusion, qk_major=qk_major)
+                     diffusion=diffusion, qk_major=qk_major, heads=heads)
 
 
 # The two launchers are jitted on their own: a model calls them once a layer
@@ -767,15 +768,25 @@ def _fa_forward(q, k, v, key_mask, *, scale, causal, interpret,
 # The tiles are an argument so that the choice is part of the cache's key;
 # the band's grain (``_FINE``, ``_WIDEST``) is read when a launcher traces.
 _STATIC = ("tiles", "scale", "causal", "interpret", "window", "diffusion",
-           "qk_major")
+           "qk_major", "heads")
+
+
+def _sizes(q, k, v, qk_major, heads):
+    """``(B, L, H, Hkv, Dk, Dv)`` of a launcher's operands: from ``v [B, L,
+    Hkv, Dv]``, or, where all three come head-major (``heads`` = H, which
+    three head-major operands do not say), from it and their rows."""
+    Dk, Dv = q.shape[-1], v.shape[-1]
+    groups = _gqa_groups(q, k, qk_major)
+    if heads is None:
+        B, L, Hkv, _ = v.shape
+        return B, L, Hkv * groups, Hkv, Dk, Dv
+    return q.shape[0] // heads, q.shape[1], heads, heads // groups, Dk, Dv
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
-              diffusion=None, qk_major=False):
-    B, L, Hkv, Dv = v.shape
-    Dk = q.shape[-1]
-    H = Hkv * _gqa_groups(q, k, qk_major)
+              diffusion=None, qk_major=False, heads=None):
+    B, L, H, Hkv, Dk, Dv = _sizes(q, k, v, qk_major, heads)
     bq, bk = tiles
     rows = _stat_rows(diffusion, Dk, Dv)
 
@@ -812,7 +823,7 @@ def _fwd_call(q, k, v, key_mask, *, tiles, scale, causal, interpret, window,
         pltpu.VMEM((bq, Dv), jnp.float32),  # running numerator acc
     ]
     in_specs = [qspec, kspec, vspec]
-    args = [qb, kb, bh(v)]
+    args = [qb, kb, v if heads else bh(v)]
     if key_mask is not None:
         # mask ships as [B, 1, L] so its block obeys the (8, 128) tile rule
         in_specs.append(
@@ -992,28 +1003,28 @@ def _fa_bwd_dkv_kernel(q_ref, k_ref, v_ref, g_ref, lse_ref, d_ref, *rest,
 
 
 def _fa_backward(q, k, v, key_mask, out, lse, g, *, scale, causal,
-                 interpret, window=None, diffusion=None, qk_major=False):
+                 interpret, window=None, diffusion=None, qk_major=False,
+                 heads=None):
     """Blockwise flash-attention backward: (dq, dk, dv) via two Pallas
     kernels, ``O(block_q · block_k)`` on-chip — no [B, H, L, L] tensors.
     Under grouped-query attention (k/v hold Hkv < H heads) dq reads the
     shared heads through the index maps and the dkv grid gains a group
     axis whose accumulators sum the whole group — dk/dv come out
     Hkv-wide, no repeated-KV tensors anywhere. ``qk_major``: q and k come,
-    and dq and dk go, head-major."""
+    and dq and dk go, head-major; with ``heads`` v and dv too."""
     L = q.shape[1]
     tiles = _tiles(L if diffusion is None else L // 2)
     return _bwd_call(q, k, v, key_mask, out, lse, g, tiles=tiles,
                      scale=scale, causal=causal, interpret=interpret,
-                     window=window, diffusion=diffusion, qk_major=qk_major)
+                     window=window, diffusion=diffusion, qk_major=qk_major,
+                     heads=heads)
 
 
 @functools.partial(jax.jit, static_argnames=_STATIC)
 def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
-              interpret, window, diffusion=None, qk_major=False):
-    B, L, Hkv, Dv = v.shape
-    Dk = q.shape[-1]
-    groups = _gqa_groups(q, k, qk_major)
-    H = Hkv * groups
+              interpret, window, diffusion=None, qk_major=False, heads=None):
+    B, L, H, Hkv, Dk, Dv = _sizes(q, k, v, qk_major, heads)
+    groups = H // Hkv
     bq, bk = tiles  # the forward's: one ladder
     plan = _band_plan(L, tiles, causal, window, False, diffusion)
     rows = _stat_rows(diffusion, Dk, Dv)
@@ -1023,7 +1034,7 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
         return jnp.moveaxis(x, 2, 1).reshape(B * h, L, d)
 
     qb, kb = (q, k) if qk_major else (bh(q), bh(k))
-    vb, gb = bh(v), bh(g)
+    vb, gb = v if heads else bh(v), bh(g)
     # delta = rowsum(dO · O): one elementwise pass, [B·H, L]
     delta = jnp.sum(gb.astype(jnp.float32) * bh(out).astype(jnp.float32),
                     axis=-1)
@@ -1128,7 +1139,7 @@ def _bwd_call(q, k, v, key_mask, out, lse, g, *, tiles, scale, causal,
         return jnp.moveaxis(x.reshape(B, h, L, d), 1, 2)
 
     if qk_major:
-        return dq, dk, unbh(dv)
+        return dq, dk, dv if heads else unbh(dv)
     return unbh(dq), unbh(dk), unbh(dv)
 
 
@@ -1182,45 +1193,48 @@ def _attention_bwd_math(q, k, v, key_mask, lse, g, *, scale, causal,
 
 
 def _forward(q, k, v, key_mask, scale, causal, interpret, window, diffusion,
-             qk_major):
+             qk_major, heads):
     return ops.on_each_device(
         functools.partial(_fa_forward, scale=scale, causal=causal,
                           interpret=interpret, window=window,
-                          diffusion=diffusion, qk_major=qk_major),
+                          diffusion=diffusion, qk_major=qk_major,
+                          heads=heads),
         q, k, v, key_mask,
     )
 
 
 def _backward(q, k, v, key_mask, out, lse, g, scale, causal, interpret,
-              window, diffusion, qk_major):
+              window, diffusion, qk_major, heads):
     return ops.on_each_device(
         functools.partial(_fa_backward, scale=scale, causal=causal,
                           interpret=interpret, window=window,
-                          diffusion=diffusion, qk_major=qk_major),
+                          diffusion=diffusion, qk_major=qk_major,
+                          heads=heads),
         q, k, v, key_mask, out, lse, g,
     )
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_core(q, k, v, key_mask, causal, scale, interpret, window,
-                diffusion, qk_major):
+                diffusion, qk_major, heads):
     out, _ = _forward(q, k, v, key_mask, scale, causal, interpret, window,
-                      diffusion, qk_major)
+                      diffusion, qk_major, heads)
     return out
 
 
 def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window, diffusion,
-            qk_major):
+            qk_major, heads):
     out, lse = _forward(q, k, v, key_mask, scale, causal, interpret, window,
-                        diffusion, qk_major)
+                        diffusion, qk_major, heads)
     # saving `out` adds no memory under jit: it aliases the primal output
     return out, (q, k, v, key_mask, out, lse)
 
 
-def _fa_bwd(causal, scale, interpret, window, diffusion, qk_major, res, g):
+def _fa_bwd(causal, scale, interpret, window, diffusion, qk_major, heads, res,
+            g):
     q, k, v, key_mask, out, lse = res
     dq, dk, dv = _backward(q, k, v, key_mask, out, lse, g, scale, causal,
-                           interpret, window, diffusion, qk_major)
+                           interpret, window, diffusion, qk_major, heads)
     dmask = None if key_mask is None else jnp.zeros_like(key_mask)
     return dq, dk, dv, dmask
 
@@ -1267,7 +1281,7 @@ def _canonical_diffusion(block, L, causal, window):
 def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
                     interpret: bool | None = None, window: int | None = None,
                     block_diffusion: int | None = None,
-                    qk_major: bool = False):
+                    qk_major: bool = False, heads: int | None = None):
     """Pallas flash attention; same contract as ``attention_reference``.
 
     ``q/k/v`` [B, L, H, D] → [B, L, H, D]; ``v`` may be another width than
@@ -1289,6 +1303,11 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
     ``[B·Hkv, L, D]`` (what ``ops.qk_prep`` writes: the layout the kernels'
     grids walk, which any other caller's q and k are copied into), and their
     gradients go back so; ``v`` and the result keep ``[B, L, heads, D]``.
+    With ``heads`` (the count of query heads a batch row has, which three
+    head-major operands do not say) ``v`` too comes head-major, ``[B·Hkv, L,
+    Dv]`` (what ``ops.mla_prep`` writes), and ``dv`` goes back so; the result
+    and its cotangent keep ``[B, L, heads, Dv]``. Both are decided while
+    tracing: a caller that asks for neither gets the copies it always got.
     """
     L = q.shape[1]
     if q.shape[-1] != k.shape[-1]:
@@ -1296,11 +1315,19 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
             f"q and k must be one width (their product is the score); got "
             f"{q.shape[-1]} and {k.shape[-1]}"
         )
-    if v.shape[-1] != q.shape[-1] and (qk_major or block_diffusion is not None):
+    if v.shape[-1] != q.shape[-1] and block_diffusion is not None:
         raise ValueError(
             f"v of another width ({v.shape[-1]}) than q and k "
             f"({q.shape[-1]}) runs under the causal band, a window or no "
-            f"mask; with qk_major or block_diffusion it is not written"
+            f"mask; with block_diffusion it is not written"
+        )
+    if (heads is not None) != (v.ndim == 3) or (
+            heads is not None and (not qk_major or q.shape[0] % heads)):
+        raise ValueError(
+            f"heads says that q, k and v ALL come head-major, [B·heads, L, "
+            f"D]: it goes with qk_major=True, v of three dimensions and rows "
+            f"that are whole batch rows of heads, and with nothing else; got "
+            f"heads={heads}, qk_major={qk_major}, q {q.shape}, v {v.shape}"
         )
     diffusion = _canonical_diffusion(block_diffusion, L, causal, window)
     if diffusion is not None and (L // 2) % BLOCK_Q:
@@ -1315,6 +1342,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None, key_mask=None,
         _canonical_window(window, L),
         diffusion,
         bool(qk_major),
+        None if heads is None else int(heads),
     )
 
 

@@ -26,6 +26,7 @@ def test_kernels_phase_interpret_mode():
         timed=((1, 256, 2, 64, 1), (1, 512, 2, 64, 1, 4),
                (1, 256, 2, (48, 32), 2)),
         prep=((2, 256, 2, 128), (1, 128, 1, 128)), wide=(1, 256, 2, 48, 32),
+        latent=((1, 256, 2, 128, 64, 128),),
     )
     assert line["phase"] == "kernels" and line["interpret"] is True
     assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
@@ -53,7 +54,8 @@ def test_kernels_phase_interpret_mode():
     # off the chip "auto" keeps the references for attention and the LSTM —
     # the phase reports it, and only a native run insists on the kernels
     assert line["auto"] == {"attention": "reference", "lstm_scan": "xla",
-                            "q_matmul": "pallas", "qk_prep": "xla"}
+                            "q_matmul": "pallas", "qk_prep": "xla",
+                            "mla_prep": "xla"}
     # qk_prep at a q and a k projection's shape: its error against the jnp
     # chain beside (off the chip) no device time
     q, k = line["qk_prep"]
@@ -63,11 +65,24 @@ def test_kernels_phase_interpret_mode():
     assert q["qk_prep_bwd"]["floor_bytes"] == 3 * 2 * 256 * 256 * 2
     assert q["chain_ms"] is None and max(q["norm_err"].values()) < 2e-2
     assert {"qk_prep2.out", "qk_prep2.dx", "qk_prep1.dw"} <= set(line["norm_err"])
+    # mla_prep at a latent sublayer's projections, q's kernels and k / v's
+    # apart: each operand read and each result written once
+    lq, lkv = line["mla_prep"]
+    assert (lq["part"], lkv["part"]) == ("q", "kv")
+    assert lq["shape"] == lkv["shape"] == [1, 256, 2, 128, 64, 128]
+    assert lq["mla_prep_fwd"] == lq["mla_prep_bwd"] == {
+        "ms": None, "floor_bytes": 2 * 256 * 2 * 192 * 2, "gb_per_s": None}
+    assert lkv["mla_prep_bwd"]["floor_bytes"] == 256 * 2 * (
+        2 * 256 + 64 + 2 * 192 + 2 * 128)
+    assert lkv["chain_ms"] is None
+    assert {"mla_prep2.q", "mla_prep2.dq", "mla_prep2.k", "mla_prep2.v",
+            "mla_prep2.dkv", "mla_prep2.dk_rope"} <= set(line["norm_err"])
 
 
 def test_kernels_phase_compares_the_block_diffusion_mask():
     line = chip_smoke.kernels(attn=(1, 256, 2, 64), qmm=((8, 128, 128),), adam=(8, 16),
-                              lstm=(8, 2, 128), interpret=True, timed=(), prep=(), wide=())
+                              lstm=(8, 2, 128), interpret=True, timed=(), prep=(), wide=(),
+                              latent=())
     assert {"flash_bd.out", "flash_bd.dq", "flash_bd.dk",
             "flash_bd.dv"} <= set(line["norm_err"])
 
@@ -85,7 +100,7 @@ def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure, match="off its reference"):
         chip_smoke.kernels(attn=(1, 128, 1, 64), qmm=((8, 128, 128),),
                            adam=(8, 16), lstm=(8, 2, 128), interpret=True,
-                           timed=(), prep=(), wide=())
+                           timed=(), prep=(), wide=(), latent=())
 
 
 def test_loss_phase_tiny():

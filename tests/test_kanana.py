@@ -117,15 +117,56 @@ def test_the_default_scale_is_the_query_width():
 @pytest.mark.parametrize("kwargs, match", [
     (dict(widths=(48, 32, 32)), "q and k must be one width"),
     (dict(widths=(48, 48, 32), block_diffusion=4), "not written"),
-    (dict(widths=(48, 48, 32), qk_major=True), "not written"),
+    # all three head-major needs the count of heads, and the count needs them so
+    (dict(widths=(48, 48, 32), qk_major=True, v_major=True), "heads says"),
+    (dict(widths=(48, 48, 32), qk_major=True, heads=2), "heads says"),
+    (dict(widths=(48, 48, 32), heads=2, v_major=True), "heads says"),
+    (dict(widths=(48, 48, 32), qk_major=True, heads=3, v_major=True), "heads says"),
 ])
 def test_flash_attention_names_the_widths_it_cannot_take(kwargs, match):
     dq, dk, dv = kwargs.pop("widths")
     q, k, v = (jnp.zeros((1, 256, 2, d)) for d in (dq, dk, dv))
     if kwargs.get("qk_major"):
         q, k = (a.reshape(2, 256, -1) for a in (q, k))
+    if kwargs.pop("v_major", False):
+        v = v.reshape(2, 256, -1)
     with pytest.raises(ValueError, match=match):
         fa.flash_attention(q, k, v, **kwargs)
+
+
+@functools.lru_cache(maxsize=None)
+def _head_major(mode):
+    """Forward and gradients at 48 / 32 with q and k (``"qk"``) or all three
+    operands (``"qkv"``) handed over head-major, grouped heads, and the
+    launcher's own copies of the same numbers; gradients in the callers'
+    layout."""
+    B, L, H, K = 2, 256, 4, 2
+    ks = jax.random.split(jax.random.PRNGKey(5), 4)
+    q, g = jax.random.normal(ks[0], (B, L, H, 48)), jax.random.normal(ks[1], (B, L, H, 32))
+    k, v = jax.random.normal(ks[2], (B, L, K, 48)), jax.random.normal(ks[3], (B, L, K, 32))
+    major = lambda a: jnp.moveaxis(a, 2, 1).reshape(-1, L, a.shape[-1])
+    back = lambda a, heads: jnp.moveaxis(a.reshape(B, heads, L, -1), 1, 2)
+    o, pull = jax.vjp(lambda q, k, v: fa.flash_attention(q, k, v, causal=True), q, k, v)
+    want = dict(zip(("out", "dq", "dk", "dv"), map(np.asarray, (o,) + pull(g))))
+    options = dict(qk_major=True, **(dict(heads=H) if mode == "qkv" else {}))
+    o, pull = jax.vjp(lambda q, k, v: fa.flash_attention(q, k, v, causal=True, **options),
+                      major(q), major(k), major(v) if mode == "qkv" else v)
+    dq, dk, dv = pull(g)
+    assert dq.shape == (B * H, L, 48) and dk.shape == (B * K, L, 48)
+    assert dv.shape == ((B * K, L, 32) if mode == "qkv" else (B, L, K, 32))
+    got = dict(out=o, dq=back(dq, H), dk=back(dk, K), dv=back(dv, K) if mode == "qkv" else dv)
+    return {n: np.asarray(a) for n, a in got.items()}, want
+
+
+@pytest.mark.parametrize("mode", ["qk", "qkv"])
+@pytest.mark.parametrize("what", ["out", "dq", "dk", "dv"])
+def test_two_widths_run_head_major(mode, what):
+    """The call that raised at PR 33 (two widths under ``qk_major``) runs, and
+    with ``heads`` v and dv travel head-major too: the same kernels on the same
+    numbers, so the same results to the bit."""
+    got, want = _head_major(mode)
+    assert np.abs(want[what]).max() > 0.1
+    np.testing.assert_array_equal(got[what], want[what])
 
 
 def test_one_width_builds_the_launchers_it_built():
@@ -139,6 +180,27 @@ def test_one_width_builds_the_launchers_it_built():
     wide = jax.jit(lambda q, v: fa.flash_attention(q, q, v, causal=True)).lower(
         jnp.zeros((1, 256, 2, 48)), q).as_text()
     assert "1x256xf32" in wide.replace(" ", "")          # [B·H, 1, L] rows
+    # and what the launchers run around the kernels is PR 33's, operation for operation: a
+    # band caller's twelve copies at one width and at two, six fewer under ``qk_major``
+    # (tests/test_qk_prep.py has every accepted cell's call), and three left (the result,
+    # dO and the saved result) where all three operands come head-major
+    from tests.test_qk_prep import _PARENTS, _primitives
+
+    def count(q, k, v, **options):
+        def both(q, k, v):
+            o, pull = jax.vjp(lambda q, k, v: fa.flash_attention(
+                q, k, v, causal=True, **options), q, k, v)
+            return (o,) + pull(o)
+        return _primitives(jax.make_jaxpr(both)(q, k, v).jaxpr, {})
+
+    major = lambda a: jnp.moveaxis(a, 2, 1).reshape(-1, 256, a.shape[-1])
+    for dk, dv, broadcasts in ((32, 32, 4), (48, 32, 2)):   # columns, rows: the statistics
+        q, v = jnp.zeros((2, 256, 4, dk)), jnp.zeros((2, 256, 4, dv))
+        want = dict(_PARENTS, broadcast_in_dim=broadcasts)
+        assert count(q, q, v) == want
+        assert count(major(q), major(q), v, qk_major=True) == dict(want, transpose=6, reshape=6)
+        assert count(major(q), major(q), major(v), qk_major=True, heads=4) == dict(
+            want, transpose=3, reshape=3)
 
 
 # -- the sigmoid router, alone ---------------------------------------------------

@@ -245,26 +245,35 @@ _PARENTS = {"jit": 2, "mul": 1, "pallas_call": 3, "reduce_sum": 1,
     (4, 2, 128, dict(causal=True), 4),           # zaya1-8b.train's
     (2, 2, 64, dict(causal=True, key_mask=True), 8),
     (4, 2, 128, dict(block_diffusion=4), 2),     # any other block-diffusion caller
+    (4, 4, (192, 128), dict(causal=True), 2),    # latent attention's two widths
 ])
 def test_the_launchers_other_callers_run_what_they_ran(heads, kv_heads, head,
                                                        mask, broadcasts):
-    """``qk_major`` is decided while tracing: a caller that does not ask for
-    it gets the parent's program, operation for operation; one that does
-    loses the six copies of q, k, dq and dk and nothing else."""
+    """``qk_major`` and ``heads`` are decided while tracing: a caller that
+    asks for neither gets the parent's program, operation for operation; one
+    that asks for the first loses the six copies of q, k, dq and dk and nothing
+    else (sdar-30b-a3b.train's call); with both, v's two and dv's go too and
+    no copy of q, k, v, dq, dk or dv is left: the result's, dO's and the saved
+    result's three stay."""
+    head, v_head = head if isinstance(head, tuple) else (head, head)
     q = jnp.zeros((2, 256, heads, head))
-    k = v = jnp.zeros((2, 256, kv_heads, head))
+    k = jnp.zeros((2, 256, kv_heads, head))
+    v = jnp.zeros((2, 256, kv_heads, v_head))
     mask = dict(mask)
     if mask.pop("key_mask", False):
         mask["key_mask"] = jnp.ones((2, 256))
 
-    def count(qk_major, q, k):
+    def count(q, k, v, **major):
         def both(q, k, v):
             o, pull = jax.vjp(lambda q, k, v: flash_attention(
-                q, k, v, qk_major=qk_major, **mask), q, k, v)
+                q, k, v, **major, **mask), q, k, v)
             return (o,) + pull(o)
         return _primitives(jax.make_jaxpr(both)(q, k, v).jaxpr, {})
 
     want = dict(_PARENTS, broadcast_in_dim=broadcasts)
-    assert count(False, q, k) == want
-    major = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, 256, head)
-    assert count(True, major(q), major(k)) == dict(want, transpose=6, reshape=6)
+    assert count(q, k, v) == want
+    major = lambda x: jnp.moveaxis(x, 2, 1).reshape(-1, 256, x.shape[-1])
+    assert count(major(q), major(k), v, qk_major=True) == dict(
+        want, transpose=6, reshape=6)
+    assert count(major(q), major(k), major(v), qk_major=True,
+                 heads=heads) == dict(want, transpose=3, reshape=3)

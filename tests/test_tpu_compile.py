@@ -91,6 +91,27 @@ def _qk_prep(B, S, heads, D=128):
                      ((B * heads, S, D), BF16)]
 
 
+def _mla_prep(B, S, heads, nope=128, rope=64, v=128):
+    """(fn, argument shapes): ``mla_prep`` and its gradients at a latent
+    sublayer's projections' results."""
+    from distkeras_tpu.models.lm import rope_angles
+    from distkeras_tpu.ops.mla_prep import mla_prep
+
+    angles = rope_angles(S, rope, 1e6)
+
+    def fwd_bwd(q, kv, k_rope, *g):
+        out, vjp = jax.vjp(lambda *x: mla_prep(
+            *x, angles, heads=heads, nope=nope, interpret=False),
+            q, kv, k_rope)
+        return out + vjp(g)
+
+    return fwd_bwd, [((B, S, heads * (nope + rope)), BF16),
+                     ((B, S, heads * (nope + v)), BF16), ((B, S, rope), BF16),
+                     ((B * heads, S, nope + rope), BF16),
+                     ((B * heads, S, nope + rope), BF16),
+                     ((B * heads, S, v), BF16)]
+
+
 def _lstm(B, T, H, workers=None):
     from distkeras_tpu.ops.recurrent import lstm_scan
 
@@ -171,6 +192,12 @@ KERNELS = {
     # to [128, 8192, 128] and [4, 8192, 512] to [16, 8192, 128], and back
     "qk_prep-fwdbwd-q-4x8192x32x128": lambda: _qk_prep(4, 8192, 32),
     "qk_prep-fwdbwd-k-4x8192x4x128": lambda: _qk_prep(4, 8192, 4),
+    # kanana-2-30b-a3b.train's q, k and v on their way to its call: [4, 8192,
+    # 6144], [4, 8192, 8192] and [4, 8192, 64] to [128, 8192, 192] twice and
+    # [128, 8192, 128], and back; and one group of 6 heads at rows of 384
+    "mla_prep-fwdbwd-4x8192x32x128+64x128": lambda: _mla_prep(4, 8192, 32),
+    "mla_prep-fwdbwd-2x384x6x256+64x128":
+        lambda: _mla_prep(2, 384, 6, nope=256),
     # fused LSTM scan: the IMDB config's batches, the stacked-worker vmap,
     # and chip_smoke's shape
     **{f"lstm-fwdbwd-T200-H128-B{b}": (lambda b=b: _lstm(b, 200, 128))
@@ -212,6 +239,7 @@ NAMED = {
         "flash_fwd", "flash_dq", "flash_dkv"),
     "qk_prep-fwdbwd-q-4x8192x32x128": ("qk_prep_fwd", "qk_prep_bwd"),
     "qk_prep-fwdbwd-k-4x8192x4x128": ("qk_prep_fwd", "qk_prep_bwd"),
+    "mla_prep-fwdbwd-4x8192x32x128+64x128": ("mla_prep_fwd", "mla_prep_bwd"),
     "lstm-fwdbwd-T200-H128-B32": ("lstm_scan_fwd", "lstm_scan_bwd"),
     "fused-adam-16384x1024": ("fused_adam",),
     "q_matmul-8x2048x2048": ("q_matmul",),
@@ -463,7 +491,12 @@ def test_latent_attention_train_step_compiles_with_its_kernels(topo, monkeypatch
     wide and values 128 (the forward twice under remat), with no operand
     padded to 256 or values carried 192 wide; the grouped products as
     ``ragged-dot`` kernels in the two expert layers only; the scopes
-    ``mla_latent``, ``moe_shared`` and ``moe_bias`` in the lowered step."""
+    ``mla_latent``, ``moe_shared`` and ``moe_bias`` in the lowered step. q, k
+    and v go from their projections to ``flash_fwd`` through ``mla_prep_fwd``
+    (a q call and a k / v call, twice a layer under remat) and their gradients
+    back through ``mla_prep_bwd`` with nothing between, and no gather, scatter
+    or array of the shared key broadcast to every head is left under
+    ``/attn/``."""
     from distkeras_tpu import ops
     from distkeras_tpu.models import MlaDims, transformer_lm
     from distkeras_tpu.trainers import MeshTrainer
@@ -511,6 +544,21 @@ def test_latent_attention_train_step_compiles_with_its_kernels(topo, monkeypatch
         line = ins[name][2]
         assert line.count(wide) >= 2 and narrow in line, line[:400]
         assert f"{L},256]" not in line, line[:400]
+        for operand in ins[name][1][:3]:        # q, k and v
+            source, _ = _source(ins, operand)
+            assert source.split(".")[0] == "mla_prep_fwd", ins[source][2][:300]
+    assert len(kernel("mla_prep_fwd")) == 4 * depth
+    assert len(kernel("mla_prep_bwd")) == 2 * depth
+    for name in kernel("mla_prep_bwd"):         # every cotangent, tables last
+        for operand in ins[name][1][:-2]:
+            source, _ = _source(ins, operand)
+            assert source.split(".")[0] in ("flash_dq", "flash_dkv"), \
+                ins[source][2][:300]
+    for name, (opcode, _, line) in ins.items():
+        if "/attn/" in line and "op_name=" in line:
+            assert "gather" not in opcode and "scatter" not in opcode \
+                and not name.startswith(("gather", "scatter")), line[:300]
+            assert f"[{B},{L},{heads},64]" not in line, line[:300]
     assert "ragged-dot" in text
     assert "blocks_0/moe" not in named and "blocks_1/moe" in named
 
