@@ -15,6 +15,13 @@ SLO-aware scheduling, shm transport) are debugged against:
   trace's clock) and the RUN LOG (``log=True``; ``trace.run_log()``:
   set-up phases, epoch ends and every JAX trace, lower and compile,
   kept with tracing off).
+- :mod:`distkeras_tpu.observability.programs` — the compiled step's
+  operations by the program's own scopes: a handle on the step
+  ``SPMDEngine.run_step`` ran (``note``), from which ``op_scopes`` makes,
+  on demand, the table ``{HLO instruction: (scope path, pass)}`` that
+  lays a profiler trace's device time on modules, ``jax.named_scope``s
+  and passes (forward, remat's forward, backward); its one run-log span
+  is ``program.op_scopes``.
 - :mod:`distkeras_tpu.observability.metrics` — a typed registry
   normalizing ``ps.stats()`` / serving / WAL counters into named
   metrics with Prometheus text + JSON snapshot exporters, served live

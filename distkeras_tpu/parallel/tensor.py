@@ -25,6 +25,7 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from distkeras_tpu.observability import programs
 from distkeras_tpu.ops import kernel_mesh
 from distkeras_tpu.parallel.mesh import put_global
 
@@ -152,6 +153,7 @@ class SPMDEngine:
         self._batch_sharding = batch_sharding(mesh, dp_axis)
         self._step = None
         self._step_fn = None
+        self._step_handle = None
         self._resident = None
 
     def _resolve_specs(self, params):
@@ -260,8 +262,9 @@ class SPMDEngine:
             # the model runs per device on its own rows of the dp split
             with kernel_mesh(mesh, dp_axis):
                 (loss, new_nt), grads = grads_of(params, nt, batch)
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("optimizer"):
+                updates, opt_state = tx.update(grads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             # pin the output layout so donation reuses the input buffers
             params = jax.tree.map(
                 lambda x, s: jax.lax.with_sharding_constraint(
@@ -273,6 +276,7 @@ class SPMDEngine:
 
         self._step_fn = train_step
         self._step = jax.jit(train_step, donate_argnums=(0, 2))
+        self._step_handle = None
         self._resident = None
 
     def _check_batch(self, B: int):
@@ -302,7 +306,13 @@ class SPMDEngine:
         self._check_batch(batch_arrays[0].shape[0])
         if not isinstance(batch_arrays[0], jax.Array):
             batch_arrays = self.place_batch(batch_arrays)
-        return self._step(params, nt, opt_state, batch_arrays)
+        out = self._step(params, nt, opt_state, batch_arrays)
+        if self._step_handle is None:
+            # after the first call, when the trace is cached: what the step's
+            # compiled text can be had from again (observability.programs)
+            self._step_handle = programs.note(
+                "train_step", self._step, (params, nt, opt_state, batch_arrays))
+        return out
 
     # -- device-resident epoch (upload once, whole epoch in one dispatch) ----
 

@@ -41,6 +41,7 @@ import optax
 from distkeras_tpu import utils
 from distkeras_tpu.data import Dataset, padded_chunks, prefetch_to_device
 from distkeras_tpu.model import ModelSpec, from_keras, keras_weights_to_model
+from distkeras_tpu.observability import programs as _programs
 from distkeras_tpu.observability import trace as _trace
 from distkeras_tpu.ops.losses import get_loss
 from distkeras_tpu.parallel.local_sgd import LocalSGDEngine
@@ -268,10 +269,14 @@ def _profile_trace_ctx(profile_dir):
     """
     if not profile_dir:
         return contextlib.nullcontext()
+    return jax.profiler.trace(_profile_path(profile_dir))
+
+
+def _profile_path(profile_dir) -> str:
     path = str(profile_dir)
     if jax.process_count() > 1:
         path = os.path.join(path, f"process{jax.process_index()}")
-    return jax.profiler.trace(path)
+    return path
 
 
 class _Validator:
@@ -2050,6 +2055,11 @@ class MeshTrainer(Trainer):
             self._finish_checkpoints()
             self.record_training_end()
             self._materialize_history()
+            if self.profile_dir and getattr(engine, "_step_handle", None):
+                # beside the trace, the table that lays its device time on
+                # the program's scopes (observability.programs; an engine
+                # that noted no step leaves none)
+                _programs.save("train_step", _profile_path(self.profile_dir))
         with _phase("train.fetch_params"):
             if jax.process_count() > 1:
                 # gather sharded leaves to host: under jax.distributed some

@@ -211,8 +211,27 @@ NAMES = [("jit_serve_decode_greedy", "tiny-serve", "serve_decode_greedy"),
          ("moe_shared", "tiny-train-mla", "train_step"),
          ("moe_bias", "tiny-train-mla", "train_step"),
          ("moe_experts", "tiny-train-mla", "train_step")]
+# what benchmark/parts.py's readers ask the program's table of its step for
+# (observability.programs.op_scopes), by block type: the loss's two scopes, the
+# scope round the optimizer, the attention sublayer's component under a block
+# (a method of the dense block, a module of the others), remat's marker
+TRAIN_CELLS = {"tiny-train": "blocks_0._attn_full/", "tiny-train-moe": "blocks_0.attend/cca/",
+               "tiny-train-bd": "blocks_0.attend/attn/", "tiny-train-mla": "blocks_0.attend/attn/"}
+PARTS = {"fused_ce_fwd": "loss_ms.train", "fused_ce_bwd": "loss_ms.train",
+         "/optimizer/": "step_scoped_pct.train", "rematted_computation": "remat_forward_ms.train",
+         "moe_route": "moe_route_ms.train"}
+NAMES += [(name, traffic, "train_step") for traffic, attention in TRAIN_CELLS.items()
+          for name in (*PARTS, attention)
+          if (name, traffic, "train_step") not in NAMES
+          and not (name == "moe_route" and traffic == "tiny-train")]
 READ_BY = {"jit_serve_decode_greedy": "benchmark/metrics/decode_roofline.py finds the decode "
                                       "program by name"}
+READ_BY.update({name: f"benchmark/metrics/{metric}.py finds the step's operations by this "
+                      f"component of their op_name (benchmark/parts.py)"
+                for name, metric in PARTS.items()})
+READ_BY.update({attention: "benchmark/metrics/attn_outside_flash_ms.train.py finds the attention "
+                           "sublayer by this component (parts.ATTENTION)"
+                for attention in TRAIN_CELLS.values()})
 BY_STEM = ("benchmark/spans.py sums a trace's kernels by name stem, and PERF.md section 5 maps "
            "them to this scope through the step's text")
 

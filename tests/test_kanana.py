@@ -571,7 +571,11 @@ def test_the_cell_finds_every_file():
                                   "delta_norm_gap", "route_count_gap", "bias_gap"}
     assert set(job["limits_why"]) == set(job["limits"])
     names = [m["name"] for m in loaded["per_layer"]]
-    assert names[-2:] == ["mfu.train.mla", "flash_roofline.mla"] and len(names) == 13
+    # the cell's own two, then PR 35's five readers of the step's parts
+    own = ["mfu.train.mla", "flash_roofline.mla"]
+    assert names[11:13] == own and len(names) == 18
+    assert names[13:] == ["step_scoped_pct.train", "loss_ms.train", "attn_outside_flash_ms.train",
+                          "moe_route_ms.train", "remat_forward_ms.train"]
     assert {"moe_expert_roofline", "moe_load_max_over_mean"} <= set(names)
     for m in loaded["per_layer"]:
         assert callable(loader.load_reader(m["reader"]))
@@ -580,7 +584,7 @@ def test_the_cell_finds_every_file():
     assert all(len(e["why"]) <= 200 for e in bench["workloads"] + bench["configs"])
     for other in ("xglm-564m.train", "zaya1-8b.train", "sdar-30b-a3b.train"):
         theirs = {m["name"] for m in loader.load_cell(other)["per_layer"]}
-        assert not theirs & set(names[-2:])
+        assert not theirs & set(own)
 
 
 def test_the_readers_return_nothing_without_what_they_read():
