@@ -724,7 +724,7 @@ def _zaya_spec(vocab, maxlen, dim, heads, kv_heads, depth, zaya, ce_chunk,
 def moe(*, vocab=8192, maxlen=1024, dim=512, heads=4, kv_heads=2, depth=2,
         head_dim=128, router_dim=128, experts=8, experts_held=(0, 4),
         expert_dim=512, ce_chunk=256, batch=4, steps=4, epochs=2,
-        kernel_calls=30, top_k=(4096, 512, 256, 32, 8, 8)):
+        kernel_calls=28, top_k=(4096, 512, 256, 32, 8, 8)):
     """``MeshTrainer(...).train`` on ``transformer_lm(zaya=...)`` in bf16 with
     flash attention, the fused cross-entropy and remat, holding half the
     router's experts: every loss finite, the first equal to the plain float32

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 
+from distkeras_tpu import ops
 from distkeras_tpu.model import ModelSpec, from_flax
 from distkeras_tpu.models.transformer import sincos_positions
 
@@ -1217,7 +1218,12 @@ class TransformerLM(nn.Module):
     #: rematerialize each block in the backward pass (jax.checkpoint) —
     #: the long-context training memory lever, same as the encoder family;
     #: decode entry points (prefill/step) are never differentiated and
-    #: stay unwrapped
+    #: stay unwrapped. A block keeps its input and what ``ops.REMAT_SAVED``
+    #: names: under ``attn_impl="flash"`` the kernel's output, ``B·L·H·Dv``
+    #: of ``dtype`` a layer (half of the block's float32 input where
+    #: ``H·Dv = dim``, as much as it under latent attention's ``2·dim``),
+    #: and one float32 a row and head, so that its backward does not run
+    #: the flash forward a second time
     remat: bool = False
     #: share the token embedding with the output head (Press & Wolf 2017):
     #: logits = hidden @ embedding.T — V·dim fewer parameters, and the
@@ -1293,7 +1299,8 @@ class TransformerLM(nn.Module):
         # nn.remat preserves the params tree (blocks_i names unchanged) and
         # transforms __call__ only — prefill/step run through the same
         # parameters un-rematted, which is exactly right for decode
-        block_cls = (nn.remat(DecoderBlock, static_argnums=(3,))
+        block_cls = (nn.remat(DecoderBlock, static_argnums=(3,),
+                              policy=ops.remat_policy())
                      if self.remat else DecoderBlock)
         self.blocks = [
             block_cls(dim=self.dim, heads=self.heads, dtype=self.dtype,
@@ -1314,10 +1321,9 @@ class TransformerLM(nn.Module):
         """Layers of a routed block (:class:`_RoutedBlock`), the head's
         RMSNorm and, untied, its bias-free head. ``kinds``: a layer's own
         fields, where the layers are not all of one kind."""
-        block_cls = (nn.remat(
-            block, static_argnums=(4,),
-            policy=jax.checkpoint_policies.save_only_these_names(
-                "router_bias")) if self.remat else block)
+        block_cls = (nn.remat(block, static_argnums=(4,),
+                              policy=ops.remat_policy())
+                     if self.remat else block)
         self.blocks = [
             block_cls(dim=self.dim, heads=self.heads,
                       kv_heads=self.kv_heads or self.heads, z=dims,

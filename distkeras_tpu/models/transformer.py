@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from distkeras_tpu import ops
 from distkeras_tpu.model import ModelSpec, from_flax
 from distkeras_tpu.parallel.mesh import put_global
 from distkeras_tpu.parallel.sequence import attention_reference
@@ -137,7 +138,10 @@ class TransformerClassifier(nn.Module):
     attn_window: int | None = None  # sliding-window (local) attention span
     #: rematerialize each block's activations in the backward pass
     #: (jax.checkpoint): ~L·dim per block of saved activations traded for
-    #: one extra forward — the standard long-context memory lever
+    #: one extra forward — the standard long-context memory lever. Under
+    #: ``attn_impl="flash"`` a block also keeps the kernel's output and
+    #: log-sum-exp (``ops.REMAT_SAVED``: ``L·dim`` of ``dtype`` more), and
+    #: the extra forward is one without the flash kernel
     remat: bool = False
 
     def setup(self):
@@ -145,7 +149,8 @@ class TransformerClassifier(nn.Module):
         # nn.remat preserves the params tree (blocks_i names unchanged), so
         # checkpoints/megatron specs/pipelining all work regardless of remat;
         # training (arg 3, counting self as 0) is a static python bool
-        block_cls = (nn.remat(EncoderBlock, static_argnums=(3,))
+        block_cls = (nn.remat(EncoderBlock, static_argnums=(3,),
+                              policy=ops.remat_policy())
                      if self.remat else EncoderBlock)
         self.blocks = [
             block_cls(dim=self.dim, heads=self.heads, causal=self.causal,

@@ -20,6 +20,24 @@ def __getattr__(name):
     raise AttributeError(f"module 'distkeras_tpu.ops' has no attribute {name!r}")
 
 
+#: What a rematted block keeps of its first forward beside its input, by the
+#: name ``jax.ad_checkpoint.checkpoint_name`` gives it where it is made;
+#: everything else the block's backward computes again. ``flash_out`` and
+#: ``flash_lse`` are the flash forward's output and per-row log-sum-exp
+#: (``flash_attention._fa_fwd``): BOTH, because the kernel runs again for
+#: whichever of its two results is missing; ``router_bias`` is the balanced
+#: selection bias (``models.lm._mlp_router``), whose sorts are not run twice.
+REMAT_SAVED = ("flash_out", "flash_lse", "router_bias")
+
+
+def remat_policy():
+    """The ``jax.checkpoint`` policy of every rematted block (``nn.remat`` in
+    ``models.lm`` and ``models.transformer``): save :data:`REMAT_SAVED`."""
+    import jax
+
+    return jax.checkpoint_policies.save_only_these_names(*REMAT_SAVED)
+
+
 def native_kernels() -> bool:
     """True when Pallas kernels compile for the chip, False when they run in
     the interpreter — THE place the ops ask which backend they are on. It

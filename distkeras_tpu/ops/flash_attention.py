@@ -58,6 +58,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -1226,7 +1227,14 @@ def _fa_fwd(q, k, v, key_mask, causal, scale, interpret, window, diffusion,
             qk_major, heads):
     out, lse = _forward(q, k, v, key_mask, scale, causal, interpret, window,
                         diffusion, qk_major, heads)
-    # saving `out` adds no memory under jit: it aliases the primal output
+    # Without remat, saving `out` adds no memory: it aliases the primal
+    # output. Under a rematted block the two names are what the block's
+    # policy keeps (``ops.REMAT_SAVED``): ``B·L·H·Dv`` of q's dtype and one
+    # float32 a row more a layer, and the block's backward does not run this
+    # kernel a second time. The NAMED values are the primal output and the
+    # residuals alike: a use of an un-named one brings the kernel back.
+    out = checkpoint_name(out, "flash_out")
+    lse = checkpoint_name(lse, "flash_lse")
     return out, (q, k, v, key_mask, out, lse)
 
 

@@ -29,13 +29,13 @@ BLOCKS = {"dense": ("tiny-sincos", program_lm), "zaya": ("tiny-zaya", train_moe.
 ATTENTION = {"dense": "blocks_*._attn_full", "zaya": "cca", "sdar": "attn", "mla": "attn"}
 
 
-def _spec(block, ce_chunk=64):
+def _spec(block, ce_chunk=64, attn_impl="reference"):
     """A two-layer ``transformer_lm`` of one block type at the benchmark's
-    tiny test widths: remat, the fused loss, XLA attention."""
+    tiny test widths: remat, the fused loss, XLA attention unless asked."""
     config, build = BLOCKS[block]
     with open(os.path.join(DATA, config + ".json")) as f:
         m = dict(json.load(f)["model"], depth=2)
-    return m, build(m, attn_impl="reference", fused_ce=True, ce_chunk=ce_chunk, remat=True)
+    return m, build(m, attn_impl=attn_impl, fused_ce=True, ce_chunk=ce_chunk, remat=True)
 
 
 def _engine(spec):
@@ -52,21 +52,21 @@ def _rows(m, n=2, length=32):
     return toks[:, :-1], (toks[:, :-1] if m.get("block") == "sdar" else toks[:, 1:])
 
 
-def _step_once(block, committed=True, **options):
+def _step_once(block, committed=True, length=32, **options):
     """One ``run_step`` of a fresh engine; returns the engine and its state."""
     m, spec = _spec(block, **options)
     engine = _engine(spec)
     p0, nt0 = spec.init_np(3)
     if committed:
         state = engine.init_state(p0, nt0)
-        batch = _rows(m)                   # host rows: run_step places them
+        batch = _rows(m, length=length)    # host rows: run_step places them
     else:
         # nothing placed: the arrays go wherever the jit sends them
         engine._resolve_specs(p0)
         engine._build_step()
         p0, nt0 = jax.tree.map(jnp.asarray, (p0, nt0))
         state = (p0, nt0, engine.optimizer.init(p0))
-        batch = tuple(jnp.asarray(a) for a in _rows(m))
+        batch = tuple(jnp.asarray(a) for a in _rows(m, length=length))
         assert not any(a.committed for a in jax.tree.leaves((state, batch)))
     out = engine.run_step(*state, batch)
     jax.block_until_ready(out[3])
@@ -131,6 +131,22 @@ def test_the_table_is_of_the_step_that_ran(compile_cache, block, committed):
     remat = {p for p, w in seen if w == "remat"}
     assert any(ATTENTION[block] in p for p in remat)
     assert not any(s in p for p in remat for s in ("fused_ce_fwd", "optimizer"))
+    del engine
+
+
+@pytest.mark.parametrize("block", ["dense", "mla"])
+def test_remats_forward_holds_no_flash_forward(compile_cache, block):
+    """A rematted block keeps the flash forward's two results
+    (``ops.REMAT_SAVED``): in the table of a flash step the kernel stands
+    under pass ``forward`` alone, while remat's forward still holds the
+    attention sublayer's projections, which nothing keeps."""
+    engine, _ = _step_once(block, length=128, attn_impl="flash")
+    seen = set(programs.op_scopes("train_step").values())
+    assert {w for p, w in seen if "flash_fwd" in p} == {"forward"}
+    assert {w for p, w in seen if "flash_dq" in p} == {"backward"}
+    remat = {p for p, w in seen if w == "remat"}
+    projection = {"dense": "qkv", "mla": "q"}[block]
+    assert any(ATTENTION[block] in p and projection in p for p in remat), sorted(remat)
     del engine
 
 

@@ -235,8 +235,9 @@ def _primitives(jaxpr, out):
 #: that tree: three kernels, the copies into and out of the head-major
 #: layout (q, k, v forward; q, k, v, dO, o and dq, dk, dv backward; the
 #: result), ``delta``; the key mask or the band's statistics as columns add
-#: broadcasts
-_PARENTS = {"jit": 2, "mul": 1, "pallas_call": 3, "reduce_sum": 1,
+#: broadcasts. Since PR 36 also the two names a rematted block keeps the
+#: forward's results by (``checkpoint_name``: no operation in the program)
+_PARENTS = {"jit": 2, "mul": 1, "name": 2, "pallas_call": 3, "reduce_sum": 1,
             "reshape": 12, "slice": 1, "squeeze": 1, "transpose": 12}
 
 
