@@ -304,6 +304,64 @@ def _mla_prep_times(shapes, interpret, reps=3) -> list:
     return out
 
 
+def _gated_delta_times(shapes, interpret, error_shape, reps=3) -> tuple:
+    """``ops.gated_delta`` (XLA, chunks of 64) at each ``(B, T, Hk, Hv, dk,
+    dv)`` of ``shapes`` (bf16 q, k, v; float32 decay and write strength; the
+    linear-attention cell's call by default): ms a call forward and forward +
+    backward on the chip (None in interpret mode), with the GB/s and TFLOP/s
+    that ``gated_delta_roofline``'s floor (``benchmark/flops_next.py``) comes
+    to; and at ``error_shape`` the normalized error of its result and of its
+    five gradients against the per-token recurrence
+    (``gated_delta_tokens``, float32). Returns ``(entries, errors)``."""
+    import jax
+    import jax.numpy as jnp
+
+    from benchmark import flops_next
+    from distkeras_tpu.ops.gated_delta import gated_delta, gated_delta_tokens
+
+    def inputs(B, T, Hk, Hv, dk, dv):
+        ks = jax.random.split(jax.random.PRNGKey(SEED + Hv), 7)
+        unit = lambda a: a / jnp.linalg.norm(a, axis=-1, keepdims=True)
+        q = (unit(jax.random.normal(ks[0], (B, T, Hk, dk))) * dk ** -0.5).astype(jnp.bfloat16)
+        k = unit(jax.random.normal(ks[1], (B, T, Hk, dk))).astype(jnp.bfloat16)
+        v = jax.random.normal(ks[2], (B, T, Hv, dv), jnp.bfloat16)
+        a_log = jnp.log(jax.random.uniform(ks[3], (Hv,), minval=1.0, maxval=16.0))
+        g = -jnp.exp(a_log) * jax.nn.softplus(jax.random.normal(ks[4], (B, T, Hv)) + 1.0)
+        beta = jax.nn.sigmoid(jax.random.normal(ks[5], (B, T, Hv)))
+        return (q, k, v, g, beta), jax.random.normal(ks[6], (B, T, Hv, dv), jnp.bfloat16)
+
+    def fwd_bwd(fn):
+        def run(x, do):
+            o, vjp = jax.vjp(fn, *x)
+            return (o,) + vjp(do.astype(o.dtype))
+        return jax.jit(run)
+
+    x, do = inputs(*error_shape)
+    got, want = fwd_bwd(gated_delta)(x, do), fwd_bwd(gated_delta_tokens)(x, do)
+    errors = {part: _norm_err(a, b) for part, a, b in zip(
+        ("out", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
+    out = []
+    for B, T, Hk, Hv, dk, dv in shapes:
+        m = dict(linear_key_heads=Hk, linear_value_heads=Hv, linear_key_dim=dk,
+                 linear_value_dim=dv)
+        entry = {"shape": [B, T, Hk, Hv, dk, dv]}
+        x, do = inputs(B, T, Hk, Hv, dk, dv)
+        fwd, both = jax.jit(gated_delta), fwd_bwd(gated_delta)
+        jax.block_until_ready((fwd(*x), both(x, do)))                  # compiles
+        for part, run, kinds in (("fwd", lambda: fwd(*x), ("fwd",)),
+                                 ("fwd_bwd", lambda: both(x, do), ("fwd", "bwd"))):
+            ms = None if interpret else round(sum(_device_ms_by_op(
+                lambda: jax.block_until_ready([run() for _ in range(reps)]),
+                reps).values()), 4)
+            flops = sum(flops_next.gated_delta_call_flops(m, k, B, T) for k in kinds)
+            moved = sum(flops_next.gated_delta_call_bytes(m, k, B, T) for k in kinds)
+            entry[part] = {"ms": ms, "floor_flops": flops, "floor_bytes": moved,
+                           "tflop_per_s": ms and round(flops / ms / 1e9, 2),
+                           "gb_per_s": ms and round(moved / ms / 1e6, 1)}
+        out.append(entry)
+    return out, errors
+
+
 def kernels(*, attn=(8, 2048, 8, 128),
             qmm=((8, 2048, 8192), (1024, 8192, 2048)),
             adam=(16384, 1024), lstm=(64, 200, 512), interpret=False,
@@ -311,7 +369,8 @@ def kernels(*, attn=(8, 2048, 8, 128),
                    (4, 8192, 32, 128, 4, 4), (4, 8192, 32, (192, 128), 32)),
             block=4, prep=((4, 8192, 32, 128), (4, 8192, 4, 128)),
             wide=(1, 8192, 2, 192, 128),
-            latent=((4, 8192, 32, 128, 64, 128),)):
+            latent=((4, 8192, 32, 128, 64, 128),),
+            delta=((8, 4096, 16, 32, 128, 128),), delta_error=(1, 4096, 2, 4, 128, 128)):
     """flash attention fwd+bwd (causal, and under the block-diffusion mask
     over blocks of ``block``; bf16), ``q_matmul`` (bf16 × int8), fused Adam
     (f32) and the fused LSTM scan fwd+bwd (bf16), each at a real call shape
@@ -328,7 +387,10 @@ def kernels(*, attn=(8, 2048, 8, 128),
     ``(B, S, heads, D)``, beside the ``jnp`` chain it replaces
     (:func:`_qk_prep_times`), and ``mla_prep`` each way at ``latent``, the
     latent-attention cell's projections ``(B, S, heads, nope, rope, v)``,
-    beside the chain it replaces (:func:`_mla_prep_times`)."""
+    beside the chain it replaces (:func:`_mla_prep_times`), and the chunked
+    gated delta rule at ``delta``, the linear-attention cell's ``(B, T, Hk,
+    Hv, dk, dv)``, with its error against the per-token recurrence at
+    ``delta_error`` (:func:`_gated_delta_times`)."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -452,10 +514,14 @@ def kernels(*, attn=(8, 2048, 8, 128),
     for entry in latents:
         for part, e in entry["norm_err"].items():
             errs[f"mla_prep{entry['shape'][2]}.{part}"] = (e, "bfloat16")
+    deltas, delta_errs = _gated_delta_times(delta, interpret, delta_error) \
+        if delta else ([], {})
+    for part, e in delta_errs.items():
+        errs[f"gated_delta.{part}"] = (e, "bfloat16")
     line = _report("kernels", t0, interpret=bool(interpret), auto=auto,
                    norm_err={k: e for k, (e, _) in errs.items()},
                    flash=_flash_times(timed, interpret), qk_prep=preps,
-                   mla_prep=latents)
+                   mla_prep=latents, gated_delta=deltas)
     for name, (e, dtype) in errs.items():
         # wh's gradient is f32 but flows through the bf16 recurrence
         tol = TOL["bfloat16"] if name.startswith("lstm") else TOL[dtype]

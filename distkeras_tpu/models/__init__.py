@@ -33,6 +33,7 @@ from distkeras_tpu.models.moe import (
 )
 from distkeras_tpu.models.lm import (
     MlaDims,
+    NextDims,
     SdarDims,
     TransformerLM,
     ZayaDims,
@@ -63,7 +64,7 @@ __all__ = [
     "pipelined_transformer_forward",
     "sequence_parallel_transformer_forward",
     "MoETransformerClassifier", "moe_transformer_classifier",
-    "TransformerLM", "MlaDims", "SdarDims", "ZayaDims", "transformer_lm", "generate", "beam_search",
+    "TransformerLM", "MlaDims", "NextDims", "SdarDims", "ZayaDims", "transformer_lm", "generate", "beam_search",
     "speculative_generate",
     "next_token_dataset", "quantize_lm",
 ]

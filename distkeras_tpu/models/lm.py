@@ -69,17 +69,20 @@ def apply_rope(x, angles):
     return out.astype(x.dtype)
 
 
-def head_norm_rope(a, w, angles, heads: int, eps: float):
+def head_norm_rope(a, w, angles, heads: int, eps: float, rotary: int | None = None):
     """A q or k projection's result ``a [B, S, heads·Dh]`` as float32
     ``[B, S, heads, Dh]``: an RMSNorm over each head with the weight
     ``w [Dh]``, then :func:`apply_rope` by ``angles [S, Dh // 2]``. The plain
     chain that ``ops.qk_prep`` does in one kernel (and hands over head-major,
     in ``a.dtype``): what runs at shapes the kernel refuses, and what its
-    tests compare with."""
+    tests compare with. ``rotary``: only a head's first ``rotary`` columns
+    are rotated (``angles [S, rotary // 2]``), the others pass as they are."""
     B, S, _ = a.shape
     a = a.astype(jnp.float32).reshape(B, S, heads, -1)
     a = a * jax.lax.rsqrt(jnp.mean(a * a, -1, keepdims=True) + eps) * w
-    return apply_rope(a, angles)
+    if rotary is None:
+        return apply_rope(a, angles)
+    return jnp.concatenate([apply_rope(a[..., :rotary], angles), a[..., rotary:]], -1)
 
 
 def latent_qkv(q, kv, k_rope, angles, heads: int, nope: int):
@@ -719,6 +722,12 @@ class RoutedExperts(nn.Module):
     router: object = _zaya_router
     join: object = _rescaled
     shared_dim: int = 0
+    #: the shared expert's result times ``sigmoid(h w)``, ``w`` a ``[dim, 1]``
+    #: ``shared_gate`` of this module's own
+    shared_gate: bool = False
+    #: the sublayer's norm: a class called as ``norm(epsilon=, dtype=,
+    #: name=)``
+    norm: object = nn.RMSNorm
 
     def counting(self) -> bool:
         return self.is_mutable_collection("counters") \
@@ -733,7 +742,7 @@ class RoutedExperts(nn.Module):
         E, F = z.experts, z.expert_dim
         first, count = z.held
         lecun = nn.initializers.lecun_normal()
-        h = nn.RMSNorm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
+        h = self.norm(epsilon=z.norm_eps, dtype=f32, name="ln")(x)
         chosen, weight, r = self.router(self, h, r)
         self.sow("intermediates", "moe_chosen", chosen)
         w_in = self.param("experts_in", lecun, (count, d, 2 * F), f32)
@@ -752,8 +761,13 @@ class RoutedExperts(nn.Module):
         y = y.reshape(B, S, d)
         if self.shared_dim:
             with jax.named_scope("moe_shared"):
-                y = y + _swiglu(self, h.astype(self.dtype), self.shared_dim,
-                                "shared").astype(jnp.float32)
+                shared = _swiglu(self, h.astype(self.dtype), self.shared_dim,
+                                 "shared").astype(jnp.float32)
+                if self.shared_gate:
+                    shared = shared * jax.nn.sigmoid(nn.Dense(
+                        1, use_bias=False, dtype=self.dtype,
+                        name="shared_gate")(h.astype(self.dtype)).astype(f32))
+                y = y + shared
         return self.join(self, x, y), r
 
 
@@ -1136,6 +1150,227 @@ def _check_mla_options(mla: MlaDims, *, quant, attn_window, pos_embedding,
                 f"the latent-attention block cannot honour {what}")
 
 
+@dataclasses.dataclass(frozen=True)
+class NextDims:
+    """The sizes of a hybrid linear-attention expert block (:class:`NextBlock`)
+    that ``dim``, ``heads`` and ``kv_heads`` do not give. Defaults are
+    Qwen3-Next-80B-A3B's published ones."""
+
+    head_dim: int = 256               # the full-attention layers' heads
+    rotary_dim: int = 64              # a head's leading columns that are rotated
+    rope_base: float = 1e7
+    #: every ``full_attention_interval``-th layer has gated full attention,
+    #: the others Gated DeltaNet
+    full_attention_interval: int = 4
+    linear_key_heads: int = 16
+    linear_value_heads: int = 32      # value head j reads key head j // 2
+    linear_key_dim: int = 128
+    linear_value_dim: int = 128
+    conv_kernel: int = 4
+    experts: int = 512                # the router's width
+    experts_per_token: int = 10
+    #: ``(first, count)``: the experts this model holds of all ``experts``
+    #: (an ``ep`` rank's share); ``None`` holds them all
+    experts_held: tuple | None = None
+    expert_dim: int = 512
+    shared_dim: int = 512             # the gated shared expert's width
+    norm_eps: float = 1e-6
+
+    @property
+    def held(self) -> tuple:
+        return self.experts_held or (0, self.experts)
+
+
+class CentredRMSNorm(nn.Module):
+    """RMSNorm whose learned weight is centred on 0: ``x / sqrt(mean(x^2) +
+    eps) * (1 + scale)``, ``scale`` initialised to 0. Float32."""
+
+    epsilon: float = 1e-6
+    dtype: jnp.dtype = jnp.float32
+
+    @nn.compact
+    def __call__(self, x):
+        w = self.param("scale", nn.initializers.zeros, (x.shape[-1],), jnp.float32)
+        x = x.astype(jnp.float32)
+        return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + self.epsilon) * (1.0 + w)
+
+
+class GatedDeltaNet(nn.Module):
+    """The linear-attention sublayer of :class:`NextBlock`: a centred RMSNorm;
+    ``h Wqkvz`` and ``h Wba`` with their columns grouped by key head (per key
+    head ``g``: q, k, the v of value heads ``2g`` and ``2g + 1``, their z; and
+    b then a of those two heads); a causal depthwise convolution of
+    ``z.conv_kernel`` taps with SiLU over the ``[q; k; v]`` channels; q and k
+    L2-normed (q also by ``linear_key_dim ** -0.5``); ``beta = sigmoid(b)``,
+    ``g = -exp(A_log) softplus(a + dt_bias)``; the gated delta rule
+    (``ops.gated_delta``); a gated RMSNorm ``o / rms(o) * w * silu(z)`` a
+    value head with one ``[linear_value_dim]`` weight; the output projection
+    and the residual. A ``mask [B, S]`` (1 = a real token) keeps a padded
+    token from decaying or writing the state."""
+
+    dim: int
+    z: NextDims
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from distkeras_tpu.ops.gated_delta import gated_delta
+
+        z, f32 = self.z, jnp.float32
+        B, S, _ = x.shape
+        Hk, Hv, dk, dv = (z.linear_key_heads, z.linear_value_heads,
+                          z.linear_key_dim, z.linear_value_dim)
+        G = Hv // Hk
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = CentredRMSNorm(z.norm_eps, name="ln")(x).astype(self.dtype)
+        qkvz = dense(Hk * (2 * dk + 2 * G * dv), name="qkvz")(h).reshape(B, S, Hk, -1)
+        ba = dense(2 * Hv, name="ba")(h).reshape(B, S, Hk, 2, G)
+        q, k, v, gate = jnp.split(qkvz, [dk, 2 * dk, 2 * dk + G * dv], axis=-1)
+        conv = self.param("conv", nn.initializers.lecun_normal(),
+                          (z.conv_kernel, Hk * (2 * dk + G * dv)), f32)
+
+        # elementwise passes over [B, S, 8192]-wide arrays: made again in the
+        # backward from their bf16 input rather than kept in float32
+        @jax.checkpoint
+        def conv_qkv(mixed, w):
+            with jax.named_scope("gdn_conv"):
+                mixed = nn.silu(_causal_depthwise(mixed, w.astype(self.dtype)))
+            q, k, v = jnp.split(mixed, [Hk * dk, 2 * Hk * dk], axis=-1)
+            unit = lambda a: a * jax.lax.rsqrt(jnp.sum(a * a, -1, keepdims=True) + 1e-6)
+            q = unit(q.astype(f32).reshape(B, S, Hk, dk)) * dk ** -0.5
+            k = unit(k.astype(f32).reshape(B, S, Hk, dk))
+            return q.astype(self.dtype), k.astype(self.dtype), v.reshape(B, S, Hv, dv)
+
+        @jax.checkpoint
+        def gated_norm(o, gate, w):
+            with jax.named_scope("gdn_norm"):
+                o = o.astype(f32)
+                o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + z.norm_eps) * w
+                o = o * nn.silu(gate.reshape(B, S, Hv, dv).astype(f32))
+            return o.reshape(B, S, Hv * dv).astype(self.dtype)
+
+        q, k, v = conv_qkv(jnp.concatenate([a.reshape(B, S, -1) for a in (q, k, v)], -1), conv)
+        a_log = self.param("A_log", lambda key, s: jnp.log(
+            jax.random.uniform(key, s, f32, 1.0, 16.0)), (Hv,))
+        dt_bias = self.param("dt_bias", nn.initializers.ones, (Hv,), f32)
+        beta = jax.nn.sigmoid(ba[..., 0, :].reshape(B, S, Hv).astype(f32))
+        g = -jnp.exp(a_log) * jax.nn.softplus(
+            ba[..., 1, :].reshape(B, S, Hv).astype(f32) + dt_bias)
+        if mask is not None:
+            keep = jnp.asarray(mask, f32)[..., None]
+            beta, g = beta * keep, g * keep
+        o = gated_delta(q, k, v, g, beta)
+        o = gated_norm(o, gate, self.param("norm", nn.initializers.ones, (dv,), f32))
+        return x + dense(self.dim, name="out")(o).astype(f32)
+
+
+class GatedAttention(nn.Module):
+    """The full-attention sublayer of :class:`NextBlock`: a centred RMSNorm;
+    ``h Wq`` as ``heads`` heads of a query and its output gate, ``z.head_dim``
+    each; ``kv_heads`` key and value heads; a centred RMSNorm over each q and
+    k head (:func:`head_norm_rope` with ``1 + w``), rotary on a head's first
+    ``z.rotary_dim`` columns; causal attention through the flash kernels
+    (query head ``i`` reads key-value head ``i // (heads // kv_heads)``);
+    ``(o * sigmoid(gate)) Wo`` and the residual."""
+
+    dim: int
+    heads: int
+    kv_heads: int
+    z: NextDims
+    dtype: jnp.dtype = jnp.bfloat16
+    attn_impl: str = "reference"
+
+    @nn.compact
+    def __call__(self, x, mask=None):
+        from distkeras_tpu.ops.flash_attention import BLOCK_Q, attention
+
+        z, f32 = self.z, jnp.float32
+        B, S, _ = x.shape
+        H, K, dh = self.heads, self.kv_heads, z.head_dim
+        dense = functools.partial(nn.Dense, use_bias=False, dtype=self.dtype)
+        h = CentredRMSNorm(z.norm_eps, name="ln")(x).astype(self.dtype)
+        q, gate = jnp.split(dense(2 * H * dh, name="q")(h).reshape(B, S, H, 2 * dh), 2, -1)
+        k, v = dense(K * dh, name="k")(h), dense(K * dh, name="v")(h)
+        wq, wk = (self.param(name, nn.initializers.zeros, (dh,), f32)
+                  for name in ("q_norm", "k_norm"))
+        angles = jnp.asarray(rope_angles(S, z.rotary_dim, z.rope_base))
+
+        # made again in the backward from their bf16 inputs rather than kept
+        # in float32, as the linear-attention layers' elementwise passes are
+        @functools.partial(jax.checkpoint, static_argnums=(2,))
+        def norm_rope(a, w, n):
+            return head_norm_rope(a, 1.0 + w, angles, n, z.norm_eps,
+                                  z.rotary_dim).astype(self.dtype)
+
+        @jax.checkpoint
+        def gated(o, gate):
+            o = o.astype(f32) * jax.nn.sigmoid(gate.astype(f32))
+            return o.reshape(B, S, H * dh).astype(self.dtype)
+
+        impl = self.attn_impl
+        if impl == "flash" and S % BLOCK_Q:
+            impl = "reference"
+        o = attention(norm_rope(q.reshape(B, S, -1), wq, H), norm_rope(k, wk, K),
+                      v.reshape(B, S, K, dh), causal=True, key_mask=mask, impl=impl)
+        return x + dense(self.dim, name="out")(gated(o, gate)).astype(f32)
+
+
+class NextBlock(_RoutedBlock):
+    """A Qwen3-Next layer: :class:`GatedDeltaNet` (``gdn``), or in a layer of
+    ``full=True`` :class:`GatedAttention` (``attn``), then
+    :class:`RoutedExperts` behind :func:`_topk_router` with a gated shared
+    expert of ``z.shared_dim``, centred RMSNorms and plain residuals.
+    ``__call__`` takes and returns ``(x, r)``; the router has no state and
+    ``r`` is None.
+
+    Training only: a linear-attention layer's cache is a fixed-size state a
+    value head (and its convolution's last taps), which ``serving/`` does not
+    hold beside paged K/V, and the experts are not on the paged path."""
+
+    full: bool = False
+
+    no_serving = ("Gated DeltaNet's cache is a fixed-size state a value head "
+                  "and a convolution tail, which serving/paged_cache.py does "
+                  "not hold beside paged K/V, and the dropless experts are not "
+                  "on the paged path")
+
+    def setup(self):
+        z = self.z
+        if self.full:
+            self.attn = GatedAttention(self.dim, self.heads, self.kv_heads, z, self.dtype,
+                                       self.attn_impl)
+        else:
+            self.gdn = GatedDeltaNet(self.dim, z, self.dtype)
+        self.moe = RoutedExperts(self.dim, z, self.dtype, router=_topk_router, join=_added,
+                                 shared_dim=z.shared_dim, shared_gate=True,
+                                 norm=CentredRMSNorm)
+
+    def attend(self, x, mask):
+        return self.attn(x, mask) if self.full else self.gdn(x, mask)
+
+
+def _check_next_options(nxt: NextDims, *, quant, attn_window, pos_embedding, others=()):
+    """Raise for an option the hybrid linear-attention block cannot honour."""
+    refused = {
+        "quant=True (quantize_lm knows the dense block's matrices only)": quant,
+        "attn_window (the full-attention layers attend to every earlier position)":
+            attn_window is not None,
+        "pos_embedding other than 'rope' (the block rotates q and k itself)":
+            pos_embedding != "rope",
+        "zaya=, sdar= or mla= as well (a model has one family of block)":
+            any(o is not None for o in others),
+        "an odd rotary_dim, or one wider than head_dim":
+            nxt.rotary_dim % 2 or nxt.rotary_dim > nxt.head_dim,
+        "linear_value_heads that are no multiple of linear_key_heads":
+            nxt.linear_value_heads % nxt.linear_key_heads,
+        "more experts a token than the router has":
+            not 1 <= nxt.experts_per_token <= nxt.experts,
+    }
+    for what, hit in refused.items():
+        if hit:
+            raise ValueError(f"the hybrid linear-attention block cannot honour {what}")
+
+
 def _check_sdar_options(sdar: SdarDims, *, quant, attn_window, pos_embedding,
                         maxlen, zaya=None):
     """Raise for an option the block-diffusion block cannot honour."""
@@ -1243,6 +1478,11 @@ class TransformerLM(nn.Module):
     #: with sigmoid-routed experts and a shared expert): layers of two kinds
     #: in the one list of blocks
     mla: MlaDims | None = None
+    #: a :class:`NextDims` makes every layer a :class:`NextBlock` (Gated
+    #: DeltaNet, or gated full attention in every
+    #: ``full_attention_interval``-th layer, then top-k experts beside a
+    #: gated shared expert): layers of two kinds in the one list of blocks
+    next: NextDims | None = None
 
     def setup(self):
         if self.kv_heads is not None and self.heads % self.kv_heads:
@@ -1261,6 +1501,15 @@ class TransformerLM(nn.Module):
                 f"{self.dim // self.heads}"
             )
         self.embed = nn.Embed(self.vocab, self.dim, dtype=self.dtype)
+        if self.next is not None:
+            _check_next_options(self.next, quant=self.quant, attn_window=self.attn_window,
+                                pos_embedding=self.pos_embedding,
+                                others=(self.zaya, self.sdar, self.mla))
+            every = self.next.full_attention_interval
+            self._setup_routed(NextBlock, self.next,
+                               [dict(full=(i + 1) % every == 0) for i in range(self.depth)],
+                               norm=CentredRMSNorm)
+            return
         if self.mla is not None:
             _check_mla_options(self.mla, quant=self.quant,
                                attn_window=self.attn_window,
@@ -1317,10 +1566,11 @@ class TransformerLM(nn.Module):
             head = QDense if self.quant else nn.Dense
             self.lm_head = head(self.vocab, dtype=self.dtype)
 
-    def _setup_routed(self, block, dims, kinds=None):
+    def _setup_routed(self, block, dims, kinds=None, norm=nn.RMSNorm):
         """Layers of a routed block (:class:`_RoutedBlock`), the head's
-        RMSNorm and, untied, its bias-free head. ``kinds``: a layer's own
-        fields, where the layers are not all of one kind."""
+        RMSNorm (of class ``norm``) and, untied, its bias-free head.
+        ``kinds``: a layer's own fields, where the layers are not all of one
+        kind."""
         block_cls = (nn.remat(block, static_argnums=(4,),
                               policy=ops.remat_policy())
                      if self.remat else block)
@@ -1330,7 +1580,7 @@ class TransformerLM(nn.Module):
                       dtype=self.dtype, attn_impl=self.attn_impl, **kind)
             for kind in kinds or [{}] * self.depth
         ]
-        self.ln_head = nn.RMSNorm(epsilon=dims.norm_eps, dtype=jnp.float32)
+        self.ln_head = norm(epsilon=dims.norm_eps, dtype=jnp.float32)
         if not self.tie_embeddings:
             self.lm_head = nn.Dense(self.vocab, use_bias=False,
                                     dtype=self.dtype)
@@ -1376,7 +1626,7 @@ class TransformerLM(nn.Module):
             return self._routed_hidden(
                 jnp.concatenate([tokens, tokens], axis=1), both,
                 training)[:, tokens.shape[1]:]
-        if self.zaya is not None or self.mla is not None:
+        if self.zaya is not None or self.mla is not None or self.next is not None:
             return self._routed_hidden(tokens, mask, training)
         x = self._embed_at(tokens)
         for blk in self.blocks:
@@ -2266,7 +2516,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
                    pos_embedding="sincos", fused_ce=False,
                    ce_chunk=256, remat=False,
                    tie_embeddings=False, zaya=None, sdar=None,
-                   mla=None) -> ModelSpec:
+                   mla=None, next=None) -> ModelSpec:
     """Causal-LM ModelSpec. Train with ``loss="sparse_softmax_cross_entropy"``
     on ``features=tokens [B, L]`` / ``label=tokens shifted left [B, L]``
     (see :func:`next_token_dataset`); decode with :func:`generate`.
@@ -2326,7 +2576,20 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
     expert) pairs by expert; :func:`moe_tokens` gives ``[expert layers,
     experts]``) and ``.../router_bias``, which every training step moves by
     ``mla.bias_rate`` against the step's own load. The serving entry points
-    raise."""
+    raise.
+    ``next=NextDims(...)`` makes every layer a :class:`NextBlock` (Qwen3-Next:
+    centred RMSNorms; Gated DeltaNet, a linear-attention sublayer whose
+    gated delta rule runs chunk by chunk (``ops.gated_delta``), in three
+    layers of every four, and gated full attention at ``next.head_dim`` with
+    partial rotary through the flash kernels in the fourth; dropless top-k
+    experts behind a linear softmax router beside a gated shared expert;
+    ``pos_embedding="rope"``). It trains like the dense model (next-token
+    loss, with or without ``fused_ce`` and ``remat``); the state holds
+    ``counters/blocks_<i>/moe/moe_tokens`` ((token, expert) pairs by
+    expert). The serving entry points raise."""
+    if next is not None:
+        _check_next_options(next, quant=False, attn_window=attn_window,
+                            pos_embedding=pos_embedding, others=(zaya, sdar, mla))
     if mla is not None:
         _check_mla_options(mla, quant=False, attn_window=attn_window,
                            pos_embedding=pos_embedding, depth=depth,
@@ -2348,7 +2611,7 @@ def transformer_lm(vocab=1024, maxlen=256, dim=128, heads=4, depth=2,
         vocab=vocab, maxlen=maxlen, dim=dim, heads=heads, depth=depth,
         dtype=dtype, attn_impl=attn_impl, attn_window=attn_window,
         kv_heads=kv_heads, pos_embedding=pos_embedding, remat=remat,
-        tie_embeddings=tie_embeddings, zaya=zaya, sdar=sdar, mla=mla,
+        tie_embeddings=tie_embeddings, zaya=zaya, sdar=sdar, mla=mla, next=next,
     )
     example = jnp.zeros((1, maxlen), jnp.int32)
     spec = from_flax(module, example, name="transformer_lm",

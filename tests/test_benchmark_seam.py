@@ -19,13 +19,13 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark import (checks, harness, loader, weights, weights_kanana, weights_sdar,
-                       weights_zaya)
-from benchmark.drivers import serve, train, train_bd, train_kanana, train_moe
+from benchmark import (checks, harness, loader, weights, weights_kanana, weights_next,
+                       weights_sdar, weights_zaya)
+from benchmark.drivers import serve, train, train_bd, train_kanana, train_moe, train_next
 
 DATA = os.path.join(loader.ROOT, "benchmark", "tests", "data")
 DRIVERS = {"train": train, "train_moe": train_moe, "train_bd": train_bd,
-           "train_kanana": train_kanana, "serve": serve}
+           "train_kanana": train_kanana, "train_next": train_next, "serve": serve}
 
 
 def _json(*parts):
@@ -43,14 +43,16 @@ def _leaves(tree) -> dict:
 
 @pytest.mark.parametrize("config, traffic", [
     ("xglm-564m", "train"), ("starcoder2-3b", "serve.closed"), ("zaya1-8b", "train.moe4k"),
-    ("sdar-30b-a3b", "train.bd4k"), ("kanana-2-30b-a3b", "train.mla8k")])
+    ("sdar-30b-a3b", "train.bd4k"), ("kanana-2-30b-a3b", "train.mla8k"),
+    ("qwen3-next-80b-a3b", "train.gdn4k")])
 def test_the_model_initialises_to_the_tree_the_benchmark_makes_by_name(config, traffic):
     """The committed configuration at full size under the options of the job
     file that runs it; ``jax.eval_shape`` on both sides, so nothing is made."""
     m, job = _json("configs", config + ".json")["model"], _json("traffic", traffic + ".json")
     options = {k: job[k] for k in ("attn_impl", "fused_ce", "ce_chunk", "remat") if k in job}
     routed = {"zaya": (train_moe, weights_zaya), "sdar": (train_bd, weights_sdar),
-              "mla": (train_kanana, weights_kanana)}.get(m.get("block"))
+              "mla": (train_kanana, weights_kanana),
+              "next": (train_next, weights_next)}.get(m.get("block"))
     spec = (routed[0].program_lm if routed else harness.program_lm)(m, **options)
     got = jax.eval_shape(spec.init, jax.random.PRNGKey(0))
     key = jax.eval_shape(lambda: weights.seed_key(2 ** 31 + 29))
@@ -130,6 +132,7 @@ def runs(tmp_path_factory):
              "tiny-train-moe": ("tiny-zaya.tiny-train-moe", "BENCHMARK.zaya.json", 1.0),
              "tiny-train-bd": ("tiny-sdar.tiny-train-bd", "BENCHMARK.sdar.json", 1.0),
              "tiny-train-mla": ("tiny-kanana.tiny-train-mla", "BENCHMARK.kanana.json", 1.0),
+             "tiny-train-gdn": ("tiny-next.tiny-train-gdn", "BENCHMARK.next.json", 1.0),
              "tiny-serve": ("tiny-rope-gqa.tiny-serve", "BENCHMARK.json", 2.0)}
     made = {}
 
@@ -151,7 +154,7 @@ def runs(tmp_path_factory):
 
 
 @pytest.mark.parametrize("traffic", ["tiny-train", "tiny-train-moe", "tiny-train-bd",
-                                     "tiny-train-mla"])
+                                     "tiny-train-mla", "tiny-train-gdn"])
 def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
     loaded, facts, _ = runs(traffic)
     job = loaded["traffic"]
@@ -162,7 +165,7 @@ def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
         facts["window"]["steps"] * job["batch_size"] * job["seq_len"]), where
     assert facts["attempted"] == job["warmup_steps"] + facts["window"]["steps"], where
     assert facts["end_to_end"]["train_tokens_per_s"] > 0 < facts["end_to_end"]["setup_s"], where
-    if job["driver"] in ("train_moe", "train_bd", "train_kanana"):
+    if job["driver"] in ("train_moe", "train_bd", "train_kanana", "train_next"):
         m = loaded["config"]["model"]
         routes = "route_gap" if job["driver"] == "train_moe" else "route_count_gap"
         assert routes in facts["checks"], where
@@ -171,7 +174,8 @@ def test_the_train_drivers_run_their_window_and_report_correct(runs, traffic):
         # its stream and a position sends experts_per_token pairs; a model
         # with leading dense layers counts in its expert layers only
         pairs = {"train_moe": 1, "train_bd": 2 * m.get("experts_per_token", 1),
-                 "train_kanana": m.get("experts_per_token", 1)}[job["driver"]]
+                 "train_kanana": m.get("experts_per_token", 1),
+                 "train_next": m.get("experts_per_token", 1)}[job["driver"]]
         layers = m["depth"] - m.get("dense_layers", 0)
         assert np.sum(facts["moe"]["window_tokens"]) == (
             facts["window"]["tokens"] * layers * pairs), (
@@ -210,13 +214,18 @@ NAMES = [("jit_serve_decode_greedy", "tiny-serve", "serve_decode_greedy"),
          ("mla_latent", "tiny-train-mla", "train_step"),
          ("moe_shared", "tiny-train-mla", "train_step"),
          ("moe_bias", "tiny-train-mla", "train_step"),
-         ("moe_experts", "tiny-train-mla", "train_step")]
+         ("moe_experts", "tiny-train-mla", "train_step"),
+         ("gdn", "tiny-train-gdn", "train_step"),
+         ("gated_delta", "tiny-train-gdn", "train_step"),
+         ("gated_delta_bwd", "tiny-train-gdn", "train_step"),
+         ("moe_shared", "tiny-train-gdn", "train_step")]
 # what benchmark/parts.py's readers ask the program's table of its step for
 # (observability.programs.op_scopes), by block type: the loss's two scopes, the
 # scope round the optimizer, the attention sublayer's component under a block
 # (a method of the dense block, a module of the others), remat's marker
 TRAIN_CELLS = {"tiny-train": "blocks_0._attn_full/", "tiny-train-moe": "blocks_0.attend/cca/",
-               "tiny-train-bd": "blocks_0.attend/attn/", "tiny-train-mla": "blocks_0.attend/attn/"}
+               "tiny-train-bd": "blocks_0.attend/attn/", "tiny-train-mla": "blocks_0.attend/attn/",
+               "tiny-train-gdn": "blocks_3.attend/attn/"}
 PARTS = {"fused_ce_fwd": "loss_ms.train", "fused_ce_bwd": "loss_ms.train",
          "/optimizer/": "step_scoped_pct.train", "rematted_computation": "remat_forward_ms.train",
          "moe_route": "moe_route_ms.train"}
@@ -225,7 +234,13 @@ NAMES += [(name, traffic, "train_step") for traffic, attention in TRAIN_CELLS.it
           if (name, traffic, "train_step") not in NAMES
           and not (name == "moe_route" and traffic == "tiny-train")]
 READ_BY = {"jit_serve_decode_greedy": "benchmark/metrics/decode_roofline.py finds the decode "
-                                      "program by name"}
+                                      "program by name",
+           "gdn": "benchmark/metrics/gdn_ms.train.py finds the Gated DeltaNet sublayer by this "
+                  "component of its operations' op_name (benchmark/parts.py)",
+           "gated_delta": "benchmark/metrics/gated_delta_roofline.py finds the delta rule by "
+                          "this scope (benchmark/parts.py)",
+           "gated_delta_bwd": "benchmark/metrics/gated_delta_roofline.py counts the rule's "
+                              "written-out backward by this scope"}
 READ_BY.update({name: f"benchmark/metrics/{metric}.py finds the step's operations by this "
                       f"component of their op_name (benchmark/parts.py)"
                 for name, metric in PARTS.items()})

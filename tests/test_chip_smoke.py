@@ -27,6 +27,7 @@ def test_kernels_phase_interpret_mode():
                (1, 256, 2, (48, 32), 2)),
         prep=((2, 256, 2, 128), (1, 128, 1, 128)), wide=(1, 256, 2, 48, 32),
         latent=((1, 256, 2, 128, 64, 128),),
+        delta=((1, 128, 2, 4, 16, 16),), delta_error=(1, 96, 2, 4, 16, 16),
     )
     assert line["phase"] == "kernels" and line["interpret"] is True
     assert set(line["norm_err"]) >= {"flash.out", "flash.dq", "lstm.dwh",
@@ -77,12 +78,22 @@ def test_kernels_phase_interpret_mode():
     assert lkv["chain_ms"] is None
     assert {"mla_prep2.q", "mla_prep2.dq", "mla_prep2.k", "mla_prep2.v",
             "mla_prep2.dkv", "mla_prep2.dk_rope"} <= set(line["norm_err"])
+    # the chunked gated delta rule: its floor beside (off the chip) no device
+    # time, and its error against the per-token recurrence at bf16 inputs
+    delta, = line["gated_delta"]
+    assert delta["shape"] == [1, 128, 2, 4, 16, 16]
+    assert delta["fwd"]["ms"] is None and delta["fwd"]["floor_flops"] == 6 * 16 * 16 * 128 * 4
+    assert delta["fwd_bwd"]["floor_flops"] == (6 + 14) * 16 * 16 * 128 * 4
+    assert delta["fwd"]["floor_bytes"] == 128 * (2 * 2 * 16 * 2 + 2 * 4 * 16 * 2 + 2 * 4 * 4)
+    errors = {k: v for k, v in line["norm_err"].items() if k.startswith("gated_delta.")}
+    assert set(errors) == {f"gated_delta.{p}" for p in ("out", "dq", "dk", "dv", "dg", "dbeta")}
+    assert max(errors.values()) < 2e-2, errors
 
 
 def test_kernels_phase_compares_the_block_diffusion_mask():
     line = chip_smoke.kernels(attn=(1, 256, 2, 64), qmm=((8, 128, 128),), adam=(8, 16),
                               lstm=(8, 2, 128), interpret=True, timed=(), prep=(), wide=(),
-                              latent=())
+                              latent=(), delta=())
     assert {"flash_bd.out", "flash_bd.dq", "flash_bd.dk",
             "flash_bd.dv"} <= set(line["norm_err"])
 
@@ -100,7 +111,7 @@ def test_kernels_phase_fails_on_a_wrong_kernel(monkeypatch):
     with pytest.raises(chip_smoke.SmokeFailure, match="off its reference"):
         chip_smoke.kernels(attn=(1, 128, 1, 64), qmm=((8, 128, 128),),
                            adam=(8, 16), lstm=(8, 2, 128), interpret=True,
-                           timed=(), prep=(), wide=(), latent=())
+                           timed=(), prep=(), wide=(), latent=(), delta=())
 
 
 def test_loss_phase_tiny():

@@ -580,7 +580,7 @@ def test_the_cell_finds_every_file():
     for m in loaded["per_layer"]:
         assert callable(loader.load_reader(m["reader"]))
     bench = loader.load_benchmark()
-    assert len(bench["workloads"]) == 4 and all(w["chips"] == 1 for w in bench["workloads"])
+    assert len(bench["workloads"]) == 5 and all(w["chips"] == 1 for w in bench["workloads"])
     assert all(len(e["why"]) <= 200 for e in bench["workloads"] + bench["configs"])
     for other in ("xglm-564m.train", "zaya1-8b.train", "sdar-30b-a3b.train"):
         theirs = {m["name"] for m in loader.load_cell(other)["per_layer"]}

@@ -173,6 +173,10 @@ KERNELS = {
     # (128 with no position + 64 rotary), values 128, 32 heads, causal
     "flash-fwdbwd-causal-qk192-v128-4x8192x32":
         lambda: _flash((4, 8192, 32, 192), v_dim=128),
+    # qwen3-next-80b-a3b.train's call: 16 query heads of 256 over 2
+    # key-value heads, causal: the widest head any cell runs
+    "flash-fwdbwd-causal-gqa-kv2-8x4096x16x256":
+        lambda: _flash((8, 4096, 16, 256), kv_heads=2),
     # and with a key mask, at tiles of 128 x 384 (rows of 384)
     "flash-fwdbwd-blockdiffusion8-keymask-2x768x4x128":
         lambda: _flash((2, 768, 4, 128), causal=False, masked=True,
@@ -236,6 +240,8 @@ NAMED = {
                                               "flash_dkv"),
     "flash-fwdbwd-causal-gqa-kv2-8x4096x8x128": ("flash_fwd", "flash_dq",
                                                  "flash_dkv"),
+    "flash-fwdbwd-causal-gqa-kv2-8x4096x16x256": ("flash_fwd", "flash_dq",
+                                                  "flash_dkv"),
     "flash-fwdbwd-blockdiffusion4-gqa-kv4-4x8192x32x128": (
         "flash_fwd", "flash_dq", "flash_dkv"),
     "qk_prep-fwdbwd-q-4x8192x32x128": ("qk_prep_fwd", "qk_prep_bwd"),
