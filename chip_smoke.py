@@ -305,19 +305,29 @@ def _mla_prep_times(shapes, interpret, reps=3) -> list:
 
 
 def _gated_delta_times(shapes, interpret, error_shape, reps=3) -> tuple:
-    """``ops.gated_delta`` (XLA, chunks of 64) at each ``(B, T, Hk, Hv, dk,
-    dv)`` of ``shapes`` (bf16 q, k, v; float32 decay and write strength; the
-    linear-attention cell's call by default): ms a call forward and forward +
-    backward on the chip (None in interpret mode), with the GB/s and TFLOP/s
-    that ``gated_delta_roofline``'s floor (``benchmark/flops_next.py``) comes
-    to; and at ``error_shape`` the normalized error of its result and of its
-    five gradients against the per-token recurrence
-    (``gated_delta_tokens``, float32). Returns ``(entries, errors)``."""
+    """``ops.gated_delta`` (chunks of 64) with its forward in the
+    ``gated_delta_fwd`` kernel (``"pallas"``) and in XLA (``"xla"``) side by
+    side, at each ``(B, T, Hk, Hv, dk, dv)`` of ``shapes`` (bf16 q, k, v;
+    float32 decay and write strength; the linear-attention cell's call by
+    default): what ``kernel_impl("gated_delta", "auto", …)`` answers there,
+    and of each form ms a call forward and forward + backward on the chip
+    (None in interpret mode), with the GB/s and TFLOP/s that
+    ``gated_delta_roofline``'s floor (``benchmark/flops_next.py``) comes to;
+    and at ``error_shape`` the normalized error of each form's result and of
+    its five gradients against the per-token recurrence
+    (``gated_delta_tokens``, float32). Returns ``(entries, errors)``, the
+    errors by ``"<form>.<part>"``."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
     from benchmark import flops_next
-    from distkeras_tpu.ops.gated_delta import gated_delta, gated_delta_tokens
+    from distkeras_tpu.ops import kernel_impl
+    from distkeras_tpu.ops.gated_delta import gated_delta, gated_delta_kernel, gated_delta_tokens
+
+    forms = {"pallas": functools.partial(gated_delta_kernel, interpret=interpret),
+             "xla": functools.partial(gated_delta, impl="xla")}
 
     def inputs(B, T, Hk, Hv, dk, dv):
         ks = jax.random.split(jax.random.PRNGKey(SEED + Hv), 7)
@@ -337,27 +347,32 @@ def _gated_delta_times(shapes, interpret, error_shape, reps=3) -> tuple:
         return jax.jit(run)
 
     x, do = inputs(*error_shape)
-    got, want = fwd_bwd(gated_delta)(x, do), fwd_bwd(gated_delta_tokens)(x, do)
-    errors = {part: _norm_err(a, b) for part, a, b in zip(
-        ("out", "dq", "dk", "dv", "dg", "dbeta"), got, want)}
+    want = fwd_bwd(gated_delta_tokens)(x, do)
+    errors = {f"{form}.{part}": _norm_err(a, b)
+              for form, fn in forms.items()
+              for part, a, b in zip(("out", "dq", "dk", "dv", "dg", "dbeta"),
+                                    fwd_bwd(fn)(x, do), want)}
     out = []
     for B, T, Hk, Hv, dk, dv in shapes:
         m = dict(linear_key_heads=Hk, linear_value_heads=Hv, linear_key_dim=dk,
                  linear_value_dim=dv)
-        entry = {"shape": [B, T, Hk, Hv, dk, dv]}
+        entry = {"shape": [B, T, Hk, Hv, dk, dv],
+                 "auto": kernel_impl("gated_delta", T=T, dk=dk, dv=dv, Hk=Hk, Hv=Hv)}
         x, do = inputs(B, T, Hk, Hv, dk, dv)
-        fwd, both = jax.jit(gated_delta), fwd_bwd(gated_delta)
-        jax.block_until_ready((fwd(*x), both(x, do)))                  # compiles
-        for part, run, kinds in (("fwd", lambda: fwd(*x), ("fwd",)),
-                                 ("fwd_bwd", lambda: both(x, do), ("fwd", "bwd"))):
-            ms = None if interpret else round(sum(_device_ms_by_op(
-                lambda: jax.block_until_ready([run() for _ in range(reps)]),
-                reps).values()), 4)
-            flops = sum(flops_next.gated_delta_call_flops(m, k, B, T) for k in kinds)
-            moved = sum(flops_next.gated_delta_call_bytes(m, k, B, T) for k in kinds)
-            entry[part] = {"ms": ms, "floor_flops": flops, "floor_bytes": moved,
-                           "tflop_per_s": ms and round(flops / ms / 1e9, 2),
-                           "gb_per_s": ms and round(moved / ms / 1e6, 1)}
+        for form, fn in forms.items():
+            fwd, both = jax.jit(fn), fwd_bwd(fn)
+            jax.block_until_ready((fwd(*x), both(x, do)))              # compiles
+            entry[form] = {}
+            for part, run, kinds in (("fwd", lambda: fwd(*x), ("fwd",)),
+                                     ("fwd_bwd", lambda: both(x, do), ("fwd", "bwd"))):
+                ms = None if interpret else round(sum(_device_ms_by_op(
+                    lambda: jax.block_until_ready([run() for _ in range(reps)]),
+                    reps).values()), 4)
+                flops = sum(flops_next.gated_delta_call_flops(m, k, B, T) for k in kinds)
+                moved = sum(flops_next.gated_delta_call_bytes(m, k, B, T) for k in kinds)
+                entry[form][part] = {"ms": ms, "floor_flops": flops, "floor_bytes": moved,
+                                     "tflop_per_s": ms and round(flops / ms / 1e9, 2),
+                                     "gb_per_s": ms and round(moved / ms / 1e6, 1)}
         out.append(entry)
     return out, errors
 
@@ -389,8 +404,9 @@ def kernels(*, attn=(8, 2048, 8, 128),
     latent-attention cell's projections ``(B, S, heads, nope, rope, v)``,
     beside the chain it replaces (:func:`_mla_prep_times`), and the chunked
     gated delta rule at ``delta``, the linear-attention cell's ``(B, T, Hk,
-    Hv, dk, dv)``, with its error against the per-token recurrence at
-    ``delta_error`` (:func:`_gated_delta_times`)."""
+    Hv, dk, dv)``, its forward's kernel beside the XLA form, with the error of
+    each against the per-token recurrence at ``delta_error``
+    (:func:`_gated_delta_times`)."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -504,6 +520,9 @@ def kernels(*, attn=(8, 2048, 8, 128),
         **({"mla_prep": kernel_impl("mla_prep", **dict(zip(
             ("S", "heads", "nope", "rope", "v"), latent[0][1:])))}
            if latent else {}),
+        **({"gated_delta": kernel_impl("gated_delta", **dict(zip(
+            ("T", "Hk", "Hv", "dk", "dv"), delta[0][1:])))}
+           if delta else {}),
     }
     preps = _qk_prep_times(prep, interpret)
     for entry in preps:

@@ -75,15 +75,16 @@ def kernel_mesh(mesh, batch_axis: str):
         _KERNEL_MESH.reset(token)
 
 
-def on_each_device(kernel, *arrays, whole=()):
+def on_each_device(kernel, *arrays, whole=(), out_dims=None):
     """``kernel(*arrays, *whole)`` for a kernel that is independent across
     dim 0 of every one of ``arrays`` (``None`` entries pass through) and of
-    its results: under :func:`kernel_mesh`, a ``shard_map`` in which each
-    device runs it on its own rows — split on the batch axis, whole along
-    every other mesh axis — and on all of every array in ``whole`` (a
-    weight, a table); with no mesh declared, on a one-device mesh, or inside
-    a region that is already manual (a strategy's own ``shard_map``), the
-    plain call."""
+    its results (or, for a kernel of several results, across the dim of each
+    that ``out_dims`` names): under :func:`kernel_mesh`, a ``shard_map`` in
+    which each device runs it on its own rows — split on the batch axis,
+    whole along every other mesh axis — and on all of every array in
+    ``whole`` (a weight, a table); with no mesh declared, on a one-device
+    mesh, or inside a region that is already manual (a strategy's own
+    ``shard_map``), the plain call."""
     import jax
     from jax.sharding import PartitionSpec as P
 
@@ -93,12 +94,15 @@ def on_each_device(kernel, *arrays, whole=()):
         return kernel(*arrays, *whole)
     mesh, batch_axis = declared
     # a mesh without that axis (tp only): every device runs the whole batch
-    rows = P(batch_axis if batch_axis in mesh.axis_names else None)
+    axis = batch_axis if batch_axis in mesh.axis_names else None
+    rows = P(axis)
     return jax.shard_map(
         kernel, mesh=mesh,
         in_specs=tuple(None if a is None else rows for a in arrays)
         + (P(),) * len(whole),
-        out_specs=rows, check_vma=False,
+        out_specs=rows if out_dims is None
+        else tuple(P(*(None,) * d, axis) for d in out_dims),
+        check_vma=False,
     )(*arrays, *whole)
 
 
@@ -125,6 +129,7 @@ def kernel_impl(op: str, impl: str = "auto", **dims) -> str:
         "q_matmul": ("quant", "q_matmul_impl"),
         "qk_prep": ("qk_prep", "qk_prep_impl"),
         "mla_prep": ("mla_prep", "mla_prep_impl"),
+        "gated_delta": ("gated_delta", "gated_delta_impl"),
     }
     if op not in resolvers:
         raise ValueError(f"unknown op {op!r}; one of {sorted(resolvers)}")

@@ -56,7 +56,7 @@ def test_kernels_phase_interpret_mode():
     # the phase reports it, and only a native run insists on the kernels
     assert line["auto"] == {"attention": "reference", "lstm_scan": "xla",
                             "q_matmul": "pallas", "qk_prep": "xla",
-                            "mla_prep": "xla"}
+                            "mla_prep": "xla", "gated_delta": "xla"}
     # qk_prep at a q and a k projection's shape: its error against the jnp
     # chain beside (off the chip) no device time
     q, k = line["qk_prep"]
@@ -78,15 +78,19 @@ def test_kernels_phase_interpret_mode():
     assert lkv["chain_ms"] is None
     assert {"mla_prep2.q", "mla_prep2.dq", "mla_prep2.k", "mla_prep2.v",
             "mla_prep2.dkv", "mla_prep2.dk_rope"} <= set(line["norm_err"])
-    # the chunked gated delta rule: its floor beside (off the chip) no device
-    # time, and its error against the per-token recurrence at bf16 inputs
+    # the chunked gated delta rule, its forward's kernel beside the XLA form:
+    # the floor beside (off the chip) no device time, and the error of each
+    # against the per-token recurrence at bf16 inputs
     delta, = line["gated_delta"]
-    assert delta["shape"] == [1, 128, 2, 4, 16, 16]
-    assert delta["fwd"]["ms"] is None and delta["fwd"]["floor_flops"] == 6 * 16 * 16 * 128 * 4
-    assert delta["fwd_bwd"]["floor_flops"] == (6 + 14) * 16 * 16 * 128 * 4
-    assert delta["fwd"]["floor_bytes"] == 128 * (2 * 2 * 16 * 2 + 2 * 4 * 16 * 2 + 2 * 4 * 4)
+    assert delta["shape"] == [1, 128, 2, 4, 16, 16] and delta["auto"] == "xla"
+    for form in ("pallas", "xla"):
+        assert delta[form]["fwd"]["ms"] is None
+        assert delta[form]["fwd"]["floor_flops"] == 6 * 16 * 16 * 128 * 4
+        assert delta[form]["fwd_bwd"]["floor_flops"] == (6 + 14) * 16 * 16 * 128 * 4
+        assert delta[form]["fwd"]["floor_bytes"] == 128 * (2 * 2 * 16 * 2 + 2 * 4 * 16 * 2 + 2 * 4 * 4)
     errors = {k: v for k, v in line["norm_err"].items() if k.startswith("gated_delta.")}
-    assert set(errors) == {f"gated_delta.{p}" for p in ("out", "dq", "dk", "dv", "dg", "dbeta")}
+    assert set(errors) == {f"gated_delta.{form}.{p}" for form in ("pallas", "xla")
+                           for p in ("out", "dq", "dk", "dv", "dg", "dbeta")}
     assert max(errors.values()) < 2e-2, errors
 
 

@@ -31,7 +31,9 @@ from benchmark.drivers.train_next import program_lm
 from distkeras_tpu.models.lm import (GatedAttention, GatedDeltaNet, NextDims, RoutedExperts,
                                      _added, _topk_router, CentredRMSNorm, moe_tokens,
                                      transformer_lm)
-from distkeras_tpu.ops.gated_delta import gated_delta, gated_delta_tokens, unit_lower_inverse
+from distkeras_tpu.ops import kernel_impl
+from distkeras_tpu.ops.gated_delta import (gated_delta, gated_delta_kernel, gated_delta_tokens,
+                                           unit_lower_inverse)
 from tests.test_kanana import CATALOG
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -87,16 +89,26 @@ def _rule_inputs(decay):
     return tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g, beta))
 
 
+def _rule(impl, chunk=64):
+    """The chunked rule with its forward in XLA (``"xla"``) or in the
+    ``gated_delta_fwd`` kernel (``"kernel"``, in the interpreter here: at any
+    shape); the backward is XLA's either way."""
+    if impl == "xla":
+        return functools.partial(gated_delta, chunk=chunk, impl="xla")
+    return functools.partial(gated_delta_kernel, chunk=chunk, interpret=True)
+
+
+@pytest.mark.parametrize("impl", ["xla", "kernel"])
 @pytest.mark.parametrize("chunk", [16, 64])
 @pytest.mark.parametrize("decay", ["none", "steep", "mixed"])
-def test_the_chunked_rule_is_the_per_token_recurrence(chunk, decay):
+def test_the_chunked_rule_is_the_per_token_recurrence(chunk, decay, impl):
     """Values and all five gradients; 96 tokens are one chunk and a half of
     64 (the tail padded with tokens that neither decay nor write). At -16 a
     token a chunk's Γ reaches -1024: exp(-Γ) would be inf, every decay here
     is exp of a masked difference."""
     x = _rule_inputs(decay)
     probe = jnp.asarray(np.random.default_rng(2).normal(size=(2, 96, 4, 8)), jnp.float32)
-    got, pull = jax.vjp(functools.partial(gated_delta, chunk=chunk), *x)
+    got, pull = jax.vjp(_rule(impl, chunk), *x)
     want, pull_ref = jax.vjp(gated_delta_tokens, *x)
     assert np.all(np.isfinite(np.asarray(got)))
     assert _err(got, want) < 2e-5
@@ -104,6 +116,119 @@ def test_the_chunked_rule_is_the_per_token_recurrence(chunk, decay):
     # (the decay's, where every token decays by e^-16)
     for a, b in zip(pull(probe), pull_ref(probe)):
         assert np.all(np.isfinite(np.asarray(a))) and _err(a, b, 1e-2) < 5e-5
+
+
+@functools.lru_cache(maxsize=None)
+def _at_the_kernels_shape():
+    """``(o, dq, dk, dv, dg, dbeta)`` of the kernel's forward (through
+    ``gated_delta(impl="pallas")``), the XLA form and the per-token rule at a
+    shape the kernel takes on the chip (key and value heads of 128, two value
+    heads a key head): 600 tokens are nine chunks of 64 and a tail of 24, three
+    grid steps of four chunks of which the third is mostly padding; a fifth of
+    the tokens neither decay nor write (``g`` and ``beta`` 0, as a mask makes
+    them)."""
+    B, T, Hk, Hv, d = 1, 600, 2, 4, 128
+    r = np.random.default_rng(5)
+    unit = lambda a: a / np.linalg.norm(a, axis=-1, keepdims=True)
+    q = unit(r.normal(size=(B, T, Hk, d))) * d ** -0.5
+    k = unit(r.normal(size=(B, T, Hk, d)))
+    v = r.normal(size=(B, T, Hv, d))
+    g = -r.uniform(1, 16, Hv) * np.log1p(np.exp(r.normal(size=(B, T, Hv)) + 1)) / 8
+    beta = 1 / (1 + np.exp(-r.normal(size=(B, T, Hv))))
+    kept = r.uniform(size=(B, T, 1)) > 0.2
+    x = tuple(jnp.asarray(a, jnp.float32) for a in (q, k, v, g * kept, beta * kept))
+    probe = jnp.asarray(r.normal(size=(B, T, Hv, d)), jnp.float32)
+    out = {}
+    for name, fn in (("kernel", functools.partial(gated_delta, impl="pallas", interpret=True)),
+                     ("xla", _rule("xla")), ("tokens", gated_delta_tokens)):
+        o, pull = jax.vjp(fn, *x)
+        out[name] = (o,) + tuple(pull(probe))
+    return out
+
+
+@pytest.mark.parametrize("against", ["tokens", "xla"])
+def test_the_kernels_forward_is_the_recurrence_and_the_xla_form(against):
+    got = _at_the_kernels_shape()
+    assert np.all(np.isfinite(np.asarray(got["kernel"][0])))
+    assert _err(got["kernel"][0], got[against][0]) < 2e-5
+
+
+@pytest.mark.parametrize("against", ["tokens", "xla"])
+@pytest.mark.parametrize("part", ["dq", "dk", "dv", "dg", "dbeta"])
+def test_the_gradients_through_the_kernels_forward(part, against):
+    """The XLA backward fed by the kernel's ``starts``: each gradient against
+    its own scale, or a hundredth where it is all but 0."""
+    i = ("dq", "dk", "dv", "dg", "dbeta").index(part) + 1
+    got = _at_the_kernels_shape()
+    assert _err(got["kernel"][i], got[against][i], 1e-2) < 5e-5
+
+
+def test_the_kernels_tiling_does_not_change_its_result():
+    """A grid step of one key head and one block of 128 tokens gives what the
+    default step (both key heads, two blocks) gives, ``o`` and the state at
+    each chunk's start alike: the (block, value head)s a step takes side by
+    side are independent, and the state is handed over across steps."""
+    from distkeras_tpu.ops.gated_delta import _kernel_fwd
+
+    B, T, Hk, Hv, d = 1, 600, 2, 4, 128
+    r = np.random.default_rng(7)
+    x = tuple(jnp.asarray(a, jnp.float32) for a in (
+        r.normal(size=(B, T, Hk, d)) / d, r.normal(size=(B, T, Hk, d)) / d ** 0.5,
+        r.normal(size=(B, T, Hv, d)), -r.uniform(0, 1, (B, T, Hv)), r.uniform(0, 1, (B, T, Hv))))
+    wide = _kernel_fwd(*x, chunk=64, interpret=True)
+    narrow = _kernel_fwd(*x, chunk=64, interpret=True, tokens=128, key_heads=1)
+    assert wide[1].shape == (10, B, Hv, d, d)
+    for a, b in zip(wide, narrow):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+CELL = dict(T=4096, dk=128, dv=128, Hk=16, Hv=32)
+
+
+@pytest.mark.parametrize("impl, dims, want", [
+    ("pallas", CELL, "pallas"),                                  # the cell's call
+    ("pallas", dict(CELL, dk=64), "xla"),                        # a key head of 64
+    ("pallas", dict(CELL, dv=192), "xla"),                       # no multiple of 128
+    ("pallas", dict(CELL, Hk=3), "xla"),                         # 32 value heads over 3
+    ("pallas", dict(CELL, dtype="float16"), "xla"),
+    ("pallas", dict(T=128, dk=16, dv=16, Hk=2, Hv=4, dtype="float32"), "xla"),  # the tiny one
+    ("xla", CELL, "xla"),
+    ("auto", CELL, "xla"),                                       # no chip here
+])
+def test_the_dispatch_takes_the_kernel_where_its_tiles_fit(impl, dims, want):
+    assert kernel_impl("gated_delta", impl, **dims) == want
+
+
+def test_the_kernel_runs_per_device_under_a_declared_mesh():
+    """Inside a jit over several chips each device runs the forward kernel on
+    its own batch rows (``o`` split on dim 0, the starts on dim 1), and the
+    backward reads them so: value and gradients are the XLA form's."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from distkeras_tpu.ops import kernel_mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    B, T, Hk, Hv, d = 2, 128, 1, 2, 128
+    r = np.random.default_rng(6)
+    x = tuple(jnp.asarray(a, jnp.float32) for a in (
+        r.normal(size=(B, T, Hk, d)) / d, r.normal(size=(B, T, Hk, d)) / d ** 0.5,
+        r.normal(size=(B, T, Hv, d)), -r.uniform(0, 1, (B, T, Hv)), r.uniform(0, 1, (B, T, Hv))))
+    probe = jnp.asarray(r.normal(size=(B, T, Hv, d)), jnp.float32)
+
+    def loss(fn, *x):
+        return jnp.sum(fn(*x) * probe)
+
+    def declared(*x):
+        with kernel_mesh(mesh, "dp"):
+            return jax.value_and_grad(functools.partial(loss, _rule("kernel")),
+                                      (0, 1, 2, 3, 4))(*x)
+
+    rows = NamedSharding(mesh, P("dp"))
+    got = jax.jit(declared)(*(jax.device_put(a, rows) for a in x))
+    want = jax.value_and_grad(functools.partial(loss, _rule("xla")), (0, 1, 2, 3, 4))(*x)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert _err(a, b, 1e-2) < 5e-5
+    assert str(jax.make_jaxpr(declared)(*x)).count("shard_map") >= 1
 
 
 def test_the_triangular_inverse_doubles_to_the_whole_chunk():

@@ -113,6 +113,21 @@ def _mla_prep(B, S, heads, nope=128, rope=64, v=128):
                      ((B * heads, S, v), BF16)]
 
 
+def _gated_delta(B, T, Hk, Hv, d=128):
+    """(fn, argument shapes): the gated delta rule with its forward in the
+    ``gated_delta_fwd`` kernel and its five gradients (the backward XLA's)."""
+    from distkeras_tpu.ops.gated_delta import gated_delta_kernel
+
+    def fwd_bwd(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(lambda *x: gated_delta_kernel(*x, interpret=False),
+                           q, k, v, g, beta)
+        return (out,) + vjp(do)
+
+    return fwd_bwd, [((B, T, Hk, d), BF16), ((B, T, Hk, d), BF16),
+                     ((B, T, Hv, d), BF16), ((B, T, Hv), F32), ((B, T, Hv), F32),
+                     ((B, T, Hv, d), BF16)]
+
+
 def _lstm(B, T, H, workers=None):
     from distkeras_tpu.ops.recurrent import lstm_scan
 
@@ -203,6 +218,9 @@ KERNELS = {
     "mla_prep-fwdbwd-4x8192x32x128+64x128": lambda: _mla_prep(4, 8192, 32),
     "mla_prep-fwdbwd-2x384x6x256+64x128":
         lambda: _mla_prep(2, 384, 6, nope=256),
+    # qwen3-next-80b-a3b.train's gated delta rule: 8 rows of 4096 tokens,
+    # 16 key heads over 32 value heads of 128, forward and backward
+    "gated_delta-fwdbwd-8x4096x16x32x128": lambda: _gated_delta(8, 4096, 16, 32),
     # fused LSTM scan: the IMDB config's batches, the stacked-worker vmap,
     # and chip_smoke's shape
     **{f"lstm-fwdbwd-T200-H128-B{b}": (lambda b=b: _lstm(b, 200, 128))
@@ -247,6 +265,7 @@ NAMED = {
     "qk_prep-fwdbwd-q-4x8192x32x128": ("qk_prep_fwd", "qk_prep_bwd"),
     "qk_prep-fwdbwd-k-4x8192x4x128": ("qk_prep_fwd", "qk_prep_bwd"),
     "mla_prep-fwdbwd-4x8192x32x128+64x128": ("mla_prep_fwd", "mla_prep_bwd"),
+    "gated_delta-fwdbwd-8x4096x16x32x128": ("gated_delta_fwd",),
     "lstm-fwdbwd-T200-H128-B32": ("lstm_scan_fwd", "lstm_scan_bwd"),
     "fused-adam-16384x1024": ("fused_adam",),
     "q_matmul-8x2048x2048": ("q_matmul",),
